@@ -13,6 +13,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # Two host workers share this machine, so the demo pins JAX to CPU (a
 # TPU chip is process-exclusive). On a real slice — one worker per host —
 # drop this pin and each worker initializes its own chips.
+# The head and every worker inherit the pin.
 os.environ.setdefault("RAY_TPU_JAX_PLATFORM", "cpu")
 
 import tempfile

@@ -11,6 +11,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # Env runners + learner are host processes sharing this machine: pin JAX
 # to CPU (on a TPU cluster the GSPMD MeshLearner owns the chips instead).
+# The head and every worker inherit the pin.
 os.environ.setdefault("RAY_TPU_JAX_PLATFORM", "cpu")
 
 import ray_tpu

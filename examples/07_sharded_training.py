@@ -16,6 +16,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+# CPU demo. The head and every worker inherit this pin: take the line out
+# (and ask for a TPU grant) to run on a chip.
 os.environ.setdefault("RAY_TPU_JAX_PLATFORM", "cpu")
 
 import jax

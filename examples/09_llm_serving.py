@@ -10,6 +10,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# CPU demo. The head and every worker inherit this pin: take the line out
+# (and ask for a TPU grant) to run on a chip.
 os.environ.setdefault("RAY_TPU_JAX_PLATFORM", "cpu")
 
 import asyncio

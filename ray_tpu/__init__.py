@@ -22,8 +22,8 @@ from ._private.jax_platform import install_hook as _install_jax_hook
 
 # Honor RAY_TPU_JAX_PLATFORM in THIS process too (workers already do via
 # worker_main): a driver that pins itself to CPU must not grab the
-# process-exclusive TPU chip — or block on a remote tunnel — just by
-# deserializing a jax array.
+# process-exclusive TPU chip just by deserializing a jax array. The same
+# call places the compile cache for this process and every child.
 _install_jax_hook()
 
 from ._private import worker as _worker_mod

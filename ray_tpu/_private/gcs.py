@@ -32,6 +32,7 @@ import time
 from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from ray_tpu.accelerators.tpu import worker_pool_key
 from ray_tpu.util import events as plane_events
 
 from . import failpoints, protocol
@@ -193,6 +194,7 @@ class TaskRecord:
             self.env_spec = spawn_spec_from_renv(renv)
             if self.env_spec is not None:
                 self.env_key = _ek(self.env_spec)
+        self.env_key = worker_pool_key(self.env_key, self.resources)
         strategy = self.strategy
         if isinstance(strategy, dict):
             strategy = tuple(sorted(strategy.items()))
@@ -274,6 +276,7 @@ class ActorRecord:
             self.env_spec = spawn_spec_from_renv(renv)
             if self.env_spec is not None:
                 self.env_key = _ek(self.env_spec)
+        self.env_key = worker_pool_key(self.env_key, self.resources)
         self.state = A_PENDING
         self.worker_id: Optional[WorkerID] = None
         self.addr: Optional[str] = None
@@ -411,7 +414,8 @@ class LeaseDemand:
         self.cancelled = False
         # Interpreter env pool this demand draws from ("" = base image);
         # reference analog: per-runtime-env worker pools, worker_pool.h:174.
-        self.env_key = msg.get("env_key", "")
+        self.env_key = worker_pool_key(msg.get("env_key", ""),
+                                       self.resources)
         self.env_spec = msg.get("renv_spawn")
         strategy = self.strategy
         if isinstance(strategy, dict):
@@ -3238,9 +3242,10 @@ class GcsServer:
         if node.agent_conn is None or node.agent_conn.closed:
             return
         spawn_msg: Dict[str, Any] = {"t": "spawn_worker"}
+        if env_key:
+            spawn_msg["env_key"] = env_key
         if env_spec is not None:
             spawn_msg["env_spec"] = env_spec
-            spawn_msg["env_key"] = env_key
         inflight_cap = _cfg().max_inflight_spawns
         while (node.spawning < min(demand, inflight_cap)
                and len(node.workers) + node.spawning < cap):
@@ -4317,7 +4322,8 @@ class GcsServer:
 
     async def _h_pg_list(self, client, msg):
         out = [{"pgid": p.pg_id.binary(), "state": p.state, "name": p.name,
-                "strategy": p.strategy, "bundles": p.bundles}
+                "strategy": p.strategy, "bundles": p.bundles,
+                "placement": [n.hex() if n else None for n in p.placement]}
                for p in self.pgs.values()]
         client.conn.reply(msg, {"ok": True, "pgs": out})
 

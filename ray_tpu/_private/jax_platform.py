@@ -1,17 +1,20 @@
-"""Per-process JAX platform pinning.
+"""Per-process JAX set-up: the platform pin and the compile cache.
 
 The TPU chip is a process-exclusive resource: only one process per host may
 own it (libtpu acquires it at backend init). The reference handles GPU
 visibility with ``CUDA_VISIBLE_DEVICES`` injection in the raylet worker pool
 (``python/ray/_private/accelerators``); the TPU analog is pinning the JAX
-platform per worker: workers without a TPU resource grant must run jax on
-CPU, the one TPU-granted worker gets the chip.
+platform per worker: the node agent starts every worker that serves no
+``TPU`` grant with ``RAY_TPU_JAX_PLATFORM=cpu`` (``node.worker_spawn_env``),
+and the one TPU-granted worker gets the chip.
 
-Some PJRT plugin environments (e.g. tunneled dev pods) override the
-``JAX_PLATFORMS`` env var at import time, so env vars alone are unreliable;
-this module installs a post-import hook that applies
-``jax.config.update("jax_platforms", ...)`` the moment jax is imported —
-paying zero cost in workers that never touch jax.
+``RAY_TPU_JAX_PLATFORM`` wins over an inherited ``JAX_PLATFORMS``: a
+post-import hook applies ``jax.config.update("jax_platforms", ...)`` the
+moment jax is imported — paying zero cost in workers that never touch jax.
+
+Every process of a session passes through :func:`install_hook` before it
+imports jax, so this is also the one place that decides where XLA's
+persistent compile cache lives (:func:`compile_cache_dir`).
 """
 
 from __future__ import annotations
@@ -20,8 +23,42 @@ import importlib.abc
 import importlib.util
 import os
 import sys
+from typing import Optional
 
 ENV_VAR = "RAY_TPU_JAX_PLATFORM"
+CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def compile_cache_dir() -> str:
+    """Where this session's processes keep compiled programs.
+
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else the
+    fixed ``<checkout>/.jax_cache``: the path is part of the cache key, so
+    it is never derived from a temporary name, a pid or the time."""
+    return os.environ.get(CACHE_ENV_VAR) or os.path.join(
+        _CHECKOUT, ".jax_cache")
+
+
+def _place_compile_cache():
+    """Export the cache placement so jax reads it at import, here and in
+    every child. The minimum compile time drops to zero (the minimum entry
+    size already is): the paged engine's page scatter and the optimizer's
+    small programs compile in well under jax's default one-second floor
+    and would otherwise be rebuilt by every process."""
+    os.environ.setdefault(CACHE_ENV_VAR, compile_cache_dir())
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    if "jax" in sys.modules:
+        # jax read its environment at import; a driver that imported it
+        # before ray_tpu gets the same placement through the config.
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir",
+                          os.environ[CACHE_ENV_VAR])
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs",
+            float(os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"]))
 
 
 def apply(platform: str | None = None):
@@ -80,10 +117,37 @@ class _JaxPostImportHook(importlib.abc.MetaPathFinder):
 
 
 def install_hook():
-    """Install the post-import hook if a platform override is requested."""
+    """Place the compile cache, and install the post-import hook if a
+    platform override is requested."""
+    _place_compile_cache()
     if not os.environ.get(ENV_VAR):
         return
     if "jax" in sys.modules:
         apply()
         return
     sys.meta_path.insert(0, _JaxPostImportHook())
+
+
+def device_report() -> Optional[dict]:
+    """The devices THIS process computes on, as jax reports them — or None
+    where it has initialised no backend (asking would initialise one, and
+    on a TPU host that takes the chip). What a replica's stats reply and a
+    train worker's first report carry, so a caller can tell a chip run
+    from a CPU run without touching jax itself."""
+    if "jax" not in sys.modules:
+        return None
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    devices = jax.local_devices()
+    stats = [d.memory_stats() or {} for d in devices]
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": jax.device_count(),
+        "pid": os.getpid(),
+        "bytes_in_use": [s.get("bytes_in_use") for s in stats],
+        "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
+    }

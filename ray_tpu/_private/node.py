@@ -37,7 +37,10 @@ _OBJ_REPORT_BATCH = 4096
 
 
 def default_session_root() -> str:
-    return os.environ.get("RAY_TPU_TMPDIR", "/tmp/ray_tpu")
+    import tempfile
+
+    return os.environ.get("RAY_TPU_TMPDIR") or os.path.join(
+        tempfile.gettempdir(), "ray_tpu")
 
 
 def get_node_ip_address() -> str:
@@ -95,7 +98,7 @@ def detect_node_resources(num_cpus: Optional[int] = None,
     out["object_store_memory"] = float(DEFAULT_STORE_CAPACITY)
     # Chip count requires an explicit signal (option, env override, or the
     # async libtpu probe) — pod-topology env vars alone aren't trusted
-    # because tunneled/dev hosts export stale topology. Once a count is
+    # because dev hosts export stale topology. Once a count is
     # known, the accelerator manager contributes the slice markers
     # (pod-type + head resource) for gang scheduling.
     if num_tpus is not None:
@@ -128,15 +131,39 @@ def detect_node_resources(num_cpus: Optional[int] = None,
     return out
 
 
+# Runs in a child of the agent (which never imports jax) and has exited
+# before its count is reported: the chip is free again by the time the
+# scheduler can grant it. It loads nothing where the session is pinned off
+# the TPU, since every worker would inherit that pin.
 _TPU_PROBE = """
-import os
-os.environ.pop("JAX_PLATFORMS", None)
-try:
-    import jax
-    print(len(jax.devices("tpu")))
-except Exception:
-    print(0)
+import jax
+print(len(jax.devices("tpu")))
 """
+
+
+def session_pinned_off_tpu() -> bool:
+    """Does the environment every process of this session inherits keep
+    jax off the TPU?"""
+    pin = (os.environ.get("RAY_TPU_JAX_PLATFORM")
+           or os.environ.get("JAX_PLATFORMS"))
+    return bool(pin) and "tpu" not in pin.split(",")
+
+
+def worker_spawn_env(env_key: str, node_id_hex: str) -> Dict[str, str]:
+    """What a worker of pool ``env_key`` gets on top of the agent's own
+    environment. A worker that serves no ``TPU`` grant is pinned to the
+    CPU here, before it can import jax: the chip belongs to the process
+    the scheduler granted it to."""
+    from ray_tpu.accelerators import get_accelerator_manager
+    from ray_tpu.accelerators.tpu import holds_tpu_grant
+
+    env = {"RAY_TPU_NODE_ID": node_id_hex}
+    if env_key:
+        env["RAY_TPU_ENV_KEY"] = env_key
+    if not holds_tpu_grant(env_key):
+        get_accelerator_manager("TPU").set_visible_accelerators(env, [])
+    return env
+
 
 _WORKER_BOOTSTRAP = (
     "import sys, os\n"
@@ -622,15 +649,25 @@ class NodeAgent:
             self.stopped.set()
 
     async def _probe_tpu(self):
-        try:
+        if session_pinned_off_tpu():
+            return
+        # One-shot at node start: opens the probe's log, writes nothing
+        # itself.  # raylint: disable=RTL006
+        with open(os.path.join(self.session_dir, "tpu_probe.out"),  # raylint: disable=RTL006
+                  "ab") as log:
             proc = await asyncio.create_subprocess_exec(
                 sys.executable, "-c", _TPU_PROBE,
-                stdout=asyncio.subprocess.PIPE,
-                stderr=asyncio.subprocess.DEVNULL)
-            out, _ = await asyncio.wait_for(proc.communicate(), timeout=120)
-            n = int(out.strip() or 0)
-        except Exception:
-            n = 0
+                stdout=asyncio.subprocess.PIPE, stderr=log)
+            try:
+                out, _ = await asyncio.wait_for(proc.communicate(),
+                                                timeout=120)
+            except asyncio.TimeoutError:
+                proc.kill()
+                await proc.wait()
+                out = b""
+        # No chip, no libtpu or a timeout all count zero chips; the
+        # child's own words are in tpu_probe.out.
+        n = int(out.strip() or 0) if proc.returncode == 0 else 0
         if n > 0 and self.conn and not self.conn.closed:
             # Probe confirmed real chips: attach slice markers for
             # gang scheduling (reference: tpu.py:71 pod-head resource).
@@ -660,7 +697,7 @@ class NodeAgent:
             threading.Thread(target=self._spawn_env_worker,
                              args=(env_spec, env_key), daemon=True).start()
             return
-        self._spawn(sys.executable, worker_sys_path(), "")
+        self._spawn(sys.executable, worker_sys_path(), env_key)
 
     def _spawn_env_worker(self, env_spec: dict, env_key: str):
         """Build (or reuse) the spec's venv — or wrap the spawn in a
@@ -810,7 +847,7 @@ class NodeAgent:
         for env_key in env_keys:
             req = {
                 "env": {**self.env_overrides,
-                        "RAY_TPU_NODE_ID": self.node_id.hex()},
+                        **worker_spawn_env(env_key, self.node_id.hex())},
                 "unset": [] if env_key else ["RAY_TPU_ENV_KEY"],
                 "gcs": self.gcs_address,
                 "node_id": self.node_id.hex(),
@@ -819,8 +856,6 @@ class NodeAgent:
                     self.session_dir,
                     f"worker-z{len(self.zygote_pids) + len(lines)}.out"),
             }
-            if env_key:
-                req["env"]["RAY_TPU_ENV_KEY"] = env_key
             lines.append(json.dumps(req) + "\n")
         try:
             z.stdin.write("".join(lines).encode())
@@ -906,12 +941,9 @@ class NodeAgent:
                     wrap=None):
         env = dict(os.environ)
         env.update(self.env_overrides)
-        env["RAY_TPU_NODE_ID"] = self.node_id.hex()
+        env.pop("RAY_TPU_ENV_KEY", None)
+        env.update(worker_spawn_env(env_key, self.node_id.hex()))
         env["RAY_TPU_SYS_PATH"] = sys_path
-        if env_key:
-            env["RAY_TPU_ENV_KEY"] = env_key
-        else:
-            env.pop("RAY_TPU_ENV_KEY", None)
         # ``-S`` skips site processing (~2s in large venvs); the bootstrap
         # restores the parent's sys.path so imports resolve identically.
         argv = [python, "-S", "-c", _WORKER_BOOTSTRAP,

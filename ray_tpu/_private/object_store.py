@@ -399,15 +399,6 @@ class PyShmStore:
             self._attached.clear()
 
 
-def _try_native_store(session_name: str, capacity: int, populate: int):
-    try:
-        from .shm_native import NativeStore
-
-        return NativeStore(session_name, capacity, populate=populate)
-    except Exception:
-        return None
-
-
 def make_store(session_name: str, capacity: int = 0, prefer_native: bool = True,
                populate: int = 0):
     """Create the host object store client for this process.
@@ -423,7 +414,10 @@ def make_store(session_name: str, capacity: int = 0, prefer_native: bool = True,
     # for real (reference: fake_multi_node provider testing, cluster_utils).
     session_name += os.environ.get("RAY_TPU_STORE_SUFFIX", "")
     if prefer_native and not os.environ.get("RAY_TPU_DISABLE_NATIVE_STORE"):
-        store = _try_native_store(session_name, capacity, populate)
-        if store is not None:
-            return store
+        # No quiet switch to the Python store: it cannot rescan the arena
+        # after a GCS restart, so a host that cannot build the native one
+        # says so (or asks for the Python store by name, above).
+        from .shm_native import NativeStore
+
+        return NativeStore(session_name, capacity, populate=populate)
     return PyShmStore(session_name)

@@ -1,9 +1,10 @@
 """ctypes binding for the C++ arena object store (``native/shm_store.cc``).
 
 Compiles the shared library on first use (g++ is part of the baked image;
-pybind11 is not, hence the plain C ABI + ctypes). The compiled .so is cached
-next to the source keyed by content hash, so rebuilds happen only when the
-C++ changes.
+pybind11 is not, hence the plain C ABI + ctypes) into ``native/_build/``,
+which git ignores: a library is always built by the host that loads it.
+Its name carries the source's content hash, so a rebuild happens only when
+the C++ changes. A missing compiler or a failed build raises.
 """
 
 from __future__ import annotations
@@ -35,21 +36,32 @@ def _build_lib() -> str:
     src = os.path.join(_NATIVE_DIR, "shm_store.cc")
     with open(src, "rb") as f:
         digest = hashlib.sha1(f.read()).hexdigest()[:12]
-    cache_dir = os.environ.get("RAY_TPU_NATIVE_CACHE",
-                               os.path.join(_NATIVE_DIR, "_build"))
-    os.makedirs(cache_dir, exist_ok=True)
-    out = os.path.join(cache_dir, f"libshm_store_{digest}.so")
-    if not os.path.exists(out):
-        tmp = out + f".tmp{os.getpid()}"
-        # One-shot native build at store bootstrap (cached .so after):
-        # runs before any plane serves traffic.  # raylint: disable=RTL101
-        subprocess.run(  # raylint: disable=RTL101
-            # -lrt: shm_open/shm_unlink live in librt before glibc 2.34
-            # (a no-op link on newer hosts where they merged into libc).
-            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp,
-             "-lpthread", "-lrt"],
-            check=True, capture_output=True)
-        os.replace(tmp, out)
+    build_dir = os.path.join(_NATIVE_DIR, "_build")
+    os.makedirs(build_dir, exist_ok=True)
+    out = os.path.join(build_dir, f"libshm_store_{digest}.so")
+    if os.path.exists(out):
+        return out
+    import fcntl
+
+    # A session's processes all reach this at once on a fresh checkout:
+    # one compiles, the rest wait on the lock and find the result.
+    with open(os.path.join(build_dir, ".lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not os.path.exists(out):
+            tmp = out + f".tmp{os.getpid()}"
+            # One-shot native build at store bootstrap (cached .so after):
+            # runs before any plane serves traffic.  # raylint: disable=RTL101
+            proc = subprocess.run(  # raylint: disable=RTL101
+                # -lrt: shm_open/shm_unlink live in librt before glibc 2.34
+                # (a no-op link on newer hosts where they merged into libc).
+                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o",
+                 tmp, "-lpthread", "-lrt"],
+                capture_output=True, text=True)
+            if proc.returncode:
+                raise RuntimeError(
+                    f"building {src} failed ({proc.returncode}):\n"
+                    f"{proc.stderr}")
+            os.replace(tmp, out)
     return out
 
 
@@ -57,26 +69,7 @@ def get_lib():
     global _lib
     with _lib_lock:
         if _lib is None:
-            try:
-                lib = ctypes.CDLL(_build_lib())
-            except OSError:
-                # The content-hash cache can hold a .so built on an
-                # INCOMPATIBLE host (e.g. a newer glibc than this
-                # container) — its presence blocks the rebuild, and
-                # every process then silently falls back to the Python
-                # shared_memory store, which cannot rescan the arena
-                # after a GCS restart. Rebuild from source into a
-                # host-local cache; exporting the env var points spawned
-                # workers/agents at the same rebuilt lib.
-                import tempfile
-
-                # uid-scoped: a shared world-writable dir could be
-                # pre-created/poisoned by another user (CDLL would load
-                # their .so) or be unwritable for us.
-                cache = os.path.join(tempfile.gettempdir(),
-                                     f"ray_tpu_native_cache_{os.getuid()}")
-                os.environ["RAY_TPU_NATIVE_CACHE"] = cache
-                lib = ctypes.CDLL(_build_lib())
+            lib = ctypes.CDLL(_build_lib())
             lib.rtpu_store_open.restype = ctypes.c_void_p
             lib.rtpu_store_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                             ctypes.c_int]

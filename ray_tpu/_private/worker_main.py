@@ -11,7 +11,10 @@ shared-memory store.
 
 Workers deliberately do NOT import jax/numpy at startup: heavyweight imports
 happen inside user functions, so per-task ``runtime_env['env_vars']`` (e.g.
-``JAX_PLATFORMS``) set before the import still takes effect.
+``JAX_PLATFORMS``) set before the import still takes effect. A pooled task
+worker that once imported jax keeps its backend for as long as it lives:
+harmless on the CPU, where every worker without a ``TPU`` grant is pinned
+(``node.worker_spawn_env``); a worker of the tpu pool exits after its task.
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import cloudpickle
 
+from ray_tpu.accelerators.tpu import holds_tpu_grant
 from ray_tpu.util import events as plane_events
 
 from . import failpoints, protocol, serialization
@@ -78,6 +82,8 @@ class Executor:
         self.running_tasks: Dict[bytes, int] = {}  # tid -> thread ident
         self.cancelled: set = set()
         self.die_after_task = False
+        self._in_tpu_pool = holds_tpu_grant(
+            os.environ.get("RAY_TPU_ENV_KEY", ""))
         self._server: Optional[asyncio.AbstractServer] = None
         self._direct_q: deque = deque()  # (conn, msg) leased exec pushes
         # Batched sync actor-call pump (see _drain_sync_calls).
@@ -631,7 +637,7 @@ class Executor:
                  "owner_wid": msg.get("owner")} for r in shm_rs]})
         if not conn.closed:
             conn.reply(msg, reply)
-        if self.die_after_task:
+        if self.die_after_task and not self.running_tasks:
             self.flush_events()
             loop = asyncio.get_running_loop()
             loop.call_later(0.01, os._exit, 0)
@@ -708,6 +714,12 @@ class Executor:
         from .runtime_context import _clear_execution, _set_execution
 
         _set_execution(task_id=bytes(tid), resources=opts.get("res"))
+        if self._in_tpu_pool:
+            # A task that held a TPU grant may have initialised the
+            # backend, and a process keeps the chip until it exits: a
+            # worker of the tpu pool serves one task, like an actor's
+            # worker serves one actor.
+            self.die_after_task = True  # raylint: disable=RTL151 (loop reads it only after the executor future resolves — happens-before)
         try:
             self._apply_runtime_env(opts)
             fn = self._get_function(msg["fid"])

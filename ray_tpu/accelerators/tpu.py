@@ -7,7 +7,12 @@ launcher exports them on real slices), the pod "head" host exports a
 ``TPU-<pod_type>-head`` marker resource so a multi-host slice can be
 gang-scheduled by claiming exactly one head, and per-worker chip pinning is
 ``TPU_VISIBLE_CHIPS`` plus a JAX platform pin (a chip is process-exclusive:
-an unpinned worker importing jax would steal it).
+an unpinned worker importing jax would steal it). The scheduler decides the
+owner: work that holds a ``TPU`` grant runs in workers of the ``tpu`` pool
+(:func:`worker_pool_key`), and the node agent pins every other worker to
+the CPU when it starts it (``node.worker_spawn_env``). One chip-holding
+process per host at a time is what this supports: a granted worker sees
+every chip of its host, because nothing sets per-process chip bounds.
 
 Topology math: a pod type ``v5p-128`` names 128 *cores*; v2–v4 and v5p have
 2 cores/chip, v5e and v6e 1 core/chip; hosts hold 4 chips (8 for v5p).
@@ -34,6 +39,21 @@ CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"  # e.g. "2,2,1"
 TOPOLOGY_ENV = "TPU_TOPOLOGY"                       # e.g. "4x4x8"
 VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 NUM_CHIPS_OVERRIDE_ENV = "RAY_TPU_CHIPS"            # explicit override
+
+#: Worker-pool key (``RAY_TPU_ENV_KEY``) of workers that may own the chip.
+TPU_POOL = "tpu"
+
+
+def worker_pool_key(env_key: str, resources: Optional[Dict[str, float]]) -> str:
+    """The worker pool a task, actor or lease draws from: work that holds
+    a ``TPU`` grant never shares a process with work that does not."""
+    if (resources or {}).get("TPU", 0) <= 0:
+        return env_key
+    return f"{TPU_POOL}+{env_key}" if env_key else TPU_POOL
+
+
+def holds_tpu_grant(pool_key: str) -> bool:
+    return pool_key == TPU_POOL or pool_key.startswith(TPU_POOL + "+")
 
 
 def _generation(pod_type: str) -> Optional[str]:

@@ -21,7 +21,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import dense_attention, flash_attention
+from ..ops.attention import dense_attention
 from ..ops.layers import rms_norm
 
 
@@ -131,9 +131,9 @@ def encode(params: Dict[str, Any], images: jax.Array,
     heads (the RL pixel policy/value module, ``rl/rl_module.py``) ride
     the same patch-embed + transformer path."""
     if attn_impl is None:
-        # flash_attention owns the platform/shape fallback internally
-        # (ops/attention.py:145); same convention as llama.py.
-        attn_impl = flash_attention
+        # 1 + n_patches tokens never tile the TPU flash kernel's 128-row
+        # blocks, and at these lengths dense attention is the right cost.
+        attn_impl = dense_attention
     patches = patchify(images.astype(cfg.dtype), cfg)
     x = patches @ params["patch_embed"]["w"] + params["patch_embed"]["b"]
     B = x.shape[0]

@@ -134,48 +134,66 @@ def _flash_attention_bhld(q, k, v, causal, scale, block_q, block_k,
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
                     scale: Optional[float] = None,
-                    segment_ids: Optional[jax.Array] = None,
-                    block_q: int = 512, block_k: int = 512,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    segment_ids: Optional[jax.Array] = None) -> jax.Array:
     """Flash attention, [B, L, H, D] layout, GQA-aware, differentiable.
 
-    On TPU this dispatches to the Mosaic flash kernel (fwd + bwd, so it is
-    safe under ``jax.grad``); elsewhere, or when shapes don't tile, it falls
-    back to ``dense_attention``.
+    The platform alone picks the path. On TPU this is the Mosaic flash
+    kernel (fwd + bwd, so it is safe under ``jax.grad``), and a shape the
+    kernel does not take raises: a caller that wants dense attention there
+    calls ``dense_attention``. Everywhere else it is ``dense_attention``.
     """
     B, L, H, D = q.shape
     if scale is None:
         scale = D ** -0.5
-    if _on_tpu() and segment_ids is None and L % 128 == 0 and D >= 64:
-        try:
-            return _tpu_flash(q, k, v, causal, scale)
-        except Exception:
-            pass
-    return dense_attention(q, k, v, causal=causal, scale=scale,
-                           segment_ids=segment_ids)
+    if not _on_tpu():
+        return dense_attention(q, k, v, causal=causal, scale=scale,
+                               segment_ids=segment_ids)
+    if segment_ids is not None or L % 128 or D < 64:
+        raise ValueError(
+            f"flash_attention on TPU takes L % 128 == 0, head_dim >= 64 "
+            f"and no segment_ids; got q{tuple(q.shape)} k{tuple(k.shape)} "
+            f"segment_ids={'set' if segment_ids is not None else None} "
+            f"(use dense_attention for this shape)")
+    return _tpu_flash(q, k, v, causal, scale)
+
+
+def make_flash_attention(mesh):
+    """``flash_attention`` for arrays sharded over ``mesh``.
+
+    XLA cannot partition a Mosaic kernel ("wrap the call in a shard_map"),
+    so a jitted step whose q/k/v are sharded refuses to lower on TPU with
+    the bare function. This wraps it: each device runs the kernel on its
+    own batch rows (split as ``parallel.mesh.batch_sharding`` splits a
+    batch) and heads (over ``tp``; GQA groups stay aligned because q and
+    kv heads split the same way). The sequence stays whole — shard that
+    with ``parallel.ring_attention``. Pass the result as a model's
+    ``attn_impl``."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(("dp", "fsdp", "ep"), None, "tp", None)
+
+    def attn(q, k, v, causal: bool = False, scale: Optional[float] = None):
+        fn = functools.partial(flash_attention, causal=causal, scale=scale)
+        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+    return attn
 
 
 #: On-chip autotuned (block_q, block_k_major, block_k) per sequence length,
-#: loaded once from records/flash_autotune.json (written + committed by
-#: benchmarks/tpu_kernels.py during a TPU window). Mosaic's own defaults are
+#: loaded once from records/flash_autotune.json (written by
+#: benchmarks/tpu_kernels.py on a chip). Mosaic's own defaults are
 #: 128/128/128 at every size — conservative for v5e, where larger q/k blocks
 #: amortize the softmax rescale and keep the MXU busy; the sweep picks per-L
 #: winners empirically.
 _AUTOTUNE_CACHE: Optional[dict] = None
-#: Diagnostics: the fwd block config the last _tpu_flash dispatch actually
-#: used — "(bq, bkm, bk)" or "mosaic-defaults" after a tiling-rejection
-#: fallback. Smoke records print this so they cannot misreport the chooser
-#: output as the executed config.
-_LAST_FLASH_BLOCKS: Any = None
 import os as _os
 _AUTOTUNE_PATH = _os.path.join(_os.path.dirname(_os.path.dirname(
     _os.path.dirname(_os.path.abspath(__file__)))),
@@ -197,8 +215,8 @@ def _autotune_table() -> dict:
                     table[int(row["seq"])] = (int(row["block_q"]),
                                               int(row["block_k_major"]),
                                               int(row["block_k"]))
-        except Exception:
-            pass
+        except FileNotFoundError:
+            pass  # no on-chip sweep recorded: the heuristic applies
         _AUTOTUNE_CACHE = table
     return _AUTOTUNE_CACHE
 
@@ -242,19 +260,8 @@ def _tpu_flash(q, k, v, causal: bool, scale: float) -> jax.Array:
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    global _LAST_FLASH_BLOCKS
-    try:
-        bs = flash_block_sizes(L, D)
-        ot = mosaic_flash(qt, kt, vt, causal=causal, sm_scale=scale,
-                          block_sizes=bs)
-        _LAST_FLASH_BLOCKS = (bs.block_q, bs.block_k_major, bs.block_k)
-    except Exception:
-        # Trace-time tiling rejection — Mosaic defaults. (Compile-time
-        # failures under an outer jit are prevented structurally instead:
-        # flash_block_sizes only returns divisibility-checked fwd blocks
-        # and conservative 128 bwd blocks.)
-        ot = mosaic_flash(qt, kt, vt, causal=causal, sm_scale=scale)
-        _LAST_FLASH_BLOCKS = "mosaic-defaults"
+    ot = mosaic_flash(qt, kt, vt, causal=causal, sm_scale=scale,
+                      block_sizes=flash_block_sizes(L, D))
     return ot.transpose(0, 2, 1, 3)
 
 

@@ -21,8 +21,7 @@ from typing import Any, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ray_tpu.parallel._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 AxisName = Union[str, Sequence[str]]
 

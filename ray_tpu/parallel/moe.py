@@ -28,9 +28,8 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ray_tpu.parallel._compat import axis_size as _axis_size, shard_map
+from jax import lax, shard_map
+from jax.lax import axis_size as _axis_size
 from jax.sharding import PartitionSpec as P
 
 

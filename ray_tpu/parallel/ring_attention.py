@@ -25,8 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ray_tpu.parallel._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 NEG_INF = -1e30
 
@@ -334,7 +333,7 @@ def make_ring_attention(mesh, *, causal: bool = True, axis: str = "sp",
     """
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     spec = P(batch_axes, axis, head_axis, None)
     fn = functools.partial(ring_attention, axis=axis, causal=causal,

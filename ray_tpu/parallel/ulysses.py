@@ -23,8 +23,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ray_tpu.parallel._compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 # Indirection point: the byte-count assertion test (CPU interpreter
 # path) wraps this to account per-shard all-to-all bytes without
@@ -82,10 +81,10 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kh = jnp.repeat(kh, rep, axis=2)
         vh = jnp.repeat(vh, rep, axis=2)
     if attn_fn is None:
-        # flash_attention == the Mosaic kernel (differentiable) on TPU
-        # when the full-seq shard tiles, dense otherwise — after the
-        # all-to-all each device holds the FULL sequence for its head
-        # subset, which is exactly the single-chip flash shape.
+        # flash_attention == the Mosaic kernel (differentiable) on TPU,
+        # dense elsewhere — after the all-to-all each device holds the
+        # FULL sequence for its head subset, which is exactly the
+        # single-chip flash shape.
         from ..ops.attention import flash_attention
 
         out = flash_attention(qh, kh, vh, causal=causal, scale=scale)

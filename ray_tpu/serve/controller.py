@@ -44,6 +44,12 @@ class ServeController:
 
         # app -> dep name -> {"deployment": blob..., "replicas": [handles]}
         self.apps: Dict[str, Dict[str, dict]] = {}
+        # replica actor id -> its first health_check, sent at spawn. An
+        # actor answers nothing until its constructor returns, and an LLM
+        # replica's builds weights for minutes: until that first call
+        # resolves the replica is STARTING, and the health loop waits on
+        # it instead of timing a fresh probe and spawning a rival.
+        self._starting: Dict[Any, Any] = {}
         # The reconciliation loop (reference: DeploymentState health loop,
         # deployment_state.py:1245) — replaces dead replicas on a period.
         self._stop_health = threading.Event()
@@ -66,6 +72,18 @@ class ServeController:
             except Exception:
                 pass  # transient cluster churn; next period retries
 
+    def _start_replica(self, app_name: str, spec: dict):
+        replica = _spawn_replica(app_name, spec)
+        self._starting[replica._id] = replica.health_check.remote()
+        return replica
+
+    def _kill_replica(self, replica):
+        self._starting.pop(replica._id, None)
+        try:
+            ray_tpu.kill(replica)
+        except Exception:
+            pass  # already dead
+
     def deploy(self, app_name: str, deployments: List[dict]):
         """deployments: [{name, blob, init_args, init_kwargs, is_class,
         num_replicas, actor_options, user_config}]"""
@@ -76,13 +94,10 @@ class ServeController:
             current = app.get(spec["name"])
             if current is not None:
                 for r in current["replicas"]:
-                    try:
-                        ray_tpu.kill(r)
-                    except Exception:
-                        pass
+                    self._kill_replica(r)
             replicas = []
             for i in range(spec["num_replicas"]):
-                replicas.append(_spawn_replica(app_name, spec))
+                replicas.append(self._start_replica(app_name, spec))
             if spec.get("user_config") is not None:
                 ray_tpu.get([r.reconfigure.remote(spec["user_config"])
                              for r in replicas])
@@ -125,10 +140,7 @@ class ServeController:
         deps = self.apps.pop(app_name, {})
         for dep in deps.values():
             for r in dep["replicas"]:
-                try:
-                    ray_tpu.kill(r)
-                except Exception:
-                    pass
+                self._kill_replica(r)
         self._notify(app_name)
         return True
 
@@ -143,14 +155,11 @@ class ServeController:
         cur = dep["replicas"]
         if num_replicas > len(cur):
             for _ in range(num_replicas - len(cur)):
-                cur.append(_spawn_replica(app_name, spec))
+                cur.append(self._start_replica(app_name, spec))
             ray_tpu.get([r.health_check.remote() for r in cur])
         elif num_replicas < len(cur):
             for r in cur[num_replicas:]:
-                try:
-                    ray_tpu.kill(r)
-                except Exception:
-                    pass
+                self._kill_replica(r)
             dep["replicas"] = cur[:num_replicas]
         self._notify(app_name, deployment_name)
         return True
@@ -186,7 +195,7 @@ class ServeController:
                 if not doomed:
                     continue
                 spec = dep["spec"]
-                fresh = [_spawn_replica(app_name, spec) for _ in doomed]
+                fresh = [self._start_replica(app_name, spec) for _ in doomed]
                 if spec.get("user_config") is not None:
                     # fan out, then collect: one straggler must not
                     # serialize the whole batch (ray_tpu check RTL002)
@@ -205,20 +214,14 @@ class ServeController:
                     # the old replicas serving until the next round — a
                     # draining node still works until its deadline.
                     for r in fresh:
-                        try:
-                            ray_tpu.kill(r)
-                        except Exception:
-                            pass
+                        self._kill_replica(r)
                     continue
                 dep["replicas"] = [r for r in dep["replicas"]
                                    if r not in doomed] + fresh
                 moved += len(doomed)
                 self._notify(app_name, spec["name"])
                 for r in doomed:
-                    try:
-                        ray_tpu.kill(r)
-                    except Exception:
-                        pass
+                    self._kill_replica(r)
         return moved
 
     def check_health(self):
@@ -231,9 +234,15 @@ class ServeController:
                 alive = []
                 # all probes in flight at once: N replicas cost one
                 # 5s timeout worst-case, not N (ray_tpu check RTL002)
-                probes = [(r, r.health_check.remote())
+                probes = [(r, self._starting.get(r._id)
+                           or r.health_check.remote())
                           for r in dep["replicas"]]
                 for r, ref in probes:
+                    if r._id in self._starting:
+                        if not ray_tpu.wait([ref], timeout=0)[0]:
+                            alive.append(r)  # constructor still running
+                            continue
+                        del self._starting[r._id]
                     try:
                         ray_tpu.get(ref, timeout=5)
                         alive.append(r)
@@ -241,7 +250,7 @@ class ServeController:
                         replaced += 1
                 spec = dep["spec"]
                 while len(alive) < spec["num_replicas"]:
-                    alive.append(_spawn_replica(app_name, spec))
+                    alive.append(self._start_replica(app_name, spec))
                 dep["replicas"] = alive
         if replaced:
             for app_name in self.apps:

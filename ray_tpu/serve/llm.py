@@ -238,8 +238,13 @@ class LLMServer:
     def _admin(self, body: dict):
         op = body["_admin"]
         if op == "stats":
+            from ray_tpu._private.jax_platform import device_report
+
             drafted = max(self._spec_drafted, 1)
             return {
+                # platform, device_kind, device_count, pid, bytes in use
+                # of the process that holds the model
+                "device": device_report(),
                 "weights_version": self._weights_version,
                 "active_requests": len(self._queues),
                 "spec_requests": self._spec_requests,
@@ -353,8 +358,19 @@ def build_llm_app(model_factory, *, max_slots: int = 4,
     "paged"`` swaps in the shared-page-pool engine (models/paged.py).
     ``draft_factory=(params, cfg) -> (draft_params, draft_cfg)`` enables
     the speculative request path (e.g. ``lambda p, c:
-    truncated_draft(p, c, n_layers)``)."""
-    dep = _deployment(LLMServer, num_replicas=num_replicas)
+    truncated_draft(p, c, n_layers)``).
+
+    On a cluster that reports ``TPU`` chips each replica asks for one: the
+    scheduler then starts it in a worker that may own the chip (every
+    other worker is pinned to the CPU) and never beside another chip
+    holder. Chips appear in ``cluster_resources()`` once the node's probe
+    has run, so build the app after that."""
+    import ray_tpu
+
+    on_tpu = (ray_tpu.is_initialized()
+              and ray_tpu.cluster_resources().get("TPU", 0) >= 1)
+    dep = _deployment(LLMServer, num_replicas=num_replicas,
+                      ray_actor_options={"num_tpus": 1} if on_tpu else None)
     return dep.bind(model_factory, max_slots=max_slots, max_len=max_len,
                     kv_cache=kv_cache, num_pages=num_pages,
                     page_size=page_size,

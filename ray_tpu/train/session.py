@@ -107,12 +107,21 @@ class TrainSession:
                     shutil.rmtree(dest)
                 shutil.copytree(checkpoint.path, dest)
             ckpt_path = dest
+        metrics = dict(metrics)
+        if self.iteration == 0:
+            # The first report names the devices this worker computes on
+            # (None if it never initialised a jax backend).
+            from ray_tpu._private.jax_platform import device_report
+
+            device = device_report()
+            if device is not None:
+                metrics.setdefault("device", device)
         self.iteration += 1
         if self.result_actor is not None:
             import ray_tpu
 
             reply = ray_tpu.get(self.result_actor.push.remote(
-                self.world_rank, dict(metrics), ckpt_path))
+                self.world_rank, metrics, ckpt_path))
             rescale_to = (reply.get("rescale_to")
                           if isinstance(reply, dict) else None)
             if rescale_to and rescale_to != self.world_size:

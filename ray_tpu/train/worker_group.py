@@ -277,6 +277,8 @@ class WorkerGroup:
             raise WorkerGroupFormationError(
                 f"could not reserve {num_workers} x {resources_per_worker} "
                 f"(cluster resources: {ray_tpu.cluster_resources()})")
+        if resources_per_worker.get("TPU", 0) > 0 and num_workers > 1:
+            self._refuse_shared_tpu_host(resources_per_worker)
         env_per_worker = env_per_worker or [{} for _ in range(num_workers)]
         self.workers = []
         # Everything past the reservation must not leak on failure: a
@@ -308,6 +310,23 @@ class WorkerGroup:
                 f"worker group formation failed for {num_workers} x "
                 f"{resources_per_worker}: {e}") from e
         self._start_gang_watcher()
+
+    def _refuse_shared_tpu_host(self, resources_per_worker):
+        """One chip-holding process per host: a TPU worker sees every chip
+        of its host (nothing sets per-process chip bounds), so two on one
+        host would fight over them inside ``jax.distributed.initialize``.
+        Say so at formation instead."""
+        from ray_tpu.util.placement_group import placement_group_table
+
+        placement = placement_group_table()[self.pg.id.hex()]["placement"]
+        if len(set(placement)) < len(placement):
+            remove_placement_group(self.pg)
+            raise WorkerGroupFormationError(
+                f"{self.num_workers} x {resources_per_worker} placed "
+                f"{len(placement) - len(set(placement))} TPU worker(s) on "
+                f"a host that already holds one; one chip-holding process "
+                f"per host is supported: use one worker per host with "
+                f"chips_per_worker = the host's chips")
 
     # ---------------------------------------------------- gang fault plane
 

@@ -106,6 +106,8 @@ def placement_group_table() -> Dict[str, dict]:
         p["pgid"].hex(): {
             "state": p["state"], "name": p["name"],
             "strategy": p["strategy"], "bundles": p["bundles"],
+            # node id (hex) each bundle is reserved on, None if pending
+            "placement": p.get("placement") or [],
         }
         for p in reply.get("pgs", [])
     }

@@ -184,3 +184,20 @@ def test_actor_ctor_args_released_on_death(ray_cluster):
             break
         time.sleep(0.25)
     assert not pinned, f"ctor arg bundle leaked past actor death: {pinned}"
+
+
+def test_native_store_failure_is_an_error_not_a_quiet_switch(monkeypatch):
+    """A host that cannot build native/shm_store.cc says so; the Python
+    store is something one asks for by name."""
+    from ray_tpu._private import object_store, shm_native
+
+    def no_compiler():
+        raise RuntimeError("building shm_store.cc failed: g++ not found")
+
+    monkeypatch.setattr(shm_native, "_lib", None)
+    monkeypatch.setattr(shm_native, "_build_lib", no_compiler)
+    with pytest.raises(RuntimeError, match="g\\+\\+ not found"):
+        object_store.make_store("session_nobuild")
+    monkeypatch.setenv("RAY_TPU_DISABLE_NATIVE_STORE", "1")
+    store = object_store.make_store("session_nobuild")
+    assert type(store).__name__ == "PyShmStore"

@@ -95,3 +95,53 @@ def test_block_chooser_rejects_nondividing_record(tmp_path, monkeypatch):
     monkeypatch.setattr(attn_mod, "_AUTOTUNE_CACHE", None)
     bs = flash_block_sizes(1536, head_dim=128)
     assert (bs.block_q, bs.block_k_major, bs.block_k) == (512,) * 3
+
+
+# ------------------------------------------------ dispatch hides nothing
+
+def test_flash_attention_on_tpu_raises_for_a_shape_the_kernel_refuses(
+        monkeypatch):
+    """On a TPU the kernel runs or the call raises with the shape; dense
+    attention there is something a caller asks for by name."""
+    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+    q = jnp.zeros((1, 100, 4, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match=r"\(1, 100, 4, 64\)"):
+        attn_mod.flash_attention(q, q, q, causal=True)
+    q = jnp.zeros((1, 128, 4, 32), jnp.bfloat16)
+    with pytest.raises(ValueError, match="head_dim >= 64"):
+        attn_mod.flash_attention(q, q, q)
+    q = jnp.zeros((1, 128, 4, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="segment_ids=set"):
+        attn_mod.flash_attention(q, q, q,
+                                 segment_ids=jnp.zeros((1, 128), jnp.int32))
+
+
+def test_on_tpu_does_not_swallow_backend_errors(monkeypatch):
+    def boom():
+        raise RuntimeError("backend setup failed")
+
+    monkeypatch.setattr(attn_mod.jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="backend setup failed"):
+        attn_mod._on_tpu()
+
+
+def test_make_flash_attention_matches_dense_under_a_mesh():
+    """The shard_map wrapper a sharded step needs on TPU (XLA cannot
+    partition a Mosaic kernel): batch rows over fsdp, heads over tp, GQA
+    groups aligned. Off the TPU each shard takes the dense path."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    mesh = make_mesh(MeshSpec(fsdp=2, tp=2), jax.devices()[:4])
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(keys[0], (4, 128, 8, 64), jnp.float32)
+    k = jax.random.normal(keys[1], (4, 128, 4, 64), jnp.float32)
+    v = jax.random.normal(keys[2], (4, 128, 4, 64), jnp.float32)
+    sh = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+    attn = attn_mod.make_flash_attention(mesh)
+    got = jax.jit(lambda q, k, v: attn(q, k, v, causal=True))(
+        *(jax.device_put(x, sh) for x in (q, k, v)))
+    want = attn_mod.dense_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

@@ -7,7 +7,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention import dense_attention
-from ray_tpu.parallel._compat import shard_map
+from jax import shard_map
 from ray_tpu.parallel import (
     MeshSpec,
     collectives,

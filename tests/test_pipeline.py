@@ -7,7 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import LlamaConfig, init_params, loss_fn
-from ray_tpu.parallel._compat import shard_map
+from jax import shard_map
 from ray_tpu.parallel import (
     MeshSpec,
     make_mesh,

@@ -224,8 +224,8 @@ def test_detect_node_resources_includes_tpu(monkeypatch):
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5e-16")
     monkeypatch.setenv("TPU_WORKER_ID", "0")
     monkeypatch.delenv("TPU_CHIPS_PER_HOST_BOUNDS", raising=False)
-    # Topology env alone must NOT register chips (tunneled dev hosts export
-    # stale topology); an explicit count signal is required.
+    # Topology env alone must NOT register chips (dev hosts export stale
+    # topology); an explicit count signal is required.
     monkeypatch.delenv("RAY_TPU_CHIPS", raising=False)
     res = detect_node_resources(num_cpus=2)
     assert "TPU" not in res

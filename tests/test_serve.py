@@ -234,3 +234,35 @@ def test_handle_retries_on_dead_replica(serve_cluster):
     rt.kill(replicas[0])
     results = [h.remote(None).result(timeout=30) for _ in range(10)]
     assert all(isinstance(r, int) for r in results)
+
+
+def test_health_loop_waits_for_a_replica_still_in_its_constructor(
+        serve_cluster, tmp_path):
+    """A replica answers nothing until its constructor returns (an LLM
+    replica's builds weights for minutes). The health loop must treat it
+    as STARTING — not time a 5 s probe, drop it and spawn a rival that
+    then wants the same chip. The constructor here outlasts one health
+    period (10 s) plus the probe's timeout (5 s)."""
+    marker = tmp_path / "constructed"
+
+    @serve.deployment
+    class SlowStart:
+        def __init__(self, marker):
+            import os
+            import time
+
+            with open(marker, "a") as f:
+                f.write(f"{os.getpid()}\n")
+            time.sleep(16)
+
+        def __call__(self, _):
+            import os
+
+            return os.getpid()
+
+    handle = serve.run(SlowStart.bind(str(marker)), name="slow-app",
+                       route_prefix=None)
+    pid = handle.remote(None).result(timeout=30)
+    time.sleep(1.0)   # a rival's constructor would have signed in by now
+    assert marker.read_text().split() == [str(pid)]   # one constructor ran
+    serve.delete("slow-app")

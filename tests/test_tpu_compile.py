@@ -1,0 +1,139 @@
+"""AOT compiles for a described TPU v5e, no chip attached.
+
+The TPU compiler is installed wherever jax[tpu] is: it compiles for a
+``v5e:2x2`` topology that is described, not present, and refuses what the
+chip's compiler would refuse (untileable blocks, too much VMEM, a program
+that does not fit 16 GB). Nothing runs, so these say nothing about results
+or speed — ``chip_smoke.py`` is the run. They keep the main paths' kernels
+and step programs compiling at real widths between chip runs.
+
+The topology is described inside a fixture, never at import: only one
+process may load libtpu, and every xdist worker imports this file.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models.llama import LLAMA3_1B, init_params, loss_fn
+from ray_tpu.ops import attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # An entry compiled for a described device cannot be read back without
+    # one; keep these out of the persistent cache.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(sharding, tree):
+    """Abstract twin of ``tree`` placed on the described chip."""
+    return jax.tree.map(lambda a: _shape(sharding, a.shape, a.dtype), tree)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [(32, 8, 64),
+                                                     (16, 8, 128)])
+def test_mosaic_flash_fwd_bwd_L2048(one_chip, heads, kv_heads, head_dim):
+    """The library kernel through ``_tpu_flash`` with the blocks
+    ``flash_block_sizes`` returns: forward blocks from the autotune table
+    or the 512 heuristic, backward blocks pinned at 128."""
+    L = 2048
+    scale = head_dim ** -0.5
+
+    def loss(q, k, v):
+        return attention._tpu_flash(q, k, v, True, scale).astype(
+            jnp.float32).sum()
+
+    q = _shape(one_chip, (2, L, heads, head_dim))
+    kv = _shape(one_chip, (2, L, kv_heads, head_dim))
+    _assert_kernel(jax.jit(loss).lower(q, kv, kv).compile())
+    _assert_kernel(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile())
+
+
+@pytest.mark.parametrize("L", [2048, 8192])
+def test_flash_attention_stats(one_chip, L):
+    """The ring-attention block kernel, compiled (not interpreted)."""
+    B, H, Hk, D = 2, 16, 8, 128
+    fn = functools.partial(attention.flash_attention_stats,
+                           interpret=False)
+    compiled = jax.jit(fn).lower(
+        _shape(one_chip, (B, L, H, D)), _shape(one_chip, (B, L, Hk, D)),
+        _shape(one_chip, (B, L, Hk, D)),
+        _shape(one_chip, (B, H, L), jnp.int32)).compile()
+    _assert_kernel(compiled)
+
+
+def test_paged_step_llama3_1b_widths(one_chip):
+    """The serving decode step: 8 slots over 2048 pages of 16, depth 2."""
+    from ray_tpu.models.paged import _paged_step
+    from ray_tpu.ops.layers import rope_frequencies
+
+    cfg = dataclasses.replace(LLAMA3_1B, n_layers=2)
+    S, pages, page, max_len = 8, 2048, 16, 2048
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = _shape(one_chip, (pages, page, cfg.n_kv_heads, cfg.head_dim))
+    cos, sin = _on(one_chip, jax.eval_shape(
+        lambda: rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)))
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = _paged_step.lower(
+        params, [pool] * cfg.n_layers, [pool] * cfg.n_layers,
+        [0] * cfg.n_layers, [0] * cfg.n_layers,
+        i32((S, max_len // page)), i32((S,)), i32((S,)), f32((S,)),
+        i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
+        cfg=cfg, cos=cos, sin=sin, page=page, kv_int8=False).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_train_step_llama3_1b_widths_takes_flash(one_chip, monkeypatch):
+    """One AdamW step at 2x2048, depth 2. ``flash_attention`` asks jax for
+    the platform, which here is the CPU: the test answers for the chip the
+    program is compiled for, and the kernel must then be in the step."""
+    import optax
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(LLAMA3_1B, n_layers=2)
+    opt = optax.adamw(3e-4, weight_decay=0.1)
+
+    def step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(p, {"tokens": tokens}, cfg))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        _on(one_chip, params), _on(one_chip, opt_state),
+        _shape(one_chip, (2, 2048), jnp.int32)).compile()
+    _assert_kernel(compiled)
