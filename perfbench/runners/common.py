@@ -1,0 +1,150 @@
+"""What both runners share in the driver process, which stays off jax."""
+
+from __future__ import annotations
+
+import os
+import sys
+import time
+
+
+def say(msg: str):
+    print(f"[perfbench {time.strftime('%H:%M:%S')}] {msg}", flush=True)
+
+
+class NoChip(SystemExit):
+    """The measuring path found no accelerator: exit non-zero, no result."""
+
+    def __init__(self, why: str):
+        print(f"perfbench: refusing to measure: {why}", file=sys.stderr,
+              flush=True)
+        super().__init__(3)
+
+
+#: room for every program of a cell: the four-chip step program alone is
+#: 172 MB, its sharded initialiser 25 MB
+CACHE_ROOM_BYTES = 4 << 30
+
+
+def make_room_in_compile_cache():
+    """A machine may cap jax's persistent compile cache
+    (``JAX_COMPILATION_CACHE_MAX_SIZE``; 192 MiB on the chip machines). Under
+    a cap smaller than one cell's programs every run evicts what the next
+    one needs, and every run compiles (measured: the four-chip cell set up in
+    370 s warm and cold alike). The benchmark's processes ask for room; where
+    the cache lives stays the program's choice
+    (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``)."""
+    cap = os.environ.get("JAX_COMPILATION_CACHE_MAX_SIZE")
+    if cap is not None and 0 <= int(cap) < CACHE_ROOM_BYTES:
+        os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = str(CACHE_ROOM_BYTES)
+
+
+def start_cluster(chips: int, rehearse: bool):
+    """``ray_tpu.init`` and, on the chip, the node's probe. Rehearsing, the
+    chips are declared (there are none to probe) and the workers inherit
+    the platform this shell is held to."""
+    import ray_tpu
+    from ray_tpu._private.node import session_pinned_off_tpu
+
+    make_room_in_compile_cache()   # before anything is spawned: inherited
+    if rehearse:
+        ray_tpu.init(num_tpus=chips)
+        return
+    if session_pinned_off_tpu():
+        raise NoChip("this environment pins the session off the TPU "
+                     f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}, "
+                     f"RAY_TPU_JAX_PLATFORM="
+                     f"{os.environ.get('RAY_TPU_JAX_PLATFORM')!r}); "
+                     "--rehearse runs the paths on the CPU")
+    ray_tpu.init()
+    t0 = time.perf_counter()
+    found = 0
+    while time.perf_counter() - t0 < 180:
+        found = ray_tpu.cluster_resources().get("TPU", 0)
+        if found >= 1:
+            break
+        time.sleep(0.25)
+    say(f"chip probe: TPU={found} after {time.perf_counter() - t0:.1f}s")
+    if found != chips:
+        ray_tpu.shutdown()
+        raise NoChip(f"the cell needs {chips} chip(s), the node reports "
+                     f"{found}")
+
+
+def _parent_if_alive(pid):
+    """The parent's pid, or None where ``pid`` has ended (a zombie has, but
+    for the reaping)."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            state, ppid = f.read().rsplit(")", 1)[1].split()[:2]
+    except (OSError, IndexError, ValueError):
+        return None   # exited while we looked
+    return None if state == "Z" else int(ppid)
+
+
+def _alive_descendants() -> set:
+    parent = {int(pid): _parent_if_alive(pid)
+              for pid in filter(str.isdigit, os.listdir("/proc"))}
+    tree, grew = {os.getpid()}, True
+    while grew:
+        more = {p for p, pp in parent.items() if pp in tree} - tree
+        tree |= more
+        grew = bool(more)
+    return tree - {os.getpid()}
+
+
+def stop_cluster(with_serve: bool = False, limit_s: float = 90.0):
+    """Shut the session down and WAIT until every process it started has
+    ended (a replica holding gigabytes on the chip takes seconds to exit):
+    a run leaves nothing behind."""
+    import signal
+
+    import ray_tpu
+
+    started = _alive_descendants()
+    try:
+        if with_serve:
+            from ray_tpu import serve
+
+            serve.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    t0 = time.perf_counter()
+    left = started
+    while left and time.perf_counter() - t0 < limit_s:
+        time.sleep(0.1)
+        left = {pid for pid in left if _parent_if_alive(pid) is not None}
+    for pid in left:
+        say(f"process {pid} outlived the shutdown by {limit_s:.0f}s: killed")
+        try:
+            os.kill(pid, signal.SIGKILL)
+        except OSError:
+            pass
+    say(f"every process of the session has ended "
+        f"({time.perf_counter() - t0:.1f}s after the shutdown)")
+
+
+def trace_sample_path():
+    """Where a traced run leaves a small piece of its trace (the recorded
+    samples beside the tests were made so); unset in every driver run."""
+    p = os.environ.get("PERFBENCH_TRACE_SAMPLE")
+    return os.path.abspath(p) if p else None
+
+
+def check_device(device: dict | None, chips: int, rehearse: bool):
+    if rehearse:
+        return
+    if (not device or device["platform"] != "tpu"
+            or device["device_count"] != chips):
+        raise NoChip(f"the process that computes reports {device}, the cell "
+                     f"needs {chips} TPU chip(s)")
+
+
+def device_line(device: dict, trace_red: dict | None, window_s=None) -> dict:
+    peaks = [p for p in device.get("peak_bytes_in_use") or [] if p]
+    out = {"platform": device["platform"], "kind": device["device_kind"],
+           "count": device["device_count"],
+           "memory_peak_bytes": max(peaks) if peaks else 0}
+    if trace_red and trace_red.get("busy_s"):
+        out["busy_s"] = sum(trace_red["busy_s"]) / len(trace_red["busy_s"])
+        out["window_s"] = window_s
+    return out
