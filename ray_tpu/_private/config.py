@@ -223,6 +223,11 @@ class RayTpuConfig:
     # counter, it never backpressures an emit site.
     plane_events: bool = True
     plane_event_ring: int = 65536
+    # Drained rows are also appended to <session_dir>/logs/events/
+    # plane-<pid>.jsonl (workers and drivers), so they outlive the GCS:
+    # at most this many bytes per process in two segments, the older
+    # dropped. 0 turns the file off.
+    plane_event_spill_bytes: int = 64 << 20
     # GCS-side plane-event table bound (rows) + retention window: the
     # maintenance sweep evicts rows older than the window, and the
     # chaos end-state invariant asserts the table honors it.

@@ -104,6 +104,7 @@ class Executor:
         # TaskEventBuffer (reference: task_event_buffer.h:220): bounded local
         # buffer of profile events, flushed to the GCS periodically.
         self.events: List[dict] = []
+        self._spilled: Optional[asyncio.Future] = None
 
     def record_event(self, tid: bytes, name: str, kind: str,
                      start: float, end: float, ok: bool):
@@ -135,6 +136,7 @@ class Executor:
                         "pid": os.getpid()})
                 except ConnectionError:
                     pass
+                self._spill(rows)
         if self.events and self.worker.gcs and not self.worker.gcs.closed:
             batch, self.events = self.events, []
             try:
@@ -145,6 +147,24 @@ class Executor:
                     "pid": os.getpid()})
             except ConnectionError:
                 pass
+
+    def _spill(self, rows):
+        """The same rows to the session's spill file. The tick runs on
+        the IO loop, so the file append goes to the executor; the last
+        flush of an exiting worker is awaited (``_spilled``)."""
+        session_dir = self.worker.session_dir
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            plane_events.spill(rows, session_dir)
+            return
+        self._spilled = loop.run_in_executor(
+            None, plane_events.spill, rows, session_dir)
+
+    async def spilled(self):
+        """Wait (bounded) for the last spill append before a hard exit."""
+        if self._spilled is not None:
+            await asyncio.wait([self._spilled], timeout=0.25)
 
     async def start(self):
         self._server = await protocol.serve(
@@ -705,6 +725,7 @@ class Executor:
         if self.die_after_task:
             self.flush_events()
             await asyncio.sleep(0.01)
+            await self.spilled()
             os._exit(0)
 
     def _execute_sync(self, msg: dict, tid: bytes, nret: int,
@@ -1351,6 +1372,7 @@ async def amain(args):
     await stop.wait()
     loop_monitor.stop()
     executor.flush_events()
+    await executor.spilled()
     worker._flush_refs()
     try:
         os.unlink(listen_path)

@@ -11,7 +11,8 @@ mode RTL131 closes for chaos sites). This pass:
 
 1. builds the registered-name set from the scanned package: first
    positional string literal of every ``<base>.emit(...)`` /
-   ``<base>.count(...)`` call where ``<base>`` is one of the recorder
+   ``<base>.count(...)`` / ``<base>.span(...)`` /
+   ``<base>.span_done(...)`` call where ``<base>`` is one of the recorder
    bindings (``events``, ``plane_events``, ``_events``, ``ev`` — the
    spellings the lazy-import shims use);
 2. validates each registered literal against the name grammar
@@ -51,6 +52,8 @@ _NAME_RE = re.compile(
 # The spellings emit sites bind the recorder module to (direct import,
 # package-qualified, and the lazy shims in protocol.py).
 _EMITTER_BASES = {"events", "plane_events", "_events", "ev"}
+# The recorder's row-writing calls: a name's first argument registers it.
+_EMITTERS = ("emit", "count", "span", "span_done")
 
 
 @register_rule
@@ -74,7 +77,7 @@ def _emit_name_literals(index: ProjectIndex) -> Dict[str, List[tuple]]:
                 continue
             fn = node.func
             if not (isinstance(fn, ast.Attribute)
-                    and fn.attr in ("emit", "count")
+                    and fn.attr in _EMITTERS
                     and isinstance(fn.value, ast.Name)
                     and fn.value.id in _EMITTER_BASES):
                 continue

@@ -16,6 +16,7 @@ Inactive slots still flow through the math (their outputs are ignored)
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -23,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..util import events as plane_events
 from .llama import LlamaConfig, _decode_step, rope_frequencies
 
 
@@ -36,6 +38,7 @@ def _single_step(params, caches, tok, length, cfg, cos, sin):
     return logits[0, -1], out
 
 
+@jax.named_scope("sampling")    # HLO metadata only: names the ops' phase
 def _pick_token(logits, temp, top_k, top_p, key):
     """Per-slot sampling: temp<=0 is greedy; otherwise temperature +
     top-k + nucleus (top-p) over one [V] logit row. k/p are traced, so
@@ -139,6 +142,8 @@ class GenerationEngine:
         self._admit_events: List[tuple] = []
         # one padded-prefill compilation per bucket, not per prompt len
         self._prefill_buckets = (16, 64, 256)
+        # what this step() did, for its ``serve.engine.step`` row
+        self._steps = self._admitted = 0
 
     # ------------------------------------------------------------ admit
     def submit(self, request_id: str, prompt: List[int], *,
@@ -154,21 +159,30 @@ class GenerationEngine:
                 f"exceeds engine max_len {self.total}")
         self.pending.append((request_id, list(prompt), max_new_tokens,
                              eos_id, float(temperature), int(top_k),
-                             float(top_p), seed))
+                             float(top_p), seed, time.perf_counter_ns()))
 
     def _admit(self):
         while self.pending and any(s is None for s in self.slots):
-            (rid, prompt, max_new, eos_id, temp, top_k, top_p,
-             seed) = self.pending.pop(0)
+            self._admit_one(*self.pending.pop(0))
+
+    def _admit_one(self, rid, prompt, max_new, eos_id, temp, top_k,
+                   top_p, seed, submitted_ns):
+        n = len(prompt)
+        pad = next((b for b in self._prefill_buckets if b >= n),
+                   self.total)
+        # the paged engine's vocabulary (it adds the phases inside)
+        with plane_events.span("serve.engine.admit", "serve",
+                               rid=str(rid)[:8], prompt_len=n,
+                               bucket=pad) as sp:
+            if sp.sid:      # recorder on: submit() to this span's start
+                sp.set(waited_ns=sp.t0_ns - submitted_ns)
+            self._admitted += 1
             idx = self.slots.index(None)
             self.temps[idx] = temp
             self.top_ks[idx] = top_k
             self.top_ps[idx] = top_p
             if seed is not None:
                 self.keys[idx] = np.asarray(jax.random.PRNGKey(seed))
-            n = len(prompt)
-            pad = next((b for b in self._prefill_buckets if b >= n),
-                       self.total)
             padded = jnp.asarray(
                 prompt + [0] * (pad - n), dtype=jnp.int32)
             first_logits, seq_caches = _prefill_one(
@@ -199,6 +213,18 @@ class GenerationEngine:
         """Admit pending, advance active slots one token. Returns the
         (request_id, token) events emitted this step in order; a token
         of ``None`` marks that request's completion."""
+        self._admitted = 0
+        with plane_events.span("serve.engine.step", "serve",
+                               k=self._steps) as sp:
+            self._steps += 1
+            events, active = self._step()
+            sp.set(active=active, admitted=self._admitted,
+                   tokens=sum(1 for _, tok in events if tok is not None),
+                   pending=len(self.pending))
+        return events
+
+    def _step(self):
+        """-> (events, slots that decoded)."""
         self._admit()
         events: List[tuple] = list(self._admit_events)
         self._admit_events = []
@@ -209,7 +235,7 @@ class GenerationEngine:
                 self.slots[i] = None
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
-            return events
+            return events, 0
         lengths = np.array([self.slots[i].length if self.slots[i] else 0
                             for i in range(self.S)], dtype=np.int32)
         toks, self.caches, new_keys = _step_all(
@@ -232,7 +258,7 @@ class GenerationEngine:
                 s.done = True
                 events.append((s.request_id, None))
                 self.slots[i] = None
-        return events
+        return events, len(active)
 
     def has_work(self) -> bool:
         return bool(self.pending) or any(s is not None

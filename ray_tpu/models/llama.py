@@ -109,10 +109,11 @@ def _attention_block(layer, x, cos, sin, cfg: LlamaConfig, attn_impl,
     new_cache = None
     if kv_cache is not None:
         k_all, v_all, cache_len = kv_cache
-        k_all = jax.lax.dynamic_update_slice(
-            k_all, k.astype(k_all.dtype), (0, cache_len, 0, 0))
-        v_all = jax.lax.dynamic_update_slice(
-            v_all, v.astype(v_all.dtype), (0, cache_len, 0, 0))
+        with jax.named_scope("kv_write"):
+            k_all = jax.lax.dynamic_update_slice(
+                k_all, k.astype(k_all.dtype), (0, cache_len, 0, 0))
+            v_all = jax.lax.dynamic_update_slice(
+                v_all, v.astype(v_all.dtype), (0, cache_len, 0, 0))
         new_cache = (k_all, v_all, cache_len + L)
         mask_len = k_all.shape[1]
         pos = cache_len + jnp.arange(L)
@@ -233,12 +234,15 @@ def _decode_step(params, tokens, caches, start, cfg: LlamaConfig, cos,
     positions = start + jnp.arange(tokens.shape[1])[None, :]
     positions = jnp.broadcast_to(positions, tokens.shape)
     new_caches = []
+    # named scopes: HLO metadata only, so a profile names each op's phase
     for layer, (kc, vc) in zip(params["layers"], caches):
-        a, nc = _attention_block(
-            layer, x, cos, sin, cfg, None,
-            kv_cache=(kc, vc, start), positions=positions)
-        x = x + a
-        x = x + ffn(layer, x, cfg)
+        with jax.named_scope("attention"):   # holds the cache write
+            a, nc = _attention_block(
+                layer, x, cos, sin, cfg, None,
+                kv_cache=(kc, vc, start), positions=positions)
+            x = x + a
+        with jax.named_scope("mlp"):
+            x = x + ffn(layer, x, cfg)
         new_caches.append((nc[0], nc[1]))
     x = rms_norm(x, params["norm"], cfg.norm_eps)
     head = (params["embedding"].T if cfg.tie_embeddings

@@ -18,6 +18,7 @@ dense cache reads); writes are one batched scatter at each slot's
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -27,7 +28,8 @@ import numpy as np
 
 from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
-from .engine import _pick_token, _prefill_one
+from ..util import events as plane_events
+from .engine import _pick_one, _pick_token, _prefill_one
 from .llama import LlamaConfig, _mlp_block
 
 
@@ -62,59 +64,62 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
     new_pools_k, new_pools_v = [], []
     new_scales_k, new_scales_v = ([], []) if kv_int8 else (scales_k,
                                                            scales_v)
+    # The named scopes change HLO metadata only: a profile then names
+    # the phase of every device op; the op sequence is as before.
     for li, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = mm(h, layer["wq"]).reshape(S, 1, cfg.n_heads, cfg.head_dim)
-        k = mm(h, layer["wk"]).reshape(S, 1, cfg.n_kv_heads,
-                                       cfg.head_dim)
-        v = mm(h, layer["wv"]).reshape(S, 1, cfg.n_kv_heads,
-                                       cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        if kv_int8:
-            kq, ks = _quant_kv(k[:, 0])
-            vq, vs = _quant_kv(v[:, 0])
-            pool_k = pools_k[li].at[page_idx, offs].set(kq)
-            pool_v = pools_v[li].at[page_idx, offs].set(vq)
-            scale_k = scales_k[li].at[page_idx, offs].set(ks)
-            scale_v = scales_v[li].at[page_idx, offs].set(vs)
-            new_scales_k.append(scale_k)
-            new_scales_v.append(scale_v)
-            # gather + dequantize each slot's pages
-            k_seq = (pool_k[tables].reshape(S, cap, cfg.n_kv_heads,
-                                            cfg.head_dim)
-                     .astype(cfg.dtype)
-                     * scale_k[tables].reshape(
-                         S, cap, cfg.n_kv_heads, 1).astype(cfg.dtype))
-            v_seq = (pool_v[tables].reshape(S, cap, cfg.n_kv_heads,
-                                            cfg.head_dim)
-                     .astype(cfg.dtype)
-                     * scale_v[tables].reshape(
-                         S, cap, cfg.n_kv_heads, 1).astype(cfg.dtype))
-        else:
-            pool_k = pools_k[li].at[page_idx, offs].set(
-                k[:, 0].astype(pools_k[li].dtype))
-            pool_v = pools_v[li].at[page_idx, offs].set(
-                v[:, 0].astype(pools_v[li].dtype))
+        with jax.named_scope("attention"):
+            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q = mm(h, layer["wq"]).reshape(S, 1, cfg.n_heads,
+                                           cfg.head_dim)
+            k = mm(h, layer["wk"]).reshape(S, 1, cfg.n_kv_heads,
+                                           cfg.head_dim)
+            v = mm(h, layer["wv"]).reshape(S, 1, cfg.n_kv_heads,
+                                           cfg.head_dim)
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+        with jax.named_scope("kv_write"):
+            if kv_int8:
+                kq, ks = _quant_kv(k[:, 0])
+                vq, vs = _quant_kv(v[:, 0])
+                pool_k = pools_k[li].at[page_idx, offs].set(kq)
+                pool_v = pools_v[li].at[page_idx, offs].set(vq)
+                scale_k = scales_k[li].at[page_idx, offs].set(ks)
+                scale_v = scales_v[li].at[page_idx, offs].set(vs)
+                new_scales_k.append(scale_k)
+                new_scales_v.append(scale_v)
+            else:
+                pool_k = pools_k[li].at[page_idx, offs].set(
+                    k[:, 0].astype(pools_k[li].dtype))
+                pool_v = pools_v[li].at[page_idx, offs].set(
+                    v[:, 0].astype(pools_v[li].dtype))
+            new_pools_k.append(pool_k)
+            new_pools_v.append(pool_v)
+        with jax.named_scope("attention"):
             k_seq = pool_k[tables].reshape(S, cap, cfg.n_kv_heads,
                                            cfg.head_dim)
             v_seq = pool_v[tables].reshape(S, cap, cfg.n_kv_heads,
                                            cfg.head_dim)
-        new_pools_k.append(pool_k)
-        new_pools_v.append(pool_v)
-        rep = cfg.n_heads // cfg.n_kv_heads
-        s = jnp.einsum("sqhd,skhd->shqk", q.astype(jnp.float32),
-                       jnp.repeat(k_seq, rep, axis=2).astype(
-                           jnp.float32)) * (cfg.head_dim ** -0.5)
-        admit = (jnp.arange(cap)[None, :] <=
-                 lengths[:, None])  # keys <= query position
-        s = jnp.where(admit[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("shqk,skhd->sqhd", p.astype(v_seq.dtype),
-                       jnp.repeat(v_seq, rep, axis=2))
-        o = o.reshape(S, 1, cfg.n_heads * cfg.head_dim)
-        x = x + mm(o, layer["wo"])
-        x = x + _mlp_block(layer, x, cfg)
+            if kv_int8:     # dequantize each slot's gathered pages
+                k_seq = (k_seq.astype(cfg.dtype)
+                         * scale_k[tables].reshape(
+                             S, cap, cfg.n_kv_heads, 1).astype(cfg.dtype))
+                v_seq = (v_seq.astype(cfg.dtype)
+                         * scale_v[tables].reshape(
+                             S, cap, cfg.n_kv_heads, 1).astype(cfg.dtype))
+            rep = cfg.n_heads // cfg.n_kv_heads
+            s = jnp.einsum("sqhd,skhd->shqk", q.astype(jnp.float32),
+                           jnp.repeat(k_seq, rep, axis=2).astype(
+                               jnp.float32)) * (cfg.head_dim ** -0.5)
+            admit = (jnp.arange(cap)[None, :] <=
+                     lengths[:, None])  # keys <= query position
+            s = jnp.where(admit[:, None, None, :], s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("shqk,skhd->sqhd", p.astype(v_seq.dtype),
+                           jnp.repeat(v_seq, rep, axis=2))
+            o = o.reshape(S, 1, cfg.n_heads * cfg.head_dim)
+            x = x + mm(o, layer["wo"])
+        with jax.named_scope("mlp"):
+            x = x + _mlp_block(layer, x, cfg)
     x = rms_norm(x, params["norm"], cfg.norm_eps)
     head = (params["embedding"].T if cfg.tie_embeddings
             else params["lm_head"])
@@ -218,6 +223,8 @@ class PagedEngine:
         self.pending: List[tuple] = []
         self._admit_events: List[tuple] = []
         self._prefill_buckets = (16, 64, 256)
+        # what this step() did, for its ``serve.engine.step`` row
+        self._steps = self._admitted = self._preempted = 0
         # Prefix cache: full-prompt-page content hash -> (page id,
         # refcount). Pages with refcount 0 stay resident (reusable)
         # until pool pressure evicts them LRU (``_reclaim``).
@@ -306,7 +313,8 @@ class PagedEngine:
                 "num_pages or shrink the request")
         self.pending.append((request_id, list(prompt), max_new_tokens,
                              eos_id, float(temperature), int(top_k),
-                             float(top_p), seed, None))
+                             float(top_p), seed, None,
+                             time.perf_counter_ns()))
 
     def _cached_prefix_pages(self, prompt: List[int]) -> List[int]:
         """Longest run of already-cached FULL prompt pages (never the
@@ -343,15 +351,34 @@ class PagedEngine:
 
     def _admit(self):
         while self.pending and any(s is None for s in self.slots):
-            head = self.pending[0]
-            prompt = head[1]
+            prompt = self.pending[0][1]
             shared = self._cached_prefix_pages(prompt)
             need = self._pages_needed(len(prompt) + 1) - len(shared)
             self._reclaim(need)
             if need > len(self.free_pages):
                 return  # wait for pages, preserve FIFO order
-            (rid, prompt, max_new, eos_id, temp, top_k, top_p,
-             seed, key_state) = self.pending.pop(0)
+            self._admit_one(self.pending.pop(0), shared, need)
+
+    def _admit_one(self, request: tuple, shared: List[int], need: int):
+        """Prefill one request, scatter its K/V into its pages, sample
+        its first token. One ``serve.engine.admit`` span, its three
+        phases inside it: they bracket what the host does, device time
+        per phase comes from the trace by program name."""
+        (rid, prompt, max_new, eos_id, temp, top_k, top_p,
+         seed, key_state, submitted_ns) = request
+        rid8 = str(rid)[:8]
+        n = len(prompt)
+        L0 = len(shared) * self.page       # cached prefix length
+        suffix = prompt[L0:]
+        pad = next((b for b in self._prefill_buckets
+                    if b >= len(suffix)), self.max_len)
+        with plane_events.span(
+                "serve.engine.admit", "serve", rid=rid8, prompt_len=n,
+                bucket=pad, shared_pages=len(shared),
+                own_pages=need) as sp:
+            if sp.sid:      # recorder on: submit() to this span's start
+                sp.set(waited_ns=sp.t0_ns - submitted_ns)
+            self._admitted += 1
             idx = self.slots.index(None)
             self.temps[idx] = temp
             self.top_ks[idx] = top_k
@@ -360,86 +387,32 @@ class PagedEngine:
                 self.keys[idx] = np.array(key_state)
             elif seed is not None:
                 self.keys[idx] = np.array(jax.random.PRNGKey(seed))
-            n = len(prompt)
             slot = _PagedSlot(rid, length=n, max_new=max_new,
                               eos_id=eos_id, prompt=list(prompt))
             own = [self.free_pages.pop() for _ in range(need)]
             slot.pages = list(shared) + own
-            L0 = len(shared) * self.page       # cached prefix length
             if shared:
                 self.prefix_hits += 1
             elif self.enable_prefix_cache:
                 self.prefix_misses += 1
-            suffix = prompt[L0:]
-            pad = next((b for b in self._prefill_buckets
-                        if b >= len(suffix)), self.max_len)
-            padded = jnp.asarray(suffix + [0] * (pad - len(suffix)),
-                                 dtype=jnp.int32)
-            if shared:
-                # Seed a dense cache with the shared prefix K/V, then
-                # run ONLY the suffix — the compute the cache saves.
-                tbl = jnp.asarray(shared, dtype=jnp.int32)
-                prefix_caches = []
-                zpad = self.max_len - L0
-                for li in range(self.cfg.n_layers):
-                    pk = self.pools_k[li][tbl].reshape(
-                        L0, self.cfg.n_kv_heads, self.cfg.head_dim)
-                    pv = self.pools_v[li][tbl].reshape(
-                        L0, self.cfg.n_kv_heads, self.cfg.head_dim)
-                    if self.kv_int8:  # dequantize borrowed pages
-                        pk = pk.astype(self.cfg.dtype) * \
-                            self.scales_k[li][tbl].reshape(
-                                L0, self.cfg.n_kv_heads, 1
-                            ).astype(self.cfg.dtype)
-                        pv = pv.astype(self.cfg.dtype) * \
-                            self.scales_v[li][tbl].reshape(
-                                L0, self.cfg.n_kv_heads, 1
-                            ).astype(self.cfg.dtype)
-                    z = jnp.zeros((zpad,) + pk.shape[1:], pk.dtype)
-                    prefix_caches.append(
-                        (jnp.concatenate([pk, z]),
-                         jnp.concatenate([pv, z])))
-                first_logits, seq_caches = _suffix_prefill(
-                    self.params, prefix_caches, padded,
-                    jnp.int32(L0), jnp.int32(n), self.max_len,
-                    self.cfg, self.cos, self.sin, pad)
-            else:
-                first_logits, seq_caches = _prefill_one(
-                    self.params, padded, n, self.max_len, self.cfg,
-                    self.cos, self.sin, pad)
+            with plane_events.span("serve.admit.prefill", "serve",
+                                   rid=rid8):
+                first_logits, seq_caches = self._prefill(
+                    suffix, pad, shared, L0, n)
             self.tables[idx] = 0
             self.tables[idx, :len(slot.pages)] = slot.pages
-            # scatter the computed K/V into the slot's OWN pages only
-            # (shared prefix pages already hold their content)
-            for li, (kc, vc) in enumerate(seq_caches):
-                pk, pv = self.pools_k[li], self.pools_v[li]
-                for pi in range(len(shared), len(slot.pages)):
-                    lo = pi * self.page
-                    pg = slot.pages[pi]
-                    ks = kc[lo:lo + self.page]
-                    vs = vc[lo:lo + self.page]
-                    if self.kv_int8:
-                        kq, ksc = _quant_kv(ks)
-                        vq, vsc = _quant_kv(vs)
-                        pk = pk.at[pg].set(kq)
-                        pv = pv.at[pg].set(vq)
-                        self.scales_k[li] = \
-                            self.scales_k[li].at[pg].set(ksc)
-                        self.scales_v[li] = \
-                            self.scales_v[li].at[pg].set(vsc)
-                    else:
-                        pk = pk.at[pg].set(ks)
-                        pv = pv.at[pg].set(vs)
-                self.pools_k[li], self.pools_v[li] = pk, pv
+            with plane_events.span("serve.admit.scatter", "serve",
+                                   rid=rid8, pages=need):
+                self._scatter(seq_caches, slot.pages, len(shared))
             self._register_prefix_pages(slot)
-            key = jnp.asarray(self.keys[idx], dtype=jnp.uint32)
-            key, sub = jax.random.split(key)
-            self.keys[idx] = np.array(key)
-            from .engine import _pick_one
-
-            tok = int(_pick_one(first_logits, jnp.float32(temp),
-                                jnp.int32(top_k), jnp.float32(top_p),
-                                sub))
+            with plane_events.span("serve.admit.sample", "serve",
+                                   rid=rid8):
+                key = jnp.asarray(self.keys[idx], dtype=jnp.uint32)
+                key, sub = jax.random.split(key)
+                self.keys[idx] = np.array(key)
+                tok = int(_pick_one(first_logits, jnp.float32(temp),
+                                    jnp.int32(top_k), jnp.float32(top_p),
+                                    sub))
             slot.emitted.append(tok)
             self.last_tok[idx] = tok
             self._admit_events.append((rid, tok))
@@ -448,22 +421,148 @@ class PagedEngine:
                 slot.done = True
             self.slots[idx] = slot
 
+    def _prefill(self, suffix: List[int], pad: int, shared: List[int],
+                 L0: int, n: int):
+        """Pad and dispatch the prefill program: the whole prompt, or —
+        seeded with the shared prefix's K/V gathered from its cached
+        pages — only the suffix, the compute the cache saves."""
+        padded = jnp.asarray(suffix + [0] * (pad - len(suffix)),
+                             dtype=jnp.int32)
+        if not shared:
+            return _prefill_one(
+                self.params, padded, n, self.max_len, self.cfg,
+                self.cos, self.sin, pad)
+        tbl = jnp.asarray(shared, dtype=jnp.int32)
+        prefix_caches = []
+        zpad = self.max_len - L0
+        for li in range(self.cfg.n_layers):
+            pk = self.pools_k[li][tbl].reshape(
+                L0, self.cfg.n_kv_heads, self.cfg.head_dim)
+            pv = self.pools_v[li][tbl].reshape(
+                L0, self.cfg.n_kv_heads, self.cfg.head_dim)
+            if self.kv_int8:  # dequantize borrowed pages
+                pk = pk.astype(self.cfg.dtype) * \
+                    self.scales_k[li][tbl].reshape(
+                        L0, self.cfg.n_kv_heads, 1
+                    ).astype(self.cfg.dtype)
+                pv = pv.astype(self.cfg.dtype) * \
+                    self.scales_v[li][tbl].reshape(
+                        L0, self.cfg.n_kv_heads, 1
+                    ).astype(self.cfg.dtype)
+            z = jnp.zeros((zpad,) + pk.shape[1:], pk.dtype)
+            prefix_caches.append(
+                (jnp.concatenate([pk, z]),
+                 jnp.concatenate([pv, z])))
+        return _suffix_prefill(
+            self.params, prefix_caches, padded,
+            jnp.int32(L0), jnp.int32(n), self.max_len,
+            self.cfg, self.cos, self.sin, pad)
+
+    def _scatter(self, seq_caches, pages: List[int], n_shared: int):
+        """The computed K/V into the slot's OWN pages only (shared
+        prefix pages already hold their content)."""
+        for li, (kc, vc) in enumerate(seq_caches):
+            pk, pv = self.pools_k[li], self.pools_v[li]
+            for pi in range(n_shared, len(pages)):
+                lo = pi * self.page
+                pg = pages[pi]
+                ks = kc[lo:lo + self.page]
+                vs = vc[lo:lo + self.page]
+                if self.kv_int8:
+                    kq, ksc = _quant_kv(ks)
+                    vq, vsc = _quant_kv(vs)
+                    pk = pk.at[pg].set(kq)
+                    pv = pv.at[pg].set(vq)
+                    self.scales_k[li] = \
+                        self.scales_k[li].at[pg].set(ksc)
+                    self.scales_v[li] = \
+                        self.scales_v[li].at[pg].set(vsc)
+                else:
+                    pk = pk.at[pg].set(ks)
+                    pv = pv.at[pg].set(vs)
+            self.pools_k[li], self.pools_v[li] = pk, pv
+
     # ----------------------------------------------------------- step
     def step(self) -> List[tuple]:
+        self._admitted = self._preempted = 0
+        with plane_events.span("serve.engine.step", "serve",
+                               k=self._steps) as sp:
+            self._steps += 1
+            events, active = self._step()
+            sp.set(active=active, admitted=self._admitted,
+                   tokens=sum(1 for _, tok in events if tok is not None),
+                   pending=len(self.pending),
+                   free_pages=len(self.free_pages),
+                   preempted=self._preempted)
+        return events
+
+    def _step(self):
+        """-> (events, slots that decoded). Four phases tile the time
+        after ``_admit``: prepare (tables and uploads), dispatch (the
+        ``_paged_step`` call until it returns), fetch (blocks on the
+        device), emit (the per-slot loop)."""
         self._admit()
-        events: List[tuple] = list(self._admit_events)
-        self._admit_events = []
-        for i, s in enumerate(self.slots):
-            if s is not None and s.done:
-                events.append((s.request_id, None))
-                self._free(s)
-                self.slots[i] = None
-                self.tables[i] = 0  # inactive lane writes -> scratch
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return events
-        # Grow page tables BEFORE the step for slots crossing a page
-        # boundary (the write this step lands at position `length`).
+        with plane_events.span("serve.step.prepare", "serve"):
+            events: List[tuple] = list(self._admit_events)
+            self._admit_events = []
+            for i, s in enumerate(self.slots):
+                if s is not None and s.done:
+                    events.append((s.request_id, None))
+                    self._free(s)
+                    self.slots[i] = None
+                    self.tables[i] = 0  # inactive lane writes -> scratch
+            active = [i for i, s in enumerate(self.slots)
+                      if s is not None]
+            if not active:
+                return events, 0
+            active = self._grow_tables(active)
+            if not active:
+                return events, 0
+            lengths = np.array([self.slots[i].length if self.slots[i]
+                                else 0 for i in range(self.S)],
+                               dtype=np.int32)
+            no_scales = [0] * self.cfg.n_layers
+            uploads = (
+                jnp.asarray(self.tables), jnp.asarray(self.last_tok),
+                jnp.asarray(lengths), jnp.asarray(self.temps),
+                jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
+                jnp.asarray(self.keys, dtype=jnp.uint32))
+        with plane_events.span("serve.step.dispatch", "serve"):
+            (toks, self.pools_k, self.pools_v, sk, sv,
+             new_keys) = _paged_step(
+                self.params, self.pools_k, self.pools_v,
+                self.scales_k if self.kv_int8 else no_scales,
+                self.scales_v if self.kv_int8 else no_scales,
+                *uploads, self.cfg, self.cos, self.sin, self.page,
+                self.kv_int8)
+            if self.kv_int8:
+                # model-dtype mode keeps scales stable at [None]*n_layers
+                self.scales_k, self.scales_v = sk, sv
+        with plane_events.span("serve.step.fetch", "serve"):
+            toks = np.asarray(toks)
+            self.keys = np.array(new_keys)
+        with plane_events.span("serve.step.emit", "serve",
+                               tokens=len(active)):
+            for i in active:
+                s = self.slots[i]
+                tok = int(toks[i])
+                s.length += 1
+                s.emitted.append(tok)
+                self.last_tok[i] = tok
+                events.append((s.request_id, tok))
+                if (s.eos_id is not None and tok == s.eos_id) or \
+                        len(s.emitted) >= s.max_new:
+                    s.done = True
+                    events.append((s.request_id, None))
+                    self._free(s)
+                    self.slots[i] = None
+                    self.tables[i] = 0
+        return events, len(active)
+
+    def _grow_tables(self, active: List[int]) -> List[int]:
+        """Grow page tables BEFORE the step for slots crossing a page
+        boundary (the write this step lands at position ``length``);
+        -> the slots still active."""
         for i in active:
             s = self.slots[i]
             if s.length % self.page == 0 and \
@@ -483,7 +582,9 @@ class PagedEngine:
                         s.request_id, s.prompt + s.emitted, remaining,
                         s.eos_id, float(self.temps[i]),
                         int(self.top_ks[i]), float(self.top_ps[i]),
-                        None, np.array(self.keys[i])))
+                        None, np.array(self.keys[i]),
+                        time.perf_counter_ns()))
+                    self._preempted += 1
                     self._free(s)
                     self.slots[i] = None
                     self.tables[i] = 0
@@ -491,42 +592,7 @@ class PagedEngine:
                 pg = self.free_pages.pop()
                 s.pages.append(pg)
                 self.tables[i, len(s.pages) - 1] = pg
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return events
-        lengths = np.array([self.slots[i].length if self.slots[i]
-                            else 0 for i in range(self.S)],
-                           dtype=np.int32)
-        (toks, self.pools_k, self.pools_v, sk, sv,
-         new_keys) = _paged_step(
-            self.params, self.pools_k, self.pools_v,
-            self.scales_k if self.kv_int8 else [0] * self.cfg.n_layers,
-            self.scales_v if self.kv_int8 else [0] * self.cfg.n_layers,
-            jnp.asarray(self.tables), jnp.asarray(self.last_tok),
-            jnp.asarray(lengths), jnp.asarray(self.temps),
-            jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
-            jnp.asarray(self.keys, dtype=jnp.uint32), self.cfg,
-            self.cos, self.sin, self.page, self.kv_int8)
-        if self.kv_int8:
-            # model-dtype mode keeps scales stable at [None]*n_layers
-            self.scales_k, self.scales_v = sk, sv
-        toks = np.asarray(toks)
-        self.keys = np.array(new_keys)
-        for i in active:
-            s = self.slots[i]
-            tok = int(toks[i])
-            s.length += 1
-            s.emitted.append(tok)
-            self.last_tok[i] = tok
-            events.append((s.request_id, tok))
-            if (s.eos_id is not None and tok == s.eos_id) or \
-                    len(s.emitted) >= s.max_new:
-                s.done = True
-                events.append((s.request_id, None))
-                self._free(s)
-                self.slots[i] = None
-                self.tables[i] = 0
-        return events
+        return [i for i, s in enumerate(self.slots) if s is not None]
 
     def has_work(self) -> bool:
         return bool(self.pending) or any(s is not None

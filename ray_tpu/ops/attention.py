@@ -130,6 +130,7 @@ def _flash_attention_bhld(q, k, v, causal, scale, block_q, block_k,
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         interpret=interpret,
+        name="ray_tpu_flash_fwd",    # the kernel's name in a profile
     )(q, k, v)
 
 
@@ -352,6 +353,7 @@ def _flash_stats_bhld(q, k, v, visible, scale, block_q, block_k,
             jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ray_tpu_flash_stats",
     )(q, k, v, visible)
     return o, m[..., 0], l[..., 0]
 

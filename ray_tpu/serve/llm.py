@@ -101,29 +101,48 @@ class LLMServer:
                 self._engine_loop())
 
     def _locked_step(self):
+        """-> (events, ns the executor waited for the engine lock, the
+        monotonic instant step() returned: the pump's hand-off starts
+        there, in this thread)."""
+        t_enter = time.perf_counter_ns()
         with self._engine_lock:
-            return self.engine.step()
+            t_locked = time.perf_counter_ns()
+            events = self.engine.step()
+            return events, t_locked - t_enter, time.perf_counter_ns()
 
     async def _engine_loop(self):
         loop = asyncio.get_running_loop()
         while self.engine.has_work():
             # The jitted step is device-bound; run it off the event loop
             # so health checks / new submissions stay responsive.
-            events = await loop.run_in_executor(None, self._locked_step)
+            events, lock_wait_ns, t_step_end = await loop.run_in_executor(
+                None, self._locked_step)
+            tokens = 0
             for rid, tok in events:
                 q = self._queues.get(rid)
                 if q is not None:
                     q.put_nowait(tok)
+                    tokens += tok is not None
+            if tokens:
+                # step()'s return in the executor thread -> the last
+                # token queued on the event loop: the hop the engine's
+                # own spans cannot see.
+                plane_events.span_done(
+                    "serve.pump.deliver", "serve", t_step_end,
+                    tokens=tokens, lock_wait_ns=lock_wait_ns)
             await asyncio.sleep(0)
 
     def _submit(self, body: dict) -> str:
         rid = uuid.uuid4().hex
         self._queues[rid] = asyncio.Queue()
+        # The request's first row in the replica, on the engine rows'
+        # clock and under their ``rid``.
         plane_events.emit("serve.req.queue", plane="serve",
                           tenant=str(body.get("tenant") or ""),
                           rid=rid[:8], prompt_len=len(body["prompt"]),
                           weights_version=self._weights_version,
-                          queued=len(self._queues))
+                          queued=len(self._queues),
+                          t0_ns=time.perf_counter_ns())
         try:
             self.engine.submit(rid, [int(t) for t in body["prompt"]],
                                max_new_tokens=int(
@@ -159,8 +178,7 @@ class LLMServer:
             return await self._speculative(body)
         if body.get("stream"):
             return self._stream(body)
-        t0 = time.time()
-        tenant = str(body.get("tenant") or "")
+        t0_ns = time.perf_counter_ns()
         rid = self._submit(body)
         q = self._queues[rid]
         toks = []
@@ -170,19 +188,18 @@ class LLMServer:
                 if tok is None:
                     break
                 if not toks:
-                    plane_events.emit(
-                        "serve.req.first_token", plane="serve",
-                        tenant=tenant, rid=rid[:8],
-                        weights_version=self._weights_version,
-                        dur=time.time() - t0)
+                    self._first_token(body, rid, t0_ns)
                 toks.append(tok)
         finally:
             self._queues.pop(rid, None)
-        plane_events.emit("serve.req.tokens_done", plane="serve",
-                          tenant=tenant, rid=rid[:8],
-                          weights_version=self._weights_version,
-                          tokens=len(toks), dur=time.time() - t0)
         return {"tokens": toks, "num_tokens": len(toks)}
+
+    def _first_token(self, body: dict, rid: str, t0_ns: int):
+        """Handler entry -> first token out of the engine's queue."""
+        plane_events.span_done(
+            "serve.req.first_token", "serve", t0_ns,
+            tenant=str(body.get("tenant") or ""), rid=rid[:8],
+            weights_version=self._weights_version)
 
     async def _speculative(self, body: dict):
         """Batch-1 speculative decode; response carries the round stats
@@ -324,7 +341,7 @@ class LLMServer:
         self._weights_version += 1  # raylint: disable=RTL151 (single-writer counter — reconfigures are controller-serialized)
 
     async def _stream(self, body: dict):
-        t0 = time.time()
+        t0_ns = time.perf_counter_ns()
         rid = self._submit(body)
         q = self._queues[rid]
         first = True
@@ -335,12 +352,7 @@ class LLMServer:
                     return
                 if first:
                     first = False
-                    plane_events.emit(
-                        "serve.req.first_token", plane="serve",
-                        tenant=str(body.get("tenant") or ""),
-                        rid=rid[:8],
-                        weights_version=self._weights_version,
-                        dur=time.time() - t0)
+                    self._first_token(body, rid, t0_ns)
                 yield tok
         finally:
             self._queues.pop(rid, None)

@@ -28,6 +28,16 @@ Contract (the reason this can sit on hot paths):
   an adopted remote context), every row carries the active trace id, so
   a Perfetto lane click joins the task-plane span tree.
 
+* **One monotonic clock for spans.** ``span`` / ``span_done`` stamp
+  ``time.perf_counter_ns()`` (``CLOCK_MONOTONIC``: one clock for every
+  process of a host) into the row's fields beside the wall ``ts``, so a
+  client's ``perf_counter`` stamps, a profiler trace (one offset) and
+  the rows of any process of the host compare directly.
+* **Rows outlive the GCS.** Where rows leave a process, a process that
+  knows its session directory also appends them to
+  ``<session_dir>/logs/events/plane-<pid>.jsonl`` (``spill`` /
+  ``read_spill``): bounded, off the emit path, silent on ``OSError``.
+
 Event names are dotted three-segment literals (``plane.noun.verb``);
 ``ray_tpu check --events`` cross-checks every name referenced by
 benchmarks/tests against the literals registered here-abouts, exactly
@@ -36,7 +46,11 @@ like ``--failpoints`` does for chaos sites.
 
 from __future__ import annotations
 
+import contextvars
+import itertools
+import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -62,16 +76,18 @@ _counts: Dict[Tuple[str, str], list] = {}
 # re-snapshotted on config change so driver-side _system_config lands.
 _enabled = True
 _cap = 65536
+_spill_cap = 64 << 20
 
 
 def _snapshot_config():
-    global _enabled, _cap
+    global _enabled, _cap, _spill_cap
     try:
         from ray_tpu._private.config import config as _cfg
 
         c = _cfg()
         _enabled = bool(c.plane_events)
         _cap = max(16, int(c.plane_event_ring))
+        _spill_cap = max(0, int(c.plane_event_spill_bytes))
     except Exception:  # pragma: no cover - bootstrap import cycles
         pass
 
@@ -87,8 +103,6 @@ def process_tenant() -> str:
     rollout egress) tag their rows with this so the GCS-side
     interference detector can attribute a plane's traffic to a tenant
     without the emit site threading a namespace through every call."""
-    import sys
-
     worker_mod = sys.modules.get("ray_tpu._private.worker")
     if worker_mod is None:
         return ""
@@ -102,8 +116,6 @@ def process_tenant() -> str:
 def _trace_id() -> str:
     """Active trace id when the tracing module is live in this process
     (module-presence gate: don't import tracing just to answer no)."""
-    import sys
-
     tracing = sys.modules.get("ray_tpu.util.tracing")
     if tracing is None:
         return ""
@@ -122,15 +134,103 @@ def emit(name: str, plane: str, tenant: str = "",
     the ambient trace id (cross-process stitch points)."""
     if not _enabled:
         return
-    row = [time.time(), name, plane, tenant,
-           trace if trace is not None else _trace_id(),
-           float(dur) if dur is not None else 0.0,
-           fields if fields else None]
+    _append([time.time(), name, plane, tenant,
+             trace if trace is not None else _trace_id(),
+             float(dur) if dur is not None else 0.0,
+             fields if fields else None])
+
+
+def _append(row: list) -> None:
     with _lock:
         if len(_ring) < _cap:
             _ring.append(row)
         else:
-            _dropped[plane] = _dropped.get(plane, 0) + 1
+            _dropped[row[2]] = _dropped.get(row[2], 0) + 1
+
+
+# ------------------------------------------------------------- spans
+
+_span_ids = itertools.count(1)          # per process
+_current_span: contextvars.ContextVar = contextvars.ContextVar(
+    "ray_tpu_plane_span", default=0)
+
+
+def _annotation(name: str):
+    """The same interval on the profiler's own clock, when jax is
+    already in the process (module-presence gate: the GCS and drivers
+    stay jax-free). With no profiler session live this is TraceMe's
+    inactive path."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation("ray_tpu/" + name)
+    except AttributeError:  # pragma: no cover - jax still importing
+        return None
+
+
+def _record_span(name, plane, tenant, t0_ns, sid, parent, fields):
+    end_ns = time.perf_counter_ns()
+    fields.update(t0_ns=t0_ns, dur_ns=end_ns - t0_ns, sid=sid,
+                  parent=parent)
+    _append([time.time(), name, plane, tenant, _trace_id(),
+             (end_ns - t0_ns) / 1e9, fields])
+
+
+def span_done(name: str, plane: str, t0_ns: int, tenant: str = "",
+              **fields) -> None:
+    """Record as a span an interval that began at ``t0_ns`` (a
+    ``time.perf_counter_ns()`` reading, possibly another thread's) and
+    ends now: the begin/end spelling for sites a ``with`` cannot
+    bracket (no profiler annotation: that cannot be entered late)."""
+    if not _enabled:
+        return
+    _record_span(name, plane, tenant, t0_ns, next(_span_ids),
+                 _current_span.get(), fields)
+
+
+class span:
+    """``with events.span("serve.engine.step", "serve", k=3) as sp:`` —
+    one row when the block ends, holding the interval on the monotonic
+    clock (``t0_ns`` / ``dur_ns``), a per-process span id and the
+    enclosing span's (``sid`` / ``parent``). ``sp.set(tokens=n)`` adds
+    what is known only at the end. A span brackets what the host does
+    today: it adds no synchronisation. With the recorder off it returns
+    before reading a clock."""
+
+    __slots__ = ("name", "plane", "tenant", "fields", "sid", "t0_ns",
+                 "_parent", "_token", "_ann")
+
+    def __init__(self, name: str, plane: str, tenant: str = "", **fields):
+        self.name, self.plane, self.tenant = name, plane, tenant
+        self.fields = fields
+        self.sid = self.t0_ns = 0
+
+    def set(self, **fields) -> None:
+        self.fields.update(fields)
+
+    def __enter__(self):
+        if not _enabled:
+            return self
+        self.sid = next(_span_ids)
+        self._parent = _current_span.get()
+        self._token = _current_span.set(self.sid)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        if not self.sid:
+            return False
+        if _enabled:
+            _record_span(self.name, self.plane, self.tenant, self.t0_ns,
+                         self.sid, self._parent, self.fields)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _current_span.reset(self._token)
+        return False
 
 
 def count(name: str, key: str = "", n: int = 1, nbytes: int = 0,
@@ -192,10 +292,10 @@ def flush_now(worker=None) -> int:
     connected). Driver processes flush through the metrics flusher's
     tick (``util/metrics.py``); workers flush through the executor's
     coalesced ``task_events`` loop (``worker_main.flush_events``) — both
-    call here. Thread-safe: the send marshals onto the worker IO loop."""
+    drain here-abouts and hand the same rows to ``spill``. Thread-safe:
+    the send marshals onto the worker IO loop."""
+    global _session_dir
     if not _enabled:
-        return 0
-    if pending() == 0:
         return 0
     if worker is None:
         from ray_tpu._private import worker as worker_mod
@@ -204,6 +304,9 @@ def flush_now(worker=None) -> int:
     if (worker is None or worker.closed or worker.gcs is None
             or worker.loop is None):
         return 0
+    _session_dir = worker.session_dir or _session_dir
+    if pending() == 0:
+        return 0
     rows, drops = drain()
     if not rows and not drops:
         return 0
@@ -211,7 +314,107 @@ def flush_now(worker=None) -> int:
            "nid": getattr(worker, "node_id", b"") or b"",
            "pid": os.getpid()}
     worker.loop.call_soon_threadsafe(worker._send_gcs, msg)
+    # the flusher thread / the disconnecting caller: never an event loop
+    spill(rows, worker.session_dir)
     return len(rows)
+
+
+# ------------------------------------------------------------- spill
+# The GCS table is an in-memory deque: it dies with the GCS, which is
+# when a post-mortem wants the rows (Ray's analog:
+# ``logs/events/event_*.log``). Two segments per process, the older
+# dropped when the newer passes half the cap.
+
+_spill_lock = threading.Lock()
+_spill_dirs: set = set()
+# the last session this process flushed into (``read_spill``'s default
+# once the worker has disconnected)
+_session_dir: Optional[str] = None
+
+
+def spill_path(session_dir: str, pid: int) -> str:
+    return os.path.join(session_dir, "logs", "events",
+                        f"plane-{pid}.jsonl")
+
+
+def _plain(value):
+    return value.hex() if isinstance(value, (bytes, bytearray)) \
+        else str(value)
+
+
+_encode_row = json.JSONEncoder(separators=(",", ":"),
+                               default=_plain).encode
+
+
+def spill(rows: List[list], session_dir: Optional[str],
+          pid: Optional[int] = None) -> int:
+    """Append drained rows as JSON lines to this process's spill file;
+    returns the bytes written. BLOCKING file I/O, off the emit path:
+    a caller on an event loop hands it to an executor
+    (``worker_main.flush_events``). Silent on ``OSError`` — a full or
+    read-only disk costs the file, never the process."""
+    global _session_dir
+    if session_dir:
+        _session_dir = session_dir
+    if not rows or not session_dir or _spill_cap <= 0:
+        return 0
+    path = spill_path(session_dir, pid or os.getpid())
+    data = "".join(_encode_row(r) + "\n" for r in rows)
+    try:
+        with _spill_lock:
+            folder = os.path.dirname(path)
+            if folder not in _spill_dirs:
+                os.makedirs(folder, exist_ok=True)
+                _spill_dirs.add(folder)
+            with open(path, "a") as f:
+                f.write(data)
+                size = f.tell()
+            if size > _spill_cap // 2:
+                os.replace(path, path + ".1")
+    except (OSError, ValueError):
+        return 0
+    return len(data)
+
+
+def read_spill(pid: Optional[int] = None,
+               session_dir: Optional[str] = None) -> List[dict]:
+    """Decoded rows (``row_to_dict`` shape) of one process's spill file,
+    or of every process's where ``pid`` is None, oldest first per
+    process. ``session_dir`` defaults to the session this process is
+    connected to, else the last one it flushed into — never another
+    session's, so concurrent clusters do not read each other's."""
+    if session_dir is None:
+        worker_mod = sys.modules.get("ray_tpu._private.worker")
+        w = worker_mod._global_worker if worker_mod else None
+        session_dir = getattr(w, "session_dir", None) or _session_dir
+    if not session_dir:
+        return []
+    folder = os.path.dirname(spill_path(session_dir, 0))
+    if pid is None:
+        try:
+            names = sorted(os.listdir(folder))
+        except OSError:
+            return []
+        stems = [n[len("plane-"):-len(".jsonl")] for n in names
+                 if n.startswith("plane-") and n.endswith(".jsonl")]
+        pids = [int(stem) for stem in stems if stem.isdigit()]
+    else:
+        pids = [int(pid)]
+    out: List[dict] = []
+    for p in pids:
+        path = spill_path(session_dir, p)
+        for segment in (path + ".1", path):
+            try:
+                with open(segment) as f:
+                    lines = f.readlines()
+            except OSError:
+                continue
+            for line in lines:
+                try:
+                    out.append(row_to_dict(json.loads(line), pid=p))
+                except ValueError:
+                    continue    # a torn last line of a killed process
+    return out
 
 
 def gauge(name: str, description: str = "",

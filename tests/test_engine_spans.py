@@ -1,0 +1,420 @@
+"""The flight recorder inside the engine and the pump (ISSUE 24).
+
+``events.span`` rows from ``PagedEngine`` / ``GenerationEngine`` /
+``LLMServer._engine_loop`` on the monotonic clock, their nesting, what they
+cost a disabled recorder (nothing, not even a clock), the profiler
+annotations they double as, and the spill file that lets the rows outlive
+the GCS (``drain`` -> ``spill`` -> ``read_spill``).
+"""
+
+import asyncio
+import glob
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import ray_tpu
+from ray_tpu.models import LlamaConfig, init_params
+from ray_tpu.models.engine import GenerationEngine
+from ray_tpu.models.paged import PagedEngine
+from ray_tpu.util import events
+
+STEP_PHASES = ["serve.step.prepare", "serve.step.dispatch",
+               "serve.step.fetch", "serve.step.emit"]
+ADMIT_PHASES = ["serve.admit.prefill", "serve.admit.scatter",
+                "serve.admit.sample"]
+REQS = {"req-aaaa-long-id": ([1, 2, 3, 4], 6), "req-bbbb": ([7, 8], 3),
+        "req-cccc": ([10, 11, 12, 13, 14, 15, 16, 17, 18], 5)}
+
+
+@pytest.fixture(autouse=True)
+def _clean_ring():
+    events.reset()
+    yield
+    events._enabled = True
+    events.reset()
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = LlamaConfig(vocab_size=96, d_model=64, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=128, max_seq_len=128,
+                      dtype=jnp.float32)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _engine(kind, model, **kw):
+    cfg, params = model
+    if kind == "paged":
+        return PagedEngine(params, cfg, max_slots=2, num_pages=24,
+                           page_size=8, max_len=64, **kw)
+    return GenerationEngine(params, cfg, max_slots=2, max_len=64)
+
+
+def _drive(eng):
+    """-> (results, calls of step(), tokens emitted)."""
+    for rid, (prompt, n) in REQS.items():
+        eng.submit(rid, prompt, max_new_tokens=n)
+    calls = tokens = 0
+    results = {rid: [] for rid in REQS}
+    while eng.has_work():
+        calls += 1
+        for rid, tok in eng.step():
+            if tok is not None:
+                tokens += 1
+                results[rid].append(tok)
+    return results, calls, tokens
+
+
+def _rows():
+    rows, _ = events.drain()
+    return [events.row_to_dict(r) for r in rows]
+
+
+def _inside(child, parent):
+    c, p = child["fields"], parent["fields"]
+    return (p["t0_ns"] <= c["t0_ns"]
+            and c["t0_ns"] + c["dur_ns"] <= p["t0_ns"] + p["dur_ns"])
+
+
+# ----------------------------------------------------- the span primitive
+
+def test_span_nests_and_stamps_one_monotonic_clock():
+    before = time.perf_counter_ns()
+    with events.span("serve.engine.step", "serve", k=7) as outer:
+        with events.span("serve.step.prepare", "serve") as inner:
+            pass
+        outer.set(tokens=3)
+    events.span_done("serve.pump.deliver", "serve", before, tokens=2)
+    after = time.perf_counter_ns()
+    prepare, step, deliver = _rows()
+    assert [r["name"] for r in (prepare, step, deliver)] == [
+        "serve.step.prepare", "serve.engine.step", "serve.pump.deliver"]
+    assert step["fields"]["k"] == 7 and step["fields"]["tokens"] == 3
+    assert step["fields"]["sid"] == outer.sid != inner.sid
+    assert prepare["fields"]["parent"] == outer.sid
+    assert step["fields"]["parent"] == 0 == deliver["fields"]["parent"]
+    assert _inside(prepare, step)
+    for r in (prepare, step, deliver):
+        f = r["fields"]
+        assert isinstance(f["t0_ns"], int) and isinstance(f["dur_ns"], int)
+        assert before <= f["t0_ns"] <= f["t0_ns"] + f["dur_ns"] <= after
+        assert r["dur"] == pytest.approx(f["dur_ns"] / 1e9)   # wall fields
+        assert abs(r["ts"] - time.time()) < 60                 # stay wall
+    assert deliver["fields"]["t0_ns"] == before
+
+
+def test_disabled_span_reads_no_clock_and_records_nothing():
+    events._enabled = False
+    with events.span("serve.engine.step", "serve", k=0) as sp:
+        sp.set(tokens=1)
+        with events.span("serve.step.prepare", "serve") as inner:
+            pass
+    events.span_done("serve.pump.deliver", "serve", 1, tokens=1)
+    assert sp.sid == sp.t0_ns == inner.t0_ns == 0
+    assert events._current_span.get() == 0
+    events._enabled = True
+    assert _rows() == []
+
+
+# ------------------------------------------------------- the engines' rows
+
+@pytest.mark.parametrize("kind", ["paged", "dense"])
+def test_step_rows_count_calls_and_tokens(kind, model):
+    _, calls, tokens = _drive(_engine(kind, model))
+    rows = _rows()
+    steps = [r for r in rows if r["name"] == "serve.engine.step"]
+    assert len(steps) == calls
+    assert [s["fields"]["k"] for s in steps] == list(range(calls))
+    assert sum(s["fields"]["tokens"] for s in steps) == tokens \
+        == sum(n for _, n in REQS.values())
+    assert sum(s["fields"]["admitted"] for s in steps) == len(REQS)
+    assert all(0 <= s["fields"]["active"] <= 2 for s in steps)
+    admits = [r for r in rows if r["name"] == "serve.engine.admit"]
+    assert [a["fields"]["rid"] for a in admits] == [r[:8] for r in REQS]
+    for a, (prompt, _) in zip(admits, REQS.values()):
+        f = a["fields"]
+        assert f["waited_ns"] >= 0 and f["prompt_len"] == len(prompt)
+        assert f["bucket"] == 16
+        parent = next(s for s in steps if s["fields"]["sid"] == f["parent"])
+        assert _inside(a, parent)
+    if kind == "dense":     # one vocabulary, the top-level spans only
+        assert {r["name"] for r in rows} == {"serve.engine.step",
+                                             "serve.engine.admit"}
+
+
+def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
+    _drive(_engine("paged", model))
+    rows = _rows()
+    admits = [r for r in rows if r["name"] == "serve.engine.admit"]
+    assert len(admits) == len(REQS)
+    for a in admits:
+        kids = [r for r in rows
+                if r["fields"].get("parent") == a["fields"]["sid"]]
+        assert [k["name"] for k in kids] == ADMIT_PHASES
+        assert all(k["fields"]["rid"] == a["fields"]["rid"] for k in kids)
+        assert all(_inside(k, a) for k in kids)
+        starts = [k["fields"]["t0_ns"] for k in kids]
+        ends = [k["fields"]["t0_ns"] + k["fields"]["dur_ns"] for k in kids]
+        assert starts == sorted(starts) and all(
+            e <= s for e, s in zip(ends, starts[1:]))
+        assert kids[1]["fields"]["pages"] == a["fields"]["own_pages"] >= 1
+        assert a["fields"]["shared_pages"] == 0
+    for s in (r for r in rows if r["name"] == "serve.engine.step"):
+        kids = [r["name"] for r in rows
+                if r["fields"].get("parent") == s["fields"]["sid"]
+                and r["name"] in STEP_PHASES]
+        # a step that decoded has the four phases, in order; one that only
+        # reaped has its prepare alone
+        assert kids == (STEP_PHASES if s["fields"]["active"]
+                        else STEP_PHASES[:1])
+        assert "free_pages" in s["fields"] and "preempted" in s["fields"]
+
+
+def test_preemption_is_counted_on_the_step_row(model):
+    cfg, params = model
+    eng = PagedEngine(params, cfg, max_slots=2, num_pages=6, page_size=4,
+                      max_len=32)
+    eng.submit("p1", [1, 2, 3], max_new_tokens=9)
+    eng.submit("p2", [4, 5, 6], max_new_tokens=9)
+    got = eng.run_to_completion()
+    assert len(got["p1"]) == len(got["p2"]) == 9
+    steps = [r for r in _rows() if r["name"] == "serve.engine.step"]
+    assert sum(s["fields"]["preempted"] for s in steps) >= 1
+    # a preempted request is admitted again: one admit row more each time
+    assert sum(s["fields"]["admitted"] for s in steps) == 2 + sum(
+        s["fields"]["preempted"] for s in steps)
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense"])
+def test_greedy_identical_with_recorder_on_and_off(kind, model):
+    on, _, _ = _drive(_engine(kind, model))
+    assert _rows()
+    events._enabled = False
+    off, _, _ = _drive(_engine(kind, model))
+    events._enabled = True
+    assert _rows() == []        # off yields no rows
+    assert on == off
+
+
+def test_spans_are_profiler_annotations_under_a_trace(model, tmp_path):
+    from jax.profiler import ProfileData
+
+    eng = _engine("paged", model)
+    with jax.profiler.trace(str(tmp_path)):
+        _drive(eng)
+    path = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("ray_tpu/")}
+    assert "ray_tpu/serve.engine.step" in names
+    assert {"ray_tpu/" + n for n in STEP_PHASES + ADMIT_PHASES} <= names
+    assert "ray_tpu/serve.engine.admit" in names
+
+
+# ------------------------------------------------------------ the pump
+
+def test_pump_and_request_rows_share_the_engine_clock(model):
+    from ray_tpu.serve.llm import LLMServer
+
+    cfg, params = model
+    server = LLMServer(lambda: (params, cfg), max_slots=2, max_len=64,
+                       kv_cache="paged", num_pages=24, page_size=8)
+
+    async def run():
+        async def stream():
+            return [t async for t in server._stream(
+                {"prompt": [5, 6, 7], "max_new_tokens": 4, "stream": True})]
+
+        return await asyncio.gather(
+            server({"prompt": [1, 2, 3, 4], "max_new_tokens": 5}), stream())
+
+    t_before = time.perf_counter_ns()
+    unary, streamed = asyncio.run(run())
+    assert unary["num_tokens"] == 5 and len(streamed) == 4
+    rows = _rows()
+    names = [r["name"] for r in rows]
+    assert not any(n.endswith(".tokens_done") for n in names)  # unread: gone
+    queued = [r for r in rows if r["name"] == "serve.req.queue"]
+    first = [r for r in rows if r["name"] == "serve.req.first_token"]
+    admits = [r for r in rows if r["name"] == "serve.engine.admit"]
+    assert len(queued) == len(first) == len(admits) == 2
+    # one identifier from the handler to the engine's phases
+    assert {r["fields"]["rid"] for r in queued} \
+        == {r["fields"]["rid"] for r in admits} \
+        == {r["fields"]["rid"] for r in first}
+    for q in queued:
+        a = next(r for r in admits
+                 if r["fields"]["rid"] == q["fields"]["rid"])
+        f = next(r for r in first
+                 if r["fields"]["rid"] == q["fields"]["rid"])
+        assert t_before <= f["fields"]["t0_ns"] <= q["fields"]["t0_ns"] \
+            <= a["fields"]["t0_ns"]
+        assert f["fields"]["t0_ns"] + f["fields"]["dur_ns"] \
+            >= a["fields"]["t0_ns"] + a["fields"]["dur_ns"]
+    delivers = [r for r in rows if r["name"] == "serve.pump.deliver"]
+    steps = [r for r in rows if r["name"] == "serve.engine.step"]
+    assert sum(d["fields"]["tokens"] for d in delivers) == 9 \
+        == sum(s["fields"]["tokens"] for s in steps)
+    for d in delivers:
+        assert d["fields"]["lock_wait_ns"] >= 0 and d["fields"]["dur_ns"] >= 0
+        # the hand-off starts where a step() ended (stamped just after it,
+        # in the executor thread)
+        assert any(0 <= d["fields"]["t0_ns"]
+                   - (s["fields"]["t0_ns"] + s["fields"]["dur_ns"]) < 5e7
+                   for s in steps)
+
+
+# ------------------------------------------------------------- the spill
+
+def _some_rows(n=3):
+    for i in range(n):
+        with events.span("serve.engine.step", "serve", k=i):
+            pass
+    events.emit("serve.req.queue", plane="serve", rid="ab", oid=b"\x01\x02")
+    rows, _ = events.drain()
+    return rows
+
+
+def test_spill_round_trip(tmp_path):
+    rows = _some_rows()
+    wrote = events.spill(rows, str(tmp_path), pid=4242)
+    path = events.spill_path(str(tmp_path), 4242)
+    assert path.endswith(os.path.join("logs", "events", "plane-4242.jsonl"))
+    assert wrote == os.path.getsize(path) > 0
+    back = events.read_spill(pid=4242, session_dir=str(tmp_path))
+    assert [r["name"] for r in back] == [r[1] for r in rows]
+    assert [r["fields"].get("k") for r in back] == [0, 1, 2, None]
+    assert back[0]["fields"]["t0_ns"] == rows[0][6]["t0_ns"]
+    assert back[0]["pid"] == 4242 and back[0]["plane"] == "serve"
+    assert back[3]["fields"]["oid"] == "0102"       # bytes leave as hex
+    # a second batch appends; another pid's file is another list
+    events.spill(_some_rows(1), str(tmp_path), pid=4242)
+    events.spill(_some_rows(1), str(tmp_path), pid=77)
+    assert len(events.read_spill(pid=4242, session_dir=str(tmp_path))) == 6
+    assert len(events.read_spill(pid=77, session_dir=str(tmp_path))) == 2
+    assert len(events.read_spill(session_dir=str(tmp_path))) == 8
+    assert events.read_spill(pid=5, session_dir=str(tmp_path)) == []
+    assert events.read_spill(session_dir=str(tmp_path / "nowhere")) == []
+    # a torn last line (the process was killed mid-write) is skipped
+    with open(path, "a") as f:
+        f.write('[1.0,"serve.engine.step","ser')
+    assert len(events.read_spill(pid=4242, session_dir=str(tmp_path))) == 6
+
+
+def test_spill_is_bounded_by_its_cap(tmp_path):
+    cap = events._spill_cap
+    events._spill_cap = 4096
+    try:
+        total = 0
+        for _ in range(40):
+            total += events.spill(_some_rows(4), str(tmp_path), pid=9)
+        folder = os.path.dirname(events.spill_path(str(tmp_path), 9))
+        on_disk = sum(os.path.getsize(os.path.join(folder, f))
+                      for f in os.listdir(folder))
+        one_batch = total // 40
+        assert total > 4 * 4096          # far more was written than is kept
+        assert on_disk <= 4096 + one_batch
+        # two segments at most: the newer, and the older it replaced
+        assert "plane-9.jsonl.1" in os.listdir(folder)
+        assert set(os.listdir(folder)) <= {"plane-9.jsonl",
+                                           "plane-9.jsonl.1"}
+        kept = events.read_spill(pid=9, session_dir=str(tmp_path))
+        assert 0 < len(kept) < 40 * 5
+        # what is kept is the newest, oldest first
+        stamps = [r["fields"]["t0_ns"] for r in kept if "t0_ns" in r["fields"]]
+        assert stamps == sorted(stamps)
+        events._spill_cap = 0            # 0 turns the file off
+        assert events.spill(_some_rows(1), str(tmp_path), pid=10) == 0
+        assert not os.path.exists(events.spill_path(str(tmp_path), 10))
+    finally:
+        events._spill_cap = cap
+
+
+@pytest.mark.parametrize("obstacle", ["read_only", "file_in_the_way"])
+def test_spill_raises_nothing_where_it_cannot_write(tmp_path, obstacle):
+    session = tmp_path / "session"
+    session.mkdir()
+    if obstacle == "read_only":
+        (session / "logs" / "events").mkdir(parents=True)
+        os.chmod(session / "logs" / "events", 0o555)
+        if os.access(session / "logs" / "events", os.W_OK):   # root
+            os.chmod(session / "logs" / "events", 0o755)
+            target = events.spill_path(str(session), 3)
+            os.mkdir(target)        # appending to a directory fails too
+    else:
+        (session / "logs").write_text("not a directory")
+    assert events.spill(_some_rows(), str(session), pid=3) == 0
+    assert events.read_spill(pid=3, session_dir=str(session)) == []
+    assert events.spill(_some_rows(), None) == 0      # no session known
+
+
+def test_worker_rows_reach_the_session_spill_file():
+    """A worker's flush tick appends what it pushes to the GCS; the driver
+    reads it back by pid, from its own session and after shutdown too."""
+    ray_tpu.init(num_cpus=2, probe_tpu=False)
+    try:
+        @ray_tpu.remote
+        def work():
+            from ray_tpu.util import events as ev
+
+            with ev.span("serve.engine.step", "serve", k=41):
+                pass
+            return os.getpid()
+
+        pid = ray_tpu.get(work.remote())
+        assert pid != os.getpid()
+        deadline = time.time() + 20
+        mine = []
+        while time.time() < deadline and not mine:
+            mine = [r for r in events.read_spill(pid=pid)
+                    if r["name"] == "serve.engine.step"]
+            time.sleep(0.1)
+        assert mine and mine[0]["fields"]["k"] == 41 and mine[0]["pid"] == pid
+        session_dir = ray_tpu._private.worker.global_worker().session_dir
+        assert os.path.isfile(events.spill_path(session_dir, pid))
+        # the same rows went to the GCS table, as before
+        from ray_tpu.util import state
+
+        deadline = time.time() + 20
+        while time.time() < deadline and not any(
+                e["name"] == "serve.engine.step"
+                for e in state.list_plane_events()):
+            time.sleep(0.1)
+        assert any(e["name"] == "serve.engine.step"
+                   and e["fields"]["k"] == 41
+                   for e in state.list_plane_events())
+    finally:
+        ray_tpu.shutdown()
+    again = events.read_spill(pid=pid)      # the last session, by default
+    assert any(r["fields"].get("k") == 41 for r in again)
+    with open(events.spill_path(session_dir, pid)) as f:
+        assert json.loads(f.readline())[2] in events.PLANES
+
+
+# ---------------------------------------------------------- the name gate
+
+def test_event_check_registers_span_names(tmp_path):
+    from ray_tpu.analysis.event_check import check_event_paths
+
+    pkg = tmp_path / "pkg"
+    pkg.mkdir()
+    (pkg / "emit.py").write_text(
+        "from ray_tpu.util import events\n"
+        "def f(t):\n"
+        "    with events.span('serve.engine.step', 'serve'):\n"
+        "        pass\n"
+        "    events.span_done('serve.pump.deliver', 'serve', t)\n")
+    ref = tmp_path / "ref"
+    ref.mkdir()
+    (ref / "test_x.py").write_text(
+        "A = 'serve.engine.step'\nB = 'serve.pump.deliver'\n"
+        "C = 'serve.engine.stepp'\n")
+    findings = check_event_paths([str(pkg)], [str(ref)])
+    assert [(f.line, "engine.stepp" in f.message)
+            for f in findings] == [(3, True)]
