@@ -33,11 +33,15 @@ from .engine import _pick_one, _pick_token, _prefill_one
 from .llama import LlamaConfig, _mlp_block
 
 
-def _quant_kv(vec):
-    """Per-head-vector symmetric int8: vec [..., d] -> (int8, scale)."""
+def _quant_kv(vec, qmax=127.0):
+    """Per-head-vector symmetric int8: vec [..., d] -> (int8, scale).
+    ``qmax`` is always 127; a caller may pass it as a traced operand.
+    Under jit XLA divides by the constant as a multiplication with its
+    reciprocal, by an operand as a division (what eager code does), and
+    the two scales can differ in their last bit."""
     amax = jnp.max(jnp.abs(vec.astype(jnp.float32)), axis=-1,
                    keepdims=True)
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+    scale = jnp.where(amax > 0, amax / qmax, 1.0)
     q = jnp.clip(jnp.round(vec.astype(jnp.float32) / scale),
                  -127, 127).astype(jnp.int8)
     return q, scale[..., 0].astype(jnp.float32)
@@ -131,6 +135,37 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
             splits[:, 0])
 
 
+@functools.partial(jax.jit, static_argnames=("page", "kv_int8"),
+                   donate_argnums=(0, 1, 2, 3))
+def _scatter_pages(pools_k, pools_v, scales_k, scales_v, seq_caches,
+                   page_ids, qmax, page, kv_int8):
+    """One admission's K/V into its pages of every layer's pool, in
+    place (the pools and scales are donated).
+
+    seq_caches: per-layer dense (k, v) of [P*page, kvh, d], as the
+    prefill programs return them; viewed as P rows of one page each.
+    page_ids: int32[P], the pool page each row lands in; a row the
+    request does not own (a shared prefix page, the padded tail) holds
+    an id past the pool and is dropped. Nothing static depends on the
+    prompt, so one program serves every admission. qmax: ``_quant_kv``'s
+    127 as an operand, which keeps the int8 pages and scales bit for
+    bit what the eager per-page loop before this program wrote.
+    """
+    new_k, new_v = [], []
+    new_sk, new_sv = ([], []) if kv_int8 else (scales_k, scales_v)
+    for li, (kc, vc) in enumerate(seq_caches):
+        rows_k = kc.reshape((-1, page) + kc.shape[1:])
+        rows_v = vc.reshape((-1, page) + vc.shape[1:])
+        if kv_int8:
+            rows_k, sk = _quant_kv(rows_k, qmax)
+            rows_v, sv = _quant_kv(rows_v, qmax)
+            new_sk.append(scales_k[li].at[page_ids].set(sk, mode="drop"))
+            new_sv.append(scales_v[li].at[page_ids].set(sv, mode="drop"))
+        new_k.append(pools_k[li].at[page_ids].set(
+            rows_k.astype(pools_k[li].dtype), mode="drop"))
+        new_v.append(pools_v[li].at[page_ids].set(
+            rows_v.astype(pools_v[li].dtype), mode="drop"))
+    return new_k, new_v, new_sk, new_sv
 
 
 @functools.partial(jax.jit,
@@ -402,7 +437,7 @@ class PagedEngine:
             self.tables[idx] = 0
             self.tables[idx, :len(slot.pages)] = slot.pages
             with plane_events.span("serve.admit.scatter", "serve",
-                                   rid=rid8, pages=need):
+                                   rid=rid8, pages=need, dispatches=1):
                 self._scatter(seq_caches, slot.pages, len(shared))
             self._register_prefix_pages(slot)
             with plane_events.span("serve.admit.sample", "serve",
@@ -460,27 +495,15 @@ class PagedEngine:
 
     def _scatter(self, seq_caches, pages: List[int], n_shared: int):
         """The computed K/V into the slot's OWN pages only (shared
-        prefix pages already hold their content)."""
-        for li, (kc, vc) in enumerate(seq_caches):
-            pk, pv = self.pools_k[li], self.pools_v[li]
-            for pi in range(n_shared, len(pages)):
-                lo = pi * self.page
-                pg = pages[pi]
-                ks = kc[lo:lo + self.page]
-                vs = vc[lo:lo + self.page]
-                if self.kv_int8:
-                    kq, ksc = _quant_kv(ks)
-                    vq, vsc = _quant_kv(vs)
-                    pk = pk.at[pg].set(kq)
-                    pv = pv.at[pg].set(vq)
-                    self.scales_k[li] = \
-                        self.scales_k[li].at[pg].set(ksc)
-                    self.scales_v[li] = \
-                        self.scales_v[li].at[pg].set(vsc)
-                else:
-                    pk = pk.at[pg].set(ks)
-                    pv = pv.at[pg].set(vs)
-            self.pools_k[li], self.pools_v[li] = pk, pv
+        prefix pages already hold their content): one dispatch of
+        ``_scatter_pages``, which consumes the pools it is given."""
+        page_ids = np.full(self.P, self.num_pages, dtype=np.int32)
+        page_ids[n_shared:len(pages)] = pages[n_shared:]
+        (self.pools_k, self.pools_v, self.scales_k,
+         self.scales_v) = _scatter_pages(
+            self.pools_k, self.pools_v, self.scales_k, self.scales_v,
+            seq_caches, page_ids, np.float32(127.0), self.page,
+            self.kv_int8)
 
     # ----------------------------------------------------------- step
     def step(self) -> List[tuple]:

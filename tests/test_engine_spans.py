@@ -163,6 +163,8 @@ def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
         assert starts == sorted(starts) and all(
             e <= s for e, s in zip(ends, starts[1:]))
         assert kids[1]["fields"]["pages"] == a["fields"]["own_pages"] >= 1
+        # one program per admission, however many pages (ISSUE 26)
+        assert kids[1]["fields"]["dispatches"] == 1
         assert a["fields"]["shared_pages"] == 0
     for s in (r for r in rows if r["name"] == "serve.engine.step"):
         kids = [r["name"] for r in rows
@@ -268,6 +270,32 @@ def test_pump_and_request_rows_share_the_engine_clock(model):
         assert any(0 <= d["fields"]["t0_ns"]
                    - (s["fields"]["t0_ns"] + s["fields"]["dur_ns"]) < 5e7
                    for s in steps)
+
+
+def test_one_admission_and_one_step_emit_all_ten_span_names(model):
+    """The names the benchmark's per-layer metrics read. Two new tokens:
+    the first comes from the admission, the second from the one step
+    that decodes (a single token would show no step phase)."""
+    from ray_tpu.serve.llm import LLMServer
+
+    cfg, params = model
+    server = LLMServer(lambda: (params, cfg), max_slots=2, max_len=64,
+                       kv_cache="paged", num_pages=24, page_size=8)
+    out = asyncio.run(server({"prompt": list(range(1, 20)),
+                              "max_new_tokens": 2}))
+    assert out["num_tokens"] == 2
+    rows = _rows()
+    assert {r["name"] for r in rows if r["name"].startswith(
+        ("serve.engine.", "serve.step.", "serve.admit.", "serve.pump."))} \
+        == {"serve.engine.step", "serve.engine.admit", "serve.pump.deliver",
+            *STEP_PHASES, *ADMIT_PHASES}
+    admit, = [r for r in rows if r["name"] == "serve.engine.admit"]
+    scatter, = [r for r in rows if r["name"] == "serve.admit.scatter"]
+    assert scatter["fields"]["parent"] == admit["fields"]["sid"]
+    assert scatter["fields"]["rid"] == admit["fields"]["rid"]
+    assert scatter["fields"]["dispatches"] == 1
+    assert scatter["fields"]["pages"] == admit["fields"]["own_pages"] == 3
+    assert sum(r["name"] == "serve.step.dispatch" for r in rows) == 1
 
 
 # ------------------------------------------------------------- the spill

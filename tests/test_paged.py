@@ -2,12 +2,15 @@
 allocation, parity with the dense-slot engine and with per-request
 greedy decode."""
 
+import warnings
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.models import LlamaConfig, generate_greedy, init_params
-from ray_tpu.models.paged import PagedEngine
+from ray_tpu.models.paged import PagedEngine, _quant_kv, _scatter_pages
 
 
 @pytest.fixture(scope="module")
@@ -162,3 +165,217 @@ def test_int8_kv_with_prefix_cache(model):
     # cold run exactly (same quantized pages, same math)
     assert got_a == got_b
     assert eng.prefix_hits == 1
+
+
+# ------------------------------------------- one jitted scatter (ISSUE 26)
+
+def _loop_scatter(eng, seq_caches, pages, n_shared):
+    """The plain reference: the per-layer, per-page eager loop that
+    ``PagedEngine._scatter`` was before it became one program."""
+    for li, (kc, vc) in enumerate(seq_caches):
+        pk, pv = eng.pools_k[li], eng.pools_v[li]
+        for pi in range(n_shared, len(pages)):
+            lo = pi * eng.page
+            pg = pages[pi]
+            ks = kc[lo:lo + eng.page]
+            vs = vc[lo:lo + eng.page]
+            if eng.kv_int8:
+                kq, ksc = _quant_kv(ks)
+                vq, vsc = _quant_kv(vs)
+                pk = pk.at[pg].set(kq)
+                pv = pv.at[pg].set(vq)
+                eng.scales_k[li] = eng.scales_k[li].at[pg].set(ksc)
+                eng.scales_v[li] = eng.scales_v[li].at[pg].set(vsc)
+            else:
+                pk = pk.at[pg].set(ks)
+                pv = pv.at[pg].set(vs)
+        eng.pools_k[li], eng.pools_v[li] = pk, pv
+
+
+class _LoopEngine(PagedEngine):
+    """The engine with the reference scatter in place of the program."""
+
+    def _scatter(self, seq_caches, pages, n_shared):
+        _loop_scatter(self, seq_caches, pages, n_shared)
+
+
+@pytest.fixture(scope="module")
+def bf16_model():
+    cfg = LlamaConfig(vocab_size=96, d_model=64, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=128, max_seq_len=128,
+                      dtype=jnp.bfloat16)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _pool_state(eng):
+    """Every pool and scale of the engine as host arrays."""
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(
+        (eng.pools_k, eng.pools_v, eng.scales_k, eng.scales_v))]
+
+
+def _same(a, b):
+    return len(a) == len(b) and all(
+        x.dtype == y.dtype and np.array_equal(x, y) for x, y in zip(a, b))
+
+
+def _noise_pools(eng, seed):
+    """Fill pools and scales with seeded noise, so a page the scatter
+    must leave alone shows if it did not."""
+    rng = np.random.default_rng(seed)
+
+    def noise(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape, np.int8))
+        return jnp.asarray(rng.standard_normal(a.shape, np.float32)
+                           ).astype(a.dtype)
+
+    (eng.pools_k, eng.pools_v, eng.scales_k,
+     eng.scales_v) = jax.tree_util.tree_map(
+        noise, (eng.pools_k, eng.pools_v, eng.scales_k, eng.scales_v))
+
+
+# page 8, max_len 64: mid-page, on a page edge (n + 1 takes a further,
+# all-padding page), and the longest prompt a table holds
+@pytest.mark.parametrize("n_prompt", [13, 16, 63])
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_scatter_program_equals_page_loop(bf16_model, kv_dtype, n_prompt):
+    cfg, params = bf16_model
+    kw = dict(max_slots=2, num_pages=24, page_size=8, max_len=64,
+              kv_dtype=kv_dtype)
+    eng, ref = PagedEngine(params, cfg, **kw), _LoopEngine(params, cfg, **kw)
+    _noise_pools(eng, 5)
+    _noise_pools(ref, 5)
+    before = _pool_state(ref)
+    prompt = [(7 * i + 3) % cfg.vocab_size for i in range(n_prompt)]
+    for e in (eng, ref):
+        e.submit("r", prompt, max_new_tokens=0)
+        e._admit()
+    assert eng.slots[0].pages == ref.slots[0].pages
+    assert len(eng.slots[0].pages) == -(-(n_prompt + 1) // 8)
+    assert _same(_pool_state(eng), _pool_state(ref))
+    assert not _same(_pool_state(ref), before)
+    assert eng.pools_k[0].dtype == (jnp.int8 if kv_dtype == "int8"
+                                    else jnp.bfloat16)
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_scatter_leaves_shared_and_foreign_pages_alone(bf16_model, kv_dtype):
+    cfg, params = bf16_model
+    kw = dict(max_slots=3, num_pages=32, page_size=4, max_len=64,
+              kv_dtype=kv_dtype, enable_prefix_cache=True)
+    eng, ref = PagedEngine(params, cfg, **kw), _LoopEngine(params, cfg, **kw)
+    prefix = list(range(50, 58))                 # two full pages
+    for e in (eng, ref):
+        _noise_pools(e, 9)
+        e.submit("x", prefix + [1, 2], max_new_tokens=12)
+        e.submit("z", [9, 8, 7], max_new_tokens=12)
+        for _ in range(3):
+            e.step()
+    before = _pool_state(eng)
+    for e in (eng, ref):
+        e.submit("y", prefix + [3], max_new_tokens=5)
+        e._admit()
+    slot = next(s for s in eng.slots if s and s.request_id == "y")
+    assert slot.n_shared == 2 and eng.prefix_hits == 1
+    x = next(s for s in eng.slots if s and s.request_id == "x")
+    assert slot.pages[:2] == x.pages[:2]
+    own = slot.pages[2:]
+    assert own and not set(own) & set(x.pages)
+    after = _pool_state(eng)
+    others = [p for p in range(32) if p not in own]
+    for a, b in zip(before, after):
+        assert np.array_equal(a[others], b[others])     # page axis first
+        assert not np.array_equal(a[own], b[own])
+    assert _same(after, _pool_state(ref))
+    got, want = eng.run_to_completion(), ref.run_to_completion()
+    assert got == want
+    assert got["y"] == _ref(params, cfg, prefix + [3], 5) \
+        or kv_dtype == "int8"
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"kv_dtype": "int8"}, {"enable_prefix_cache": True},
+    {"kv_dtype": "int8", "enable_prefix_cache": True}],
+    ids=["model", "int8", "model-prefix", "int8-prefix"])
+def test_mixed_batch_greedy_identical_to_loop_engine(bf16_model, kw):
+    """Admissions between decode steps, a pool so small that one request
+    is preempted and resumed: token for token the reference engine's."""
+    cfg, params = bf16_model
+    late = {2: ("c", [5, 6, 7, 8, 9, 10, 11, 12, 13], 6),
+            5: ("d", [1, 2, 3, 4, 20], 4)}
+    outs = []
+    for cls in (PagedEngine, _LoopEngine):
+        eng = cls(params, cfg, max_slots=2, num_pages=7, page_size=4,
+                  max_len=32, **kw)
+        eng.submit("a", [1, 2, 3, 4, 5], max_new_tokens=11)
+        eng.submit("b", [1, 2, 3, 4, 6], max_new_tokens=11)
+        acc, k, preempted = {}, 0, 0
+        while eng.has_work() or k <= max(late):
+            if k in late:
+                rid, prompt, n = late[k]
+                eng.submit(rid, prompt, max_new_tokens=n)
+            for rid, tok in eng.step():
+                if tok is not None:
+                    acc.setdefault(rid, []).append(tok)
+            preempted += eng._preempted
+            k += 1
+        assert preempted >= 1
+        assert {r: len(t) for r, t in acc.items()} == {
+            "a": 11, "b": 11, "c": 6, "d": 4}
+        outs.append(acc)
+    assert outs[0] == outs[1]
+
+
+def test_scatter_compiles_once_whatever_the_prompt(model):
+    """Prompts of 1, 17, 255 and 700 tokens (four prefill buckets, 1 to
+    44 pages), with none and with two shared prefix pages, decode steps
+    between them: ONE entry in the program's cache."""
+    cfg, params = model
+    eng = PagedEngine(params, cfg, max_slots=2, num_pages=131,
+                      page_size=16, max_len=1024,
+                      enable_prefix_cache=True)
+    seen, scatter = [], eng._scatter
+    eng._scatter = lambda caches, pages, n_shared: seen.append(
+        (len(pages), n_shared)) or scatter(caches, pages, n_shared)
+    base = _scatter_pages._cache_size()
+    head = [(3 * i + 1) % cfg.vocab_size for i in range(32)]   # two pages
+    for i, n in enumerate([1, 17, 255, 700, 40, 700]):
+        prompt = (head + [(5 * j + i) % cfg.vocab_size
+                          for j in range(n)])[:n]
+        eng.submit(f"r{i}", prompt, max_new_tokens=3)
+        eng.step()          # admits it, and decodes one token
+        assert _scatter_pages._cache_size() == base + 1, (i, n)
+        eng.run_to_completion()
+    assert [p for p, _ in seen] == [1, 2, 16, 44, 3, 44]
+    assert [s for _, s in seen] == [0, 0, 1, 2, 2, 2]
+    assert _scatter_pages._cache_size() == base + 1
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_scatter_donates_the_pools_and_keeps_no_handle(model, kv_dtype):
+    cfg, params = model
+    eng = PagedEngine(params, cfg, max_slots=2, num_pages=24,
+                      page_size=8, max_len=64, kv_dtype=kv_dtype,
+                      enable_prefix_cache=True)
+    prefix = list(range(1, 17))
+    eng.submit("a", prefix + [20], max_new_tokens=3)
+    eng.step()
+    eng.submit("b", prefix + [30], max_new_tokens=3)  # gathers its prefix
+    given = jax.tree_util.tree_leaves(
+        (eng.pools_k, eng.pools_v, eng.scales_k, eng.scales_v))
+    assert len(given) == (8 if kv_dtype == "int8" else 4)
+    with warnings.catch_warnings():
+        # a backend that cannot donate says so and copies: still correct
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        eng._admit()
+    assert eng.prefix_hits == 1
+    held = {id(x) for x in jax.tree_util.tree_leaves(
+        [v for v in vars(eng).values()
+         if isinstance(v, (list, tuple, dict, jax.Array))])}
+    assert not held & {id(a) for a in given}
+    if jax.default_backend() in ("cpu", "tpu"):  # these donate
+        assert all(a.is_deleted() for a in given)
+    got = eng.run_to_completion()
+    assert got["b"] == _ref(params, cfg, prefix + [30], 3) \
+        or kv_dtype == "int8"

@@ -115,6 +115,30 @@ def test_paged_step_llama3_1b_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_scatter_pages_writes_the_pools_in_place(one_chip, kv_int8):
+    """The admission's one scatter at the benchmark's widths (16 layers of
+    2048 pages x 16 x 8 x 128): every pool and scale comes back aliased to
+    the donated argument, so an admission holds no second copy of them."""
+    from ray_tpu.models.paged import _scatter_pages
+
+    L, pages, page, kvh, d, max_len = 16, 2048, 16, 8, 128, 2048
+    pool = _shape(one_chip, (pages, page, kvh, d),
+                  jnp.int8 if kv_int8 else jnp.bfloat16)
+    scale = _shape(one_chip, (pages, page, kvh), jnp.float32) \
+        if kv_int8 else None
+    dense = _shape(one_chip, (max_len, kvh, d))
+    compiled = _scatter_pages.lower(
+        [pool] * L, [pool] * L, [scale] * L, [scale] * L,
+        [(dense, dense)] * L, _shape(one_chip, (max_len // page,), jnp.int32),
+        _shape(one_chip, (), jnp.float32), page=page,
+        kv_int8=kv_int8).compile()
+    m = compiled.memory_analysis()
+    held = 2 * L * pages * page * kvh * (d + 4 if kv_int8 else 2 * d)
+    assert held <= m.alias_size_in_bytes <= m.output_size_in_bytes < held + 4096
+    assert m.temp_size_in_bytes < 0.1e9
+
+
 def test_train_step_llama3_1b_widths_takes_flash(one_chip, monkeypatch):
     """One AdamW step at 2x2048, depth 2. ``flash_attention`` asks jax for
     the platform, which here is the CPU: the test answers for the chip the
