@@ -17,9 +17,14 @@ SETUP = {}
 
 def shape_of(config: dict, rehearse: bool) -> dict:
     """The published keys as they are run: the file's, or — rehearsing on
-    the CPU — the file's toy widths with the same ratios."""
+    the CPU — the file's toy widths with the same ratios. Every top-level
+    number, bool and null travels, and besides them whatever
+    ``program.config_kwargs`` names, of any JSON type (a layer pattern is a
+    string, a list of layer kinds a list): a key the config class is built
+    from reaches it, and the reference, without an edit here."""
+    named = set((config.get("program") or {}).get("config_kwargs", {}).values())
     shape = {k: v for k, v in config.items()
-             if isinstance(v, (int, float, bool)) or v is None}
+             if k in named or isinstance(v, (int, float, bool)) or v is None}
     if rehearse:
         shape.update(config["rehearsal"]["shape"])
     return shape

@@ -3,8 +3,13 @@
 The subclass changes no behaviour. It times ``engine.step()`` and
 ``engine._admit()`` by wrapping the engine's bound methods (only while a
 traced run asks for spans), counts compilations, starts and stops
-``jax.profiler`` on an admin op, and runs the reference check where the
-weights and the chip are.
+``jax.profiler`` on an admin op, and calls the configuration's reference
+check where the weights and the chip are.
+
+What it reads of the engine object, which a later family inside
+``PagedEngine`` keeps until the wrapper spans are retired: ``step``,
+``_admit``, ``has_work``, ``pending`` rows as ``(rid, prompt, ...)``,
+``slots[i].length``, ``_prefill_buckets``, ``max_len``, ``S``.
 """
 
 from __future__ import annotations
@@ -164,41 +169,13 @@ class BenchLLMServer(LLMServer):
         return out
 
     def _bench_reference(self, body):
-        """One seeded request through THIS engine's own programs, against
-        the plain reference's full forward: the prompt's last-position
-        logits row for row, and every emitted token's margin under the
-        reference's best logit at its position."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.models.engine import _prefill_one
-
+        """The configuration's own reference check (``program.reference_check``
+        in its file; the contract is ``perfbench/reference/__init__.py``'s),
+        run where the weights and the chip are, with the engine held still."""
         from perfbench.manifest import resolve
 
-        prompt, emitted = body["prompt"], body["tokens"]
-        eng = self.engine
+        config = self._bench["config"]
+        check = resolve(config["program"]["reference_check"])
         with self._engine_lock:
-            params = eng.params
-        pad = next(b for b in list(eng._prefill_buckets) + [eng.max_len]
-                   if b >= len(prompt))
-        padded = jnp.asarray(prompt + [0] * (pad - len(prompt)), jnp.int32)
-        first, _ = _prefill_one(params, padded, len(prompt), eng.max_len,
-                                eng.cfg, eng.cos, eng.sin, pad)
-        ref = resolve(self._bench["config"]["program"]["reference"])
-        to_ref = resolve(self._bench["config"]["program"]["reference_weights"])
-        seq = list(prompt) + list(emitted)
-        rows = np.asarray(ref(to_ref(params), seq, program.SETUP["shape"]))
-        engine_row = np.asarray(first.astype(jnp.float32))
-        ref_row = rows[len(prompt) - 1]
-        picked = rows[np.arange(len(prompt) - 1, len(seq) - 1),
-                      np.asarray(emitted)]
-        best = rows[len(prompt) - 1:len(seq) - 1].max(axis=-1)
-        jax.block_until_ready(first)
-        return {"prefill_max_abs_err": float(np.abs(engine_row - ref_row).max()),
-                "ref_logit_std": float(ref_row.std()),
-                "max_margin": float((best - picked).max()),
-                "exact_argmax": int((best == picked).sum()),
-                "tokens": len(emitted),
-                "finite": bool(np.isfinite(rows).all()
-                               and np.isfinite(engine_row).all())}
+            return check(self.engine, body["prompt"], body["tokens"], config,
+                         program.SETUP["shape"])

@@ -11,6 +11,28 @@ def say(msg: str):
     print(f"[perfbench {time.strftime('%H:%M:%S')}] {msg}", flush=True)
 
 
+def say_compared(lines):
+    """Each number that decided ``correct`` beside its limit, as the run's
+    last lines on standard error too (the standard output has them
+    earlier): what a record of a run that was not correct keeps."""
+    for line in lines:
+        print(f"perfbench: compared: {line}", file=sys.stderr, flush=True)
+
+
+def every_listed_metric(man, cell: str, metrics: dict):
+    """A traced line of the chip carries every per-layer metric the manifest
+    lists for its cell, or the run ends here with another code than 0 and no
+    result line: the driver refuses a line that lacks one, so a reader that
+    came up empty (a traced stretch that held no admission, say) is said
+    aloud, by name, instead."""
+    lacking = [m["name"] for m in man.metrics_for(cell, "per_layer")
+               if m["name"] not in metrics]
+    if lacking:
+        raise SystemExit(
+            f"perfbench: {cell}: the traced run has nothing to read for "
+            f"{lacking}, which BENCHMARK.json lists for this cell: no result")
+
+
 class NoChip(SystemExit):
     """The measuring path found no accelerator: exit non-zero, no result."""
 

@@ -8,6 +8,7 @@ only process that can trace it.
 from __future__ import annotations
 
 import asyncio
+import math
 import os
 import sys
 import time
@@ -15,26 +16,14 @@ import time
 from perfbench import stats, traffic as tg
 from perfbench.manifest import Manifest, layer_values
 from perfbench.program import Factory, section, shape_of
-from perfbench.runners.common import (check_device, device_line, say,
+from perfbench.reference import REF_NEW, REF_PROMPT
+from perfbench.runners.common import (check_device, device_line,
+                                      every_listed_metric, say, say_compared,
                                       start_cluster, stop_cluster,
                                       trace_sample_path)
 
 now = time.perf_counter
 TRACE_SECONDS = 6.0
-#: the reference check: one prompt in the 256 bucket, decoded through the cache
-REF_PROMPT, REF_NEW = 200, 24
-#: worst |engine - reference| over the 32768 logits of the prompt's last
-#: position, in units of that row's standard deviation; and how far under the
-#: reference's best logit an emitted token's reference logit may sit, in the
-#: same units. bf16 keeps 8 bits: each product rounds by up to 2**-9 relative
-#: and 16 layers of residual sums carry those roundings into logits of
-#: standard deviation about 1. On the chip that measured 0.056 sigma for the
-#: worst of 32768 logits (about 4.3 standard deviations of a per-logit error
-#: near 0.013) and 0.037 sigma for the worst margin (my chip run, PR 23). A
-#: path with 3-4 fewer mantissa bits (int8 weights or cache, fp8) errs 8-16
-#: times as much and lands far outside; fp16 or better lands inside.
-REF_ROW_TOL_SIGMA = 0.15
-REF_MARGIN_TOL_SIGMA = 0.10
 
 
 class ServeSession:
@@ -93,7 +82,7 @@ class ServeSession:
         page slices compile per page offset, so the longest covers the
         rest), each decoded a few steps."""
         t0 = now()
-        lo, hi = mix["prompt_len"]["min"], mix["prompt_len"]["max"]
+        lo, hi = tg.length_range(mix["prompt_len"])
         lengths, floor = [], 0
         for b in self.info["prefill_buckets"]:
             top = min(b, hi)
@@ -110,19 +99,28 @@ class ServeSession:
             f"{now() - t0:.1f}s")
 
     def reference_check(self) -> bool:
+        """One seeded request through the engine, then the configuration's
+        own check of it (``perfbench/reference/__init__.py`` has the
+        contract). The runner's own conditions: every token asked for was
+        emitted, everything is finite, no reading is over its limit."""
         t0 = now()
         prompt = tg.prompt_tokens(self.seed, 10**6 + 99, REF_PROMPT, self.vocab)
         out = self.stream_all([{"prompt": prompt, "max_new_tokens": REF_NEW}])[0]
         r = self.admin("bench_reference", prompt=prompt, tokens=out)
-        sigma = r["ref_logit_std"]
-        ok = (r["finite"] and len(out) == REF_NEW
-              and r["prefill_max_abs_err"] <= REF_ROW_TOL_SIGMA * sigma
-              and r["max_margin"] <= REF_MARGIN_TOL_SIGMA * sigma)
-        say(f"reference check ({now() - t0:.1f}s): prefill row max|err| "
-            f"{r['prefill_max_abs_err']:.5f} and worst emitted-token margin "
-            f"{r['max_margin']:.5f} against {REF_ROW_TOL_SIGMA} and "
-            f"{REF_MARGIN_TOL_SIGMA} x sigma {sigma:.4f}; {r['exact_argmax']}/{r['tokens']} exactly the "
-            f"reference's argmax -> {'ok' if ok else 'FAILED'}")
+        within = all(math.isfinite(x["value"]) and x["value"] <= x["limit"]
+                     for x in r["readings"])
+        ok = bool(r["ok"] and r["finite"] and within and len(out) == REF_NEW)
+        self.compared = [
+            f"{x['name']} {x['value']:.5f} (limit {x['limit']:.5f})"
+            for x in r["readings"]] + [
+            f"tokens emitted {len(out)} (must be {REF_NEW})",
+            f"finite {r['finite']}"]
+        notes = "; ".join(f"{k} {v:.4f}" if isinstance(v, float)
+                          else f"{k} {v}" for k, v in
+                          (r.get("notes") or {}).items())
+        say(f"reference check ({now() - t0:.1f}s): "
+            + "; ".join(self.compared) + (f"; {notes}" if notes else "")
+            + f" -> {'ok' if ok else 'FAILED'}")
         self.reference = r
         return ok
 
@@ -163,19 +161,30 @@ async def _stream(sess: ServeSession, s: Sent, prompt):
         s.error = repr(e)
 
 
-async def _trace_mid_window(sess, t_open, seconds, trace, ctl):
-    """Trace the middle of the window; ``ctl`` takes the two spans of the
+async def _trace_in_window(sess, t_open, seconds, trace, ctl, dues=None):
+    """Trace a stretch of the window; ``ctl`` takes the two spans of the
     client's clock in which the profiler was started and stopped (the
-    replica stands still in them)."""
+    replica stands still in them). A closed loop (no ``dues``) admits all
+    through its window and is traced in the middle. An open loop's stretch
+    is planned from its schedule (``traffic.trace_start``) so that it holds
+    admissions: the chat cell's trace readers need one, and a line without
+    them is refused (``every_listed_metric``)."""
     if not trace:
         return
     length = min(TRACE_SECONDS, seconds / 3.0)
-    await asyncio.sleep(max(0.0, t_open + (seconds - length) / 2 - now()))
-    for op, pause in (("bench_trace_start", length), ("bench_trace_stop", 0)):
-        t0 = now()
-        await sess.admin_async(op)
-        ctl.append((t0, now()))
-        await asyncio.sleep(pause)
+    start = ((seconds - length) / 2 if dues is None
+             else tg.trace_start(dues, seconds, length))
+    await asyncio.sleep(max(0.0, t_open + start - now()))
+    t0 = now()
+    await sess.admin_async("bench_trace_start")
+    t_begun = now()
+    ctl.append((t0, t_begun))
+    say(f"trace from {t_begun - t_open:.2f}s into the window (its middle: "
+        f"{(seconds - length) / 2:.2f}s) for {length:.1f}s")
+    await asyncio.sleep(length)
+    t0 = now()
+    await sess.admin_async("bench_trace_stop")
+    ctl.append((t0, now()))
 
 
 async def open_loop(sess: ServeSession, mix: dict, seconds: float, seed: int,
@@ -193,8 +202,9 @@ async def open_loop(sess: ServeSession, mix: dict, seconds: float, seed: int,
 
     tasks = [asyncio.ensure_future(one(s, p)) for s, p in zip(sent, prompts)]
     ctl = []
-    tracer = asyncio.ensure_future(
-        _trace_mid_window(sess, t_open, seconds, trace, ctl))
+    tracer = asyncio.ensure_future(_trace_in_window(
+        sess, t_open, seconds, trace, ctl,
+        dues=[r.due_s for r in reqs if r.measured]))
     await asyncio.sleep(max(0.0, t_open - now()))
     backlog0 = sum(1 for s in sent if s.due < t_open and not s.times)
     c0 = (await sess.admin_async("bench_info"))["compiles"]
@@ -246,7 +256,7 @@ async def closed_loop(sess: ServeSession, mix: dict, seconds: float,
     t_close = state["close"] = t_open + seconds
     ctl = []
     tracer = asyncio.ensure_future(
-        _trace_mid_window(sess, t_open, seconds, trace, ctl))
+        _trace_in_window(sess, t_open, seconds, trace, ctl))
     c0 = (await sess.admin_async("bench_info"))["compiles"]
     await asyncio.sleep(max(0.0, t_close - now()))
     c1 = (await sess.admin_async("bench_info"))["compiles"]
@@ -272,6 +282,7 @@ def summarise(sess: ServeSession, mix: dict, run: dict) -> dict:
         not (isinstance(t, int) and 0 <= t < sess.vocab) for t in s.tokens)]
     ttft = [(s.times[0] - s.due) * 1e3 for s in judged
             if s.times and mix["loop"] == "open"]
+    served = [s for s in judged if s.times]
     streams = [s.times for s in sent]
     gaps = [g * 1e3 for g in stats.pooled_gaps(streams, t_open, t_close)]
     out_tokens = stats.tokens_in_window(streams, t_open, t_close)
@@ -280,6 +291,8 @@ def summarise(sess: ServeSession, mix: dict, run: dict) -> dict:
         if mix["loop"] == "open" else [0.0]
     return {"attempted": len(judged), "failed": len(failed),
             "bad_tokens": len(partial_bad), "ttft_ms": ttft, "gaps_ms": gaps,
+            "latency_ms": [(s.times[-1] - s.due) * 1e3 for s in served],
+            "tokens_got": sum(len(s.times) for s in served),
             "out_tokens": out_tokens, "seconds": t_close - t_open,
             "late_max_ms": max(late, default=0.0) * 1e3,
             "errors": [s.error for s in sent if s.error][:3]}
@@ -293,6 +306,13 @@ def end_to_end(summary: dict) -> dict:
         out["ttft_p95_ms"] = stats.percentile(summary["ttft_ms"], 95)
     if summary["gaps_ms"]:
         out["itl_p99_ms"] = stats.percentile(summary["gaps_ms"], 99)
+    if summary["tokens_got"]:
+        # all the time the judged requests' clients waited, due instant to
+        # last token (queueing, admission, first token and every gap), over
+        # all the tokens they got: Orca's and vLLM's normalised latency,
+        # weighted by tokens so that it is one rate over the whole window
+        out["latency_ms_per_out_token"] = (sum(summary["latency_ms"])
+                                           / summary["tokens_got"])
     out["out_tokens_per_s"] = summary["out_tokens"] / summary["seconds"]
     return out
 
@@ -326,10 +346,13 @@ def run(man: Manifest, cell: dict, args, t_start: float) -> dict:
     if s["ttft_ms"]:
         say(f"ttft ms: n {len(s['ttft_ms'])}, median "
             f"{stats.median(s['ttft_ms']):.1f}, p95 "
-            f"{stats.percentile(s['ttft_ms'], 95):.1f}")
+            f"{stats.percentile(s['ttft_ms'], 95):.1f}; due instant to "
+            f"last token, summed: {sum(s['latency_ms']) / 1e3:.3f}s for "
+            f"{s['tokens_got']} tokens")
     if s["gaps_ms"]:
         say(f"gap ms: n {len(s['gaps_ms'])}, median "
-            f"{stats.median(s['gaps_ms']):.2f}, p99 "
+            f"{stats.median(s['gaps_ms']):.2f}, mean "
+            f"{sum(s['gaps_ms']) / len(s['gaps_ms']):.2f}, p99 "
             f"{stats.percentile(s['gaps_ms'], 99):.1f}; output tokens in "
             f"window {s['out_tokens']}")
     if run_["trace_ctl"]:
@@ -339,6 +362,12 @@ def run(man: Manifest, cell: dict, args, t_start: float) -> dict:
         say(f"errors: {s['errors']}")
     correct = (ref_ok and s["failed"] == 0 and s["bad_tokens"] == 0
                and run_["unfinished"] == 0 and run_["compiles_in_window"] == 0)
+    say_compared(sess.compared + [
+        f"{name} {value} (must be 0)" for name, value in (
+            ("failed requests", s["failed"]),
+            ("requests with a bad token", s["bad_tokens"]),
+            ("unfinished at the drain limit", run_["unfinished"]),
+            ("compilations inside the window", run_["compiles_in_window"]))])
     e2e = {**end_to_end(s), "setup_s": setup_s}
     red = collected.get("trace")
     ctx = {"cell": cell, "config": sess.config, "shape": sess.shape,
@@ -361,6 +390,8 @@ def run(man: Manifest, cell: dict, args, t_start: float) -> dict:
 
         ctx["host"] = serve_spans.align(collected, red)
         line["metrics"] = layer_values(man, cell["name"], ctx)
+        if not rehearse:
+            every_listed_metric(man, cell["name"], line["metrics"])
         if red and red.get("busy_s"):
             line["breakdown"] = serve_spans.breakdown(ctx)
     else:

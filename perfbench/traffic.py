@@ -44,6 +44,13 @@ def quantile_lengths(dist: dict, n: int) -> List[int]:
     return out
 
 
+def length_range(dist: dict):
+    """(shortest, longest) a length distribution can give."""
+    if dist["dist"] == "fixed":
+        return int(dist["value"]), int(dist["value"])
+    return int(dist["min"]), int(dist["max"])
+
+
 def quantile_gaps(n: int, total_s: float) -> List[float]:
     """n evenly spaced quantiles of the exponential distribution, scaled to
     sum to ``total_s``: a Poisson process's gaps (CV about 1) with no luck
@@ -99,6 +106,38 @@ def open_loop_schedule(mix: dict, seconds: float, seed: int) -> List[Request]:
     for j, k in enumerate(order):
         reqs.append(Request(len(reqs), dues[j], prompts[k], outputs[k], True))
     return reqs
+
+
+#: a traced stretch holds TRACE_NEED requests due no less than TRACE_AFTER_S
+#: past its start (the profiler's start call, a step in flight) and
+#: TRACE_BEFORE_S ahead of its end (queueing, then a 20-120 ms admission)
+TRACE_NEED, TRACE_AFTER_S, TRACE_BEFORE_S = 2, 0.5, 1.5
+
+
+def trace_start(dues, seconds: float, length: float) -> float:
+    """Where in an open loop's window (seconds from its opening) a traced
+    stretch of ``length`` seconds begins: wholly inside the window, and as
+    near its middle as leaves ``TRACE_NEED`` requests due no less than
+    ``TRACE_AFTER_S`` past its start and ``TRACE_BEFORE_S`` ahead of its
+    end. An admission begins within a step or two of its due instant, so
+    such a stretch holds the start, the prefill and the scatter of one:
+    the readers that need an admission inside the trace find it whatever
+    the seed. The schedule is known before the window opens, so this is
+    planned, not searched for on the chip. Where no start holds that many
+    (a short trial window), the start that holds most, nearest the middle."""
+    room = max(0.0, seconds - length)
+    mid, grid = room / 2.0, 0.05
+    starts = sorted({mid, *(i * grid for i in range(int(room / grid) + 1))},
+                    key=lambda s: (abs(s - mid), s))
+
+    def held(s):
+        return sum(1 for d in dues if s + TRACE_AFTER_S <= d
+                   <= s + length - TRACE_BEFORE_S)
+
+    for s in starts:
+        if held(s) >= TRACE_NEED:
+            return s
+    return max(starts, key=held)   # the first of the fullest: nearest mid
 
 
 def closed_loop_requests(mix: dict) -> List[Request]:

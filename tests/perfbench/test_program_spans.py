@@ -134,12 +134,13 @@ def session(tmp_path):
 
 def test_the_new_entries_are_the_nine_and_only_appended():
     names = [m["name"] for m in MAN.doc["per_layer"]]
-    assert names[-len(EXPECTED):] == list(EXPECTED)
+    at = names.index(list(EXPECTED)[0])   # later PRs append after them
+    assert names[at:at + len(EXPECTED)] == list(EXPECTED)
     by_name = {m["name"]: m for m in MAN.doc["per_layer"]}
     chat, decode = ["serve-chat-steady"], ["serve-decode-heavy"]
     for name in list(EXPECTED)[:6]:
         assert by_name[name]["workloads"] == chat
-        assert by_name[name]["moves"] == "ttft_p95_ms"
+        assert by_name[name]["moves"] == "latency_ms_per_out_token"
     for name in list(EXPECTED)[6:]:
         assert by_name[name]["workloads"] == decode
     assert by_name["pump_handoff_ms"]["layer"] == "replica pump"
