@@ -20,7 +20,7 @@ from __future__ import annotations
 import functools
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -30,21 +30,10 @@ from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..util import events as plane_events
 from .engine import _pick_one, _pick_token, _prefill_one
+from .paged_ops import _quant_kv, paged_attention  # noqa: F401 (re-export)
 from .llama import LlamaConfig, _mlp_block
-
-
-def _quant_kv(vec, qmax=127.0):
-    """Per-head-vector symmetric int8: vec [..., d] -> (int8, scale).
-    ``qmax`` is always 127; a caller may pass it as a traced operand.
-    Under jit XLA divides by the constant as a multiplication with its
-    reciprocal, by an operand as a division (what eager code does), and
-    the two scales can differ in their last bit."""
-    amax = jnp.max(jnp.abs(vec.astype(jnp.float32)), axis=-1,
-                   keepdims=True)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0)
-    q = jnp.clip(jnp.round(vec.astype(jnp.float32) / scale),
-                 -127, 127).astype(jnp.int8)
-    return q, scale[..., 0].astype(jnp.float32)
+from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
+                         _write_state, init_state)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "page", "kv_int8"))
@@ -58,8 +47,7 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
     (page_of(length), length % page). Reads: gather each slot's pages
     into its [P*page, kvh, d] view, mask by position.
     """
-    S, P = tables.shape
-    cap = P * page
+    S = tables.shape[0]
     x = params["embedding"][toks].astype(cfg.dtype)[:, None, :]  # [S,1,D]
     positions = lengths[:, None]
     page_idx = jnp.take_along_axis(
@@ -81,46 +69,17 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
                                            cfg.head_dim)
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
-        with jax.named_scope("kv_write"):
-            if kv_int8:
-                kq, ks = _quant_kv(k[:, 0])
-                vq, vs = _quant_kv(v[:, 0])
-                pool_k = pools_k[li].at[page_idx, offs].set(kq)
-                pool_v = pools_v[li].at[page_idx, offs].set(vq)
-                scale_k = scales_k[li].at[page_idx, offs].set(ks)
-                scale_v = scales_v[li].at[page_idx, offs].set(vs)
-                new_scales_k.append(scale_k)
-                new_scales_v.append(scale_v)
-            else:
-                pool_k = pools_k[li].at[page_idx, offs].set(
-                    k[:, 0].astype(pools_k[li].dtype))
-                pool_v = pools_v[li].at[page_idx, offs].set(
-                    v[:, 0].astype(pools_v[li].dtype))
-            new_pools_k.append(pool_k)
-            new_pools_v.append(pool_v)
+        o, pool_k, pool_v, scale_k, scale_v = paged_attention(
+            q, k, v, pools_k[li], pools_v[li],
+            scales_k[li] if kv_int8 else None,
+            scales_v[li] if kv_int8 else None, tables, lengths, page_idx,
+            offs, kv_int8, cfg.dtype)
+        new_pools_k.append(pool_k)
+        new_pools_v.append(pool_v)
+        if kv_int8:
+            new_scales_k.append(scale_k)
+            new_scales_v.append(scale_v)
         with jax.named_scope("attention"):
-            k_seq = pool_k[tables].reshape(S, cap, cfg.n_kv_heads,
-                                           cfg.head_dim)
-            v_seq = pool_v[tables].reshape(S, cap, cfg.n_kv_heads,
-                                           cfg.head_dim)
-            if kv_int8:     # dequantize each slot's gathered pages
-                k_seq = (k_seq.astype(cfg.dtype)
-                         * scale_k[tables].reshape(
-                             S, cap, cfg.n_kv_heads, 1).astype(cfg.dtype))
-                v_seq = (v_seq.astype(cfg.dtype)
-                         * scale_v[tables].reshape(
-                             S, cap, cfg.n_kv_heads, 1).astype(cfg.dtype))
-            rep = cfg.n_heads // cfg.n_kv_heads
-            s = jnp.einsum("sqhd,skhd->shqk", q.astype(jnp.float32),
-                           jnp.repeat(k_seq, rep, axis=2).astype(
-                               jnp.float32)) * (cfg.head_dim ** -0.5)
-            admit = (jnp.arange(cap)[None, :] <=
-                     lengths[:, None])  # keys <= query position
-            s = jnp.where(admit[:, None, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("shqk,skhd->sqhd", p.astype(v_seq.dtype),
-                           jnp.repeat(v_seq, rep, axis=2))
-            o = o.reshape(S, 1, cfg.n_heads * cfg.head_dim)
             x = x + mm(o, layer["wo"])
         with jax.named_scope("mlp"):
             x = x + _mlp_block(layer, x, cfg)
@@ -206,9 +165,18 @@ class PagedEngine:
     sequences; a request only ever holds ceil(current_len / page_size)
     pages, so short requests don't pay for long ones. Admission waits
     for pages, not for a worst-case slot.
+
+    Which device programs run follows from the type of ``cfg``. A
+    ``LlamaConfig`` has a K/V pool for every layer. A ``NemotronHConfig``
+    has pools for its attention layers only and, beside them, per-slot
+    recurrent state of its Mamba layers (SSM state and convolution tail,
+    ``self.ssm`` / ``self.conv``): written whole at admission, advanced
+    by the step for all slots, donated to both. Pages, tables, admission
+    order, preemption by recompute and the spans are the same code.
     """
 
-    def __init__(self, params, cfg: LlamaConfig, *, max_slots: int = 8,
+    def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig], *,
+                 max_slots: int = 8,
                  num_pages: int = 64, page_size: int = 16,
                  max_len: int = 512, enable_prefix_cache: bool = False,
                  kv_dtype: str = "model"):
@@ -219,8 +187,22 @@ class PagedEngine:
         self.num_pages = num_pages
         self.P = max_len // page_size           # table width per slot
         self.max_len = self.P * page_size
-        self.cos, self.sin = rope_frequencies(cfg.head_dim, self.max_len,
-                                              cfg.rope_theta)
+        self.recurrent = isinstance(cfg, NemotronHConfig)
+        if self.recurrent:
+            if enable_prefix_cache:
+                raise ValueError(
+                    "enable_prefix_cache needs snapshots of the recurrent "
+                    "state at page boundaries, which this engine does not "
+                    "keep: a model with recurrent layers runs without it")
+            self.n_kv = cfg.n_attn_layers
+            self.ssm, self.conv = init_state(cfg, max_slots)
+            # the last step's chosen experts [expert layers, S, k]: left
+            # on the device, for a reference check to read
+            self.last_routing = None
+        else:
+            self.n_kv = cfg.n_layers
+            self.cos, self.sin = rope_frequencies(
+                cfg.head_dim, self.max_len, cfg.rope_theta)
         if kv_dtype not in ("model", "int8"):
             raise ValueError("kv_dtype must be 'model' or 'int8'")
         # kv_dtype="int8": pages store per-head-vector-quantized K/V
@@ -231,16 +213,16 @@ class PagedEngine:
         shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
         pool_dt = jnp.int8 if self.kv_int8 else cfg.dtype
         self.pools_k = [jnp.zeros(shape, pool_dt)
-                        for _ in range(cfg.n_layers)]
+                        for _ in range(self.n_kv)]
         self.pools_v = [jnp.zeros(shape, pool_dt)
-                        for _ in range(cfg.n_layers)]
+                        for _ in range(self.n_kv)]
         sshape = shape[:-1]
         self.scales_k = [jnp.ones(sshape, jnp.float32)
-                         for _ in range(cfg.n_layers)] \
-            if self.kv_int8 else [None] * cfg.n_layers
+                         for _ in range(self.n_kv)] \
+            if self.kv_int8 else [None] * self.n_kv
         self.scales_v = [jnp.ones(sshape, jnp.float32)
-                         for _ in range(cfg.n_layers)] \
-            if self.kv_int8 else [None] * cfg.n_layers
+                         for _ in range(self.n_kv)] \
+            if self.kv_int8 else [None] * self.n_kv
         # Page 0 is a reserved scratch page: INACTIVE slots still flow
         # through the jitted step (static shapes) and their writes land
         # at tables[i,0]=0 / offset 0 — which must never be a page a
@@ -260,6 +242,7 @@ class PagedEngine:
         self._prefill_buckets = (16, 64, 256)
         # what this step() did, for its ``serve.engine.step`` row
         self._steps = self._admitted = self._preempted = 0
+        self._expert_load = None    # (held experts hit, most tokens of one)
         # Prefix cache: full-prompt-page content hash -> (page id,
         # refcount). Pages with refcount 0 stay resident (reusable)
         # until pool pressure evicts them LRU (``_reclaim``).
@@ -395,10 +378,11 @@ class PagedEngine:
             self._admit_one(self.pending.pop(0), shared, need)
 
     def _admit_one(self, request: tuple, shared: List[int], need: int):
-        """Prefill one request, scatter its K/V into its pages, sample
-        its first token. One ``serve.engine.admit`` span, its three
-        phases inside it: they bracket what the host does, device time
-        per phase comes from the trace by program name."""
+        """Prefill one request, scatter its K/V into its pages, write its
+        recurrent state (where the model has any) into its slot, sample
+        its first token. One ``serve.engine.admit`` span, its phases
+        inside it: they bracket what the host does, device time per phase
+        comes from the trace by program name."""
         (rid, prompt, max_new, eos_id, temp, top_k, top_p,
          seed, key_state, submitted_ns) = request
         rid8 = str(rid)[:8]
@@ -432,7 +416,7 @@ class PagedEngine:
                 self.prefix_misses += 1
             with plane_events.span("serve.admit.prefill", "serve",
                                    rid=rid8):
-                first_logits, seq_caches = self._prefill(
+                first_logits, seq_caches, state = self._prefill(
                     suffix, pad, shared, L0, n)
             self.tables[idx] = 0
             self.tables[idx, :len(slot.pages)] = slot.pages
@@ -440,6 +424,12 @@ class PagedEngine:
                                    rid=rid8, pages=need, dispatches=1):
                 self._scatter(seq_caches, slot.pages, len(shared))
             self._register_prefix_pages(slot)
+            if state is not None:
+                with plane_events.span("serve.admit.state", "serve",
+                                       rid=rid8, layers=len(state),
+                                       dispatches=1):
+                    self.ssm, self.conv = _write_state(
+                        self.ssm, self.conv, state, np.int32(idx))
             with plane_events.span("serve.admit.sample", "serve",
                                    rid=rid8):
                 key = jnp.asarray(self.keys[idx], dtype=jnp.uint32)
@@ -460,13 +450,17 @@ class PagedEngine:
                  L0: int, n: int):
         """Pad and dispatch the prefill program: the whole prompt, or —
         seeded with the shared prefix's K/V gathered from its cached
-        pages — only the suffix, the compute the cache saves."""
+        pages — only the suffix, the compute the cache saves.
+        -> (first logits, per-layer dense K/V, recurrent state or None)"""
         padded = jnp.asarray(suffix + [0] * (pad - len(suffix)),
                              dtype=jnp.int32)
+        if self.recurrent:
+            return _hybrid_prefill(self.params, padded, n, self.max_len,
+                                   self.cfg, pad)[:3]
         if not shared:
             return _prefill_one(
                 self.params, padded, n, self.max_len, self.cfg,
-                self.cos, self.sin, pad)
+                self.cos, self.sin, pad) + (None,)
         tbl = jnp.asarray(shared, dtype=jnp.int32)
         prefix_caches = []
         zpad = self.max_len - L0
@@ -491,7 +485,7 @@ class PagedEngine:
         return _suffix_prefill(
             self.params, prefix_caches, padded,
             jnp.int32(L0), jnp.int32(n), self.max_len,
-            self.cfg, self.cos, self.sin, pad)
+            self.cfg, self.cos, self.sin, pad) + (None,)
 
     def _scatter(self, seq_caches, pages: List[int], n_shared: int):
         """The computed K/V into the slot's OWN pages only (shared
@@ -517,13 +511,17 @@ class PagedEngine:
                    pending=len(self.pending),
                    free_pages=len(self.free_pages),
                    preempted=self._preempted)
+            if self._expert_load is not None:
+                sp.set(experts_hit=self._expert_load[0],
+                       expert_tokens_max=self._expert_load[1])
         return events
 
     def _step(self):
         """-> (events, slots that decoded). Four phases tile the time
         after ``_admit``: prepare (tables and uploads), dispatch (the
-        ``_paged_step`` call until it returns), fetch (blocks on the
+        step program's call until it returns), fetch (blocks on the
         device), emit (the per-slot loop)."""
+        self._expert_load = None
         self._admit()
         with plane_events.span("serve.step.prepare", "serve"):
             events: List[tuple] = list(self._admit_events)
@@ -544,26 +542,36 @@ class PagedEngine:
             lengths = np.array([self.slots[i].length if self.slots[i]
                                 else 0 for i in range(self.S)],
                                dtype=np.int32)
-            no_scales = [0] * self.cfg.n_layers
+            no_scales = [0] * self.n_kv
             uploads = (
                 jnp.asarray(self.tables), jnp.asarray(self.last_tok),
                 jnp.asarray(lengths), jnp.asarray(self.temps),
                 jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
                 jnp.asarray(self.keys, dtype=jnp.uint32))
         with plane_events.span("serve.step.dispatch", "serve"):
-            (toks, self.pools_k, self.pools_v, sk, sv,
-             new_keys) = _paged_step(
-                self.params, self.pools_k, self.pools_v,
-                self.scales_k if self.kv_int8 else no_scales,
-                self.scales_v if self.kv_int8 else no_scales,
-                *uploads, self.cfg, self.cos, self.sin, self.page,
-                self.kv_int8)
+            scales = ((self.scales_k, self.scales_v) if self.kv_int8
+                      else (no_scales, no_scales))
+            if self.recurrent:
+                (toks, self.pools_k, self.pools_v, sk, sv, self.ssm,
+                 self.conv, new_keys, self.last_routing) = _hybrid_step(
+                    self.params, self.pools_k, self.pools_v, *scales,
+                    self.ssm, self.conv, *uploads, self.cfg, self.page,
+                    self.kv_int8)
+            else:
+                (toks, self.pools_k, self.pools_v, sk, sv,
+                 new_keys) = _paged_step(
+                    self.params, self.pools_k, self.pools_v, *scales,
+                    *uploads, self.cfg, self.cos, self.sin, self.page,
+                    self.kv_int8)
             if self.kv_int8:
                 # model-dtype mode keeps scales stable at [None]*n_layers
                 self.scales_k, self.scales_v = sk, sv
         with plane_events.span("serve.step.fetch", "serve"):
             toks = np.asarray(toks)
             self.keys = np.array(new_keys)
+            if self.recurrent:  # the expert layers' load rode with the tokens
+                self._expert_load = (int(toks[self.S]),
+                                     int(toks[self.S + 1]))
         with plane_events.span("serve.step.emit", "serve",
                                tokens=len(active)):
             for i in active:

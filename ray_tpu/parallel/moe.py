@@ -7,7 +7,12 @@ axis; tokens are dispatched to their routed experts with a single
 ``lax.all_to_all`` each way (ICI-friendly, compiled into the program by
 XLA), using the capacity-buffer formulation so every shape is static.
 
-Two implementations with identical semantics:
+Three implementations:
+  * ``moe_ffn_share`` — the layer of ONE chip of an expert-parallel
+    deployment, told which experts it holds: routes over all experts,
+    computes the held experts' part of the result, dropless (sort by
+    expert, grouped matrix product, unsort). On one chip it runs without
+    its exchange.
   * ``moe_ffn_dense`` — computes every expert on every token and weights
     by the top-k gates. O(E) FLOPs; the correctness oracle and the
     single-device path.
@@ -55,28 +60,96 @@ def load_balance_loss(probs: jax.Array, gate_idx: jax.Array,
     return n_experts * jnp.sum(frac_tokens * mean_probs)
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """Squared ReLU, the activation of a non-gated expert."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def _expert_ffn(h: jax.Array, experts: Dict[str, jax.Array],
                 tp_psum: bool) -> jax.Array:
-    """SwiGLU over stacked experts. h: [E, S, D], weights [E, D, F]/[E, F, D]."""
-    g = jnp.einsum("esd,edf->esf", h, experts["w_gate"])
+    """Stacked experts. h: [E, S, D], weights [E, D, F]/[E, F, D]. SwiGLU
+    where the tree holds a gate matrix, squared ReLU where it does not."""
     u = jnp.einsum("esd,edf->esf", h, experts["w_up"])
-    y = jnp.einsum("esf,efd->esd", jax.nn.silu(g) * u, experts["w_down"])
+    if "w_gate" in experts:
+        g = jnp.einsum("esd,edf->esf", h, experts["w_gate"])
+        u = jax.nn.silu(g) * u
+    else:
+        u = relu2(u)
+    y = jnp.einsum("esf,efd->esd", u, experts["w_down"])
     if tp_psum:
         y = lax.psum(y, "tp")
     return y
 
 
+def sigmoid_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                  k: int, scale: float, norm: bool = True
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid router with a selection bias, in float32. x: [T, D],
+    w_router: [D, E], bias: [E] -> (weights [T, k], experts [T, k]). The
+    top k are chosen by ``score + bias``; the weights are the chosen
+    experts' scores WITHOUT the bias, normalised to sum to one where
+    ``norm``, times ``scale``."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                    w_router.astype(jnp.float32)))
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+    vals = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm:
+        vals = vals / (vals.sum(-1, keepdims=True) + 1e-20)
+    return vals * scale, idx
+
+
+def moe_ffn_share(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
+                  experts_held: Dict[str, jax.Array], expert_offset: int,
+                  token_mask: jax.Array | None = None
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The routed part of the result that THIS chip's experts give.
+
+    x: [T, D]; gate_vals / gate_idx: [T, k] over ALL experts of the layer;
+    experts_held: ``w_up`` [Eh, D, F] and ``w_down`` [Eh, F, D] of experts
+    ``expert_offset .. expert_offset + Eh - 1``. A pair (token, expert)
+    whose expert is not held here adds nothing: in the deployment another
+    chip computes it. Dropless: the pairs are sorted by expert and each
+    expert multiplies exactly its rows (``lax.ragged_dot``), however many
+    (one expert may get every token). ``token_mask`` [T] leaves a lane out
+    (an engine's empty slot). Returns (out [T, D], the held experts that got
+    a token, the most tokens one expert got).
+    """
+    T, k = gate_idx.shape
+    Eh = experts_held["w_up"].shape[0]
+    local = gate_idx - expert_offset
+    held = (local >= 0) & (local < Eh)
+    if token_mask is not None:
+        held = held & token_mask[:, None]
+    group = jnp.where(held, local, Eh).reshape(T * k)    # Eh: computed nowhere
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+    rows = x[order // k]                                 # [T*k, D]
+    h = relu2(lax.ragged_dot(rows, experts_held["w_up"], sizes))
+    y = lax.ragged_dot(h, experts_held["w_down"], sizes)
+    # Rows past the held groups belong to no expert. On a TPU ragged_dot
+    # leaves them UNINITIALISED (inf and NaN among them, measured), so they
+    # are selected away, not multiplied by a zero weight.
+    w = gate_vals.reshape(T * k)[order]
+    in_group = jnp.arange(T * k) < jnp.sum(sizes)
+    y = jnp.where(in_group[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
+    # unsort by gather (a scatter-add would sum in no fixed order)
+    out = y[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    return out.astype(x.dtype), jnp.sum(sizes > 0), jnp.max(sizes)
+
+
 def moe_ffn_dense(x: jax.Array, w_router: jax.Array,
-                  experts: Dict[str, jax.Array], k: int
+                  experts: Dict[str, jax.Array], k: int, gates=None
                   ) -> Tuple[jax.Array, jax.Array]:
     """Reference MoE: all experts computed, gated by top-k weights.
 
-    x: [B, L, D]; experts leaves have leading dim E.
+    x: [B, L, D]; experts leaves have leading dim E. ``gates``: (values,
+    experts) of [B, L, k] from another router (``sigmoid_gates``), in place
+    of the softmax router's own.
     Returns (out [B, L, D], aux_loss scalar).
     """
     E = w_router.shape[1]
     probs = router_probs(x, w_router)
-    gate_vals, gate_idx = top_k_gates(probs, k)
+    gate_vals, gate_idx = top_k_gates(probs, k) if gates is None else gates
     gates = jnp.sum(
         jax.nn.one_hot(gate_idx, E) * gate_vals[..., None], axis=-2)  # [B,L,E]
     B, L, D = x.shape

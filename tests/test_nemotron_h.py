@@ -1,0 +1,372 @@
+"""The hybrid family (models/nemotron_h.py) through ``PagedEngine``: the
+Mamba-2 scan (ops/ssm.py), the per-slot recurrent state beside the page
+pool, the expert layer that holds a share (parallel/moe.py), at toy sizes on
+the CPU. The plain reference is ``perfbench/reference/nemotron_h.py``."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench.reference import nemotron_h as ref
+from ray_tpu.models import nemotron_h as nh
+from ray_tpu.models.paged import PagedEngine
+from ray_tpu.ops import ssm
+from ray_tpu.parallel import moe
+from ray_tpu.util import events
+
+CFG = nh.NEMOTRON_H_DEBUG
+SHAPE = {"norm_eps": CFG.norm_eps, "hybrid_override_pattern": CFG.pattern,
+         "num_experts_per_tok": CFG.top_k, "mamba_num_heads": CFG.mamba_heads,
+         "mamba_head_dim": CFG.mamba_head_dim, "n_groups": CFG.n_groups,
+         "ssm_state_size": CFG.ssm_state,
+         "num_attention_heads": CFG.n_heads,
+         "num_key_value_heads": CFG.n_kv_heads,
+         "routed_scaling_factor": CFG.routed_scale,
+         "norm_topk_prob": CFG.norm_topk, "expert_offset": 0}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return nh.init_params(CFG, jax.random.PRNGKey(0))
+
+
+def _tokens(n, seed=0):
+    return np.random.RandomState(seed).randint(0, CFG.vocab_size, n).tolist()
+
+
+def _engine(params, cfg=CFG, **kw):
+    kw = {"max_slots": 3, "num_pages": 24, "page_size": 8, "max_len": 64,
+          **kw}
+    return PagedEngine(params, cfg, **kw)
+
+
+def _alone(params, prompt, n, cfg=CFG):
+    eng = _engine(params, cfg)
+    eng.submit("r", prompt, max_new_tokens=n)
+    return eng.run_to_completion()["r"]
+
+
+# ------------------------------------------------------------------- scan
+def _scan_inputs(L, H=8, P=4, G=2, N=8, seed=1):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(k[0], (L, H, P)),
+            jax.nn.softplus(jax.random.normal(k[1], (L, H)) - 2.0),
+            -jnp.exp(jax.random.normal(k[2], (H,))),
+            jax.random.normal(k[3], (L, G, N)),
+            jax.random.normal(k[4], (L, G, N)),
+            jax.random.normal(k[5], (H, P, N)))
+
+
+@pytest.mark.parametrize("L", [1, 7, 8, 9, 37, 64])
+@pytest.mark.parametrize("with_h0", [False, True])
+def test_chunked_scan_is_the_sequential_recurrence(L, with_h0):
+    x, dt, A, B, C, h0 = _scan_inputs(L)
+    h0 = h0 if with_h0 else None
+    y, s = ssm.ssd_chunked(x, dt, A, B, C, 8, h0)
+    y_seq, s_seq = ssm.ssm_sequential(x, dt, A, B, C, h0)
+    np.testing.assert_allclose(y, y_seq, atol=2e-5)
+    np.testing.assert_allclose(s, s_seq, atol=2e-5)
+
+
+@pytest.mark.parametrize("n_valid", [1, 5, 8, 13])
+def test_a_padded_tail_leaves_state_and_tail_untouched(n_valid):
+    """dt = 0 past ``n_valid``: the state a padded scan returns is the
+    state at ``n_valid``, and the convolution tail is the last three VALID
+    inputs (zeros where the prompt is shorter)."""
+    x, dt, A, B, C, _ = _scan_inputs(16)
+    masked = jnp.where((jnp.arange(16) < n_valid)[:, None], dt, 0.0)
+    _, padded = ssm.ssd_chunked(x, masked, A, B, C, 8)
+    _, short = ssm.ssm_sequential(x[:n_valid], dt[:n_valid], A,
+                                  B[:n_valid], C[:n_valid])
+    np.testing.assert_allclose(padded, short, atol=2e-5)
+    seq = jnp.arange(16 * 3, dtype=jnp.float32).reshape(16, 3) + 1.0
+    tail = np.asarray(ssm.conv_tail(seq, n_valid, 4))
+    want = np.concatenate([np.zeros((3, 3)), np.asarray(seq[:n_valid])])[-3:]
+    np.testing.assert_array_equal(tail, want)
+
+
+def test_conv_step_continues_the_causal_convolution():
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(k[0], (9, 5))
+    w, b = jax.random.normal(k[1], (4, 5)), jax.random.normal(k[2], (5,))
+    full = ssm.causal_conv(x, w, b)
+    tail = ssm.conv_tail(x, 6, 4)[None]
+    for t in range(6, 9):
+        out, tail = ssm.conv_step(tail, x[t][None], w, b)
+        np.testing.assert_allclose(out[0], full[t], atol=1e-5)
+
+
+# ----------------------------------------------------- forward, reference
+def test_forward_matches_the_plain_reference(params):
+    toks = _tokens(29)
+    got = nh.forward(params, jnp.asarray(toks, jnp.int32), CFG)
+    want = ref.logits(ref.from_program_tree(params), toks, SHAPE)
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    assert float(jnp.std(want)) > 0.5
+
+
+@pytest.mark.parametrize("n_prompt", [3, 9, 16, 21])
+def test_prefill_then_decode_matches_the_reference_row_by_row(params,
+                                                              n_prompt):
+    """A prompt in a PADDED bucket through the prefill program, its state
+    written into a slot beside two others, then the decode step over that
+    state: every logits row against the reference's full forward."""
+    toks = _tokens(n_prompt + 6, seed=n_prompt)
+    want = np.asarray(ref.logits(ref.from_program_tree(params), toks, SHAPE))
+    page, P, S, slot = 8, 8, 3, 1
+    pad = 16 if n_prompt <= 16 else 64
+    padded = jnp.asarray(toks[:n_prompt] + [0] * (pad - n_prompt), jnp.int32)
+    first, caches, state, _ = nh._hybrid_prefill(params, padded, n_prompt,
+                                                 P * page, CFG, pad)
+    np.testing.assert_allclose(first, want[n_prompt - 1], atol=2e-4)
+    pools_k = [jnp.zeros((24, page, CFG.n_kv_heads, CFG.head_dim))
+               for _ in range(CFG.n_attn_layers)]
+    pools_v = [jnp.zeros_like(p) for p in pools_k]
+    tables = np.zeros((S, P), np.int32)
+    tables[slot] = np.arange(1, P + 1)
+    for li, (k, v) in enumerate(caches):     # rows of the slot's own pages
+        pools_k[li] = pools_k[li].at[tables[slot]].set(
+            k.reshape(P, page, *k.shape[1:]))
+        pools_v[li] = pools_v[li].at[tables[slot]].set(
+            v.reshape(P, page, *v.shape[1:]))
+    ssm_s, conv = nh.init_state(CFG, S)
+    ssm_s, conv = nh._write_state(ssm_s, conv, state, np.int32(slot))
+    none = [0] * CFG.n_attn_layers
+    step = jax.jit(nh._decode_logits,
+                   static_argnames=("cfg", "page", "kv_int8"))
+    for t in range(n_prompt, len(toks)):
+        lengths = np.zeros(S, np.int32)
+        lengths[slot] = t
+        last = np.zeros(S, np.int32)
+        last[slot] = toks[t]
+        (logits, pools_k, pools_v, _, _, ssm_s, conv, load, routing) = step(
+            params, pools_k, pools_v, none, none, ssm_s, conv,
+            jnp.asarray(tables), jnp.asarray(last), jnp.asarray(lengths),
+            cfg=CFG, page=page, kv_int8=False)
+        np.testing.assert_allclose(logits[slot], want[t], atol=3e-4)
+        assert 0 < int(load[0]) <= CFG.n_moe_layers * CFG.top_k
+        assert int(load[1]) == 1        # one active token: one row an expert
+        assert routing.shape == (CFG.n_moe_layers, S, CFG.top_k)
+
+
+# ---------------------------------------------------------------- engine
+def test_engine_streams_the_greedy_continuation(params):
+    prompt = _tokens(11, seed=5)
+    seq = list(prompt)
+    for _ in range(8):
+        lg = nh.forward(params, jnp.asarray(seq, jnp.int32), CFG)
+        seq.append(int(jnp.argmax(lg[-1])))
+    assert _alone(params, prompt, 8) == seq[len(prompt):]
+
+
+def test_requests_admitted_at_different_steps_stream_what_each_streams_alone(
+        params):
+    reqs = {"a": (_tokens(4, 1), 12), "b": (_tokens(2, 2), 5),
+            "c": (_tokens(21, 3), 9), "d": (_tokens(17, 4), 7)}
+    eng = _engine(params, max_slots=2)        # c and d wait for a slot
+    got = {r: [] for r in reqs}
+    eng.submit("a", reqs["a"][0], max_new_tokens=reqs["a"][1])
+    for _ in range(3):                        # b joins three steps later
+        for rid, tok in eng.step():
+            if tok is not None:
+                got[rid].append(tok)
+    for r in "bcd":
+        eng.submit(r, reqs[r][0], max_new_tokens=reqs[r][1])
+    while eng.has_work():
+        for rid, tok in eng.step():
+            if tok is not None:
+                got[rid].append(tok)
+    for r, (prompt, n) in reqs.items():
+        assert got[r] == _alone(params, prompt, n), r
+    # every page is free or a reclaimable full prompt page (page 0 reserved)
+    assert eng._available_pages() == 23
+
+
+def test_preemption_by_recompute_resumes_a_recurrent_sequence_exactly(params):
+    """A pool too small for both sequences: one is preempted, requeued with
+    prompt + emitted, prefilled again (state recomputed at the new length)
+    and goes on exactly where it paused."""
+    reqs = {"x": (_tokens(6, 7), 30), "y": (_tokens(5, 8), 30)}
+    eng = _engine(params, max_slots=2, num_pages=8, page_size=8, max_len=64)
+    for r, (p, n) in reqs.items():
+        eng.submit(r, p, max_new_tokens=n)
+    got, preempted = {r: [] for r in reqs}, 0
+    while eng.has_work():
+        for rid, tok in eng.step():
+            if tok is not None:
+                got[rid].append(tok)
+        preempted += eng._preempted
+    assert preempted > 0
+    for r, (p, n) in reqs.items():
+        assert got[r] == _alone(params, p, n), r
+
+
+def test_prefix_cache_is_refused_for_a_model_with_recurrent_layers(params):
+    with pytest.raises(ValueError, match="recurrent"):
+        _engine(params, enable_prefix_cache=True)
+
+
+def test_int8_pages_stay_close(params):
+    prompt = _tokens(12, 9)
+    eng = _engine(params, kv_dtype="int8")
+    eng.submit("q", prompt, max_new_tokens=6)
+    out = eng.run_to_completion()["q"]
+    assert len(out) == 6 and out[0] == _alone(params, prompt, 1)[0]
+
+
+def test_the_dense_family_still_takes_its_own_programs():
+    from ray_tpu.models import LlamaConfig, init_params
+
+    cfg = LlamaConfig(vocab_size=96, d_model=64, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=128, max_seq_len=128,
+                      dtype=jnp.float32)
+    eng = PagedEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg,
+                      max_slots=2, num_pages=24, page_size=8, max_len=64)
+    assert not eng.recurrent and eng.n_kv == 2
+    assert not hasattr(eng, "ssm")
+    eng.submit("d", [1, 2, 3], max_new_tokens=4)
+    events.reset()
+    assert len(eng.run_to_completion()["d"]) == 4
+    names = {events.row_to_dict(r)["name"] for r in events.drain()[0]}
+    assert "serve.admit.state" not in names
+
+
+# ----------------------------------------------------------------- spans
+@pytest.fixture
+def _clean_ring():
+    events.reset()
+    yield
+    events._enabled = True
+    events.reset()
+
+
+def test_state_write_span_and_expert_load_on_the_step_row(params,
+                                                          _clean_ring):
+    eng = _engine(params)
+    eng.submit("req-aaaa-long", _tokens(9, 2), max_new_tokens=4)
+    eng.run_to_completion()
+    rows = [events.row_to_dict(r) for r in events.drain()[0]]
+    by = {}
+    for r in rows:
+        by.setdefault(r["name"], []).append(r["fields"])
+    (admit,), (state,) = by["serve.engine.admit"], by["serve.admit.state"]
+    assert state["parent"] == admit["sid"] and state["rid"] == "req-aaaa"
+    assert state["layers"] == CFG.n_mamba_layers and state["dispatches"] == 1
+    order = [r["name"] for r in rows if r["name"].startswith("serve.admit.")]
+    assert order == ["serve.admit.prefill", "serve.admit.scatter",
+                     "serve.admit.state", "serve.admit.sample"]
+    decoded = [f for f in by["serve.engine.step"] if f["active"]]
+    assert decoded
+    for f in decoded:
+        assert 1 <= f["experts_hit"] <= CFG.n_moe_layers * CFG.top_k
+        assert f["expert_tokens_max"] == 1
+    idle = [f for f in by["serve.engine.step"] if not f["active"]]
+    assert all("experts_hit" not in f for f in idle)
+
+
+def test_greedy_identical_with_recorder_on_and_off(params, _clean_ring):
+    prompt = _tokens(10, 6)
+    on = _alone(params, prompt, 6)
+    assert events.pending() > 0
+    events.reset()
+    events._enabled = False
+    off = _alone(params, prompt, 6)
+    assert on == off and events.pending() == 0
+
+
+# ------------------------------------------------ the layer holding a share
+def _layer(key, T=24, D=32, E=16, F=40, Fs=48):
+    k = jax.random.split(key, 7)
+    x = jax.random.normal(k[0], (T, D))
+    layer = {"w_router": jax.random.normal(k[1], (D, E)) / np.sqrt(D),
+             "router_bias": 0.1 * jax.random.normal(k[2], (E,)),
+             "w_up": jax.random.normal(k[3], (E, D, F)) / np.sqrt(D),
+             "w_down": jax.random.normal(k[4], (E, F, D)) / np.sqrt(F),
+             "ws_up": jax.random.normal(k[5], (D, Fs)) / np.sqrt(D),
+             "ws_down": jax.random.normal(k[6], (Fs, D)) / np.sqrt(Fs)}
+    return x, layer
+
+
+@pytest.mark.parametrize("routing", ["even", "uneven", "one_expert"])
+def test_eight_shares_add_up_to_the_whole_layer(routing):
+    """Each of eight chips holds two of sixteen experts. Their routed parts,
+    plus the shared expert counted ONCE, are the uncut reference's whole
+    layer, and the routed parts alone are ``moe_ffn_dense``'s under the
+    same scores; however uneven the routing, no token is dropped."""
+    x, layer = _layer(jax.random.PRNGKey(11))
+    T, E, k = x.shape[0], 16, 3
+    if routing == "uneven":     # the bias sends most tokens to experts 0-2
+        layer["router_bias"] = layer["router_bias"].at[:3].add(0.6)
+    if routing == "one_expert":  # ... and expert 5 gets every token
+        layer["router_bias"] = layer["router_bias"].at[5].add(5.0)
+    vals, idx = moe.sigmoid_gates(x, layer["w_router"], layer["router_bias"],
+                                  k, 2.5)
+    if routing == "one_expert":
+        assert bool((idx == 5).any(axis=-1).all())
+    parts, hits, most = [], 0, 0
+    for share in range(8):
+        held = {n: layer[n][2 * share:2 * share + 2]
+                for n in ("w_up", "w_down")}
+        out, hit, m = moe.moe_ffn_share(x, vals, idx, held, 2 * share)
+        parts.append(out)
+        hits += int(hit)
+        most = max(most, int(m))
+    routed = sum(parts)
+    counts = np.bincount(np.asarray(idx).ravel(), minlength=E)
+    assert hits == int((counts > 0).sum()) and most == counts.max()
+    if routing == "one_expert":
+        assert most == T
+    dense, _ = moe.moe_ffn_dense(
+        x[None], layer["w_router"],
+        {"w_up": layer["w_up"], "w_down": layer["w_down"]}, k,
+        gates=(vals[None], idx[None]))
+    np.testing.assert_allclose(routed, dense[0], atol=2e-5)
+    shared = moe.relu2(x @ layer["ws_up"]) @ layer["ws_down"]
+    ref_w = {**layer, "norm": jnp.ones((x.shape[1],))}
+    whole, own, under = ref.expert_block(
+        x, ref_w, jnp.zeros((T, k), jnp.int32), 0, top_k=k, offset=0,
+        scale=2.5, norm=True, eps=0.0)
+    # the reference normalises its input; feed it rows of unit mean square
+    unit = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))
+    vals_u, idx_u = moe.sigmoid_gates(unit, layer["w_router"],
+                                      layer["router_bias"], k, 2.5)
+    routed_u = sum(moe.moe_ffn_share(
+        unit, vals_u, idx_u, {n: layer[n][2 * s:2 * s + 2]
+                              for n in ("w_up", "w_down")}, 2 * s)[0]
+        for s in range(8))
+    shared_u = moe.relu2(unit @ layer["ws_up"]) @ layer["ws_down"]
+    np.testing.assert_allclose(whole - x, routed_u + shared_u, atol=3e-5)
+    np.testing.assert_array_equal(np.sort(own, -1), np.sort(idx_u, -1))
+    assert float(jnp.abs(shared).max()) > 0 and float(under.max()) == 0.0
+
+
+def test_a_masked_token_reaches_no_expert():
+    x, layer = _layer(jax.random.PRNGKey(12), T=6)
+    vals, idx = moe.sigmoid_gates(x, layer["w_router"], layer["router_bias"],
+                                  3, 2.5)
+    held = {n: layer[n] for n in ("w_up", "w_down")}
+    mask = jnp.asarray([True, False, True, False, False, True])
+    out, hit, most = moe.moe_ffn_share(x, vals, idx, held, 0, mask)
+    full, _, _ = moe.moe_ffn_share(x, vals, idx, held, 0)
+    np.testing.assert_allclose(out[mask], full[mask], atol=1e-5)
+    assert float(jnp.abs(out[~mask]).max()) == 0.0
+    counts = np.bincount(np.asarray(idx)[np.asarray(mask)].ravel(),
+                         minlength=16)
+    assert int(hit) == (counts > 0).sum() and int(most) == counts.max()
+
+
+def test_a_share_of_the_model_is_the_reference_with_the_same_share(params):
+    """The engine's family on experts 4-7 of 16 against the reference given
+    the same share: what the absent experts would add is left out alike."""
+    cfg = dataclasses.replace(CFG, experts_held=4, expert_offset=4)
+    share = nh.expert_share(params, 4, 4)
+    toks = _tokens(19, 4)
+    got = nh.forward(share, jnp.asarray(toks, jnp.int32), cfg)
+    want = ref.logits(ref.from_program_tree(share), toks,
+                      {**SHAPE, "expert_offset": 4})
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    whole = nh.forward(params, jnp.asarray(toks, jnp.int32), CFG)
+    assert float(jnp.abs(whole - got).max()) > 1e-2

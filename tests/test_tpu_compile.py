@@ -115,6 +115,36 @@ def test_paged_step_llama3_1b_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
+def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
+    """The hybrid family's programs at the benchmark's widths and one period
+    of its pattern (``MEM*E``), 32 slots: the decode step gives its pools
+    and per-slot state back aliased to the donated arguments, and the prefill
+    of a padded prompt compiles with its chunked scan and ragged dots."""
+    from ray_tpu.models import nemotron_h as nh
+
+    cfg = nh.NemotronHConfig(vocab_size=16384, pattern="MEM*E",
+                             experts_held=16)
+    S, pages, page, max_len = 32, 2048, 16, 1280
+    params = _on(one_chip, jax.eval_shape(
+        lambda: nh.init_params(cfg, jax.random.PRNGKey(0))))
+    pools = [_shape(one_chip, (pages, page, cfg.n_kv_heads, cfg.head_dim))]
+    ssm, conv = _on(one_chip, jax.eval_shape(lambda: nh.init_state(cfg, S)))
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = nh._hybrid_step.lower(
+        params, pools, pools, [0], [0], ssm, conv,
+        i32((S, max_len // page)), i32((S,)), i32((S,)), f32((S,)),
+        i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
+        cfg=cfg, page=page, kv_int8=False).compile()
+    m = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pools, pools, ssm, conv)))
+    assert m.alias_size_in_bytes >= donated
+    compiled = nh._hybrid_prefill.lower(
+        params, i32((256,)), 1, max_len, cfg, 256).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 def test_scatter_pages_writes_the_pools_in_place(one_chip, kv_int8):
     """The admission's one scatter at the benchmark's widths (16 layers of
