@@ -58,15 +58,18 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
             v_seq = (v_seq.astype(dtype)
                      * scale_v[tables].reshape(
                          S, cap, n_kv_heads, 1).astype(dtype))
+        # Head h = g*rep + r shares K/V head g: contract the group against
+        # K/V as gathered, at n_kv_heads and in their own dtype (float32
+        # accumulation), never a copy repeated to every head.
         rep = n_heads // n_kv_heads
-        s = jnp.einsum("sqhd,skhd->shqk", q.astype(jnp.float32),
-                       jnp.repeat(k_seq, rep, axis=2).astype(
-                           jnp.float32)) * (head_dim ** -0.5)
+        qg = q.reshape(S, 1, n_kv_heads, rep, head_dim)
+        s = jnp.einsum("sqgrd,skgd->sgrqk", qg, k_seq,
+                       preferred_element_type=jnp.float32
+                       ) * (head_dim ** -0.5)
         admit = (jnp.arange(cap)[None, :] <=
                  lengths[:, None])  # keys <= query position
-        s = jnp.where(admit[:, None, None, :], s, -1e30)
+        s = jnp.where(admit[:, None, None, None, :], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("shqk,skhd->sqhd", p.astype(v_seq.dtype),
-                       jnp.repeat(v_seq, rep, axis=2))
+        o = jnp.einsum("sgrqk,skgd->sqgrd", p.astype(v_seq.dtype), v_seq)
         o = o.reshape(S, 1, n_heads * head_dim)
     return o, pool_k, pool_v, scale_k, scale_v

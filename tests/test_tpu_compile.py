@@ -59,6 +59,16 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_no_repeated_table(compiled, S, cap, kvh, rep, d):
+    """The decode attention contracts grouped heads against the gathered
+    table at ``kvh`` heads in the pool's dtype: no float32 array of the
+    table repeated to every head, in either spelling of its shape."""
+    text = compiled.as_text()
+    for shape in (f"f32[{S},{cap},{kvh},{rep},{d}]",
+                  f"f32[{S},{cap},{kvh * rep},{d}]"):
+        assert shape not in text, shape
+
+
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [(32, 8, 64),
                                                      (16, 8, 128)])
 def test_mosaic_flash_fwd_bwd_L2048(one_chip, heads, kv_heads, head_dim):
@@ -112,7 +122,14 @@ def test_paged_step_llama3_1b_widths(one_chip):
         i32((S, max_len // page)), i32((S,)), i32((S,)), f32((S,)),
         i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
         cfg=cfg, cos=cos, sin=sin, page=page, kv_int8=False).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+    rep = cfg.n_heads // cfg.n_kv_heads
+    _assert_no_repeated_table(compiled, S, max_len, cfg.n_kv_heads, rep,
+                              cfg.head_dim)
+    # All the step's temporaries together are smaller than ONE float32 copy
+    # of a layer's table repeated to every head: 90 MB against 134 MB at
+    # these widths (a step that repeats the table holds 341 MB).
+    repeated = S * max_len * cfg.n_heads * cfg.head_dim * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < repeated
 
 
 def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
@@ -140,6 +157,8 @@ def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pools, pools, ssm, conv)))
     assert m.alias_size_in_bytes >= donated
+    _assert_no_repeated_table(compiled, S, max_len, cfg.n_kv_heads,
+                              cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
     compiled = nh._hybrid_prefill.lower(
         params, i32((256,)), 1, max_len, cfg, 256).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
