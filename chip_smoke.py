@@ -506,7 +506,7 @@ def serve_phase(rehearse: bool) -> dict:
     app = serve.deployment(
         CheckedLLMServer, ray_actor_options={"num_tpus": 1}).bind(
         model_factory(rehearse), max_slots=SLOTS, max_len=MAX_LEN,
-        kv_cache="paged", num_pages=NUM_PAGES, page_size=PAGE)
+        num_pages=NUM_PAGES, page_size=PAGE)
     handle = serve.run(app, name="chip_smoke", route_prefix=None)
     weights_s = time.perf_counter() - t0
     stats = handle.remote({"_admin": "stats"}).result(timeout=120)

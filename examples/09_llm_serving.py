@@ -1,7 +1,7 @@
 """LLM serving: continuous batching, streaming tokens, speculative decode.
 
 Reference-Ray equivalent: the vLLM-backed ``serve`` LLM examples — here
-the engine is framework-native (``ray_tpu/models/engine.py``) and the
+the engine is framework-native (``ray_tpu/models/paged.py``) and the
 speculative decoder is ``ray_tpu/models/speculative.py``.
 """
 
@@ -34,10 +34,12 @@ def tiny_model():
 
 def main():
     ray_tpu.init(num_cpus=4, probe_tpu=False)
-    # kv_cache="paged": K/V in a shared page pool with prefix caching —
-    # short requests stop paying for worst-case length.
+    # K/V live in a shared page pool, here with prefix caching. 48 pages
+    # of 8 hold less than 4 slots x 128 positions: short requests stop
+    # paying for worst-case length (leave num_pages out and the pool is
+    # sized so that no request ever waits for memory).
     handle = serve.run(build_llm_app(tiny_model, max_slots=4,
-                                     max_len=128, kv_cache="paged",
+                                     max_len=128,
                                      num_pages=48, page_size=8,
                                      enable_prefix_cache=True),
                        name="llm", route_prefix="/generate")

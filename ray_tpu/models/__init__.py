@@ -12,7 +12,6 @@ from .llama import (
 )
 
 from . import mixtral, vit
-from .engine import GenerationEngine
 from .paged import PagedEngine
 from .speculative import generate_speculative
 from .mixtral import (
@@ -27,6 +26,6 @@ __all__ = [
     "LlamaConfig", "LLAMA3_8B", "LLAMA3_1B", "LLAMA_DEBUG", "init_params",
     "forward", "loss_fn", "generate_greedy", "generate_sample", "flops_per_token",
     "mixtral", "MixtralConfig", "MIXTRAL_8X7B", "MIXTRAL_DEBUG",
-    "generate_speculative", "GenerationEngine", "PagedEngine",
+    "generate_speculative", "PagedEngine",
     "mixtral_shardings", "mixtral_generate_greedy",
 ]

@@ -1,18 +1,18 @@
-"""Paged KV cache: on-demand block allocation for the generation engine.
+"""The serving engine: continuous batching over a paged KV cache.
 
-The vLLM memory model, TPU-shaped: instead of one preallocated
-``[S, max_len]`` cache per slot (paying worst-case length for every
-request), K/V live in a shared page pool — ``[num_pages, page_size]``
-per layer — and each sequence holds a page table. Pages are allocated
-as a sequence actually grows and return to the free list when it
-finishes, so the pool admits far more concurrent sequences than a dense
-cache of the same bytes whenever lengths vary.
-
-Reads gather a sequence's pages (XLA batched gather — same bytes the
-dense cache reads); writes are one batched scatter at each slot's
-(page, offset). Decode math is otherwise identical to
-``llama._decode_step``, and the engine API mirrors
-``engine.GenerationEngine`` (parity-tested against it).
+S slots share one jitted step; requests join and leave between steps, so
+a long request never blocks a short one and the chip sees a full [S, 1]
+decode batch (inactive slots flow through the math, outputs ignored:
+static shapes, one compilation). ``submit`` enqueues; ``step`` returns
+the (request_id, token) events it produced, a token of ``None`` marking
+completion; ``run_to_completion`` drives the loop without streaming.
+The vLLM memory model, TPU-shaped: K/V live in a shared page pool
+(``[num_pages, page_size]`` per layer) and each sequence holds a page
+table; pages are allocated as a sequence grows and freed when it ends,
+so the pool admits far more sequences than a worst-case ``[S, max_len]``
+cache of the same bytes. Reads gather a sequence's pages, writes are one
+batched scatter at each slot's (page, offset). The math is
+``llama._decode_step``'s: tests hold it to ``llama.generate_greedy``.
 """
 
 from __future__ import annotations
@@ -159,7 +159,7 @@ class _PagedSlot:
 
 
 class PagedEngine:
-    """``GenerationEngine`` semantics over a shared page pool.
+    """Slot-based continuous batching over a shared page pool.
 
     ``num_pages * page_size`` total cache positions are shared by ALL
     sequences; a request only ever holds ceil(current_len / page_size)

@@ -1,10 +1,11 @@
 """LLM serving: a deployment hosting the continuous-batching engine.
 
 The reference serves LLMs by embedding vLLM inside Serve deployments;
-the TPU-native equivalent pairs ``models/engine.py``'s slot-based
-continuous batching with an ordinary Serve deployment: unary calls get
-the full token list, streaming calls get tokens as the engine emits
-them, and concurrent requests share every decode step.
+the TPU-native equivalent pairs ``models/paged.py``'s slot-based
+continuous batching over a paged KV cache with an ordinary Serve
+deployment: unary calls get the full token list, streaming calls get
+tokens as the engine emits them, and concurrent requests share every
+decode step.
 """
 
 from __future__ import annotations
@@ -22,21 +23,31 @@ from .deployment import deployment as _deployment
 
 
 class LLMServer:
-    """Serve callable hosting one :class:`GenerationEngine`.
+    """Serve callable hosting one :class:`~ray_tpu.models.paged.PagedEngine`.
 
     Construct via ``build_llm_app`` (which wraps it in a deployment) or
     directly inside ``@serve.deployment`` with a params/config factory —
     the factory runs replica-side, so weights never ride the deploy RPC.
     Requests: ``{"prompt": [token ids], "max_new_tokens": n,
     "eos_id": optional, "stream": bool}``.
+
+    ``num_pages=None`` sizes the pool so that ``max_slots`` sequences of
+    ``max_len`` fit at once and no request waits for memory; a smaller
+    pool admits by pages and preempts by recompute when it runs dry.
     """
 
     def __init__(self, model_factory, *, max_slots: int = 4,
-                 max_len: int = 512, kv_cache: str = "dense",
-                 num_pages: int = 64, page_size: int = 16,
+                 max_len: int = 512, kv_cache: str = "paged",
+                 num_pages: Optional[int] = None, page_size: int = 16,
                  enable_prefix_cache: bool = False,
                  kv_dtype: str = "model",
                  draft_factory=None, draft_k: int = 4):
+        # Selects nothing: accepted for callers that still pass the one
+        # value left (the benchmark's configuration files).
+        if kv_cache != "paged":
+            raise ValueError(
+                f"kv_cache={kv_cache!r}: PagedEngine (models/paged.py) is "
+                f"the only serving engine; leave the keyword out")
         params, cfg = model_factory()
         # Speculative decoding: a replica-side draft factory (a distilled
         # checkpoint loader, or models.speculative.truncated_draft over
@@ -63,25 +74,14 @@ class LLMServer:
         if draft_factory is not None:
             draft_params, draft_cfg = draft_factory(params, cfg)
             self._spec = (params, cfg, draft_params, draft_cfg, draft_k)
-        if kv_cache == "paged":
-            from ray_tpu.models.paged import PagedEngine
+        from ray_tpu.models.paged import PagedEngine
 
-            self.engine = PagedEngine(params, cfg, max_slots=max_slots,
-                                      num_pages=num_pages,
-                                      page_size=page_size,
-                                      max_len=max_len,
-                                      enable_prefix_cache=
-                                      enable_prefix_cache,
-                                      kv_dtype=kv_dtype)
-        elif kv_cache == "dense":
-            from ray_tpu.models.engine import GenerationEngine
-
-            self.engine = GenerationEngine(params, cfg,
-                                           max_slots=max_slots,
-                                           max_len=max_len)
-        else:
-            raise ValueError(f"kv_cache must be 'dense' or 'paged', "
-                             f"got {kv_cache!r}")
+        if num_pages is None:   # every slot at max_len, + scratch page 0
+            num_pages = max_slots * (max_len // page_size) + 1
+        self.engine = PagedEngine(
+            params, cfg, max_slots=max_slots, num_pages=num_pages,
+            page_size=page_size, max_len=max_len,
+            enable_prefix_cache=enable_prefix_cache, kv_dtype=kv_dtype)
         self._queues: Dict[str, asyncio.Queue] = {}
         self._loop_task: Optional[asyncio.Task] = None
         # Serializes engine stepping against live weight refresh: step()
@@ -218,7 +218,7 @@ class LLMServer:
         prompt = jnp.asarray([[int(t) for t in body["prompt"]]], jnp.int32)
         max_new = int(body.get("max_new_tokens", 32))
         k = int(body.get("k", k))
-        # Same admission bound as the engine path (models/engine.py):
+        # Same admission bound as the engine path (PagedEngine.submit):
         # the speculative KV caches are sized prompt + max_new + k + 1.
         total = prompt.shape[1] + max_new + k + 1
         if k < 1 or total > self._max_len:
@@ -325,11 +325,10 @@ class LLMServer:
         # or re-register old-weight pages after the wipe.
         with self._engine_lock:
             self.engine.params = params
-            # Paged engine: cached prefix pages hold K/V computed with
-            # the OLD weights — a post-refresh hit would seed sequences
-            # with stale state matching neither checkpoint's greedy.
-            if hasattr(self.engine, "invalidate_prefix_cache"):
-                self.engine.invalidate_prefix_cache()
+            # Cached prefix pages hold K/V computed with the OLD
+            # weights — a post-refresh hit would seed sequences with
+            # stale state matching neither checkpoint's greedy.
+            self.engine.invalidate_prefix_cache()
         if self._spec is not None:
             dparams, dcfg = self._draft_factory(params, self._cfg)
             # Single-writer handoff: reconfigure calls are serialized by
@@ -360,17 +359,17 @@ class LLMServer:
 
 def build_llm_app(model_factory, *, max_slots: int = 4,
                   max_len: int = 512, num_replicas: int = 1,
-                  kv_cache: str = "dense", num_pages: int = 64,
-                  page_size: int = 16,
+                  num_pages: Optional[int] = None, page_size: int = 16,
                   enable_prefix_cache: bool = False,
                   kv_dtype: str = "model",
                   draft_factory=None, draft_k: int = 4):
     """Bind an LLM serving app (reference shape: ``serve.llm``
-    builders): ``serve.run(build_llm_app(factory))``. ``kv_cache=
-    "paged"`` swaps in the shared-page-pool engine (models/paged.py).
-    ``draft_factory=(params, cfg) -> (draft_params, draft_cfg)`` enables
-    the speculative request path (e.g. ``lambda p, c:
-    truncated_draft(p, c, n_layers)``).
+    builders): ``serve.run(build_llm_app(factory))`` serves from
+    ``models/paged.py``'s engine, its page pool sized so that no request
+    waits for memory unless ``num_pages`` says otherwise (see
+    :class:`LLMServer`). ``draft_factory=(params, cfg) -> (draft_params,
+    draft_cfg)`` enables the speculative request path (e.g. ``lambda p,
+    c: truncated_draft(p, c, n_layers)``).
 
     On a cluster that reports ``TPU`` chips each replica asks for one: the
     scheduler then starts it in a worker that may own the chip (every
@@ -384,8 +383,7 @@ def build_llm_app(model_factory, *, max_slots: int = 4,
     dep = _deployment(LLMServer, num_replicas=num_replicas,
                       ray_actor_options={"num_tpus": 1} if on_tpu else None)
     return dep.bind(model_factory, max_slots=max_slots, max_len=max_len,
-                    kv_cache=kv_cache, num_pages=num_pages,
-                    page_size=page_size,
+                    num_pages=num_pages, page_size=page_size,
                     enable_prefix_cache=enable_prefix_cache,
                     kv_dtype=kv_dtype,
                     draft_factory=draft_factory, draft_k=draft_k)

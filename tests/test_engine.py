@@ -1,13 +1,14 @@
-"""Continuous-batching engine (models/engine.py): interleaved requests
-of different lengths must produce EXACTLY what per-request greedy decode
-produces, and slots must recycle."""
+"""Continuous-batching engine (models/paged.py) and its sampler
+(models/engine.py): interleaved requests of different lengths must
+produce EXACTLY what per-request greedy decode produces, and slots must
+recycle."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import LlamaConfig, generate_greedy, init_params
-from ray_tpu.models.engine import GenerationEngine
+from ray_tpu.models.paged import PagedEngine
 
 
 @pytest.fixture(scope="module")
@@ -25,9 +26,13 @@ def _ref(params, cfg, prompt, n):
     return out[0].tolist()
 
 
-def test_batched_equals_sequential(model):
+@pytest.mark.parametrize("page_size", [4, 16])
+def test_batched_equals_sequential(model, page_size):
+    """Request "d" takes the slot "b" leaves: with pages of 4 both cross
+    page boundaries, with pages of 16 each lives inside one page."""
     cfg, params = model
-    eng = GenerationEngine(params, cfg, max_slots=3, max_len=96)
+    eng = PagedEngine(params, cfg, max_slots=3, max_len=96,
+                      page_size=page_size, num_pages=3 * 96 // page_size + 1)
     prompts = {
         "a": ([1, 2, 3, 4], 12),
         "b": ([7, 8], 5),            # finishes early, frees its slot
@@ -46,7 +51,7 @@ def test_eos_stops_early(model):
     cfg, params = model
     ref = _ref(params, cfg, [5, 6, 7], 20)
     eos = ref[4]  # force an early stop at the 5th generated token
-    eng = GenerationEngine(params, cfg, max_slots=2, max_len=96)
+    eng = PagedEngine(params, cfg, max_slots=2, max_len=96)
     eng.submit("x", [5, 6, 7], max_new_tokens=20, eos_id=eos)
     got = eng.run_to_completion()
     assert got["x"] == ref[:5]
@@ -54,14 +59,14 @@ def test_eos_stops_early(model):
 
 def test_capacity_guard(model):
     cfg, params = model
-    eng = GenerationEngine(params, cfg, max_slots=1, max_len=32)
-    with pytest.raises(ValueError, match="exceeds engine max_len"):
+    eng = PagedEngine(params, cfg, max_slots=1, max_len=32)
+    with pytest.raises(ValueError, match="exceeds per-sequence capacity"):
         eng.submit("big", list(range(20)), max_new_tokens=20)
 
 
 def test_sampling_deterministic_and_bounded(model):
     cfg, params = model
-    eng = GenerationEngine(params, cfg, max_slots=2, max_len=64)
+    eng = PagedEngine(params, cfg, max_slots=2, max_len=64)
     eng.submit("s1", [1, 2, 3], max_new_tokens=10, temperature=0.8,
                top_k=10, seed=42)
     eng.submit("greedy", [1, 2, 3], max_new_tokens=10)  # temp 0
@@ -70,11 +75,11 @@ def test_sampling_deterministic_and_bounded(model):
     assert got["greedy"] == _ref(params, cfg, [1, 2, 3], 10)
     assert len(got["s1"]) == 10
     # same seed -> same sample; different seed -> (almost surely) differs
-    eng2 = GenerationEngine(params, cfg, max_slots=1, max_len=64)
+    eng2 = PagedEngine(params, cfg, max_slots=1, max_len=64)
     eng2.submit("s1", [1, 2, 3], max_new_tokens=10, temperature=0.8,
                 top_k=10, seed=42)
     assert eng2.run_to_completion()["s1"] == got["s1"]
-    eng3 = GenerationEngine(params, cfg, max_slots=1, max_len=64)
+    eng3 = PagedEngine(params, cfg, max_slots=1, max_len=64)
     eng3.submit("s1", [1, 2, 3], max_new_tokens=10, temperature=0.8,
                 top_k=10, seed=7)
     assert eng3.run_to_completion()["s1"] != got["s1"]

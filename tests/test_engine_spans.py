@@ -1,6 +1,6 @@
 """The flight recorder inside the engine and the pump (ISSUE 24).
 
-``events.span`` rows from ``PagedEngine`` / ``GenerationEngine`` /
+``events.span`` rows from ``PagedEngine`` and
 ``LLMServer._engine_loop`` on the monotonic clock, their nesting, what they
 cost a disabled recorder (nothing, not even a clock), the profiler
 annotations they double as, and the spill file that lets the rows outlive
@@ -19,7 +19,6 @@ import pytest
 
 import ray_tpu
 from ray_tpu.models import LlamaConfig, init_params
-from ray_tpu.models.engine import GenerationEngine
 from ray_tpu.models.paged import PagedEngine
 from ray_tpu.util import events
 
@@ -47,12 +46,10 @@ def model():
     return cfg, init_params(cfg, jax.random.PRNGKey(0))
 
 
-def _engine(kind, model, **kw):
+def _engine(model):
     cfg, params = model
-    if kind == "paged":
-        return PagedEngine(params, cfg, max_slots=2, num_pages=24,
-                           page_size=8, max_len=64, **kw)
-    return GenerationEngine(params, cfg, max_slots=2, max_len=64)
+    return PagedEngine(params, cfg, max_slots=2, num_pages=24,
+                       page_size=8, max_len=64)
 
 
 def _drive(eng):
@@ -121,11 +118,10 @@ def test_disabled_span_reads_no_clock_and_records_nothing():
     assert _rows() == []
 
 
-# ------------------------------------------------------- the engines' rows
+# -------------------------------------------------------- the engine's rows
 
-@pytest.mark.parametrize("kind", ["paged", "dense"])
-def test_step_rows_count_calls_and_tokens(kind, model):
-    _, calls, tokens = _drive(_engine(kind, model))
+def test_step_rows_count_calls_and_tokens(model):
+    _, calls, tokens = _drive(_engine(model))
     rows = _rows()
     steps = [r for r in rows if r["name"] == "serve.engine.step"]
     assert len(steps) == calls
@@ -142,13 +138,10 @@ def test_step_rows_count_calls_and_tokens(kind, model):
         assert f["bucket"] == 16
         parent = next(s for s in steps if s["fields"]["sid"] == f["parent"])
         assert _inside(a, parent)
-    if kind == "dense":     # one vocabulary, the top-level spans only
-        assert {r["name"] for r in rows} == {"serve.engine.step",
-                                             "serve.engine.admit"}
 
 
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
-    _drive(_engine("paged", model))
+    _drive(_engine(model))
     rows = _rows()
     admits = [r for r in rows if r["name"] == "serve.engine.admit"]
     assert len(admits) == len(REQS)
@@ -192,12 +185,11 @@ def test_preemption_is_counted_on_the_step_row(model):
         s["fields"]["preempted"] for s in steps)
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense"])
-def test_greedy_identical_with_recorder_on_and_off(kind, model):
-    on, _, _ = _drive(_engine(kind, model))
+def test_greedy_identical_with_recorder_on_and_off(model):
+    on, _, _ = _drive(_engine(model))
     assert _rows()
     events._enabled = False
-    off, _, _ = _drive(_engine(kind, model))
+    off, _, _ = _drive(_engine(model))
     events._enabled = True
     assert _rows() == []        # off yields no rows
     assert on == off
@@ -206,7 +198,7 @@ def test_greedy_identical_with_recorder_on_and_off(kind, model):
 def test_spans_are_profiler_annotations_under_a_trace(model, tmp_path):
     from jax.profiler import ProfileData
 
-    eng = _engine("paged", model)
+    eng = _engine(model)
     with jax.profiler.trace(str(tmp_path)):
         _drive(eng)
     path = glob.glob(os.path.join(
@@ -226,7 +218,7 @@ def test_pump_and_request_rows_share_the_engine_clock(model):
 
     cfg, params = model
     server = LLMServer(lambda: (params, cfg), max_slots=2, max_len=64,
-                       kv_cache="paged", num_pages=24, page_size=8)
+                       num_pages=24, page_size=8)
 
     async def run():
         async def stream():
@@ -280,7 +272,7 @@ def test_one_admission_and_one_step_emit_all_ten_span_names(model):
 
     cfg, params = model
     server = LLMServer(lambda: (params, cfg), max_slots=2, max_len=64,
-                       kv_cache="paged", num_pages=24, page_size=8)
+                       num_pages=24, page_size=8)
     out = asyncio.run(server({"prompt": list(range(1, 20)),
                               "max_new_tokens": 2}))
     assert out["num_tokens"] == 2

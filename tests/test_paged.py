@@ -1,6 +1,6 @@
 """Paged KV engine (models/paged.py): shared page pool, on-demand
-allocation, parity with the dense-slot engine and with per-request
-greedy decode."""
+allocation, parity with per-request greedy decode and with the page
+loop the scatter program replaced."""
 
 import warnings
 
@@ -40,6 +40,18 @@ def test_paged_matches_greedy(model):
         assert got[rid] == _ref(params, cfg, p, n), rid
     # every page returned to the pool (page 0 stays reserved)
     assert sorted(eng.free_pages) == list(range(1, 24))
+
+
+@pytest.mark.parametrize("kw, request_, match", [
+    # 20 + 20 + 1 positions fit max_len 64 but need 3 of the pool's 2 pages
+    (dict(num_pages=3), ([1] * 20, 20), "more pages than the pool holds"),
+    (dict(kv_dtype="fp8"), None, "kv_dtype must be"),
+])
+def test_what_cannot_be_served_is_refused_in_words(model, kw, request_, match):
+    cfg, params = model
+    with pytest.raises(ValueError, match=match):
+        eng = PagedEngine(params, cfg, max_slots=1, max_len=64, **kw)
+        eng.submit("r", request_[0], max_new_tokens=request_[1])
 
 
 def test_pages_allocated_on_demand(model):

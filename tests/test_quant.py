@@ -97,11 +97,11 @@ def test_quant_composes_with_speculative(small):
 def test_quant_composes_with_engine(small):
     """int8 params drive the continuous-batching engine unchanged."""
     from ray_tpu.models import generate_greedy
-    from ray_tpu.models.engine import GenerationEngine
+    from ray_tpu.models.paged import PagedEngine
 
     cfg, params = small
     qparams = quantize_params(params)
-    eng = GenerationEngine(qparams, cfg, max_slots=2, max_len=48)
+    eng = PagedEngine(qparams, cfg, max_slots=2, max_len=48)
     eng.submit("a", [3, 4, 5], max_new_tokens=8)
     eng.submit("b", [9, 8], max_new_tokens=6)
     got = eng.run_to_completion()

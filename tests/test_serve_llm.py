@@ -94,7 +94,7 @@ def test_paged_llm_app(llm_app):
     from ray_tpu.serve.llm import build_llm_app
 
     handle = serve.run(build_llm_app(tiny_model, max_slots=4,
-                                     kv_cache="paged", num_pages=24,
+                                     num_pages=24,   # < 4 x 96 positions
                                      page_size=8, max_len=96),
                        name="llm-paged", route_prefix=None)
     got = handle.remote({"prompt": [2, 3, 4],
@@ -191,7 +191,7 @@ def test_weight_refresh_invalidates_prefix_cache(llm_app):
     from ray_tpu.serve.llm import build_llm_app
 
     handle = serve.run(
-        build_llm_app(tiny_model, max_slots=2, kv_cache="paged",
+        build_llm_app(tiny_model, max_slots=2,
                       num_pages=24, page_size=8, max_len=96,
                       enable_prefix_cache=True),
         name="llm-paged-refresh", route_prefix="/llm-paged-refresh")
@@ -245,3 +245,57 @@ def test_speculative_request_path(llm_app):
     with pytest.raises(Exception):
         llm_app.remote({"prompt": [1], "max_new_tokens": 4,
                         "speculative": True}).result(timeout=120)
+
+
+# ------------------------------------- the replica nobody configured
+# In-process, no cluster: the constructor and the engine it builds.
+
+def test_default_replica_is_paged_and_never_waits_for_pages():
+    """``LLMServer(factory)`` sizes its pool from ``max_slots`` and
+    ``max_len``: every slot can run to ``max_len`` at once, so no request
+    waits for a page and none is preempted."""
+    from ray_tpu.models.paged import PagedEngine
+    from ray_tpu.serve.llm import LLMServer
+
+    server = LLMServer(tiny_model, max_slots=2, max_len=64)
+    eng = server.engine
+    assert isinstance(eng, PagedEngine)
+    assert eng.num_pages == 2 * (64 // 16) + 1
+    prompts = {"a": [1, 2, 3], "b": [7, 8, 9, 10, 11]}
+    for rid, prompt in prompts.items():     # each fills its max_len
+        eng.submit(rid, prompt, max_new_tokens=64 - len(prompt) - 1)
+    got = {rid: [] for rid in prompts}
+    fewest_free = eng.num_pages
+    while eng.has_work():
+        for rid, tok in eng.step():
+            if tok is not None:
+                got[rid].append(tok)
+        assert eng._preempted == 0 and not eng.pending
+        fewest_free = min(fewest_free, len(eng.free_pages))
+    assert fewest_free == 0     # the pool is that size and no larger
+    for rid, prompt in prompts.items():
+        assert got[rid] == _ref(prompt, 64 - len(prompt) - 1), rid
+
+
+def test_explicit_num_pages_is_used_as_given():
+    """A configuration that sized its pool to the chip (every cell of the
+    benchmark) gets that pool, not the derived one."""
+    from ray_tpu.serve.llm import LLMServer
+
+    server = LLMServer(tiny_model, max_slots=4, max_len=96, num_pages=24,
+                       page_size=8)
+    assert server.engine.num_pages == 24 < 4 * (96 // 8) + 1
+    assert len(server.engine.free_pages) == 23      # page 0 is scratch
+
+
+def test_kv_cache_other_than_paged_is_refused():
+    """One engine: the keyword the benchmark's configuration files still
+    pass is accepted with the one value left, and selects nothing."""
+    from ray_tpu.models.paged import PagedEngine
+    from ray_tpu.serve.llm import LLMServer
+
+    with pytest.raises(ValueError, match="PagedEngine"):
+        LLMServer(tiny_model, kv_cache="dense")
+    server = LLMServer(tiny_model, max_slots=2, max_len=64,
+                       kv_cache="paged")
+    assert isinstance(server.engine, PagedEngine)
