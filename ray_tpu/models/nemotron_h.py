@@ -37,7 +37,7 @@ from ..ops import ssm
 from ..ops.layers import rms_norm
 from ..ops.quant import mm
 from ..parallel.moe import moe_ffn_share, relu2, sigmoid_gates
-from .engine import _pick_token
+from .engine import _pick_tokens
 from .paged_ops import paged_attention
 
 F32 = jnp.float32
@@ -484,8 +484,8 @@ def _hybrid_step(params, pools_k, pools_v, scales_k, scales_v, ssm_states,
                             ssm_states, conv_tails, tables, toks, lengths,
                             cfg, page, kv_int8)
     splits = jax.vmap(jax.random.split)(keys)
-    picked = jax.vmap(_pick_token)(logits, temps, top_ks, top_ps,
-                                   splits[:, 1])
+    picked = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
+                          lengths)
     out = jnp.concatenate([picked.astype(jnp.int32), load])
     return (out, new_k, new_v, new_sk, new_sv, new_ssm, new_conv,
             splits[:, 0], routing)

@@ -29,7 +29,7 @@ import numpy as np
 from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..util import events as plane_events
-from .engine import _pick_one, _pick_token, _prefill_one
+from .engine import _pick_one, _pick_tokens, _prefill_one
 from .paged_ops import _quant_kv, paged_attention  # noqa: F401 (re-export)
 from .llama import LlamaConfig, _mlp_block
 from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
@@ -88,8 +88,8 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
             else params["lm_head"])
     logits = mm(x[:, 0], head)                     # [S, V]
     splits = jax.vmap(jax.random.split)(keys)
-    out = jax.vmap(_pick_token)(logits, temps, top_ks, top_ps,
-                                splits[:, 1])
+    out = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
+                       lengths)
     return (out, new_pools_k, new_pools_v, new_scales_k, new_scales_v,
             splits[:, 0])
 
@@ -505,8 +505,9 @@ class PagedEngine:
         with plane_events.span("serve.engine.step", "serve",
                                k=self._steps) as sp:
             self._steps += 1
-            events, active = self._step()
-            sp.set(active=active, admitted=self._admitted,
+            events, active, sampling = self._step()
+            sp.set(active=active, sampling=sampling,
+                   admitted=self._admitted,
                    tokens=sum(1 for _, tok in events if tok is not None),
                    pending=len(self.pending),
                    free_pages=len(self.free_pages),
@@ -517,10 +518,11 @@ class PagedEngine:
         return events
 
     def _step(self):
-        """-> (events, slots that decoded). Four phases tile the time
-        after ``_admit``: prepare (tables and uploads), dispatch (the
-        step program's call until it returns), fetch (blocks on the
-        device), emit (the per-slot loop)."""
+        """-> (events, slots that decoded, those of them that sampled:
+        0 means the step program took ``_pick_tokens``' argmax side).
+        Four phases tile the time after ``_admit``: prepare (tables and
+        uploads), dispatch (the step program's call until it returns),
+        fetch (blocks on the device), emit (the per-slot loop)."""
         self._expert_load = None
         self._admit()
         with plane_events.span("serve.step.prepare", "serve"):
@@ -535,13 +537,14 @@ class PagedEngine:
             active = [i for i, s in enumerate(self.slots)
                       if s is not None]
             if not active:
-                return events, 0
+                return events, 0, 0
             active = self._grow_tables(active)
             if not active:
-                return events, 0
+                return events, 0, 0
             lengths = np.array([self.slots[i].length if self.slots[i]
                                 else 0 for i in range(self.S)],
                                dtype=np.int32)
+            sampling = int(np.count_nonzero(self.temps[active] > 0.0))
             no_scales = [0] * self.n_kv
             uploads = (
                 jnp.asarray(self.tables), jnp.asarray(self.last_tok),
@@ -588,7 +591,7 @@ class PagedEngine:
                     self._free(s)
                     self.slots[i] = None
                     self.tables[i] = 0
-        return events, len(active)
+        return events, len(active), sampling
 
     def _grow_tables(self, active: List[int]) -> List[int]:
         """Grow page tables BEFORE the step for slots crossing a page

@@ -107,3 +107,121 @@ def test_top_p_and_top_k_masks(model):
                             jnp.float32(1.0), jax.random.PRNGKey(s)))
             for s in range(30)}
     assert seen <= {1, 2, 4} and len(seen) > 1
+
+
+# ------------------------------------------------------ the batch picker
+# One case a batch: (temps, top_ks, top_ps, lengths) for S = 6 slots.
+_S = 6
+_BATCHES = {
+    "all_greedy": ([0.0] * _S, [0] * _S, [1.0] * _S, [5, 1, 9, 2, 7, 3]),
+    "sampling_k0_p1": ([0.8] * _S, [0] * _S, [1.0] * _S, [5, 1, 9, 2, 7, 3]),
+    "sampling_k3_p1": ([0.8] * _S, [3] * _S, [1.0] * _S, [5, 1, 9, 2, 7, 3]),
+    "sampling_k0_p.5": ([1.3] * _S, [0] * _S, [0.5] * _S, [5, 1, 9, 2, 7, 3]),
+    "sampling_k3_p.5": ([1.3] * _S, [3] * _S, [0.5] * _S, [5, 1, 9, 2, 7, 3]),
+    "mixed": ([0.0, 0.8, 0.0, 2.0, 0.0, 0.3], [0, 3, 0, 0, 5, 40],
+              [1.0, 1.0, 0.5, 0.5, 1.0, 0.9], [5, 1, 0, 2, 7, 3]),
+    "one_sampler_beside_empty_slots": (
+        [0.0, 0.0, 0.7, 0.0, 0.0, 0.0], [0, 0, 4, 0, 0, 0],
+        [1.0] * _S, [0, 0, 6, 0, 0, 0]),
+    # the only temperature above 0 is what a finished request left in a
+    # slot that is now empty: the batch is greedy
+    "stale_temperature": ([0.0, 0.9, 0.0, 0.0, 1.5, 0.0], [0, 3, 0, 0, 0, 0],
+                          [1.0, 0.5, 1.0, 1.0, 1.0, 1.0], [5, 0, 9, 2, 0, 3]),
+}
+_GREEDY_BATCHES = {"all_greedy", "stale_temperature"}
+
+
+def _logit_rows(seed, vocab=128):
+    """bf16-rounded logits with exact ties among them, the top two of row
+    0 included: the picker must break them as the row function does."""
+    rows = jax.random.normal(jax.random.PRNGKey(seed), (_S, vocab)) * 3.0
+    rows = rows.astype(jnp.bfloat16).astype(jnp.float32)
+    top = jnp.max(rows[0])
+    rows = rows.at[0, 17].set(top).at[0, 90].set(top)
+    rows = rows.at[3, 5:9].set(rows[3, 40])
+    assert int(jnp.sum(rows[0] == top)) >= 2
+    return rows
+
+
+def _batch(name):
+    temps, top_ks, top_ps, lengths = _BATCHES[name]
+    return (jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
+            jnp.asarray(top_ps, jnp.float32),
+            jax.random.split(jax.random.PRNGKey(3), _S),
+            jnp.asarray(lengths, jnp.int32))
+
+
+@pytest.mark.parametrize("name", list(_BATCHES))
+def test_batch_picker_equals_the_row_function_under_vmap(name):
+    from ray_tpu.models.engine import _pick_token, _pick_tokens
+
+    temps, top_ks, top_ps, keys, lengths = _batch(name)
+    live = lengths > 0              # an empty slot's token is never read
+    stale_differs = False
+    for seed in range(4):
+        logits = _logit_rows(seed)
+        rows = jax.vmap(_pick_token)(logits, temps, top_ks, top_ps, keys)
+        got = jax.jit(_pick_tokens)(logits, temps, top_ks, top_ps, keys,
+                                    lengths)
+        assert got.dtype == rows.dtype and got.shape == (_S,)
+        if name in _GREEDY_BATCHES:
+            # the argmax side ran: every slot holds the argmax, the empty
+            # ones too, whose stale temperature the row function obeys
+            assert got.tolist() == jnp.argmax(logits, axis=-1).tolist()
+            assert got[live].tolist() == rows[live].tolist()
+            stale_differs |= got.tolist() != rows.tolist()
+        else:
+            assert got.tolist() == rows.tolist()
+    if name == "stale_temperature":
+        assert stale_differs    # the row function would have sampled there
+
+
+def _eqns(jaxpr, into_cond):
+    """Every equation of a jaxpr with nested calls opened; the branches
+    of a ``cond`` only where asked: what is left out then runs whatever
+    the ``cond`` chooses."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "cond" and not into_cond:
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, into_cond)
+
+
+def _names(jaxpr, into_cond=True):
+    return [e.primitive.name for e in _eqns(jaxpr, into_cond)]
+
+
+_SORT_PATH = {"sort", "cumsum", "gather", "random_bits", "exp"}
+
+
+@pytest.mark.parametrize("picker", ["_pick_tokens", "_pick_one"])
+def test_the_sort_lives_under_one_cond_on_the_batch(picker):
+    """A later edit that hoists the sort, the gathers or the cumulative
+    sum back out of the ``cond`` (or puts the ``cond`` under the ``vmap``,
+    where it lowers to a ``select`` that runs both sides) fails here and
+    not only in a chip run."""
+    from ray_tpu.models import engine
+
+    temps, top_ks, top_ps, keys, lengths = _batch("mixed")
+    logits = _logit_rows(0)
+    if picker == "_pick_tokens":
+        jaxpr = jax.make_jaxpr(engine._pick_tokens)(
+            logits, temps, top_ks, top_ps, keys, lengths)
+    else:
+        jaxpr = jax.make_jaxpr(engine._pick_one)(
+            logits[0], temps[0], top_ks[0], top_ps[0], keys[0])
+    outside = _names(jaxpr.jaxpr, into_cond=False)
+    assert outside.count("cond") == 1
+    assert "argmax" in outside and not _SORT_PATH & set(outside)
+    assert "select_n" not in outside        # no per-slot choice out here
+    cond = next(e for e in _eqns(jaxpr.jaxpr, False)
+                if e.primitive.name == "cond")
+    assert cond.invars[0].aval.shape == ()  # one predicate for the batch
+    greedy, sample = sorted((_names(b.jaxpr) for b in
+                             cond.params["branches"]), key=len)
+    assert not greedy                       # hands on the argmax
+    assert {"sort", "cumsum", "gather"} <= set(sample)

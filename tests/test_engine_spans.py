@@ -140,6 +140,26 @@ def test_step_rows_count_calls_and_tokens(model):
         assert _inside(a, parent)
 
 
+def test_step_rows_count_the_slots_that_sample(model):
+    """``sampling`` on the step row is the active slots with a
+    temperature above 0: 0 means the step program took the argmax side of
+    ``_pick_tokens``. The slot a finished sampling request leaves EMPTY
+    keeps its temperature (nothing clears it) and must not count. The
+    tokens are the parent commit's for this seed: the keys are split as
+    before the ``cond``."""
+    eng = _engine(model)
+    eng.submit("req-samp", [1, 2, 3, 4], max_new_tokens=4, temperature=0.8,
+               top_k=10, top_p=0.9, seed=5)
+    eng.submit("req-greedy", [7, 8], max_new_tokens=9)
+    got = eng.run_to_completion()
+    assert got == {"req-samp": [63, 78, 19, 78],
+                   "req-greedy": [57, 32, 85, 85, 85, 85, 85, 85, 85]}
+    steps = [r["fields"] for r in _rows() if r["name"] == "serve.engine.step"]
+    assert [f["sampling"] for f in steps] == [1, 1, 1, 0, 0, 0, 0, 0]
+    assert [f["active"] for f in steps] == [2, 2, 2, 1, 1, 1, 1, 1]
+    assert eng.slots == [None, None] and eng.temps[0] > 0   # left stale
+
+
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
     _drive(_engine(model))
     rows = _rows()

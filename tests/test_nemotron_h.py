@@ -267,6 +267,31 @@ def test_state_write_span_and_expert_load_on_the_step_row(params,
     assert all("experts_hit" not in f for f in idle)
 
 
+def test_a_sampled_request_streams_the_parents_tokens_and_is_counted(
+        params, _clean_ring):
+    """The hybrid step through the same picker: the sampled tokens are
+    the parent commit's for this seed, alone and beside a greedy
+    neighbour, and ``sampling`` falls to 0 on the rows after the sampling
+    request left its slot empty with its temperature still in it."""
+    samp = dict(max_new_tokens=4, temperature=0.8, top_k=10, top_p=0.9,
+                seed=5)
+    alone = _engine(params)
+    alone.submit("req-samp", _tokens(9, 2), **samp)
+    assert alone.run_to_completion() == {"req-samp": [63, 78, 37, 76]}
+    events.reset()
+    eng = _engine(params)
+    eng.submit("req-samp", _tokens(9, 2), **samp)
+    eng.submit("req-greedy", _tokens(5, 3), max_new_tokens=9)
+    assert eng.run_to_completion() == {
+        "req-samp": [63, 78, 37, 76],
+        "req-greedy": [57, 18, 42, 24, 0, 57, 71, 33, 16]}
+    assert eng.slots == [None] * 3 and eng.temps[0] > 0     # left stale
+    rows = [events.row_to_dict(r) for r in events.drain()[0]]
+    steps = [r["fields"] for r in rows if r["name"] == "serve.engine.step"]
+    assert [f["sampling"] for f in steps] == [1, 1, 1, 0, 0, 0, 0, 0]
+    assert [f["active"] for f in steps] == [2, 2, 2, 1, 1, 1, 1, 1]
+
+
 def test_greedy_identical_with_recorder_on_and_off(params, _clean_ring):
     prompt = _tokens(10, 6)
     on = _alone(params, prompt, 6)

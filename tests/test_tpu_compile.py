@@ -69,6 +69,22 @@ def _assert_no_repeated_table(compiled, S, cap, kvh, rep, d):
         assert shape not in text, shape
 
 
+def _assert_vocabulary_sorted_under_a_conditional(compiled, S, V):
+    """The chip's compiler keeps ``_pick_tokens``' choice a choice: one
+    ``conditional`` in the entry computation, and the vocabulary-wide
+    sort and the two flat gathers of S x V elements (``f32[S*V]``, the
+    two largest operations of a step before PR 31) in a branch of it,
+    not beside it."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert entry.count(" conditional(") == 1
+    wide_sort = [ln for ln in text.splitlines()
+                 if " sort(" in ln and f"[{S},{V}]" in ln]
+    assert wide_sort and not [ln for ln in wide_sort if ln in entry]
+    assert f"f32[{S * V}]" in text and f"f32[{S * V}]" not in entry
+
+
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [(32, 8, 64),
                                                      (16, 8, 128)])
 def test_mosaic_flash_fwd_bwd_L2048(one_chip, heads, kv_heads, head_dim):
@@ -130,6 +146,8 @@ def test_paged_step_llama3_1b_widths(one_chip):
     # these widths (a step that repeats the table holds 341 MB).
     repeated = S * max_len * cfg.n_heads * cfg.head_dim * 4
     assert compiled.memory_analysis().temp_size_in_bytes < repeated
+    _assert_vocabulary_sorted_under_a_conditional(compiled, S,
+                                                  cfg.vocab_size)
 
 
 def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
@@ -159,6 +177,8 @@ def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
     assert m.alias_size_in_bytes >= donated
     _assert_no_repeated_table(compiled, S, max_len, cfg.n_kv_heads,
                               cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
+    _assert_vocabulary_sorted_under_a_conditional(compiled, S,
+                                                  cfg.vocab_size)
     compiled = nh._hybrid_prefill.lower(
         params, i32((256,)), 1, max_len, cfg, 256).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
