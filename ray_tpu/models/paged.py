@@ -32,6 +32,8 @@ from ..util import events as plane_events
 from .engine import _pick_one, _pick_tokens, _prefill_one
 from .paged_ops import _quant_kv, paged_attention  # noqa: F401 (re-export)
 from .llama import LlamaConfig, _mlp_block
+from . import minicpm_sala as sala
+from .minicpm_sala import MiniCPMSALAConfig
 from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
                          _write_state, init_state)
 
@@ -145,6 +147,126 @@ def _suffix_prefill(params, prefix_caches, suffix_padded, prefix_len,
     return first, [(kc[0], vc[0]) for kc, vc in new]
 
 
+# ------------------------------------------- families with per-slot state
+# What a family with recurrent layers brings, by the type of its config:
+# how many layers have K/V pools, the per-slot state, the prefill, the
+# scatter where it has more than K/V to scatter, and the step. Each takes
+# the engine; the state write (``_write_state``) is the same for all.
+def _nemotron_state(eng):
+    eng.ssm, eng.conv = init_state(eng.cfg, eng.S)
+    # the last step's chosen experts [expert layers, S, k]: left on the
+    # device, for a reference check to read
+    eng.last_routing = None
+
+
+def _nemotron_prefill(eng, suffix, pad, n):
+    padded = jnp.asarray(suffix + [0] * (pad - len(suffix)), dtype=jnp.int32)
+    return _hybrid_prefill(eng.params, padded, n, eng.max_len, eng.cfg,
+                           pad)[:3]
+
+
+def _nemotron_step(eng, scales, uploads):
+    (toks, eng.pools_k, eng.pools_v, sk, sv, eng.ssm, eng.conv, new_keys,
+     eng.last_routing) = _hybrid_step(
+        eng.params, eng.pools_k, eng.pools_v, *scales, eng.ssm, eng.conv,
+        *uploads, eng.cfg, eng.page, eng.kv_int8)
+    return toks, sk, sv, new_keys, None
+
+
+def _nemotron_counts(eng, tail, sp):
+    # the expert layers' load rode with the tokens
+    sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]))
+
+
+def _sala_state(eng):
+    cfg = eng.cfg
+    if eng.page != cfg.block or eng.max_len % cfg.prefill_chunk \
+            or eng.kv_int8 or cfg.topk > eng.P:
+        raise ValueError(
+            "this family's block is the page (page_size == cfg.block), its "
+            "prefill fills max_len in whole chunks, its table holds topk "
+            "pages and its pages are kept in the model's dtype")
+    eng.ssm, eng.conv = sala.init_state(cfg, eng.S), []
+    # beside each sparse layer's K/V pool the indexer's cache: one
+    # compressed key per ``stride`` positions, on the page of its first
+    eng.pools_c = [jnp.zeros((eng.num_pages, eng.page // cfg.stride,
+                              cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+                   for _ in range(eng.n_kv)]
+    # the last step's chosen blocks [sparse layers, S, kvh, topk]: left on
+    # the device, for a reference check to read
+    eng.last_selection = None
+
+
+def _sala_prefill(eng, suffix, pad, n):
+    first, caches, states = sala.prefill(
+        eng.params, suffix, eng.max_len, eng.cfg)
+    return first, caches, [(s,) for s in states]
+
+
+def _sala_scatter(eng, caches, page_ids):
+    kv, ckeys = caches
+    eng.pools_k, eng.pools_v, eng.pools_c = sala._scatter_sala(
+        eng.pools_k, eng.pools_v, eng.pools_c, kv, ckeys, page_ids)
+
+
+def _sala_admit_fields(eng, n):
+    """What an admission's prefill and scatter spans say besides: the
+    chunk program's dispatches, the compressed-key rows scattered."""
+    return ({"chunks": -(-n // eng.cfg.prefill_chunk)},
+            {"ckeys": eng.max_len // eng.cfg.stride * eng.n_kv})
+
+
+def _sala_step(eng, scales, uploads):
+    (toks, eng.pools_k, eng.pools_v, eng.pools_c, eng.ssm, new_keys,
+     chosen, next_tok) = sala._sala_step(
+        eng.params, eng.pools_k, eng.pools_v, eng.pools_c, eng.ssm,
+        *uploads, eng.cfg, eng.page)
+    return toks, None, None, new_keys, (next_tok, chosen)
+
+
+def _sala_landed(eng, chosen):
+    eng.last_selection = chosen
+
+
+def _sala_counts(eng, tail, sp):
+    sp.set(sparse_pages_read=int(tail[0]), sparse_pages_live=int(tail[1]),
+           sparse_slots=int(tail[2]))
+
+
+@dataclass(frozen=True)
+class _Recurrent:
+    n_kv: object            # cfg -> layers with a K/V pool
+    state: object           # engine -> None: allocates the per-slot state
+    prefill: object         # (engine, prompt, pad, n) -> (first logits,
+    #                         caches, per-layer state tuples)
+    step: object            # (engine, scales, uploads) -> (tokens and the
+    #                         step's counts, scales_k, scales_v, keys, and
+    #                         None or (the tokens alone as the next step
+    #                         takes them, what ``landed`` publishes): with
+    #                         them the engine runs ahead of the device, ``_step``)
+    counts: object          # (engine, what rode with the tokens, the step's
+    #                         span): the family's fields of the step row
+    scatter: object = None  # (engine, caches, page_ids), where the prefill
+    #                         returns more than K/V
+    admit_fields: object = None     # (engine, prompt length) -> fields of
+    #                         the admission's prefill and scatter spans
+    buckets: tuple = (16, 64, 256)  # what a prompt is padded to under
+    #                         max_len; none where it is admitted in chunks
+    landed: object = None   # (engine, what the step kept on the device):
+    #                         called when that step's tokens are fetched
+
+
+_RECURRENT = {
+    NemotronHConfig: _Recurrent(
+        lambda cfg: cfg.n_attn_layers, _nemotron_state, _nemotron_prefill,
+        _nemotron_step, _nemotron_counts),
+    MiniCPMSALAConfig: _Recurrent(
+        lambda cfg: cfg.n_sparse_layers, _sala_state, _sala_prefill,
+        _sala_step, _sala_counts, _sala_scatter, _sala_admit_fields, (),
+        _sala_landed),
+}
+
+
 @dataclass
 class _PagedSlot:
     request_id: str
@@ -156,6 +278,29 @@ class _PagedSlot:
     n_shared: int = 0        # leading pages borrowed from the prefix cache
     emitted: List[int] = field(default_factory=list)
     done: bool = False
+
+
+#: Steps kept dispatched beyond the one whose tokens a call fetches, where
+#: the family's step can run ahead. One hides the host's part of a step;
+#: ten of ~16 ms ride out the ~0.1 s for which a shared host stops every
+#: process on it once or twice a minute (the chip goes on with what it was
+#: given: PERF.md section 6, PR 32). The cost: a request that finds a free
+#: slot waits for them to land before it is admitted.
+_STEPS_AHEAD = 10
+
+
+@dataclass
+class _Flight:
+    """A dispatched step whose tokens the host has not fetched yet."""
+    toks: object        # device: the tokens (a recurrent step's counts after)
+    keys: object        # device: the slots' sampling keys after the step
+    active: List[int]   # the slots that decoded in it
+    next_tok: object = None     # device int32[S]: the tokens alone
+    kept: object = None         # what the family publishes when it lands
+
+    def ended(self) -> bool:
+        """Whether the device has finished the step (asks, never waits)."""
+        return self.toks.is_ready()
 
 
 class PagedEngine:
@@ -171,11 +316,30 @@ class PagedEngine:
     has pools for its attention layers only and, beside them, per-slot
     recurrent state of its Mamba layers (SSM state and convolution tail,
     ``self.ssm`` / ``self.conv``): written whole at admission, advanced
-    by the step for all slots, donated to both. Pages, tables, admission
-    order, preemption by recompute and the spans are the same code.
+    by the step for all slots, donated to both. A ``MiniCPMSALAConfig``
+    has pools for its sparse layers only, a compressed-key pool beside each
+    (``self.pools_c``: the cache of the layer's block selection, on the
+    same pages) and per-slot lightning state (``self.ssm``); its prompts
+    are admitted in chunks. Pages, tables, admission order, preemption by
+    recompute and the spans are the same code (``_RECURRENT`` holds what
+    differs).
+
+    Where the family's step program hands its tokens on as a device array
+    (``_Recurrent.step``), the engine **runs ahead of the device**:
+    ``step()`` dispatches the next step on the tokens and keys the last
+    one left on the device, keeps up to ``_STEPS_AHEAD`` dispatched and
+    fetches the oldest's tokens as it ends. The host's part of a step
+    (uploads, dispatch, transfer, the pump between calls) then lies under
+    the device's, and a host that stands still for a tenth of a second
+    finds the device still at work. Events come some calls after their
+    step's dispatch, at the instant they would have come. It runs ahead
+    only while nothing but the count of tokens decides what the next step
+    holds (``_runs_ahead``); otherwise the steps in flight land, one a
+    call, and the call that lands the last is the synchronous one.
     """
 
-    def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig], *,
+    def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig,
+                                          MiniCPMSALAConfig], *,
                  max_slots: int = 8,
                  num_pages: int = 64, page_size: int = 16,
                  max_len: int = 512, enable_prefix_cache: bool = False,
@@ -187,18 +351,14 @@ class PagedEngine:
         self.num_pages = num_pages
         self.P = max_len // page_size           # table width per slot
         self.max_len = self.P * page_size
-        self.recurrent = isinstance(cfg, NemotronHConfig)
+        self.recurrent = _RECURRENT.get(type(cfg))
         if self.recurrent:
             if enable_prefix_cache:
                 raise ValueError(
                     "enable_prefix_cache needs snapshots of the recurrent "
                     "state at page boundaries, which this engine does not "
                     "keep: a model with recurrent layers runs without it")
-            self.n_kv = cfg.n_attn_layers
-            self.ssm, self.conv = init_state(cfg, max_slots)
-            # the last step's chosen experts [expert layers, S, k]: left
-            # on the device, for a reference check to read
-            self.last_routing = None
+            self.n_kv = self.recurrent.n_kv(cfg)
         else:
             self.n_kv = cfg.n_layers
             self.cos, self.sin = rope_frequencies(
@@ -240,9 +400,13 @@ class PagedEngine:
         self.pending: List[tuple] = []
         self._admit_events: List[tuple] = []
         self._prefill_buckets = (16, 64, 256)
+        if self.recurrent:
+            self._prefill_buckets = self.recurrent.buckets
+            self.recurrent.state(self)
         # what this step() did, for its ``serve.engine.step`` row
         self._steps = self._admitted = self._preempted = 0
-        self._expert_load = None    # (held experts hit, most tokens of one)
+        self._step_counts = None    # what rode with a recurrent step's tokens
+        self._flights: List[_Flight] = []   # dispatched, not fetched, in order
         # Prefix cache: full-prompt-page content hash -> (page id,
         # refcount). Pages with refcount 0 stay resident (reusable)
         # until pool pressure evicts them LRU (``_reclaim``).
@@ -414,16 +578,25 @@ class PagedEngine:
                 self.prefix_hits += 1
             elif self.enable_prefix_cache:
                 self.prefix_misses += 1
+            more = ({}, {})
+            if self.recurrent and self.recurrent.admit_fields:
+                more = self.recurrent.admit_fields(self, n)
             with plane_events.span("serve.admit.prefill", "serve",
-                                   rid=rid8):
+                                   rid=rid8, **more[0]):
                 first_logits, seq_caches, state = self._prefill(
                     suffix, pad, shared, L0, n)
             self.tables[idx] = 0
             self.tables[idx, :len(slot.pages)] = slot.pages
             with plane_events.span("serve.admit.scatter", "serve",
-                                   rid=rid8, pages=need, dispatches=1):
+                                   rid=rid8, pages=need, dispatches=1,
+                                   **more[1]):
                 self._scatter(seq_caches, slot.pages, len(shared))
-            self._register_prefix_pages(slot)
+            if self.enable_prefix_cache:
+                # off, no page is ever shared: the registry's keys (every
+                # full-page prefix of the prompt as a tuple, quadratic in
+                # its length: 0.3 s and 100 MB of a 40k-token admission)
+                # would only be built, scanned at every free, and evicted
+                self._register_prefix_pages(slot)
             if state is not None:
                 with plane_events.span("serve.admit.state", "serve",
                                        rid=rid8, layers=len(state),
@@ -452,11 +625,10 @@ class PagedEngine:
         seeded with the shared prefix's K/V gathered from its cached
         pages — only the suffix, the compute the cache saves.
         -> (first logits, per-layer dense K/V, recurrent state or None)"""
+        if self.recurrent:
+            return self.recurrent.prefill(self, suffix, pad, n)
         padded = jnp.asarray(suffix + [0] * (pad - len(suffix)),
                              dtype=jnp.int32)
-        if self.recurrent:
-            return _hybrid_prefill(self.params, padded, n, self.max_len,
-                                   self.cfg, pad)[:3]
         if not shared:
             return _prefill_one(
                 self.params, padded, n, self.max_len, self.cfg,
@@ -493,6 +665,8 @@ class PagedEngine:
         ``_scatter_pages``, which consumes the pools it is given."""
         page_ids = np.full(self.P, self.num_pages, dtype=np.int32)
         page_ids[n_shared:len(pages)] = pages[n_shared:]
+        if self.recurrent and self.recurrent.scatter:
+            return self.recurrent.scatter(self, seq_caches, page_ids)
         (self.pools_k, self.pools_v, self.scales_k,
          self.scales_v) = _scatter_pages(
             self.pools_k, self.pools_v, self.scales_k, self.scales_v,
@@ -512,9 +686,8 @@ class PagedEngine:
                    pending=len(self.pending),
                    free_pages=len(self.free_pages),
                    preempted=self._preempted)
-            if self._expert_load is not None:
-                sp.set(experts_hit=self._expert_load[0],
-                       expert_tokens_max=self._expert_load[1])
+            if self._step_counts is not None:
+                self.recurrent.counts(self, self._step_counts, sp)
         return events
 
     def _step(self):
@@ -522,11 +695,22 @@ class PagedEngine:
         0 means the step program took ``_pick_tokens``' argmax side).
         Four phases tile the time after ``_admit``: prepare (tables and
         uploads), dispatch (the step program's call until it returns),
-        fetch (blocks on the device), emit (the per-slot loop)."""
-        self._expert_load = None
-        self._admit()
+        fetch (blocks on the device), emit (the per-slot loop). With steps
+        in flight that this one can run ahead of, the fetch and the emit
+        are the oldest's, after this one's dispatch; with steps in flight
+        it cannot run ahead of, the call only lands the oldest, and the
+        call that lands the last goes on as the synchronous one."""
+        self._step_counts = None
+        events: List[tuple] = []
+        flights = self._flights
+        if flights and not self._runs_ahead():
+            self._land(flights.pop(0), events)
+            if flights:     # at the device's pace: one step a call
+                return events, 0, 0
+        if not flights:
+            self._admit()
         with plane_events.span("serve.step.prepare", "serve"):
-            events: List[tuple] = list(self._admit_events)
+            events.extend(self._admit_events)
             self._admit_events = []
             for i, s in enumerate(self.slots):
                 if s is not None and s.done:
@@ -546,20 +730,26 @@ class PagedEngine:
                                dtype=np.int32)
             sampling = int(np.count_nonzero(self.temps[active] > 0.0))
             no_scales = [0] * self.n_kv
-            uploads = (
-                jnp.asarray(self.tables), jnp.asarray(self.last_tok),
-                jnp.asarray(lengths), jnp.asarray(self.temps),
-                jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
-                jnp.asarray(self.keys, dtype=jnp.uint32))
+            # one batched transfer: seven small uploads one by one were
+            # 2.0 ms of every step on the chip (PERF.md section 6, PR 32)
+            if not flights:
+                uploads = jax.device_put((
+                    self.tables, self.last_tok, lengths, self.temps,
+                    self.top_ks, self.top_ps,
+                    self.keys.astype(np.uint32, copy=False)))
+            else:   # the last step dispatched hands on its tokens and keys
+                tables, *rest = jax.device_put((
+                    self.tables, lengths, self.temps, self.top_ks,
+                    self.top_ps))
+                uploads = (tables, flights[-1].next_tok, *rest,
+                           flights[-1].keys)
         with plane_events.span("serve.step.dispatch", "serve"):
             scales = ((self.scales_k, self.scales_v) if self.kv_int8
                       else (no_scales, no_scales))
+            ahead = None
             if self.recurrent:
-                (toks, self.pools_k, self.pools_v, sk, sv, self.ssm,
-                 self.conv, new_keys, self.last_routing) = _hybrid_step(
-                    self.params, self.pools_k, self.pools_v, *scales,
-                    self.ssm, self.conv, *uploads, self.cfg, self.page,
-                    self.kv_int8)
+                toks, sk, sv, new_keys, ahead = self.recurrent.step(
+                    self, scales, uploads)
             else:
                 (toks, self.pools_k, self.pools_v, sk, sv,
                  new_keys) = _paged_step(
@@ -569,18 +759,52 @@ class PagedEngine:
             if self.kv_int8:
                 # model-dtype mode keeps scales stable at [None]*n_layers
                 self.scales_k, self.scales_v = sk, sv
+            for i in active:    # positions written or on their way
+                self.slots[i].length += 1
+        now = _Flight(toks, new_keys, active, *(ahead or ()))
+        if ahead and all(self.slots[i].eos_id is None for i in active):
+            # no token's value ends a stream: later calls may dispatch
+            # before this step's tokens are fetched
+            flights.append(now)
+            # the oldest lands when it has ended (while the steps in
+            # flight are still few, after an admission, its tokens are
+            # not kept waiting) or when enough are in flight (the call
+            # then waits for it, with the device at work on the others)
+            if len(flights) > _STEPS_AHEAD or flights[0].ended():
+                self._land(flights.pop(0), events)
+        else:
+            self._land(now, events)
+        return events, len(active), sampling
+
+    def _runs_ahead(self) -> bool:
+        """Whether one more step can be dispatched before the tokens in
+        flight are fetched: nothing waits for a free slot, no stream ends
+        with a token in flight (the count says so: a stream with an
+        ``eos_id`` is never left in flight), and every slot can take one
+        more page, so the step holds the slots the last one held and no
+        admission, no release and no preemption comes between them."""
+        held = self._flights[-1].active
+        return (not (self.pending and any(s is None for s in self.slots))
+                and len(self.free_pages) >= len(held)
+                and all(len(self.slots[i].emitted) + len(self._flights)
+                        < self.slots[i].max_new for i in held))
+
+    def _land(self, flight: _Flight, events: List[tuple]) -> None:
+        """Fetch a dispatched step's tokens and emit them."""
         with plane_events.span("serve.step.fetch", "serve"):
-            toks = np.asarray(toks)
-            self.keys = np.array(new_keys)
-            if self.recurrent:  # the expert layers' load rode with the tokens
-                self._expert_load = (int(toks[self.S]),
-                                     int(toks[self.S + 1]))
+            toks, keys = jax.device_get((flight.toks, flight.keys))
+            self.keys = np.array(keys)
+            if self.recurrent:  # the step's counts rode with the tokens
+                tail = toks[self.S:]
+                self._step_counts = (tail if self._step_counts is None
+                                     else self._step_counts + tail)
+                if flight.kept is not None:
+                    self.recurrent.landed(self, flight.kept)
         with plane_events.span("serve.step.emit", "serve",
-                               tokens=len(active)):
-            for i in active:
+                               tokens=len(flight.active)):
+            for i in flight.active:
                 s = self.slots[i]
                 tok = int(toks[i])
-                s.length += 1
                 s.emitted.append(tok)
                 self.last_tok[i] = tok
                 events.append((s.request_id, tok))
@@ -591,7 +815,6 @@ class PagedEngine:
                     self._free(s)
                     self.slots[i] = None
                     self.tables[i] = 0
-        return events, len(active), sampling
 
     def _grow_tables(self, active: List[int]) -> List[int]:
         """Grow page tables BEFORE the step for slots crossing a page
@@ -629,8 +852,8 @@ class PagedEngine:
         return [i for i, s in enumerate(self.slots) if s is not None]
 
     def has_work(self) -> bool:
-        return bool(self.pending) or any(s is not None
-                                         for s in self.slots)
+        return bool(self.pending or self._flights) or any(
+            s is not None for s in self.slots)
 
     def run_to_completion(self) -> Dict[str, List[int]]:
         results: Dict[str, List[int]] = {}

@@ -1,6 +1,11 @@
 """Device arithmetic of the page pool that every family's step shares:
 one K/V row of every slot written at its (page, offset), and each slot's
-query attended over its gathered pages."""
+query attended over its gathered pages (``paged_attention``: every page of
+the slot's table). Beside it the read path of a block-sparse layer, whose
+block is a page: a compressed-key pool written as windows of keys complete
+(``write_ckeys``: the indexer's cache), the choice of blocks from it
+(``choose_blocks``, shared with the prefill of such a family) and attention
+over the chosen pages only (``attend_chosen``)."""
 
 from __future__ import annotations
 
@@ -22,19 +27,8 @@ def _quant_kv(vec, qmax=127.0):
     return q, scale[..., 0].astype(jnp.float32)
 
 
-def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
-                    lengths, page_idx, offs, kv_int8, dtype):
-    """One layer's cache write and attention for every slot.
-
-    q [S, 1, H, d], k and v [S, 1, kvh, d] (rotated already where the family
-    rotates); pool_* [num_pages, page, kvh, d]; tables [S, P]. Writes each
-    slot's row at (page_idx, offs), gathers each slot's pages into its
-    [P*page, kvh, d] view and masks by position (keys <= the query's).
-    Returns (o [S, 1, H*d], pool_k, pool_v, scale_k, scale_v)."""
-    S, P = tables.shape
-    n_heads, head_dim = q.shape[2], q.shape[3]
-    n_kv_heads = k.shape[2]
-    cap = P * pool_k.shape[1]
+def write_kv(k, v, pool_k, pool_v, scale_k, scale_v, page_idx, offs, kv_int8):
+    """Each slot's K/V row [S, 1, kvh, d] into its (page_idx, offs)."""
     with jax.named_scope("kv_write"):
         if kv_int8:
             kq, ks = _quant_kv(k[:, 0])
@@ -48,6 +42,17 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
                 k[:, 0].astype(pool_k.dtype))
             pool_v = pool_v.at[page_idx, offs].set(
                 v[:, 0].astype(pool_v.dtype))
+    return pool_k, pool_v, scale_k, scale_v
+
+
+def attend_pages(q, pool_k, pool_v, scale_k, scale_v, tables, lengths,
+                 kv_int8, dtype):
+    """Each slot's query [S, 1, H, d] over every page of its table [S, P],
+    keys masked by position (<= the query's). -> o [S, 1, H*d]."""
+    S, P = tables.shape
+    n_heads, head_dim = q.shape[2], q.shape[3]
+    n_kv_heads = pool_k.shape[2]
+    cap = P * pool_k.shape[1]
     with jax.named_scope("attention"):
         k_seq = pool_k[tables].reshape(S, cap, n_kv_heads, head_dim)
         v_seq = pool_v[tables].reshape(S, cap, n_kv_heads, head_dim)
@@ -72,4 +77,154 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("sgrqk,skgd->sqgrd", p.astype(v_seq.dtype), v_seq)
         o = o.reshape(S, 1, n_heads * head_dim)
+    return o
+
+
+def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
+                    lengths, page_idx, offs, kv_int8, dtype):
+    """One layer's cache write and attention for every slot.
+
+    q [S, 1, H, d], k and v [S, 1, kvh, d] (rotated already where the family
+    rotates); pool_* [num_pages, page, kvh, d]; tables [S, P]. Writes each
+    slot's row at (page_idx, offs), gathers each slot's pages into its
+    [P*page, kvh, d] view and masks by position (keys <= the query's).
+    Returns (o [S, 1, H*d], pool_k, pool_v, scale_k, scale_v)."""
+    pool_k, pool_v, scale_k, scale_v = write_kv(
+        k, v, pool_k, pool_v, scale_k, scale_v, page_idx, offs, kv_int8)
+    o = attend_pages(q, pool_k, pool_v, scale_k, scale_v, tables, lengths,
+                     kv_int8, dtype)
     return o, pool_k, pool_v, scale_k, scale_v
+
+
+# ------------------------------------------------ a block-sparse layer's reads
+def block_scores(logits, n, sizes):
+    """Each query's score of every block. logits [N, kvh, rep, J] float32: the
+    query heads of each K/V group against the J compressed keys (key ``j``
+    is the mean of keys ``[stride j, stride j + kernel)``), scaled; n [N]:
+    each query's context length (its position + 1). ``sizes`` has
+    ``block``, ``stride``, ``kernel`` (= 2 stride), ``topk``,
+    ``init_blocks`` and ``window`` (positions).
+
+    Per head a softmax over the compressed keys the context holds whole;
+    summed over the group's heads; a block's score is the largest over the
+    windows that overlap it (a max-pool of width block/stride + 1, stride
+    block/stride, padding 1); the first ``init_blocks`` and the last
+    ``window / block`` blocks up to the query's own are forced (+inf), the
+    blocks past the query's own are out (-inf); the ``topk`` highest are
+    read (``choose_blocks`` / ``choose_block_mask``). -> [N, kvh, J / r]."""
+    block, stride, kernel = sizes.block, sizes.stride, sizes.kernel
+    r = block // stride
+    N, kvh, _, J = logits.shape
+    j = jnp.arange(J)
+    whole = (j * stride + kernel)[None, :] <= n[:, None]            # [N, J]
+    a = jax.nn.softmax(jnp.where(whole[:, None, None, :], logits, -1e30), -1)
+    A = jnp.where(whole[:, None, :], a.sum(axis=2), -jnp.inf)   # [N, kvh, J]
+    Ab = A.reshape(N, kvh, J // r, r)
+    before = jnp.concatenate(
+        [jnp.full((N, kvh, 1), -jnp.inf), Ab[:, :, :-1, r - 1]], axis=2)
+    B = jnp.maximum(Ab.max(axis=-1), before)
+    b = jnp.arange(J // r)[None, :]
+    cur = ((n - 1) // block)[:, None]
+    forced = (b < sizes.init_blocks) | (b > cur - sizes.window // block)
+    B = jnp.where(forced[:, None, :], jnp.inf, B)
+    return jnp.where((b <= cur)[:, None, :], B, -jnp.inf)
+
+
+def choose_blocks(logits, n, sizes):
+    """The ``topk`` blocks of highest score, as indices (equal scores: the
+    lower block first). -> (idx int32 [N, kvh, topk], the block scores)."""
+    B = block_scores(logits, n, sizes)
+    _, idx = jax.lax.top_k(B, sizes.topk)
+    return idx.astype(jnp.int32), B
+
+
+def choose_block_mask(logits, n, sizes):
+    """The same choice as a mask [N, kvh, J / r], without a sort (a sort of
+    704 scores for each of a prompt's queries was a quarter of a prefill:
+    PERF.md section 6, PR 32): the ``topk``-th highest score by bisection
+    over the scores' bits, everything above it, and of the scores equal to
+    it the lowest blocks, which is ``lax.top_k``'s set exactly."""
+    B = block_scores(logits, n, sizes)
+    bits = jax.lax.bitcast_convert_type(B, jnp.int32)
+    # float order as unsigned order: flip all bits of a negative, the sign
+    # bit of a positive
+    key = jax.lax.bitcast_convert_type(
+        bits ^ jnp.where(bits < 0, -1, jnp.int32(-2 ** 31)), jnp.uint32)
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= sizes.topk
+        return jnp.where(enough, cand, t)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(B.shape[:-1], jnp.uint32))
+    above = key > kth[..., None]
+    level = key == kth[..., None]
+    room = sizes.topk - jnp.sum(above, axis=-1)
+    return above | (level & (jnp.cumsum(level, axis=-1) <= room[..., None]))
+
+
+def write_ckeys(pool_c, pool_k, tables, lengths, sizes):
+    """Complete a compressed key where a window of keys does: for a slot
+    whose context after this step's write, ``n = length + 1``, is a multiple
+    of ``stride`` and at least ``kernel``, key ``j = (n - kernel) / stride``
+    becomes the mean of the pool's K rows ``[n - kernel, n)`` (they may lie
+    on two pages), written at row ``j % r`` of page ``tables[j // r]`` of
+    ``pool_c`` [num_pages, r, kvh, d]. Every other slot's write is dropped.
+    An inactive slot (length 0) has ``n = 1``."""
+    with jax.named_scope("ckey_write"):
+        page = pool_k.shape[1]
+        r = page // sizes.stride
+        n = lengths + 1
+        due = (n % sizes.stride == 0) & (n >= sizes.kernel)
+        start = jnp.maximum(n - sizes.kernel, 0)
+        pos = start[:, None] + jnp.arange(sizes.kernel)[None, :]  # [S, kernel]
+        pg = jnp.take_along_axis(tables, pos // page, axis=1)
+        rows = pool_k[pg, pos % page]                   # [S, kernel, kvh, d]
+        ck = jnp.mean(rows.astype(jnp.float32), axis=1).astype(pool_c.dtype)
+        j = start // sizes.stride
+        at = jnp.take_along_axis(tables, (j // r)[:, None], axis=1)[:, 0]
+        at = jnp.where(due, at, pool_c.shape[0])        # past the pool: dropped
+        return pool_c.at[at, j % r].set(ck, mode="drop")
+
+
+def select_pages(q, pool_c, tables, lengths, sizes):
+    """q [S, 1, H, d] against each slot's compressed keys (gathered through
+    its table: [S, P*r, kvh, d]) -> the chosen blocks [S, kvh, topk], which
+    index the slot's table."""
+    with jax.named_scope("sparse_select"):
+        S, P = tables.shape
+        kvh, d = pool_c.shape[2], pool_c.shape[3]
+        ck = pool_c[tables].reshape(S, -1, kvh, d)
+        qg = q.reshape(S, kvh, -1, d)
+        logits = jnp.einsum("sgrd,sjgd->sgrj", qg, ck,
+                            preferred_element_type=jnp.float32) * (d ** -0.5)
+        idx, _ = choose_blocks(logits, lengths + 1, sizes)
+        return idx
+
+
+def attend_chosen(q, pool_k, pool_v, tables, idx, lengths):
+    """Each slot's query over its chosen pages only. q [S, 1, H, d], idx
+    [S, kvh, topk] entries of the slot's table -> o [S, 1, H*d]. Keys past
+    the query's position are masked, so a block chosen beyond the context
+    (fewer blocks than ``topk``) adds nothing."""
+    with jax.named_scope("sparse_attn"):
+        S = tables.shape[0]
+        page, kvh, d = pool_k.shape[1], pool_k.shape[2], pool_k.shape[3]
+        topk = idx.shape[-1]
+        pg = jnp.take_along_axis(tables, idx.reshape(S, -1), axis=1
+                                 ).reshape(S, kvh, topk)
+        # whole pages as the pool lays them out (both K/V heads of a page:
+        # a gather of one head's slices makes the compiler transpose the
+        # whole pool every step), then each group's own head of its pages
+        g = jnp.arange(kvh)
+        k_sel = pool_k[pg][:, g, :, :, g].reshape(kvh, S, topk * page, d)
+        v_sel = pool_v[pg][:, g, :, :, g].reshape(kvh, S, topk * page, d)
+        k_sel, v_sel = k_sel.swapaxes(0, 1), v_sel.swapaxes(0, 1)
+        pos = (idx[..., None] * page + jnp.arange(page)).reshape(S, kvh, -1)
+        qg = q.reshape(S, kvh, -1, d)
+        s = jnp.einsum("sgrd,sgkd->sgrk", qg, k_sel,
+                       preferred_element_type=jnp.float32) * (d ** -0.5)
+        s = jnp.where((pos <= lengths[:, None, None])[:, :, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("sgrk,sgkd->sgrd", p.astype(v_sel.dtype), v_sel)
+        return o.reshape(S, 1, -1)

@@ -184,6 +184,61 @@ def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def test_sala_step_and_prefill_chunk_minicpm_sala_widths(one_chip):
+    """The lightning / block-sparse family's programs at the benchmark's
+    widths and four of its layers (``L M L L``), 8 slots of 704 pages: the
+    decode step gives its pools, compressed-key pools and states back
+    aliased to the donated arguments; it holds no copy of a whole pool (a
+    gather of one K/V head's slices of the chosen pages made the compiler
+    transpose every pool every step: PR 32) and no gather of every slot's
+    whole table outside the dense branch's ``conditional``; the prefill
+    chunk builds no array of chunk x ``max_len`` scores."""
+    from ray_tpu.models import minicpm_sala as ms
+
+    cfg = ms.MiniCPMSALAConfig(
+        mixer_types=(ms.LIGHTNING, ms.SPARSE, ms.LIGHTNING, ms.LIGHTNING),
+        layer_offset=8)
+    S, pages, page, max_len = 8, 5120, 64, 45056
+    params = _on(one_chip, jax.eval_shape(
+        lambda: ms.init_params(cfg, jax.random.PRNGKey(0))))
+    pools = [_shape(one_chip, (pages, page, cfg.n_kv_heads, cfg.head_dim))]
+    pools_c = [_shape(one_chip, (pages, page // cfg.stride, cfg.n_kv_heads,
+                                 cfg.head_dim))]
+    states = _on(one_chip, jax.eval_shape(lambda: ms.init_state(cfg, S)))
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = ms._sala_step.lower(
+        params, pools, pools, pools_c, states, i32((S, max_len // page)),
+        i32((S,)), i32((S,)), f32((S,)), i32((S,)), f32((S,)),
+        _shape(one_chip, (S, 2), jnp.uint32), cfg=cfg, page=page).compile()
+    m = compiled.memory_analysis()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pools, pools, pools_c, states)))
+    assert m.alias_size_in_bytes >= donated
+    text = compiled.as_text()
+    pool = f"bf16[{pages},{page},{cfg.n_kv_heads},{cfg.head_dim}]"
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"= {pool}" in ln]
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    # the dense branch (a slot of at most dense_len positions) and the
+    # sampler's sort are choices of the program, not paid by every step
+    assert entry.count(" conditional(") == 2
+    whole_table = f"[{S},{max_len},{cfg.n_kv_heads},{cfg.head_dim}]"
+    assert whole_table not in text
+    assert m.temp_size_in_bytes < 0.3e9
+    carry = _on(one_chip, jax.eval_shape(
+        lambda: ms.prefill_carry(cfg, max_len)))
+    compiled = ms._sala_prefill_chunk.lower(
+        params, i32((cfg.prefill_chunk,)), i32(()), i32(()), *carry,
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
+    assert m.temp_size_in_bytes < 2e9
+    assert f"{cfg.prefill_chunk},{max_len}]" not in compiled.as_text()
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 def test_scatter_pages_writes_the_pools_in_place(one_chip, kv_int8):
     """The admission's one scatter at the benchmark's widths (16 layers of
