@@ -10,9 +10,9 @@ XLA), using the capacity-buffer formulation so every shape is static.
 Three implementations:
   * ``moe_ffn_share`` — the layer of ONE chip of an expert-parallel
     deployment, told which experts it holds: routes over all experts,
-    computes the held experts' part of the result, dropless (sort by
-    expert, grouped matrix product, unsort). On one chip it runs without
-    its exchange.
+    computes the held experts' part of the result, dropless (every held
+    expert on every row, then each row's own picked). On one chip it runs
+    without its exchange.
   * ``moe_ffn_dense`` — computes every expert on every token and weights
     by the top-k gates. O(E) FLOPs; the correctness oracle and the
     single-device path.
@@ -108,11 +108,18 @@ def moe_ffn_share(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
     experts_held: ``w_up`` [Eh, D, F] and ``w_down`` [Eh, F, D] of experts
     ``expert_offset .. expert_offset + Eh - 1``. A pair (token, expert)
     whose expert is not held here adds nothing: in the deployment another
-    chip computes it. Dropless: the pairs are sorted by expert and each
-    expert multiplies exactly its rows (``lax.ragged_dot``), however many
-    (one expert may get every token). ``token_mask`` [T] leaves a lane out
-    (an engine's empty slot). Returns (out [T, D], the held experts that got
-    a token, the most tokens one expert got).
+    chip computes it. Dropless: every held expert multiplies every row, the
+    weights read where and as the tree stores them, and each pair's row is
+    picked from the result, so one expert may get every token. That costs
+    ``Eh / k`` times the arithmetic of a product grouped by expert
+    (``lax.ragged_dot`` over the sorted pairs, which this was until PR 33)
+    and was faster at every row count an engine runs: 0.44 against 1.75 ms
+    a layer at 16 rows, 2.75 against 5.27 at 1280, 16 experts of 2688 x 1856
+    on a TPU v5e (PERF.md section 5). The gate is applied in float32 after
+    the second product and a token's ``k`` rows are summed in gate order.
+    ``token_mask`` [T] leaves a lane out (an engine's empty slot). Returns
+    (out [T, D], the held experts that got a token, the most tokens one
+    expert got).
     """
     T, k = gate_idx.shape
     Eh = experts_held["w_up"].shape[0]
@@ -121,19 +128,14 @@ def moe_ffn_share(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
     if token_mask is not None:
         held = held & token_mask[:, None]
     group = jnp.where(held, local, Eh).reshape(T * k)    # Eh: computed nowhere
-    order = jnp.argsort(group, stable=True)
     sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
-    rows = x[order // k]                                 # [T*k, D]
-    h = relu2(lax.ragged_dot(rows, experts_held["w_up"], sizes))
-    y = lax.ragged_dot(h, experts_held["w_down"], sizes)
-    # Rows past the held groups belong to no expert. On a TPU ragged_dot
-    # leaves them UNINITIALISED (inf and NaN among them, measured), so they
-    # are selected away, not multiplied by a zero weight.
-    w = gate_vals.reshape(T * k)[order]
-    in_group = jnp.arange(T * k) < jnp.sum(sizes)
-    y = jnp.where(in_group[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
-    # unsort by gather (a scatter-add would sum in no fixed order)
-    out = y[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    y = _expert_ffn(jnp.broadcast_to(x, (Eh,) + x.shape), experts_held,
+                    tp_psum=False)                       # [Eh, T, D]
+    rows = jnp.take_along_axis(
+        y, jnp.where(held, local, 0).T[:, :, None], axis=0)      # [k, T, D]
+    out = jnp.where(held.T[:, :, None],
+                    rows.astype(jnp.float32) * gate_vals.T[:, :, None],
+                    0.0).sum(axis=0)
     return out.astype(x.dtype), jnp.sum(sizes > 0), jnp.max(sizes)
 
 

@@ -315,13 +315,50 @@ def _layer(key, T=24, D=32, E=16, F=40, Fs=48):
     return x, layer
 
 
+# a decode step's rows and a prompt's: one form, whatever the count
+ROWS = {"few_rows": 24, "many_rows": 512}
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("rows", [32, 1280],
+                         ids=["decode_step", "prompt_1280"])
+def test_a_share_holds_no_grouped_product_and_no_sort(rows):
+    """At the cell's widths, traced only: a step's 32 rows and a 1280-row
+    prompt alike go through two batched products and one gather."""
+    D, F, Eh, k = 2688, 1856, 16, 6
+    sd = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(
+        lambda x, v, i, up, down, m: moe.moe_ffn_share(
+            x, v, i, {"w_up": up, "w_down": down}, 32, m))(
+        sd((rows, D), jnp.bfloat16), sd((rows, k), jnp.float32),
+        sd((rows, k), jnp.int32), sd((Eh, D, F), jnp.bfloat16),
+        sd((Eh, F, D), jnp.bfloat16), sd((rows,), bool))
+    names = list(_primitives(jaxpr.jaxpr))
+    assert (names.count("dot_general"), names.count("ragged_dot_general"),
+            names.count("sort")) == (2, 0, 0)
+    # the weights enter the products as they are stored: nothing transposes
+    # or converts an [Eh, D, F] array on the way
+    big = [e for e in jaxpr.jaxpr.eqns if e.primitive.name != "dot_general"
+           and any(getattr(v.aval, "shape", None)
+                   in ((Eh, D, F), (Eh, F, D)) for v in e.invars)]
+    assert not big
+
+
+@pytest.mark.parametrize("rows", ROWS.values(), ids=ROWS.keys())
 @pytest.mark.parametrize("routing", ["even", "uneven", "one_expert"])
-def test_eight_shares_add_up_to_the_whole_layer(routing):
+def test_eight_shares_add_up_to_the_whole_layer(routing, rows):
     """Each of eight chips holds two of sixteen experts. Their routed parts,
     plus the shared expert counted ONCE, are the uncut reference's whole
     layer, and the routed parts alone are ``moe_ffn_dense``'s under the
-    same scores; however uneven the routing, no token is dropped."""
-    x, layer = _layer(jax.random.PRNGKey(11))
+    same scores; however uneven the routing and however many the rows, no
+    token is dropped."""
+    x, layer = _layer(jax.random.PRNGKey(11), T=rows)
     T, E, k = x.shape[0], 16, 3
     if routing == "uneven":     # the bias sends most tokens to experts 0-2
         layer["router_bias"] = layer["router_bias"].at[:3].add(0.6)
@@ -381,6 +418,67 @@ def test_a_masked_token_reaches_no_expert():
     counts = np.bincount(np.asarray(idx)[np.asarray(mask)].ravel(),
                          minlength=16)
     assert int(hit) == (counts > 0).sum() and int(most) == counts.max()
+
+
+@pytest.mark.parametrize("rows", ROWS.values(), ids=ROWS.keys())
+@pytest.mark.parametrize("case", ["no_held_expert_chosen", "lanes_left_out",
+                                  "experts_8_to_11"])
+def test_a_share_counts_and_leaves_out_pair_by_pair(case, rows):
+    """A share nobody is routed to gives zeros and counts nothing; lanes the
+    mask leaves out reach no expert and are not counted; a share that starts
+    past expert 0 computes its own pairs only. ``hit`` and ``most`` are
+    ``numpy.bincount``'s over the pairs that count, and the result is the
+    pair-by-pair sum in float32."""
+    x, layer = _layer(jax.random.PRNGKey(13), T=rows)
+    offset, Eh, k = (8, 4, 3) if case == "experts_8_to_11" else (2, 3, 3)
+    if case == "no_held_expert_chosen":
+        layer["router_bias"] = layer["router_bias"].at[2:5].add(-9.0)
+    vals, idx = moe.sigmoid_gates(x, layer["w_router"], layer["router_bias"],
+                                  k, 2.5)
+    mask = None
+    if case == "lanes_left_out":
+        mask = jnp.arange(rows) % 3 != 1
+    held = {n: layer[n][offset:offset + Eh] for n in ("w_up", "w_down")}
+    out, hit, most = moe.moe_ffn_share(x, vals, idx, held, offset, mask)
+    lanes = np.ones(rows, bool) if mask is None else np.asarray(mask)
+    counts = np.bincount(np.asarray(idx)[lanes].ravel(),
+                         minlength=16)[offset:offset + Eh]
+    assert int(hit) == (counts > 0).sum()
+    assert int(most) == counts.max()
+    want = np.zeros(x.shape, np.float32)
+    for e in range(offset, offset + Eh):
+        y = moe.relu2(x @ layer["w_up"][e]) @ layer["w_down"][e]
+        gate = jnp.sum(jnp.where(idx == e, vals, 0.0), axis=-1)
+        want += np.asarray(y * gate[:, None]) * lanes[:, None]
+    np.testing.assert_allclose(out, want, atol=3e-5)
+    if case == "no_held_expert_chosen":
+        assert int(hit) == 0 and float(jnp.abs(out).max()) == 0.0
+    if case == "lanes_left_out":
+        assert float(jnp.abs(out[~mask]).max()) == 0.0
+
+
+@pytest.mark.parametrize("rows", ROWS.values(), ids=ROWS.keys())
+def test_bfloat16_products_are_gated_and_summed_in_float32(rows):
+    """bfloat16 operands as the cell's: each pair's row is the expert's two
+    bfloat16 products, the gate multiplies it in float32 AFTER the second
+    product and a token's ``k`` rows are summed in float32 in gate order:
+    bit for bit the pair-by-pair computation rounded once at the end."""
+    x, layer = _layer(jax.random.PRNGKey(17), T=rows)
+    k, bf = 3, jnp.bfloat16
+    vals, idx = moe.sigmoid_gates(x, layer["w_router"], layer["router_bias"],
+                                  k, 2.5)
+    x, up, down = x.astype(bf), layer["w_up"].astype(bf), \
+        layer["w_down"].astype(bf)
+    out, _, _ = moe.moe_ffn_share(x, vals, idx,
+                                  {"w_up": up, "w_down": down}, 0)
+    assert out.dtype == bf
+    ys = jnp.stack([moe.relu2(x @ up[e]) @ down[e] for e in range(16)])
+    want = jnp.zeros(x.shape, jnp.float32)
+    for j in range(k):
+        row = ys[idx[:, j], jnp.arange(rows)].astype(jnp.float32)
+        want = want + row * vals[:, j, None]
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want.astype(bf), np.float32))
 
 
 def test_a_share_of_the_model_is_the_reference_with_the_same_share(params):
