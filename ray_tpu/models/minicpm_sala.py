@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import linear_attn
-from ..ops.layers import apply_rope, rms_norm
+from ..ops.layers import apply_rope, rms_norm, rope_rows as _rope_rows
 from ..ops.quant import mm
 from .engine import _pick_tokens
 from .llama import _mlp_block
@@ -194,14 +194,6 @@ def init_params(cfg: MiniCPMSALAConfig, key: jax.Array) -> Dict[str, Any]:
 def _slopes(cfg: MiniCPMSALAConfig, i: int):
     return linear_attn.decay_slopes(cfg.lightning_heads, cfg.layer_offset + i,
                                     cfg.n_layers_published)
-
-
-def _rope_rows(positions, head_dim: int, theta: float):
-    """cos, sin [N, head_dim / 2] of the given positions [N]."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=F32)
-                                / head_dim))
-    freqs = positions.astype(F32)[:, None] * inv_freq[None, :]
-    return jnp.cos(freqs), jnp.sin(freqs)
 
 
 def _qkv(layer, h, heads: int, kv_heads: int, head_dim: int, eps: float):

@@ -30,9 +30,12 @@ from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..util import events as plane_events
 from .engine import _pick_one, _pick_tokens, _prefill_one
-from .paged_ops import _quant_kv, paged_attention  # noqa: F401 (re-export)
+from .paged_ops import (_quant_kv, latent_pool_shape,  # noqa: F401
+                        paged_attention)    # (re-exports)
 from .llama import LlamaConfig, _mlp_block
+from . import longcat_flash as longcat
 from . import minicpm_sala as sala
+from .longcat_flash import LongcatFlashConfig
 from .minicpm_sala import MiniCPMSALAConfig
 from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
                          _write_state, init_state)
@@ -147,11 +150,13 @@ def _suffix_prefill(params, prefix_caches, suffix_padded, prefix_len,
     return first, [(kc[0], vc[0]) for kc, vc in new]
 
 
-# ------------------------------------------- families with per-slot state
-# What a family with recurrent layers brings, by the type of its config:
-# how many layers have K/V pools, the per-slot state, the prefill, the
-# scatter where it has more than K/V to scatter, and the step. Each takes
-# the engine; the state write (``_write_state``) is the same for all.
+# --------------------------------------------- families beside the dense one
+# What a family brings, by the type of its config: how many layers have a
+# pool and, where a position's row is not K beside V, its shape; what it
+# keeps beside the pools (per-slot state, a second pool, what a check
+# reads); the prefill, the scatter where it has more or other than K/V to
+# scatter, and the step. Each takes the engine; the state write
+# (``_write_state``) is the same for all that have per-slot state.
 def _nemotron_state(eng):
     eng.ssm, eng.conv = init_state(eng.cfg, eng.S)
     # the last step's chosen experts [expert layers, S, k]: left on the
@@ -233,10 +238,51 @@ def _sala_counts(eng, tail, sp):
            sparse_slots=int(tail[2]))
 
 
+def _longcat_state(eng):
+    if eng.max_len % eng.cfg.prefill_chunk or eng.kv_int8:
+        raise ValueError(
+            "this family's prefill fills max_len in whole chunks and its "
+            "latent pages are kept in the model's dtype")
+    # the last step's chosen experts [layers, S, k]: left on the device, for
+    # a reference check to read
+    eng.last_routing = None
+
+
+def _longcat_prefill(eng, suffix, pad, n):
+    first, lats = longcat.prefill(eng.params, suffix, eng.max_len, eng.cfg)
+    return first, lats, None
+
+
+def _longcat_scatter(eng, lats, page_ids):
+    eng.pools_k = longcat._scatter_latent(eng.pools_k, lats, page_ids)
+
+
+def _longcat_admit_fields(eng, n):
+    return ({"chunks": -(-n // eng.cfg.prefill_chunk)},
+            {"latent_rows": eng.max_len * eng.n_kv})
+
+
+def _longcat_step(eng, scales, uploads):
+    toks, eng.pools_k, new_keys, routing, next_tok = longcat._longcat_step(
+        eng.params, eng.pools_k, *uploads, eng.cfg, eng.page)
+    return toks, None, None, new_keys, (next_tok, routing)
+
+
+def _longcat_landed(eng, routing):
+    eng.last_routing = routing
+
+
+def _longcat_counts(eng, tail, sp):
+    sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]),
+           zero_picks=int(tail[2]), latent_positions=int(tail[3]),
+           moe_rows=int(tail[4]), landed=int(tail[5]))
+
+
 @dataclass(frozen=True)
-class _Recurrent:
-    n_kv: object            # cfg -> layers with a K/V pool
-    state: object           # engine -> None: allocates the per-slot state
+class _Family:
+    n_kv: object            # cfg -> layers (or sublayers) with a pool
+    state: object           # engine -> None: allocates what the family
+    #                         keeps beside the pools, refuses what it cannot
     prefill: object         # (engine, prompt, pad, n) -> (first logits,
     #                         caches, per-layer state tuples)
     step: object            # (engine, scales, uploads) -> (tokens and the
@@ -254,16 +300,27 @@ class _Recurrent:
     #                         max_len; none where it is admitted in chunks
     landed: object = None   # (engine, what the step kept on the device):
     #                         called when that step's tokens are fetched
+    pool_shape: object = None   # (cfg, num_pages, page_size) -> the shape
+    #                         of a layer's pool where a position's row is
+    #                         not (n_kv_heads, head_dim) of keys beside the
+    #                         same of values: the layer then has ONE pool
+    #                         and no V pool
 
 
-_RECURRENT = {
-    NemotronHConfig: _Recurrent(
+_FAMILIES = {
+    NemotronHConfig: _Family(
         lambda cfg: cfg.n_attn_layers, _nemotron_state, _nemotron_prefill,
         _nemotron_step, _nemotron_counts),
-    MiniCPMSALAConfig: _Recurrent(
+    MiniCPMSALAConfig: _Family(
         lambda cfg: cfg.n_sparse_layers, _sala_state, _sala_prefill,
         _sala_step, _sala_counts, _sala_scatter, _sala_admit_fields, (),
         _sala_landed),
+    LongcatFlashConfig: _Family(
+        lambda cfg: cfg.n_sublayers, _longcat_state, _longcat_prefill,
+        _longcat_step, _longcat_counts, _longcat_scatter,
+        _longcat_admit_fields, (), _longcat_landed,
+        lambda cfg, pages, page: latent_pool_shape(
+            pages, page, cfg.latent_width)),
 }
 
 
@@ -320,12 +377,17 @@ class PagedEngine:
     has pools for its sparse layers only, a compressed-key pool beside each
     (``self.pools_c``: the cache of the layer's block selection, on the
     same pages) and per-slot lightning state (``self.ssm``); its prompts
-    are admitted in chunks. Pages, tables, admission order, preemption by
-    recompute and the spans are the same code (``_RECURRENT`` holds what
+    are admitted in chunks. A ``LongcatFlashConfig`` has ONE pool for each of
+    its ``2 x layers`` latent-attention sublayers, a position's row the
+    compressed latent and the one rotary key all heads share
+    (``self.pools_k``; there is no V pool and no per-slot state); its prompts
+    are admitted in chunks through the expanded attention form and its step
+    attends in the absorbed form. Pages, tables, admission order, preemption by
+    recompute and the spans are the same code (``_FAMILIES`` holds what
     differs).
 
     Where the family's step program hands its tokens on as a device array
-    (``_Recurrent.step``), the engine **runs ahead of the device**:
+    (``_Family.step``), the engine **runs ahead of the device**:
     ``step()`` dispatches the next step on the tokens and keys the last
     one left on the device, keeps up to ``_STEPS_AHEAD`` dispatched and
     fetches the oldest's tokens as it ends. The host's part of a step
@@ -339,7 +401,8 @@ class PagedEngine:
     """
 
     def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig,
-                                          MiniCPMSALAConfig], *,
+                                          MiniCPMSALAConfig,
+                                          LongcatFlashConfig], *,
                  max_slots: int = 8,
                  num_pages: int = 64, page_size: int = 16,
                  max_len: int = 512, enable_prefix_cache: bool = False,
@@ -351,14 +414,18 @@ class PagedEngine:
         self.num_pages = num_pages
         self.P = max_len // page_size           # table width per slot
         self.max_len = self.P * page_size
-        self.recurrent = _RECURRENT.get(type(cfg))
-        if self.recurrent:
+        self.family = _FAMILIES.get(type(cfg))
+        shape = None
+        if self.family:
             if enable_prefix_cache:
                 raise ValueError(
-                    "enable_prefix_cache needs snapshots of the recurrent "
-                    "state at page boundaries, which this engine does not "
-                    "keep: a model with recurrent layers runs without it")
-            self.n_kv = self.recurrent.n_kv(cfg)
+                    "enable_prefix_cache needs what this engine does not "
+                    "keep for this family (snapshots of recurrent state at "
+                    "page boundaries; a prefill of the suffix alone over "
+                    "latent pages): it runs without it")
+            self.n_kv = self.family.n_kv(cfg)
+            if self.family.pool_shape:
+                shape = self.family.pool_shape(cfg, num_pages, page_size)
         else:
             self.n_kv = cfg.n_layers
             self.cos, self.sin = rope_frequencies(
@@ -370,12 +437,14 @@ class PagedEngine:
         # lever). Dequantize happens in the gather; outputs are CLOSE
         # to full precision, not bit-identical.
         self.kv_int8 = kv_dtype == "int8"
-        shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        one_pool = shape is not None
+        if not one_pool:
+            shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
         pool_dt = jnp.int8 if self.kv_int8 else cfg.dtype
         self.pools_k = [jnp.zeros(shape, pool_dt)
                         for _ in range(self.n_kv)]
         self.pools_v = [jnp.zeros(shape, pool_dt)
-                        for _ in range(self.n_kv)]
+                        for _ in range(0 if one_pool else self.n_kv)]
         sshape = shape[:-1]
         self.scales_k = [jnp.ones(sshape, jnp.float32)
                          for _ in range(self.n_kv)] \
@@ -400,9 +469,9 @@ class PagedEngine:
         self.pending: List[tuple] = []
         self._admit_events: List[tuple] = []
         self._prefill_buckets = (16, 64, 256)
-        if self.recurrent:
-            self._prefill_buckets = self.recurrent.buckets
-            self.recurrent.state(self)
+        if self.family:
+            self._prefill_buckets = self.family.buckets
+            self.family.state(self)
         # what this step() did, for its ``serve.engine.step`` row
         self._steps = self._admitted = self._preempted = 0
         self._step_counts = None    # what rode with a recurrent step's tokens
@@ -579,8 +648,8 @@ class PagedEngine:
             elif self.enable_prefix_cache:
                 self.prefix_misses += 1
             more = ({}, {})
-            if self.recurrent and self.recurrent.admit_fields:
-                more = self.recurrent.admit_fields(self, n)
+            if self.family and self.family.admit_fields:
+                more = self.family.admit_fields(self, n)
             with plane_events.span("serve.admit.prefill", "serve",
                                    rid=rid8, **more[0]):
                 first_logits, seq_caches, state = self._prefill(
@@ -625,8 +694,8 @@ class PagedEngine:
         seeded with the shared prefix's K/V gathered from its cached
         pages — only the suffix, the compute the cache saves.
         -> (first logits, per-layer dense K/V, recurrent state or None)"""
-        if self.recurrent:
-            return self.recurrent.prefill(self, suffix, pad, n)
+        if self.family:
+            return self.family.prefill(self, suffix, pad, n)
         padded = jnp.asarray(suffix + [0] * (pad - len(suffix)),
                              dtype=jnp.int32)
         if not shared:
@@ -665,8 +734,8 @@ class PagedEngine:
         ``_scatter_pages``, which consumes the pools it is given."""
         page_ids = np.full(self.P, self.num_pages, dtype=np.int32)
         page_ids[n_shared:len(pages)] = pages[n_shared:]
-        if self.recurrent and self.recurrent.scatter:
-            return self.recurrent.scatter(self, seq_caches, page_ids)
+        if self.family and self.family.scatter:
+            return self.family.scatter(self, seq_caches, page_ids)
         (self.pools_k, self.pools_v, self.scales_k,
          self.scales_v) = _scatter_pages(
             self.pools_k, self.pools_v, self.scales_k, self.scales_v,
@@ -687,7 +756,7 @@ class PagedEngine:
                    free_pages=len(self.free_pages),
                    preempted=self._preempted)
             if self._step_counts is not None:
-                self.recurrent.counts(self, self._step_counts, sp)
+                self.family.counts(self, self._step_counts, sp)
         return events
 
     def _step(self):
@@ -747,8 +816,8 @@ class PagedEngine:
             scales = ((self.scales_k, self.scales_v) if self.kv_int8
                       else (no_scales, no_scales))
             ahead = None
-            if self.recurrent:
-                toks, sk, sv, new_keys, ahead = self.recurrent.step(
+            if self.family:
+                toks, sk, sv, new_keys, ahead = self.family.step(
                     self, scales, uploads)
             else:
                 (toks, self.pools_k, self.pools_v, sk, sv,
@@ -794,12 +863,12 @@ class PagedEngine:
         with plane_events.span("serve.step.fetch", "serve"):
             toks, keys = jax.device_get((flight.toks, flight.keys))
             self.keys = np.array(keys)
-            if self.recurrent:  # the step's counts rode with the tokens
+            if self.family:  # the step's counts rode with the tokens
                 tail = toks[self.S:]
                 self._step_counts = (tail if self._step_counts is None
                                      else self._step_counts + tail)
                 if flight.kept is not None:
-                    self.recurrent.landed(self, flight.kept)
+                    self.family.landed(self, flight.kept)
         with plane_events.span("serve.step.emit", "serve",
                                tokens=len(flight.active)):
             for i in flight.active:

@@ -5,7 +5,11 @@ the slot's table). Beside it the read path of a block-sparse layer, whose
 block is a page: a compressed-key pool written as windows of keys complete
 (``write_ckeys``: the indexer's cache), the choice of blocks from it
 (``choose_blocks``, shared with the prefill of such a family) and attention
-over the chosen pages only (``attend_chosen``)."""
+over the chosen pages only (``attend_chosen``). And the read path of a
+latent (MLA) layer, whose cache is ONE pool of one row a position (the
+compressed latent and the one rotary key all heads share), two positions
+side by side: ``write_latent`` and ``attend_latent``, the absorbed form,
+which never expands a cached row to per-head keys and values."""
 
 from __future__ import annotations
 
@@ -228,3 +232,85 @@ def attend_chosen(q, pool_k, pool_v, tables, idx, lengths):
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("sgrk,sgkd->sgrd", p.astype(v_sel.dtype), v_sel)
         return o.reshape(S, 1, -1)
+
+
+# ------------------------------------------------- a latent (MLA) layer's reads
+# A position's row is ``W = kv_lora_rank + qk_rope_head_dim`` values (576 at
+# the published widths: 4.5 lanes of 128). A pool ``[num_pages, page, W]``
+# would be kept by the chip with the PAGES as its minor axis (no padding
+# that way) and copied whole to a gatherable layout in every step (AOT, PR
+# 34: eight copies of 302 MB each way and 5.7 GB of temporaries), so the
+# pool holds TWO positions a row: ``[num_pages, page / 2, 2 W]``, 1152 = 9
+# lanes, position ``t`` of a page in row ``t // 2`` at ``(t % 2) W``.
+def latent_pool_shape(num_pages: int, page: int, width: int):
+    if page % 2:
+        raise ValueError("a latent pool keeps two positions a row: page_size "
+                         "must be even")
+    return (num_pages, page // 2, 2 * width)
+
+
+def latent_pages(rows, page: int):
+    """A sequence's cache rows [T, W] as whole pages [T / page, page / 2,
+    2 W], as a latent pool lays them out."""
+    return rows.reshape(-1, page // 2, 2 * rows.shape[-1])
+
+
+def write_latent(row, pool, page_idx, offs):
+    """Each slot's latent row [S, W] (the normed, scaled latent, then the
+    rotated rotary key) at position ``offs`` of its page ``page_idx``: the
+    pair's row is read, its half replaced, and written back."""
+    with jax.named_scope("latent_write"):
+        W = row.shape[-1]
+        old = pool[page_idx, offs // 2]                         # [S, 2 W]
+        row = row.astype(pool.dtype)
+        new = jnp.where((offs % 2 == 0)[:, None],
+                        jnp.concatenate([row, old[:, W:]], axis=-1),
+                        jnp.concatenate([old[:, :W], row], axis=-1))
+        return pool.at[page_idx, offs // 2].set(new)
+
+
+def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
+    """Each slot's query over every page of its table, in the ABSORBED form.
+
+    q_nope [S, H, dn], q_rope [S, H, dr] (rotated); w_uk [C, H, dn] and w_uv
+    [C, H, dv]: the up-projection of the latent to each head's keys and
+    values; pool [num_pages, page / 2, 2 (C + dr)]; tables [S, P]. Per head
+    ``q~ = W_UK q_nope`` in the latent's C dims, the score of a cached row
+    is ``(q~ . c + q_rope . k_r) * scale``, rows past the query's position
+    are masked, the softmax weights sum the LATENTS, and ``W_UV`` is applied
+    once a head. The same mathematics as attention over ``k = [c W_UK |
+    k_r]``, ``v = c W_UV``, at ``2 H (2 C + dr)`` operations a cached
+    position where expanding one would cost ``2 C H (dn + dv)``.
+
+    The gathered pages are contracted as the pool stores them, two positions
+    a row: the queries stand twice, ``[q, 0]`` against a row's first half and
+    ``[0, q]`` against its second (2 H rows fill the 128-wide unit that H =
+    64 would leave half empty), and the weighted sum of rows is read from the
+    matching halves. No copy of the gathered rows is sliced or reshaped.
+    -> o [S, H * dv]."""
+    with jax.named_scope("latent_attn"):
+        S, P = tables.shape
+        H, C = q_nope.shape[1], w_uk.shape[0]
+        W = pool.shape[2] // 2
+        R = P * pool.shape[1]               # rows of two positions a slot
+        dt = pool.dtype
+        q_lat = jnp.einsum("shd,chd->shc", q_nope, w_uk,
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat.astype(dt), q_rope.astype(dt)], axis=-1)
+        z = jnp.zeros_like(q)
+        q2 = jnp.concatenate([jnp.concatenate([q, z], axis=-1),
+                              jnp.concatenate([z, q], axis=-1)], axis=1)
+        rows = pool[tables].reshape(S, R, 2 * W)
+        s = jnp.einsum("sgw,srw->sgr", q2, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = s.reshape(S, 2, H, R)
+        pos = 2 * jnp.arange(R)[None, :] + jnp.arange(2)[:, None]   # [2, R]
+        admit = pos[None] <= lengths[:, None, None]
+        s = jnp.where(admit[:, :, None, :], s, -1e30)
+        e = jnp.exp(s - s.max(axis=(1, 3), keepdims=True))
+        p = e / e.sum(axis=(1, 3), keepdims=True)
+        o2 = jnp.einsum("sgr,srw->sgw", p.reshape(S, 2 * H, R).astype(dt),
+                        rows, preferred_element_type=jnp.float32)
+        o_lat = o2[:, :H, :C] + o2[:, H:, W:W + C]
+        o = jnp.einsum("shc,chd->shd", o_lat.astype(dt), w_uv)
+        return o.reshape(S, -1)

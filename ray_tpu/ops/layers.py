@@ -32,6 +32,15 @@ def rope_frequencies(head_dim: int, max_len: int,
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+def rope_rows(positions: jax.Array, head_dim: int,
+              theta: float) -> Tuple[jax.Array, jax.Array]:
+    """cos, sin [N, head_dim / 2] of the given positions [N]."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                           dtype=jnp.float32) / head_dim))
+    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
                positions: Optional[jax.Array] = None) -> jax.Array:
     """Rotary embedding. x: [B, L, H, D]; cos/sin: [max_len, D//2]."""

@@ -7,12 +7,16 @@ axis; tokens are dispatched to their routed experts with a single
 ``lax.all_to_all`` each way (ICI-friendly, compiled into the program by
 XLA), using the capacity-buffer formulation so every shape is static.
 
-Three implementations:
+Four implementations:
   * ``moe_ffn_share`` — the layer of ONE chip of an expert-parallel
     deployment, told which experts it holds: routes over all experts,
     computes the held experts' part of the result, dropless (every held
     expert on every row, then each row's own picked). On one chip it runs
-    without its exchange.
+    without its exchange. ``moe_ffn_grouped`` gives the same result by a
+    product grouped by expert (each held expert on its own rows), faster
+    where few pairs are held and the widths are multiples of 128;
+    ``moe_ffn_zero`` is that layer where the router's last outputs are
+    zero-compute (identity) experts.
   * ``moe_ffn_dense`` — computes every expert on every token and weights
     by the top-k gates. O(E) FLOPs; the correctness oracle and the
     single-device path.
@@ -98,6 +102,23 @@ def sigmoid_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
     return vals * scale, idx
 
 
+def _held_pairs(gate_idx: jax.Array, Eh: int, expert_offset: int,
+                token_mask: jax.Array | None):
+    """Of the pairs (token, expert) [T, k], those whose expert is one of the
+    ``Eh`` held from ``expert_offset`` (and whose lane is not masked out):
+    (the held experts' local index, which pairs are held, each pair's group
+    [T k] with ``Eh`` for "computed nowhere", the pairs of each held expert
+    int32[Eh])."""
+    T, k = gate_idx.shape
+    local = gate_idx - expert_offset
+    held = (local >= 0) & (local < Eh)
+    if token_mask is not None:
+        held = held & token_mask[:, None]
+    group = jnp.where(held, local, Eh).reshape(T * k)
+    sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+    return local, held, group, sizes
+
+
 def moe_ffn_share(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
                   experts_held: Dict[str, jax.Array], expert_offset: int,
                   token_mask: jax.Array | None = None
@@ -121,14 +142,9 @@ def moe_ffn_share(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
     (out [T, D], the held experts that got a token, the most tokens one
     expert got).
     """
-    T, k = gate_idx.shape
     Eh = experts_held["w_up"].shape[0]
-    local = gate_idx - expert_offset
-    held = (local >= 0) & (local < Eh)
-    if token_mask is not None:
-        held = held & token_mask[:, None]
-    group = jnp.where(held, local, Eh).reshape(T * k)    # Eh: computed nowhere
-    sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+    local, held, _, sizes = _held_pairs(gate_idx, Eh, expert_offset,
+                                        token_mask)
     y = _expert_ffn(jnp.broadcast_to(x, (Eh,) + x.shape), experts_held,
                     tp_psum=False)                       # [Eh, T, D]
     rows = jnp.take_along_axis(
@@ -137,6 +153,107 @@ def moe_ffn_share(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
                     rows.astype(jnp.float32) * gate_vals.T[:, :, None],
                     0.0).sum(axis=0)
     return out.astype(x.dtype), jnp.sum(sizes > 0), jnp.max(sizes)
+
+
+def softmax_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                  k: int, scale: float) -> Tuple[jax.Array, jax.Array]:
+    """Softmax router with a selection bias, in float32, over the router's
+    whole width (zero-compute experts among them). x: [T, D], w_router:
+    [D, E], bias: [E] -> (weights [T, k], experts [T, k]). The top k are
+    chosen by ``score + bias``; the weights are the chosen experts' scores
+    WITHOUT the bias, NOT normalised, times ``scale``."""
+    scores = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                                    w_router.astype(jnp.float32)), axis=-1)
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+    return jnp.take_along_axis(scores, idx, axis=-1) * scale, idx
+
+
+def moe_ffn_grouped(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
+                    experts_held: Dict[str, jax.Array], expert_offset: int,
+                    token_mask: jax.Array | None = None
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``moe_ffn_share``'s result by a product GROUPED by expert: the pairs
+    (token, expert) are sorted with the held ones first, by expert, and each
+    held expert multiplies exactly its rows (``lax.ragged_dot``), so an
+    expert no token picked is not read and no row meets an expert it did
+    not pick. Only the first ``cap`` sorted pairs are gathered and
+    multiplied: all ``T k`` of them at a decode step's few rows, ``T`` of
+    them at a prompt's (a router that spreads its picks sends ``T k Eh / E``
+    pairs here, a fortieth of them at 16 of 768); should more pairs than
+    ``cap`` be held, the call takes ``moe_ffn_share`` instead (one
+    ``lax.cond``), so it stays dropless and exact.
+
+    On a TPU v5e at 16 gated experts of 6144 x 2048 this was 0.48 against
+    ``moe_ffn_share``'s 1.69 ms a layer at 16 rows (4 of the 16 experts hit:
+    a quarter of the bytes) and 5.7 against 17.1 at 2048 (PERF.md section 5,
+    PR 34); at 16 experts of 2688 x 1856 it lost at every row count (a
+    width that is no multiple of 128 costs a layout copy of every expert:
+    PR 33), which is why ``moe_ffn_share`` is the other form. The rows
+    ``lax.ragged_dot`` leaves past its last group are UNINITIALISED on a TPU
+    (inf and NaN among them): they are selected away, never multiplied by a
+    zero weight. The un-sort is a gather (a scatter-add would sum in no
+    fixed order)."""
+    T, k = gate_idx.shape
+    Eh = experts_held["w_up"].shape[0]
+    cap = min(T * k, max(T, 16 * k))
+    _, _, group, sizes = _held_pairs(gate_idx, Eh, expert_offset, token_mask)
+    n_held = jnp.sum(sizes)
+
+    def grouped():
+        order = jnp.argsort(group, stable=True)
+        top = order[:cap]
+        rows = x[top // k]                                   # [cap, D]
+        u = lax.ragged_dot(rows, experts_held["w_up"], sizes)
+        if "w_gate" in experts_held:
+            u = jax.nn.silu(lax.ragged_dot(rows, experts_held["w_gate"],
+                                           sizes)) * u
+        else:
+            u = relu2(u)
+        y = lax.ragged_dot(u, experts_held["w_down"], sizes)
+        w = gate_vals.reshape(T * k)[top]
+        y = jnp.where((jnp.arange(cap) < n_held)[:, None],
+                      y.astype(jnp.float32) * w[:, None], 0.0)
+        at = jnp.argsort(order).reshape(T, k)   # a pair's place when sorted
+        out = jnp.zeros((T, y.shape[1]), jnp.float32)
+        for j in range(k):      # a token's k rows, summed in gate order
+            out = out + jnp.where((at[:, j] < cap)[:, None],
+                                  y[jnp.minimum(at[:, j], cap - 1)], 0.0)
+        return out.astype(x.dtype)
+
+    if cap == T * k:
+        out = grouped()
+    else:
+        out = lax.cond(
+            n_held <= cap, grouped,
+            lambda: moe_ffn_share(x, gate_vals, gate_idx, experts_held,
+                                  expert_offset, token_mask)[0])
+    return out, jnp.sum(sizes > 0), jnp.max(sizes)
+
+
+def moe_ffn_zero(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
+                 experts_held: Dict[str, jax.Array], expert_offset: int,
+                 n_real: int, token_mask: jax.Array | None = None
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The layer of one chip where the router's outputs from ``n_real`` on
+    are **zero-compute (identity) experts**: a pair (token, expert) with
+    ``expert >= n_real`` adds ``gate x input``. Such a pair needs no
+    exchange, so in the deployment a token's home chip adds it, and this
+    chip adds it whole for its own tokens (as a shared expert is counted
+    once); a held pair adds the held expert's result (``moe_ffn_grouped``:
+    with a third of the picks computing nothing and 16 of 512 computing
+    experts here, few pairs are held), an absent pair nothing. Returns (out
+    [T, D], held experts hit, most tokens of one expert, the pairs routed to
+    zero experts)."""
+    routed, hit, most = moe_ffn_grouped(x, gate_vals, gate_idx, experts_held,
+                                        expert_offset, token_mask)
+    with jax.named_scope("zero_experts"):
+        zero = gate_idx >= n_real
+        if token_mask is not None:
+            zero = zero & token_mask[:, None]
+        gate = jnp.where(zero, gate_vals, 0.0).sum(axis=-1)
+        out = routed.astype(jnp.float32) \
+            + x.astype(jnp.float32) * gate[:, None]
+    return out.astype(x.dtype), hit, most, jnp.sum(zero)
 
 
 def moe_ffn_dense(x: jax.Array, w_router: jax.Array,

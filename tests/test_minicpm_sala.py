@@ -457,7 +457,7 @@ def test_the_other_families_hold_no_compressed_keys():
                       dtype=jnp.float32)
     eng = PagedEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg,
                       max_slots=2, num_pages=24, page_size=8, max_len=64)
-    assert not eng.recurrent and not hasattr(eng, "pools_c")
+    assert not eng.family and not hasattr(eng, "pools_c")
     assert eng._prefill_buckets == (16, 64, 256)
 
 

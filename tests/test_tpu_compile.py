@@ -257,6 +257,61 @@ def test_sala_step_and_prefill_chunk_minicpm_sala_widths(one_chip):
     assert f"{cfg.prefill_chunk},{max_len}]" not in compiled.as_text()
 
 
+def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
+    """The latent-attention / zero-expert family's programs at the
+    benchmark's widths and one of its double layers, 16 slots of 288 pages:
+    the decode step gives its pools back aliased to the donated arguments,
+    holds no copy of a whole pool (a pool of one 576-wide row a position is
+    kept with its pages minor and copied whole each way every step: PR 34;
+    two positions a row are not) and never expands a cached latent row (no
+    array of the gathered positions x heads x a per-head key or value); the
+    prefill chunk builds no array of chunk x ``max_len`` scores and no
+    expanded cache."""
+    from perfbench.aot_longcat import expanded_shapes
+    from ray_tpu.models import longcat_flash as lc
+    from ray_tpu.models.paged_ops import latent_pool_shape
+
+    cfg = lc.LongcatFlashConfig(vocab_size=16384, n_layers=1,
+                                experts_held=16)
+    S, pages, page, max_len = 16, 4096, 64, 18432
+    params = _on(one_chip, jax.eval_shape(
+        lambda: lc._seeded_params(cfg, jax.random.PRNGKey(0))))
+    shape = latent_pool_shape(pages, page, cfg.latent_width)
+    assert shape == (4096, 32, 1152)
+    pools = [_shape(one_chip, shape)] * cfg.n_sublayers
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = lc._longcat_step.lower(
+        params, pools, i32((S, max_len // page)), i32((S,)), i32((S,)),
+        f32((S,)), i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
+        cfg=cfg, page=page).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+    text = compiled.as_text()
+    pool = "bf16[%d,%d,%d]" % shape
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"= {pool}" in ln]
+    assert expanded_shapes(text, S * max_len, cfg) == []
+    # the guard sees an expansion where there is one
+    assert expanded_shapes(f"bf16[{S},{max_len},64,128]", S * max_len, cfg)
+    # the held experts' products are grouped by expert (PR 34): three a layer
+    assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
+    assert m.temp_size_in_bytes < 0.6e9
+    carry = _on(one_chip, jax.eval_shape(
+        lambda: lc.prefill_carry(cfg, max_len)))
+    compiled = lc._longcat_prefill_chunk.lower(
+        params, i32((cfg.prefill_chunk,)), i32(()), i32(()), carry,
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
+    assert m.temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    assert f"{cfg.prefill_chunk},{max_len}]" not in text
+    assert f"[{max_len},{cfg.n_heads}," not in text     # no expanded cache
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 def test_scatter_pages_writes_the_pools_in_place(one_chip, kv_int8):
     """The admission's one scatter at the benchmark's widths (16 layers of
