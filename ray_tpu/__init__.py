@@ -97,29 +97,40 @@ def init(address: Optional[str] = None, *,
         cur = "/tmp/ray_tpu/ray_current_cluster"
         if os.path.exists(cur):
             address = open(cur).read().strip() or None
-    client_mode = False
-    if address is not None and address.startswith("ray://"):
-        # Remote-driver ("Ray Client") connection — reference:
-        # ``python/ray/util/client/`` ray:// proxy. Here the same control
-        # protocol serves remote drivers directly; client mode switches the
-        # object plane to the GCS transfer relay since no host store is
-        # shared with the cluster.
-        address = address[len("ray://"):]
-        client_mode = True
-    if address is None:
-        from ._private.node import HeadNode
+    from .util import events as plane_events
 
-        res = dict(resources or {})
-        if object_store_memory is not None:
-            res["object_store_memory"] = float(object_store_memory)
-        _head_node = HeadNode(num_cpus=num_cpus, num_tpus=num_tpus,
-                              resources=res or None,
-                              num_initial_workers=num_initial_workers,
-                              probe_tpu=probe_tpu, port=port, host=host)
-        address = _head_node.address
-    w = _worker_mod.Worker(role="driver")
-    w.namespace = namespace
-    w.connect(address, client_mode=client_mode)
+    # The cluster's start as this driver sees it, until it is connected:
+    # the first row of a session's set-up on the recorder's one clock.
+    with plane_events.span("gcs.cluster.start", "gcs",
+                           started_head=address is None):
+        client_mode = False
+        if address is not None and address.startswith("ray://"):
+            # Remote-driver ("Ray Client") connection — reference:
+            # ``python/ray/util/client/`` ray:// proxy. Here the same
+            # control protocol serves remote drivers directly; client mode
+            # switches the object plane to the GCS transfer relay since no
+            # host store is shared with the cluster.
+            address = address[len("ray://"):]
+            client_mode = True
+        if address is None:
+            from ._private.node import HeadNode
+
+            res = dict(resources or {})
+            if object_store_memory is not None:
+                res["object_store_memory"] = float(object_store_memory)
+            # head process spawned -> GCS serving and its node agent
+            # registered (``gcs.ready`` is written after both)
+            with plane_events.span("gcs.head.spawn", "gcs"):
+                _head_node = HeadNode(
+                    num_cpus=num_cpus, num_tpus=num_tpus,
+                    resources=res or None,
+                    num_initial_workers=num_initial_workers,
+                    probe_tpu=probe_tpu, port=port, host=host)
+            address = _head_node.address
+        w = _worker_mod.Worker(role="driver")
+        w.namespace = namespace
+        with plane_events.span("gcs.driver.connect", "gcs"):
+            w.connect(address, client_mode=client_mode)
     _worker_mod.set_global_worker(w)
     _initialized = True
     atexit.register(shutdown)

@@ -291,6 +291,9 @@ class ActorRecord:
         # reconnect; ``restored`` marks records awaiting re-claim).
         self.owner_wid: Optional[bytes] = None
         self.restored = False
+        # perf_counter_ns of the creation request of an actor that asks
+        # for a chip, until its lease is granted (``lease.actor.place``)
+        self.place_t0_ns = 0
 
 
 # Gang lifecycle (train fault plane): FORMING is client-side (the group
@@ -3609,6 +3612,8 @@ class GcsServer:
             # connection's 'default'.
             opts["namespace"] = tenant
         record = ActorRecord(aid, msg, client)
+        if record.resources.get("TPU", 0) > 0:
+            record.place_t0_ns = time.perf_counter_ns()
         if record.name is not None:
             key = (record.namespace, record.name)
             if key in self.named_actors:
@@ -3667,6 +3672,14 @@ class GcsServer:
         worker.acquired = self._acquire(node, record)
         record.worker_id = worker.worker_id
         record.node_id = node.node_id
+        if record.place_t0_ns:
+            # creation request -> the grant: the wait for a node with the
+            # chips free and for a worker of the chip-holding pool
+            plane_events.span_done(
+                "lease.actor.place", "lease", record.place_t0_ns,
+                actor=record.actor_id.hex(), resources=record.resources,
+                node=node.node_id.hex(), worker_pid=worker.pid)
+            record.place_t0_ns = 0
         fwd = dict(record.msg)
         fwd["t"] = "actor_init"
         fwd.pop("i", None)
@@ -4366,6 +4379,9 @@ class GcsServer:
         when read (same stance as task_events). ``drops`` carries the
         sender's per-plane drop DELTA since its last drain — accumulated
         here so a ring overflow anywhere is visible cluster-wide."""
+        self._store_plane_events(msg)
+
+    def _store_plane_events(self, msg: dict):
         nid = bytes(msg.get("nid") or b"")
         pid = msg.get("pid", 0)
         for row in msg.get("ev") or []:
@@ -4376,15 +4392,10 @@ class GcsServer:
 
     def _ingest_local_plane_events(self):
         """Fold this process's OWN ring into the table (the GCS emits
-        lease/admission/wait events but has no worker to push through)."""
-        if not plane_events.enabled() or plane_events.pending() == 0:
-            return
-        rows, drops = plane_events.drain()
-        for row in rows:
-            self.plane_events.append((b"", os.getpid(), row))
-        for plane, n in drops.items():
-            self.plane_event_drops[plane] = \
-                self.plane_event_drops.get(plane, 0) + n
+        lease/admission/wait events but has no worker to push through),
+        and into its spill file like every other process's."""
+        plane_events.drain_and_spill(self._store_plane_events,
+                                     self.session_dir)
 
     def _retention_sweep(self):
         """Bounded-retention sweep, one owner for both stores: evict

@@ -14,7 +14,9 @@ moment jax is imported — paying zero cost in workers that never touch jax.
 
 Every process of a session passes through :func:`install_hook` before it
 imports jax, so this is also the one place that decides where XLA's
-persistent compile cache lives (:func:`compile_cache_dir`).
+persistent compile cache lives (:func:`compile_cache_dir`), and the one
+place that puts jax's compile path on the flight recorder
+(:func:`record_program_builds`).
 """
 
 from __future__ import annotations
@@ -23,7 +25,11 @@ import importlib.abc
 import importlib.util
 import os
 import sys
+import threading
+import time
 from typing import Optional
+
+from ray_tpu.util import events
 
 ENV_VAR = "RAY_TPU_JAX_PLATFORM"
 CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -71,8 +77,68 @@ def apply(platform: str | None = None):
     jax.config.update("jax_platforms", platform)
 
 
+# a compile and a load from the persistent cache alike
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_BUILD_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                 _BACKEND_EVENT)
+# A step program's trace holds thousands of nested traces of microseconds
+# each (4 786 of a replica's 4 866 rows: PERF.md section 6, PR 41): a
+# trace or a lowering shorter than this writes no row; the backend's
+# event always does.
+_MIN_BUILD_ROW_S = 1e-3
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+# whether the program this thread is building came from the persistent
+# cache: jax says so in the same thread, just before the backend's event
+_building = threading.local()
+_recording = False
+
+
+def _on_cache_event(event: str, **_):
+    if event == _CACHE_HIT:
+        _building.cache_hit = True
+    elif event == _CACHE_MISS:
+        _building.cache_hit = False
+
+
+def _on_build_event(event: str, duration_secs: float, **kw):
+    if event not in _BUILD_EVENTS or (
+            duration_secs < _MIN_BUILD_ROW_S and event != _BACKEND_EVENT):
+        return
+    fields = {"event": event.rsplit("/", 1)[1],
+              "program": kw.get("fun_name", "")}
+    if event == _BACKEND_EVENT:
+        # neither flag was raised where the cache was not asked
+        fields["cache_hit"] = getattr(_building, "cache_hit", None)
+        _building.cache_hit = None
+    events.span_done("jit.program.build", "jit",
+                     time.perf_counter_ns() - int(duration_secs * 1e9),
+                     **fields)
+
+
+def record_program_builds():
+    """One ``jit.program.build`` row each time jax traces or lowers a
+    program for a millisecond or more, and each time it compiles one or
+    loads it from its cache, in this process
+    (``event``, ``program``; ``cache_hit`` on the backend's row), from
+    ``jax.monitoring`` listeners installed once, and only where the
+    recorder is on. A listener learns of an interval at its end, so the
+    rows are ``span_done``'s; it runs when a program is built, never on
+    the call of one that is."""
+    global _recording
+    if _recording or not events.enabled():
+        return
+    import jax.monitoring as monitoring
+
+    monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_build_event)
+    _recording = True
+
+
 class _JaxPostImportHook(importlib.abc.MetaPathFinder):
-    """Applies the platform config right after ``jax`` executes.
+    """Applies the platform config right after ``jax`` executes, and
+    records the import and, from then on, the programs jax builds.
 
     The hook stays installed until ``exec_module`` actually runs (a bare
     ``find_spec('jax')`` probe from optional-dependency checks must not
@@ -103,10 +169,12 @@ class _JaxPostImportHook(importlib.abc.MetaPathFinder):
                 return orig_loader.create_module(s)
 
             def exec_module(self, mod):
-                orig_loader.exec_module(mod)
+                with events.span("jit.jax.import", "jit"):
+                    orig_loader.exec_module(mod)
                 platform = os.environ.get(ENV_VAR)
                 if platform:
                     mod.config.update("jax_platforms", platform)
+                record_program_builds()
                 try:
                     sys.meta_path.remove(hook)
                 except ValueError:
@@ -117,15 +185,18 @@ class _JaxPostImportHook(importlib.abc.MetaPathFinder):
 
 
 def install_hook():
-    """Place the compile cache, and install the post-import hook if a
-    platform override is requested."""
+    """Place the compile cache, and arm the post-import hook where it has
+    something to do once jax is there: a platform override to apply, or
+    the recorder to tell of the programs jax builds."""
     _place_compile_cache()
-    if not os.environ.get(ENV_VAR):
+    if not os.environ.get(ENV_VAR) and not events.enabled():
         return
     if "jax" in sys.modules:
         apply()
+        record_program_builds()
         return
-    sys.meta_path.insert(0, _JaxPostImportHook())
+    if not any(isinstance(f, _JaxPostImportHook) for f in sys.meta_path):
+        sys.meta_path.insert(0, _JaxPostImportHook())
 
 
 def device_report() -> Optional[dict]:

@@ -23,6 +23,8 @@ import time
 import uuid
 from typing import Dict, List, Optional
 
+from ray_tpu.util import events as plane_events
+
 from . import failpoints, protocol
 from .ids import NodeID
 
@@ -651,6 +653,7 @@ class NodeAgent:
     async def _probe_tpu(self):
         if session_pinned_off_tpu():
             return
+        t0_ns = time.perf_counter_ns()
         # One-shot at node start: opens the probe's log, writes nothing
         # itself.  # raylint: disable=RTL006
         with open(os.path.join(self.session_dir, "tpu_probe.out"),  # raylint: disable=RTL006
@@ -679,6 +682,10 @@ class NodeAgent:
             self.conn.send({"t": "update_resources",
                             "node_id": self.node_id.binary(),
                             "resources": res})
+        # subprocess start -> the node's TPU count sent: what a driver
+        # waits for before it can deploy onto the chip
+        plane_events.span_done("gcs.node.probe", "gcs", t0_ns, chips=n,
+                               rc=proc.returncode)
 
     def spawn_worker(self, env_spec: Optional[dict] = None,
                      env_key: str = ""):
@@ -832,7 +839,7 @@ class NodeAgent:
         self._zygote = None  # raylint: disable=RTL151 (atomic rebind; loop readers snapshot + poll()-validate)
         self._zygote_rbuf = b""
 
-    def _spawn_batch_via_zygote(self, env_keys: List[str]) -> int:
+    def _spawn_batch_via_zygote(self, spawns: List[tuple]) -> int:
         """Fork a burst of workers from the pre-imported template.
 
         Pipelined: all requests are written first, then the pids are
@@ -844,7 +851,7 @@ class NodeAgent:
         if z is None:
             return 0
         lines = []
-        for env_key in env_keys:
+        for env_key, _ in spawns:
             req = {
                 "env": {**self.env_overrides,
                         **worker_spawn_env(env_key, self.node_id.hex())},
@@ -865,8 +872,9 @@ class NodeAgent:
             return 0
         done = 0
         try:
-            for _ in env_keys:
+            for env_key, t0_ns in spawns:
                 pid = int(self._pipe_read_line(15.0).strip())
+                self._spawned(env_key, t0_ns, pid, zygote=True)
                 # Copy-on-write rebind, NOT .add(): the memory-monitor
                 # path iterates this set from the IO loop
                 # (_is_zygote_child candidates), and a concurrent .add()
@@ -904,9 +912,9 @@ class NodeAgent:
             ok = 0
             try:
                 ok = self._spawn_batch_via_zygote(batch)
-                for env_key in batch[ok:]:
+                for env_key, t0_ns in batch[ok:]:
                     self._spawn_cold(sys.executable, worker_sys_path(),
-                                     env_key)
+                                     env_key, None, t0_ns)
                     ok += 1
             except Exception as e:  # noqa: BLE001 — keep the spawner alive
                 import logging
@@ -920,7 +928,17 @@ class NodeAgent:
                     self._loop.call_soon_threadsafe(
                         self._send_spawn_failed, err)
 
+    @staticmethod
+    def _spawned(env_key: str, t0_ns: int, pid: int, zygote: bool):
+        """``_spawn``'s entry -> the worker process exists. Its hello
+        goes to the GCS, not here: the stretch from the process's start
+        to it is the worker's own ``lease.worker.boot``, joined by
+        ``worker_pid``."""
+        plane_events.span_done("lease.worker.spawn", "lease", t0_ns,
+                               worker_pid=pid, pool=env_key, zygote=zygote)
+
     def _spawn(self, python: str, sys_path: str, env_key: str, wrap=None):
+        t0_ns = time.perf_counter_ns()
         if self._zygote_available(python, wrap):
             # Queue for the spawner thread: the agent loop never blocks on
             # the zygote handshake (ADVICE r2: a stalled template must not
@@ -933,12 +951,12 @@ class NodeAgent:
                 self._spawner = threading.Thread(
                     target=self._spawner_thread_main, daemon=True)
                 self._spawner.start()
-            self._spawn_q.put(env_key)
+            self._spawn_q.put((env_key, t0_ns))
             return
-        self._spawn_cold(python, sys_path, env_key, wrap)
+        self._spawn_cold(python, sys_path, env_key, wrap, t0_ns)
 
     def _spawn_cold(self, python: str, sys_path: str, env_key: str,
-                    wrap=None):
+                    wrap, t0_ns: int):
         env = dict(os.environ)
         env.update(self.env_overrides)
         env.pop("RAY_TPU_ENV_KEY", None)
@@ -962,6 +980,7 @@ class NodeAgent:
             stderr=subprocess.STDOUT,
         )
         self.procs.append(proc)
+        self._spawned(env_key, t0_ns, proc.pid, zygote=False)
 
     async def _on_msg(self, msg: dict):
         t = msg.get("t")
@@ -976,26 +995,17 @@ class NodeAgent:
             self.stopped.set()
 
     async def _reap_loop(self):
-        from ray_tpu.util import events as plane_events
-
         while not self.stopped.is_set():
             for p in self.procs:
                 p.poll()
             # Agent-side plane events (this process's chunk-serve
-            # threads emit bcast rows) flush on the reap tick — agents
-            # have no executor flush loop.
-            if plane_events.pending() and self.conn is not None \
-                    and not self.conn.closed:
-                rows, drops = plane_events.drain()
-                if rows or drops:
-                    try:
-                        self.conn.send({
-                            "t": "plane_events", "ev": rows,
-                            "drops": drops,
-                            "nid": self.node_id.binary(),
-                            "pid": os.getpid()})
-                    except ConnectionError:
-                        pass
+            # threads emit bcast rows, the probe and the spawns their
+            # spans) flush on the reap tick — agents have no executor
+            # flush loop.
+            if self.conn is not None and not self.conn.closed:
+                plane_events.drain_and_spill(
+                    self.conn.send, self.session_dir,
+                    self.node_id.binary())
             await asyncio.sleep(0.5)
 
     async def run_until_stopped(self):
@@ -1133,6 +1143,10 @@ async def head_amain(args):
             if not gcs.restart_requested:
                 agent.stopped.set()
                 agent.shutdown_workers()
+                # what this process recorded since its last tick (a
+                # placement, a spawn) reaches its spill file
+                gcs._ingest_local_plane_events()
+                await plane_events.spilled()
                 if hasattr(gcs.store, "unlink"):
                     try:
                         gcs.store.unlink()
@@ -1231,6 +1245,8 @@ async def agent_amain(args):
                       env_overrides=json.loads(args.env or "{}"))
     await agent.start()
     await agent.run_until_stopped()
+    plane_events.drain_and_spill(lambda frame: None, args.session_dir)
+    await plane_events.spilled()
 
 
 def agent_main():

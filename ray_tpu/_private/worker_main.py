@@ -45,19 +45,6 @@ from .worker import ObjectRef, Worker, set_global_worker
 _MISSING = object()
 
 
-def _boot_ts(label: str):
-    """Env-gated boot diagnostics (RAY_TPU_BOOT_TS=1): prints this
-    process's cumulative CPU at each boot phase to the worker log — the
-    tool that found the 87 ms/actor launch-storm costs (arena walk,
-    per-child module imports)."""
-    if os.environ.get("RAY_TPU_BOOT_TS"):
-        import resource
-
-        r = resource.getrusage(resource.RUSAGE_SELF)
-        print(f"BOOT {label} cpu={r.ru_utime + r.ru_stime:.3f} "
-              f"flt={r.ru_minflt}", file=sys.stderr, flush=True)
-
-
 class Executor:
     def __init__(self, worker: Worker, listen_path: str):
         self.worker = worker
@@ -104,7 +91,6 @@ class Executor:
         # TaskEventBuffer (reference: task_event_buffer.h:220): bounded local
         # buffer of profile events, flushed to the GCS periodically.
         self.events: List[dict] = []
-        self._spilled: Optional[asyncio.Future] = None
 
     def record_event(self, tid: bytes, name: str, kind: str,
                      start: float, end: float, ok: bool):
@@ -125,18 +111,10 @@ class Executor:
                 pass
         # Plane-event recorder rows ride the same coalesced cadence as
         # task_events (ISSUE 14): one drain + one frame per tick.
-        if (plane_events.pending()
-                and self.worker.gcs and not self.worker.gcs.closed):
-            rows, drops = plane_events.drain()
-            if rows or drops:
-                try:
-                    self.worker.gcs.send({
-                        "t": "plane_events", "ev": rows, "drops": drops,
-                        "nid": self.worker.node_id or b"",
-                        "pid": os.getpid()})
-                except ConnectionError:
-                    pass
-                self._spill(rows)
+        if self.worker.gcs and not self.worker.gcs.closed:
+            plane_events.drain_and_spill(
+                self.worker.gcs.send, self.worker.session_dir,
+                self.worker.node_id)
         if self.events and self.worker.gcs and not self.worker.gcs.closed:
             batch, self.events = self.events, []
             try:
@@ -147,24 +125,6 @@ class Executor:
                     "pid": os.getpid()})
             except ConnectionError:
                 pass
-
-    def _spill(self, rows):
-        """The same rows to the session's spill file. The tick runs on
-        the IO loop, so the file append goes to the executor; the last
-        flush of an exiting worker is awaited (``_spilled``)."""
-        session_dir = self.worker.session_dir
-        try:
-            loop = asyncio.get_running_loop()
-        except RuntimeError:
-            plane_events.spill(rows, session_dir)
-            return
-        self._spilled = loop.run_in_executor(
-            None, plane_events.spill, rows, session_dir)
-
-    async def spilled(self):
-        """Wait (bounded) for the last spill append before a hard exit."""
-        if self._spilled is not None:
-            await asyncio.wait([self._spilled], timeout=0.25)
 
     async def start(self):
         self._server = await protocol.serve(
@@ -725,7 +685,7 @@ class Executor:
         if self.die_after_task:
             self.flush_events()
             await asyncio.sleep(0.01)
-            await self.spilled()
+            await plane_events.spilled()
             os._exit(0)
 
     def _execute_sync(self, msg: dict, tid: bytes, nret: int,
@@ -850,7 +810,6 @@ class Executor:
             and not self.group_thread_sems
         try:
             await loop.run_in_executor(self.pool, self._init_actor_sync, msg)
-            _boot_ts("actor_ready")
             self.worker.gcs.send({"t": "actor_ready",
                                   "aid": msg["aid"]})
         except Exception as e:  # noqa: BLE001
@@ -860,18 +819,34 @@ class Executor:
             self.actor_id = None
 
     def _init_actor_sync(self, msg: dict):
-        self._apply_runtime_env(msg.get("opts") or {})
-        cls = self._get_function(msg["fid"])
-        if (msg.get("opts") or {}).get("xlang"):
-            # Non-Python owner (C++ client): args are a msgpack array.
-            import msgpack
+        from .runtime_context import _clear_execution, _set_execution
 
-            args = tuple(msgpack.unpackb(bytes(msg.get("args") or b"\x90"),
-                                         raw=False))
-            kwargs = {}
-        else:
-            args, kwargs = self._load_args(msg)
-        self.actor_instance = cls(*args, **kwargs)  # raylint: disable=RTL151 (loop awaits the init executor future before any call dispatch — happens-before)
+        # The constructor knows its actor as a method does
+        # (get_runtime_context().get_actor_id(), the rows' ``actor``).
+        _set_execution(actor_id=self.actor_id.binary(),
+                       resources=(msg.get("opts") or {}).get("res"))
+        try:
+            # The creation request's arrival -> the constructor's first
+            # line: the runtime env, the class and the arguments loaded
+            # (where a class that uses jax imports it: ``jit.jax.import``
+            # is the child).
+            with plane_events.span("lease.actor.load", "lease",
+                                   **plane_events.process_actor()):
+                self._apply_runtime_env(msg.get("opts") or {})
+                cls = self._get_function(msg["fid"])
+                if (msg.get("opts") or {}).get("xlang"):
+                    # Non-Python owner (C++ client): args are a msgpack
+                    # array.
+                    import msgpack
+
+                    args = tuple(msgpack.unpackb(
+                        bytes(msg.get("args") or b"\x90"), raw=False))
+                    kwargs = {}
+                else:
+                    args, kwargs = self._load_args(msg)
+            self.actor_instance = cls(*args, **kwargs)  # raylint: disable=RTL151 (loop awaits the init executor future before any call dispatch — happens-before)
+        finally:
+            _clear_execution()
 
     async def _run_actor_call(self, conn: protocol.Connection, msg: dict):
         loop = asyncio.get_running_loop()
@@ -1177,7 +1152,6 @@ class Executor:
 
 
 async def amain(args):
-    _boot_ts("amain")
     worker = Worker(role="worker")
     worker.loop = asyncio.get_running_loop()
     worker._loop_thread = threading.main_thread()
@@ -1355,7 +1329,12 @@ async def amain(args):
             stop.set()
 
     reply = await connect_gcs()
-    _boot_ts("connected")
+    # This process's start (its fork from the zygote, or interpreter and
+    # imports) -> the GCS's answer to its hello: a worker is claimable
+    # from here on, and one spawned for a waiting actor is claimed at once.
+    plane_events.span_done(
+        "lease.worker.boot", "lease", plane_events.process_start_ns(),
+        worker_pid=os.getpid(), pool=os.environ.get("RAY_TPU_ENV_KEY", ""))
     worker.session_name = reply["session"]
     worker.session_dir = reply["session_dir"]
     from .object_store import make_store
@@ -1364,7 +1343,6 @@ async def amain(args):
     # boot (launch storms of store-less actors skip it entirely).
     worker._store_factory = (
         lambda s=worker.session_name: make_store(s))
-    _boot_ts("store")
     set_global_worker(worker)
     worker._flusher_handle = worker.loop.call_later(0.1, worker._flush_refs_cb)
     asyncio.get_running_loop().create_task(flush_events_loop())
@@ -1372,7 +1350,7 @@ async def amain(args):
     await stop.wait()
     loop_monitor.stop()
     executor.flush_events()
-    await executor.spilled()
+    await plane_events.spilled()
     worker._flush_refs()
     try:
         os.unlink(listen_path)
@@ -1406,11 +1384,9 @@ def main_from_req(req: dict):
     from .jax_platform import install_hook
     from .node import _run_with_optional_profile
 
-    _boot_ts("pre-hook")
     install_hook()
     args = types.SimpleNamespace(gcs=req["gcs"], node_id=req["node_id"],
                                  session_dir=req["session_dir"])
-    _boot_ts("pre-run")
     _run_with_optional_profile(lambda: amain(args), "worker")
 
 

@@ -10,6 +10,7 @@ import cloudpickle
 from typing import Any, Dict, Optional
 
 import ray_tpu
+from ray_tpu.util import events as plane_events
 
 from dataclasses import dataclass as _dataclass
 
@@ -179,6 +180,14 @@ def run(target: Application, *, name: str = "default",
         return _run_local(target, name)
     if not ray_tpu.is_initialized():
         ray_tpu.init(ignore_reinit_error=True)
+    # Until every replica answers: each one's placement, worker and
+    # constructor are rows of their own processes inside this interval.
+    with plane_events.span("serve.app.run", "serve", app=name):
+        return _deploy(target, name, route_prefix)
+
+
+def _deploy(target: Application, name: str,
+            route_prefix: Optional[str]) -> DeploymentHandle:
     graph: Dict[str, Application] = {}
     _collect_graph(target, graph, name)
     specs = []

@@ -196,6 +196,17 @@ class Replica:
                  init_kwargs: dict, is_class: bool,
                  app_name: str = "default", deployment_name: str = "",
                  replica_tag: str = ""):
+        # The constructor's span: the user's class loaded (its imports)
+        # and built. ``actor`` joins it to the rows of this replica's
+        # placement, spawn, boot and load in their own processes.
+        with plane_events.span("serve.replica.init", "serve",
+                               deployment=deployment_name,
+                               **plane_events.process_actor()):
+            self._build(cls_or_fn_blob, init_args, init_kwargs, is_class,
+                        app_name, deployment_name, replica_tag)
+
+    def _build(self, cls_or_fn_blob, init_args, init_kwargs, is_class,
+               app_name, deployment_name, replica_tag):
         import importlib
 
         import cloudpickle

@@ -48,7 +48,8 @@ class LLMServer:
             raise ValueError(
                 f"kv_cache={kv_cache!r}: PagedEngine (models/paged.py) is "
                 f"the only serving engine; leave the keyword out")
-        params, cfg = model_factory()
+        with plane_events.span("serve.replica.weights", "serve"):
+            params, cfg = model_factory()
         # Speculative decoding: a replica-side draft factory (a distilled
         # checkpoint loader, or models.speculative.truncated_draft over
         # the target). Requests opting in with {"speculative": true} run
@@ -78,10 +79,13 @@ class LLMServer:
 
         if num_pages is None:   # every slot at max_len, + scratch page 0
             num_pages = max_slots * (max_len // page_size) + 1
-        self.engine = PagedEngine(
-            params, cfg, max_slots=max_slots, num_pages=num_pages,
-            page_size=page_size, max_len=max_len,
-            enable_prefix_cache=enable_prefix_cache, kv_dtype=kv_dtype)
+        # pools, tables and the first device allocations
+        with plane_events.span("serve.replica.engine", "serve",
+                               slots=max_slots, pages=num_pages):
+            self.engine = PagedEngine(
+                params, cfg, max_slots=max_slots, num_pages=num_pages,
+                page_size=page_size, max_len=max_len,
+                enable_prefix_cache=enable_prefix_cache, kv_dtype=kv_dtype)
         self._queues: Dict[str, asyncio.Queue] = {}
         self._loop_task: Optional[asyncio.Task] = None
         # Serializes engine stepping against live weight refresh: step()

@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, List, Optional
 import cloudpickle
 
 import ray_tpu
+from ray_tpu.util import events as plane_events
 
 from .checkpoint import Checkpoint
 from .config import CheckpointConfig, FailureConfig, RunConfig, ScalingConfig
@@ -176,6 +177,9 @@ class JaxTrainer:
         self.resume_from_checkpoint = resume_from_checkpoint
 
     def fit(self) -> Result:
+        # ``train.fit.start`` of the first attempt begins here; a later
+        # attempt's (a restart, a reshape) where that attempt begins
+        t0_ns = time.perf_counter_ns()
         if not ray_tpu.is_initialized():
             ray_tpu.init(ignore_reinit_error=True)
         run_name = self.run_config.name or f"JaxTrainer_{uuid.uuid4().hex[:8]}"
@@ -198,7 +202,8 @@ class JaxTrainer:
 
         while True:
             result = self._run_attempt(run_name, storage, restore_path,
-                                       num_workers=workers)
+                                       num_workers=workers, t0_ns=t0_ns)
+            t0_ns = 0
             if result.error is None:
                 if result.rescaled_to is not None:
                     # Cooperative rescale exit: capacity returned — grow
@@ -418,10 +423,12 @@ class JaxTrainer:
 
     def _run_attempt(self, run_name: str, storage: str,
                      restore_path: Optional[str],
-                     num_workers: Optional[int] = None) -> Result:
+                     num_workers: Optional[int] = None,
+                     t0_ns: int = 0) -> Result:
         sc = self.scaling_config
         n_workers = num_workers if num_workers is not None else sc.num_workers
         run_path = os.path.join(storage, run_name)
+        t0_ns = t0_ns or time.perf_counter_ns()
         collector = _ResultCollector.remote(n_workers)
         group = None
         monitor_stop = None
@@ -477,6 +484,12 @@ class JaxTrainer:
                 futs.append(w.run.remote(fn_blob, self.train_loop_config,
                                          session_kwargs, collector,
                                          shard_refs[rank]))
+            # fit() (or this attempt's start) -> every worker placed,
+            # the backend set up and the loop sent to each: what a start
+            # or an elastic restart costs before a worker runs user code
+            plane_events.span_done(
+                "train.fit.start", "train", t0_ns,
+                run=run_name, workers=n_workers)
             outs = ray_tpu.get(futs)
             state = ray_tpu.get(collector.state.remote())
             err = self._classify_failure(group, outs, n_workers)

@@ -17,6 +17,7 @@ import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.util import events as plane_events
 from ray_tpu.util import PlacementGroupSchedulingStrategy, placement_group, remove_placement_group
 
 
@@ -26,7 +27,10 @@ class TrainWorker:
 
     def __init__(self, rank: int, world_size: int, env: Dict[str, str]):
         import os as _os
+        import time as _time
 
+        # ``train.worker.setup`` runs from here to the user's loop
+        self._setup_t0_ns = _time.perf_counter_ns()
         self.rank = rank
         self.world_size = world_size
         _os.environ.update(env)
@@ -102,6 +106,14 @@ class TrainWorker:
             dataset_shards=dataset_shards or {}, **session_kwargs)
         if session_kwargs.get("restore_path"):
             sess.restore_path = session_kwargs["restore_path"]
+        # Constructor's first line -> the user's loop entered: the
+        # backend's rendezvous, the loop unpickled (its imports, jax's
+        # among them), the session. ``actor`` joins this worker's
+        # placement, spawn and boot rows.
+        plane_events.span_done(
+            "train.worker.setup", "train", self._setup_t0_ns,
+            rank=self.rank, world_size=self.world_size,
+            **plane_events.process_actor())
         try:
             import inspect
 

@@ -46,6 +46,7 @@ like ``--failpoints`` does for chaos sites.
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import itertools
 import json
@@ -62,9 +63,11 @@ from typing import Dict, List, Optional, Tuple
 # control plane's action journal — cause (slo.*) and action (enforce.*)
 # share one clock with every other plane, which is what lets
 # ``timeline --planes`` prove breach -> attribution -> action ->
-# recovery on a single trace.
+# recovery on a single trace. "train" and "jit" carry the set-up
+# path: ``JaxTrainer.fit`` until the user's loop runs, and every program
+# jax traces, lowers, compiles or loads from its cache.
 PLANES = ("task", "proto", "gcs", "lease", "wait", "bcast", "coll",
-          "serve", "rl", "pipe", "slo", "enforce")
+          "serve", "rl", "pipe", "slo", "enforce", "train", "jit")
 
 _lock = threading.Lock()
 _ring: List[list] = []
@@ -111,6 +114,34 @@ def process_tenant() -> str:
         return ""
     ns = getattr(w, "namespace", "")
     return "" if ns in ("", "default", None) else str(ns)
+
+
+def process_actor() -> Dict[str, object]:
+    """``{"actor": <id hex>, "worker_pid": <pid>}`` inside an actor's
+    constructor or method, else ``{}``: what the set-up rows of one actor
+    carry in every process that writes one (the GCS's placement, the
+    agent's spawn, the worker's boot and load, the constructor's span),
+    so a reader joins them across spill files."""
+    ctx_mod = sys.modules.get("ray_tpu._private.runtime_context")
+    ctx = ctx_mod._exec_ctx.get() if ctx_mod is not None else None
+    if not ctx or not ctx[1]:
+        return {}
+    return {"actor": ctx[1].hex(), "worker_pid": os.getpid()}
+
+
+def process_start_ns() -> int:
+    """This process's start — its fork, for a zygote's child — on
+    ``perf_counter_ns``'s clock, from ``/proc/self/stat`` (the kernel
+    counts it in ticks of 10 ms since boot); now, where that cannot be
+    read."""
+    now = time.perf_counter_ns()
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return now - max(0, time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+                         - ticks * 10**9 // os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
 
 
 def _trace_id() -> str:
@@ -287,16 +318,50 @@ def reset() -> None:
         _dropped.clear()
 
 
+def drain_and_spill(deliver, session_dir: Optional[str],
+                    nid: bytes = b"") -> int:
+    """The one drain-and-send of a process's ring: the rows go to
+    ``deliver`` as a ``plane_events`` frame (a connection's ``send``, or
+    the GCS's own table) and to this process's spill file. Every flusher
+    calls it: a driver's metrics tick and disconnect (``flush_now``), a
+    worker's coalesced ``task_events`` tick, the node agent's reap tick,
+    the GCS's fold of its own ring. On an event loop the file append
+    goes to an executor (``spilled`` waits for the last one); a
+    connection lost under the send costs the frame, never the file."""
+    if not _enabled or pending() == 0:
+        return 0
+    rows, drops = drain()
+    if not rows and not drops:
+        return 0
+    try:
+        deliver({"t": "plane_events", "ev": rows, "drops": drops,
+                 "nid": nid or b"", "pid": os.getpid()})
+    except ConnectionError:
+        pass
+    global _last_spill
+    try:
+        loop = asyncio.get_running_loop()
+    except RuntimeError:
+        spill(rows, session_dir)
+    else:
+        _last_spill = loop.run_in_executor(None, spill, rows, session_dir)
+    return len(rows)
+
+
+async def spilled(timeout: float = 0.25) -> None:
+    """Wait (bounded) for the last spill append an event loop handed to
+    its executor: what a process does before a hard exit."""
+    if _last_spill is not None:
+        await asyncio.wait([_last_spill], timeout=timeout)
+
+
 def flush_now(worker=None) -> int:
     """Push buffered rows to the GCS plane-event table (no-op when not
     connected). Driver processes flush through the metrics flusher's
-    tick (``util/metrics.py``); workers flush through the executor's
-    coalesced ``task_events`` loop (``worker_main.flush_events``) — both
-    drain here-abouts and hand the same rows to ``spill``. Thread-safe:
-    the send marshals onto the worker IO loop."""
+    tick (``util/metrics.py``) and once more when they disconnect;
+    workers flush through the executor's coalesced ``task_events`` loop.
+    Thread-safe: the send marshals onto the worker IO loop."""
     global _session_dir
-    if not _enabled:
-        return 0
     if worker is None:
         from ray_tpu._private import worker as worker_mod
 
@@ -305,18 +370,10 @@ def flush_now(worker=None) -> int:
             or worker.loop is None):
         return 0
     _session_dir = worker.session_dir or _session_dir
-    if pending() == 0:
-        return 0
-    rows, drops = drain()
-    if not rows and not drops:
-        return 0
-    msg = {"t": "plane_events", "ev": rows, "drops": drops,
-           "nid": getattr(worker, "node_id", b"") or b"",
-           "pid": os.getpid()}
-    worker.loop.call_soon_threadsafe(worker._send_gcs, msg)
-    # the flusher thread / the disconnecting caller: never an event loop
-    spill(rows, worker.session_dir)
-    return len(rows)
+    return drain_and_spill(
+        lambda frame: worker.loop.call_soon_threadsafe(
+            worker._send_gcs, frame),
+        worker.session_dir, getattr(worker, "node_id", b""))
 
 
 # ------------------------------------------------------------- spill
@@ -327,6 +384,8 @@ def flush_now(worker=None) -> int:
 
 _spill_lock = threading.Lock()
 _spill_dirs: set = set()
+# the last append handed to an event loop's executor (``spilled``)
+_last_spill = None
 # the last session this process flushed into (``read_spill``'s default
 # once the worker has disconnected)
 _session_dir: Optional[str] = None
@@ -350,9 +409,9 @@ def spill(rows: List[list], session_dir: Optional[str],
           pid: Optional[int] = None) -> int:
     """Append drained rows as JSON lines to this process's spill file;
     returns the bytes written. BLOCKING file I/O, off the emit path:
-    a caller on an event loop hands it to an executor
-    (``worker_main.flush_events``). Silent on ``OSError`` — a full or
-    read-only disk costs the file, never the process."""
+    ``drain_and_spill`` hands it to an executor where it runs on an
+    event loop. Silent on ``OSError`` — a full or read-only disk costs
+    the file, never the process."""
     global _session_dir
     if session_dir:
         _session_dir = session_dir

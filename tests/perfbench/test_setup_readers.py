@@ -1,0 +1,333 @@
+"""The six set-up readers of ``perfbench/setup_spans.py``: each against a
+small recorded spill of four processes with the expected value worked out by
+hand beside it, against no spill (None), against a program that wrote other
+rows but no set-up span (None: the parent of the PR that added them), and in
+a traced rehearsal of one serving cell and of the train cell.
+
+``BENCHMARK.json`` does not list the six yet (PERF.md section 7 says why: a
+traced run of the PARENT would end in ``every_listed_metric``), so the
+rehearsals run from a copy of the benchmark's files that does: the six
+one-line reader files and the six entries the next ``benchmark`` PR appends,
+built here from ``setup_spans.METRICS``."""
+
+import argparse
+import json
+import os
+import shutil
+import time
+
+import pytest
+
+from perfbench import setup_spans as ss
+from perfbench.manifest import BENCH_DIR, ROOT, Manifest
+
+S = 10**9
+WALL = 1790000000.0     # wall clock = perf_counter + WALL
+DRIVER, HEAD, REPLICA, IDLE = 100, 101, 102, 103
+ACTOR = "ab" * 16
+
+
+def _row(name, t0, dur, **fields):
+    """One line of ``plane-<pid>.jsonl``; ``ts`` is taken where the span
+    ends, as the recorder takes it."""
+    fields.update(t0_ns=int(t0 * S), dur_ns=int(dur * S))
+    return [WALL + t0 + dur, name, name.split(".")[0], "", "", dur, fields]
+
+
+def _build(t0, dur, event, program, **fields):
+    return _row("jit.program.build", t0, dur, event=event, program=program,
+                **fields)
+
+
+# The stretch is [10 s, 70 s): the window opens at 70 s on perf_counter.
+ROWS = {
+    DRIVER: [
+        _row("gcs.head.spawn", 10.0, 1.5, sid=2, parent=1),
+        _row("gcs.driver.connect", 11.5, 0.5, sid=3, parent=1),
+        _row("gcs.cluster.start", 10.0, 2.0, sid=1, parent=0,
+             started_head=True),
+        _row("serve.app.run", 27.0, 26.0, sid=4, parent=0, app="perfbench"),
+        # rows that are not spans ride the same file
+        [WALL + 30.0, "proto.send.frame", "proto", "", "", 0.0,
+         {"key": "actor_call", "n": 12, "bytes": 3400, "agg": 1}],
+    ],
+    HEAD: [
+        _row("lease.worker.spawn", 11.0, 0.9, sid=1, parent=0,
+             worker_pid=IDLE, pool="", zygote=True),
+        _row("gcs.node.probe", 11.2, 15.0, sid=2, parent=0, chips=1, rc=0),
+        _row("lease.worker.spawn", 27.5, 0.5, sid=3, parent=0,
+             worker_pid=REPLICA, pool="tpu", zygote=True),
+        _row("lease.actor.place", 27.2, 1.8, sid=4, parent=0, actor=ACTOR,
+             resources={"TPU": 1.0}, node="n", worker_pid=REPLICA),
+    ],
+    REPLICA: [
+        _row("lease.worker.boot", 28.0, 1.2, sid=1, parent=0,
+             worker_pid=REPLICA, pool="tpu"),
+        _row("lease.actor.load", 29.3, 0.7, sid=15, parent=0, actor=ACTOR,
+             worker_pid=REPLICA),
+        _row("jit.jax.import", 30.5, 3.0, sid=3, parent=2),
+        # a trace inside a trace: [36, 37) lies inside [35.5, 38)
+        _build(36.0, 1.0, "jaxpr_trace_duration", "inner", sid=5, parent=4),
+        _build(35.5, 2.5, "jaxpr_trace_duration", "init_weights", sid=6,
+               parent=4),
+        _build(38.0, 1.0, "jaxpr_to_mlir_module_duration",
+               "jit(init_weights)", sid=7, parent=4),
+        _build(39.0, 4.0, "backend_compile_duration", "jit(init_weights)",
+               sid=8, parent=4, cache_hit=True),
+        _row("serve.replica.weights", 34.0, 11.0, sid=4, parent=2),
+        _build(46.0, 2.0, "backend_compile_duration", "jit(zeros)", sid=10,
+               parent=9, cache_hit=False),
+        _row("serve.replica.engine", 45.5, 4.5, sid=9, parent=2, slots=16,
+             pages=2048),
+        _row("serve.replica.init", 30.0, 22.0, sid=2, parent=0, actor=ACTOR,
+             worker_pid=REPLICA, deployment="BenchLLMServer"),
+        # warm-up: the step's program is loaded inside its first step
+        _build(55.0, 6.0, "backend_compile_duration", "jit(_paged_step)",
+               sid=12, parent=11, cache_hit=True),
+        _row("serve.engine.step", 54.0, 8.0, sid=11, parent=0, k=0),
+        _row("serve.engine.step", 66.0, 3.0, sid=13, parent=0, k=1),
+        # built INSIDE the window: not set-up (and a fault of the run)
+        _build(71.0, 0.5, "backend_compile_duration", "jit(late)", sid=14,
+               parent=0, cache_hit=False),
+    ],
+    IDLE: [     # a worker that holds no chip builds a program too
+        _build(40.0, 9.0, "backend_compile_duration", "jit(elsewhere)",
+               sid=1, parent=0, cache_hit=False),
+    ],
+}
+
+EXPECTED = {
+    "setup_cluster_s": 2.0,
+    "setup_chip_probe_s": 15.0,
+    # serve.app.run begins at 27, the replica's constructor at 30
+    "setup_actor_start_s": 3.0,
+    "setup_actor_init_s": 22.0,
+    # the replica's rows that end before 70: [35.5, 43) + [46, 48) + [55, 61)
+    "setup_program_build_s": 7.5 + 2.0 + 6.0,
+    # covered: cluster [10, 12), probe to 26.2, run [27, 53), the idle
+    # worker's build inside it, steps [54, 62) and [66, 69); bare: 26.2-27,
+    # 53-54, 62-66, 69-70
+    "setup_untraced_s": 0.8 + 1.0 + 4.0 + 1.0,
+}
+
+# each instant to the first phase of PHASES that covers it
+EXPECTED_PHASES = {
+    # replica [35.5, 43) + [46, 48) + [55, 61), and the idle worker's
+    # [40, 49) adds [43, 46) and [48, 49)
+    "program build or cache load": 7.5 + 2.0 + 6.0 + 3.0 + 1.0,
+    "weights": 1.5,                         # [34, 35.5)
+    "engine construction": 1.0,             # [45.5, 50) less builds: [49, 50)
+    "engine steps and admissions (warm-up, lead-in, fill)": 2.0 + 3.0 + 0.0,
+    "jax import": 3.0,
+    "actor constructor, rest": 0.5 + 0.5 + 2.0,   # 30-30.5, 33.5-34, 50-52
+    "actor class and arguments load": 0.7,
+    "worker boot": 1.2,
+    "worker spawn": 0.9 + 0.5,              # [11, 11.9) is taken first
+    "actor placement": 0.3,                 # [27.2, 27.5)
+    "cluster start": 2.0 - 0.9,
+    "chip probe": 26.2 - 12.0,
+    "deploy or fit, rest": 0.2 + 0.1 + 1.0,  # 27-27.2, 29.2-29.3, 52-53
+    "other spans": 0.0,
+    "untraced": 6.8,
+}
+
+
+def _write_spill(session_dir, rows_by_pid):
+    folder = os.path.join(session_dir, "logs", "events")
+    os.makedirs(folder)
+    for pid, rows in rows_by_pid.items():
+        with open(os.path.join(folder, f"plane-{pid}.jsonl"), "w") as f:
+            f.writelines(json.dumps(r) + "\n" for r in rows)
+
+
+@pytest.fixture
+def session(tmp_path):
+    _write_spill(str(tmp_path), ROWS)
+    return str(tmp_path)
+
+
+def _serve_ctx(session_dir):
+    return {"session_dir": session_dir, "device": {"pid": REPLICA},
+            "run": {"t_open": 70.0, "t_close": 120.0}}
+
+
+def _train_ctx(session_dir):
+    return {"session_dir": session_dir, "device": {"pid": REPLICA},
+            "train": {"t_open_wall": WALL + 70.0}}
+
+
+@pytest.mark.parametrize("metric", sorted(EXPECTED))
+def test_reader_against_a_recorded_spill(metric, session, capsys):
+    value = getattr(ss, metric)(_serve_ctx(session))
+    assert value == pytest.approx(EXPECTED[metric], abs=1e-6)
+    out = capsys.readouterr().out
+    if metric == "setup_program_build_s":
+        assert ("programs built before the window: 3, 2 of them from the "
+                "persistent cache; longest: jit(_paged_step) 6.00s, "
+                "jit(init_weights) 4.00s, jit(zeros) 2.00s") in out
+    if metric == "setup_actor_init_s":
+        assert ("serve.replica.init 22.00s; jit.jax.import 3.00s; "
+                "serve.replica.weights 11.00s; serve.replica.engine 4.50s"
+                ) in out
+    if metric == "setup_untraced_s":
+        assert "60.0s from the start of gcs.cluster.start" in out
+        assert "untraced 6.8" in out and "chip probe 14.2" in out
+        assert "16.2-17.0, 43.0-44.0, 52.0-56.0, 59.0-60.0" in out
+
+
+def test_phases_tile_the_stretch(session):
+    stretch, table, gaps = ss.phases(_serve_ctx(session))
+    assert stretch == pytest.approx(60.0)
+    assert [p for p, _ in table] == list(EXPECTED_PHASES)
+    for phase, seconds in table:
+        assert seconds == pytest.approx(EXPECTED_PHASES[phase], abs=1e-6), \
+            phase
+    assert sum(s for _, s in table) == pytest.approx(stretch)
+    assert gaps == [pytest.approx(g) for g in (
+        (16.2, 17.0), (43.0, 44.0), (52.0, 56.0), (59.0, 60.0))]
+
+
+@pytest.mark.parametrize("metric", ["setup_program_build_s",
+                                    "setup_untraced_s"])
+def test_a_train_window_opens_on_the_wall_clock(metric, session):
+    """``t_open_wall`` lands on the spans' clock by the rows' own ``ts``."""
+    assert ss.t_open_ns(_train_ctx(session)) == pytest.approx(70.0 * S,
+                                                              abs=1e3)
+    assert getattr(ss, metric)(_train_ctx(session)) == pytest.approx(
+        EXPECTED[metric], abs=1e-5)
+
+
+def test_the_slowest_of_several_chip_holders_counts(tmp_path):
+    rows = {pid: list(r) for pid, r in ROWS.items()}
+    other = "cd" * 16
+    rows[HEAD] = rows[HEAD] + [_row(
+        "lease.actor.place", 27.2, 3.0, sid=9, parent=0, actor=other,
+        resources={"TPU": 1.0}, node="n", worker_pid=IDLE)]
+    rows[IDLE] = rows[IDLE] + [_row(
+        "train.worker.setup", 36.0, 30.0, sid=2, parent=0, actor=other,
+        worker_pid=IDLE, rank=1, world_size=2)]
+    _write_spill(str(tmp_path), rows)
+    ctx = _serve_ctx(str(tmp_path))
+    assert ss.setup_actor_start_s(ctx) == pytest.approx(9.0)
+    assert ss.setup_actor_init_s(ctx) == pytest.approx(30.0)
+    # both processes hold a chip now: the idle worker's 9 s count too
+    assert ss.setup_program_build_s(ctx) == pytest.approx(15.5 + 9.0)
+
+
+@pytest.mark.parametrize("metric", sorted(EXPECTED))
+def test_reader_finds_nothing_without_a_spill(metric, tmp_path, monkeypatch):
+    from ray_tpu.util import events
+
+    monkeypatch.setattr(events, "_session_dir", None)
+    read = getattr(ss, metric)
+    assert read(_serve_ctx(str(tmp_path))) is None
+    assert read(_train_ctx(str(tmp_path))) is None
+    assert read({"run": {"t_open": 70.0}}) is None
+
+
+@pytest.mark.parametrize("metric", sorted(EXPECTED))
+def test_reader_finds_nothing_in_a_program_without_set_up_spans(
+        metric, tmp_path):
+    """The parent of the PR that added the spans spills the engine's rows
+    and no ``gcs.cluster.start``: nothing is read, nothing is raised."""
+    _write_spill(str(tmp_path), {REPLICA: [
+        r for r in ROWS[REPLICA] if r[1].startswith("serve.engine.")]})
+    assert getattr(ss, metric)(_serve_ctx(str(tmp_path))) is None
+
+
+def test_every_metric_has_a_reader_and_a_layer():
+    assert set(ss.METRICS) == set(EXPECTED)
+    assert all(callable(getattr(ss, name)) for name in ss.METRICS)
+    listed = {m["layer"] for m in Manifest(ROOT).doc["per_layer"]}
+    assert "entry points" in listed & set(ss.METRICS.values())
+
+
+def test_the_driver_side_imports_no_jax():
+    """The readers run in the benchmark's driver, which stays off jax."""
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, %r)\n"
+         "from perfbench import setup_spans\n"
+         "from ray_tpu.util import events\n"
+         "setup_spans.setup_untraced_s({'session_dir': %r, "
+         "'run': {'t_open': 1.0}})\n"
+         "print('jax' in sys.modules)" % (ROOT, os.devnull)],
+        capture_output=True, text=True, timeout=120)
+    assert out.stdout.strip() == "False", out
+
+
+# --------------------------------------------- the harness runs the six
+def _tree_with_the_six(root):
+    """The benchmark's files as the next ``benchmark`` PR leaves them."""
+    for part in ("layer_metrics", "traffic", "configs"):
+        shutil.copytree(os.path.join(BENCH_DIR, part),
+                        os.path.join(root, "perfbench", part))
+    shutil.copy(os.path.join(BENCH_DIR, "peaks.json"),
+                os.path.join(root, "perfbench"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    for name, layer in ss.METRICS.items():
+        with open(os.path.join(root, "perfbench", "layer_metrics",
+                               name + ".py"), "w") as f:
+            f.write(f"from perfbench.setup_spans import {name} as read"
+                    f"  # noqa: F401\n")
+        doc["per_layer"].append({
+            "name": name, "unit": "s", "better": "lower",
+            "source": "program_span", "layer": layer, "moves": "setup_s"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+    return Manifest(root)
+
+
+def _rehearse(tmp_path_factory, cell_name):
+    from perfbench.manifest import resolve
+
+    man = _tree_with_the_six(str(tmp_path_factory.mktemp("tree")))
+    cell = man.cell(cell_name)
+    runner = resolve(man.config(cell["config"])["runner"])
+    args = argparse.Namespace(seed=3_000_000_019, seconds=2.0, trace=1,
+                              rehearse=True, workload=cell_name)
+    # the train runner appends its device count to XLA_FLAGS for its
+    # workers: not for the tests that share this process
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
+        line = runner(man, cell, args, time.time())
+    assert line["correct"] is True and line["failed"] == 0
+    return line["metrics"]
+
+
+@pytest.fixture(scope="module")
+def serving_line(tmp_path_factory):
+    return _rehearse(tmp_path_factory, "serve-decode-heavy")
+
+
+@pytest.fixture(scope="module")
+def training_line(tmp_path_factory):
+    return _rehearse(tmp_path_factory, "train-fsdp2-tp2")
+
+
+@pytest.mark.parametrize("metric", sorted(EXPECTED))
+@pytest.mark.parametrize("cell", ["serving", "training"])
+def test_a_traced_rehearsal_carries_the_reader(metric, cell, request):
+    metrics = request.getfixturevalue(cell + "_line")
+    if metric == "setup_chip_probe_s":
+        # the rehearsal declares its chips: there is no probe to read
+        assert metric not in metrics
+        return
+    assert metrics[metric]["unit"] == "s"
+    assert 0 < metrics[metric]["value"] < 600
+    # what the line had before is still there
+    assert {"setup_weights_s", "setup_programs_s"} <= set(metrics)
+
+
+@pytest.mark.parametrize("cell", ["serving", "training"])
+def test_the_parts_stay_inside_the_stretch(cell, request):
+    m = {k: v["value"] for k, v in
+         request.getfixturevalue(cell + "_line").items()}
+    assert m["setup_untraced_s"] < m["setup_cluster_s"] \
+        + m["setup_actor_start_s"] + m["setup_actor_init_s"] \
+        + m["setup_programs_s"] + 30
+    assert m["setup_program_build_s"] > 0.05
