@@ -11,8 +11,11 @@ The vLLM memory model, TPU-shaped: K/V live in a shared page pool
 table; pages are allocated as a sequence grows and freed when it ends,
 so the pool admits far more sequences than a worst-case ``[S, max_len]``
 cache of the same bytes. Reads gather a sequence's pages, writes are one
-batched scatter at each slot's (page, offset). The math is
-``llama._decode_step``'s: tests hold it to ``llama.generate_greedy``.
+batched scatter at each slot's (page, offset), in place: every program
+that writes the pools (each family's step, each family's scatter)
+consumes the pools it is given and the engine holds what it returns. The
+math is ``llama._decode_step``'s: tests hold it to
+``llama.generate_greedy``.
 """
 
 from __future__ import annotations
@@ -41,7 +44,8 @@ from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
                          _write_state, init_state)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "page", "kv_int8"))
+@functools.partial(jax.jit, static_argnames=("cfg", "page", "kv_int8"),
+                   donate_argnums=(1, 2, 3, 4))
 def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
                 toks, lengths, temps, top_ks, top_ps, keys, cfg, cos,
                 sin, page, kv_int8):
@@ -49,8 +53,12 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
 
     pools_*: per-layer [num_pages, page, kvh, d]. tables: [S, P] page
     ids per slot. Writes: one batched scatter per layer at each slot's
-    (page_of(length), length % page). Reads: gather each slot's pages
-    into its [P*page, kvh, d] view, mask by position.
+    (page_of(length), length % page), in place: the pools and the int8
+    scales are donated, as every other family's step donates its own, so
+    the caller keeps the returned ones and no handle on those it gave
+    (un-donated, each of a step's pools was copied whole for its one
+    row). Reads: gather each slot's pages into its [P*page, kvh, d]
+    view, mask by position.
     """
     S = tables.shape[0]
     x = params["embedding"][toks].astype(cfg.dtype)[:, None, :]  # [S,1,D]
@@ -367,6 +375,11 @@ class PagedEngine:
     sequences; a request only ever holds ceil(current_len / page_size)
     pages, so short requests don't pay for long ones. Admission waits
     for pages, not for a worst-case slot.
+
+    The pools (``self.pools_k`` / ``self.pools_v``, and the int8 scales)
+    are rebound by every step and every admission's scatter, which consume
+    the ones they are given: read them through the engine, between two
+    calls, and keep no handle across one.
 
     Which device programs run follows from the type of ``cfg``. A
     ``LlamaConfig`` has a K/V pool for every layer. A ``NemotronHConfig``
@@ -731,7 +744,8 @@ class PagedEngine:
     def _scatter(self, seq_caches, pages: List[int], n_shared: int):
         """The computed K/V into the slot's OWN pages only (shared
         prefix pages already hold their content): one dispatch of
-        ``_scatter_pages``, which consumes the pools it is given."""
+        ``_scatter_pages``, which consumes the pools it is given, as the
+        step does: both leave ``self.pools_*`` the only handles."""
         page_ids = np.full(self.P, self.num_pages, dtype=np.int32)
         page_ids[n_shared:len(pages)] = pages[n_shared:]
         if self.family and self.family.scatter:

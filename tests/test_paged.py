@@ -1,7 +1,9 @@
 """Paged KV engine (models/paged.py): shared page pool, on-demand
 allocation, parity with per-request greedy decode and with the page
-loop the scatter program replaced."""
+loop the scatter program replaced; the step and the scatter write the
+pools they are given in place."""
 
+import re
 import warnings
 
 import jax
@@ -10,7 +12,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import LlamaConfig, generate_greedy, init_params
-from ray_tpu.models.paged import PagedEngine, _quant_kv, _scatter_pages
+from ray_tpu.models.paged import (PagedEngine, _paged_step, _quant_kv,
+                                  _scatter_pages)
 
 
 @pytest.fixture(scope="module")
@@ -225,6 +228,30 @@ def _pool_state(eng):
         (eng.pools_k, eng.pools_v, eng.scales_k, eng.scales_v))]
 
 
+def _held_arrays(eng):
+    """Every device array the engine holds in its own attributes."""
+    return {id(x) for x in jax.tree_util.tree_leaves(
+        [v for v in vars(eng).values()
+         if isinstance(v, (list, tuple, dict, jax.Array))])}
+
+
+def _drive(eng, late):
+    """Step the engine until it is idle, submitting ``late[k]`` = (rid,
+    prompt, max_new) before call ``k``: admissions between decode steps.
+    -> (tokens by request, preemptions seen)."""
+    acc, k, preempted = {}, 0, 0
+    while eng.has_work() or k <= max(late):
+        if k in late:
+            rid, prompt, n = late[k]
+            eng.submit(rid, prompt, max_new_tokens=n)
+        for rid, tok in eng.step():
+            if tok is not None:
+                acc.setdefault(rid, []).append(tok)
+        preempted += eng._preempted
+        k += 1
+    return acc, preempted
+
+
 def _same(a, b):
     return len(a) == len(b) and all(
         x.dtype == y.dtype and np.array_equal(x, y) for x, y in zip(a, b))
@@ -321,16 +348,7 @@ def test_mixed_batch_greedy_identical_to_loop_engine(bf16_model, kw):
                   max_len=32, **kw)
         eng.submit("a", [1, 2, 3, 4, 5], max_new_tokens=11)
         eng.submit("b", [1, 2, 3, 4, 6], max_new_tokens=11)
-        acc, k, preempted = {}, 0, 0
-        while eng.has_work() or k <= max(late):
-            if k in late:
-                rid, prompt, n = late[k]
-                eng.submit(rid, prompt, max_new_tokens=n)
-            for rid, tok in eng.step():
-                if tok is not None:
-                    acc.setdefault(rid, []).append(tok)
-            preempted += eng._preempted
-            k += 1
+        acc, preempted = _drive(eng, late)
         assert preempted >= 1
         assert {r: len(t) for r, t in acc.items()} == {
             "a": 11, "b": 11, "c": 6, "d": 4}
@@ -382,12 +400,95 @@ def test_scatter_donates_the_pools_and_keeps_no_handle(model, kv_dtype):
             "ignore", message="Some donated buffers were not usable")
         eng._admit()
     assert eng.prefix_hits == 1
-    held = {id(x) for x in jax.tree_util.tree_leaves(
-        [v for v in vars(eng).values()
-         if isinstance(v, (list, tuple, dict, jax.Array))])}
-    assert not held & {id(a) for a in given}
+    assert not _held_arrays(eng) & {id(a) for a in given}
     if jax.default_backend() in ("cpu", "tpu"):  # these donate
         assert all(a.is_deleted() for a in given)
     got = eng.run_to_completion()
     assert got["b"] == _ref(params, cfg, prefix + [30], 3) \
         or kv_dtype == "int8"
+
+
+# ------------------------------ the step writes its pools in place (ISSUE 42)
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_step_program_aliases_every_pool_to_its_output(model, kv_dtype):
+    """Lowered at toy widths: each K and V pool (and each int8 scale) is an
+    argument the program may overwrite, tied to the output that replaces
+    it; the weights and the slots' uploads are not."""
+    cfg, params = model
+    eng = PagedEngine(params, cfg, max_slots=2, num_pages=24, page_size=8,
+                      max_len=64, kv_dtype=kv_dtype)
+    S, P = eng.S, eng.P
+    scales = ((eng.scales_k, eng.scales_v) if eng.kv_int8
+              else ([0] * eng.n_kv,) * 2)
+    lowered = _paged_step.lower(
+        params, eng.pools_k, eng.pools_v, *scales,
+        np.zeros((S, P), np.int32), np.zeros(S, np.int32),
+        np.zeros(S, np.int32), np.zeros(S, np.float32),
+        np.zeros(S, np.int32), np.ones(S, np.float32),
+        np.zeros((S, 2), np.uint32), cfg, eng.cos, eng.sin, eng.page,
+        eng.kv_int8)
+    args = lowered.args_info[0]
+
+    def donated(tree):
+        return [a.donated for a in jax.tree_util.tree_leaves(
+            tree, is_leaf=lambda a: hasattr(a, "donated"))]
+
+    assert donated(args[1:5]) == [True] * 4 * cfg.n_layers
+    assert not any(donated((args[0], args[5:])))
+    # the outputs the pools (and scales) alias: 1.. after the tokens
+    text = lowered.as_text()
+    pooled = 4 * cfg.n_layers if eng.kv_int8 else 2 * cfg.n_layers
+    aliased = {int(i) for i in re.findall(
+        r"tf\.aliasing_output = (\d+)", text)}
+    assert set(range(1, 1 + pooled)) <= aliased, sorted(aliased)
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_step_consumes_the_pools_and_the_engine_holds_the_new(model,
+                                                              kv_dtype):
+    cfg, params = model
+    eng = PagedEngine(params, cfg, max_slots=2, num_pages=24, page_size=8,
+                      max_len=64, kv_dtype=kv_dtype)
+    eng.submit("a", [1, 2, 3, 4], max_new_tokens=6)
+    got = [tok for _, tok in eng.step()]    # admits, decodes one token
+    for _ in range(2):              # each step runs on the last one's pools
+        given = jax.tree_util.tree_leaves(
+            (eng.pools_k, eng.pools_v, eng.scales_k, eng.scales_v))
+        assert len(given) == (4 if eng.kv_int8 else 2) * cfg.n_layers
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")  # every donated buffer is used
+            got += [tok for _, tok in eng.step()]
+        assert not _held_arrays(eng) & {id(a) for a in given}
+        if jax.default_backend() in ("cpu", "tpu"):  # these donate
+            assert all(a.is_deleted() for a in given)
+    got += eng.run_to_completion()["a"]
+    assert len(got) == 6
+    assert got == _ref(params, cfg, [1, 2, 3, 4], 6) or kv_dtype == "int8"
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_prefix_hit_between_decode_steps_reads_the_rebound_pools(
+        bf16_model, kv_dtype):
+    """Decode steps, then an admission whose prefix is gathered from the
+    cached pages, then more of both: the gather in ``_prefill`` reads the
+    pools the last step returned (a donated one would raise), and the
+    tokens are the reference engine's, which writes page by page."""
+    cfg, params = bf16_model
+    prefix = list(range(1, 17))             # two full pages of 8
+    late = {3: ("b", prefix + [30, 31], 5), 6: ("c", prefix + [40], 4),
+            9: ("d", prefix[:8] + [50, 51, 52], 4)}
+    reqs = {"a": (prefix + [20], 14),
+            **{rid: (prompt, n) for rid, prompt, n in late.values()}}
+    outs = []
+    for cls in (PagedEngine, _LoopEngine):
+        eng = cls(params, cfg, max_slots=3, num_pages=24, page_size=8,
+                  max_len=64, kv_dtype=kv_dtype, enable_prefix_cache=True)
+        eng.submit("a", reqs["a"][0], max_new_tokens=reqs["a"][1])
+        outs.append(_drive(eng, late)[0])
+        assert eng.prefix_hits == 3 and eng.prefix_misses == 1
+    assert outs[0] == outs[1]
+    for rid, (prompt, n) in reqs.items():
+        assert len(outs[0][rid]) == n
+        assert outs[0][rid] == _ref(params, cfg, prompt, n) \
+            or kv_dtype == "int8", rid

@@ -13,6 +13,7 @@ process may load libtpu, and every xdist worker imports this file.
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -118,34 +119,52 @@ def test_flash_attention_stats(one_chip, L):
     _assert_kernel(compiled)
 
 
-def test_paged_step_llama3_1b_widths(one_chip):
-    """The serving decode step: 8 slots over 2048 pages of 16, depth 2."""
+def _compile_paged_step(one_chip, cfg, S, pages, page, max_len,
+                        kv_int8=False):
+    """The dense family's decode step compiled for the described chip.
+    -> (compiled, the pools' shape)"""
     from ray_tpu.models.paged import _paged_step
     from ray_tpu.ops.layers import rope_frequencies
 
-    cfg = dataclasses.replace(LLAMA3_1B, n_layers=2)
-    S, pages, page, max_len = 8, 2048, 16, 2048
     params = _on(one_chip, jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    pool = _shape(one_chip, (pages, page, cfg.n_kv_heads, cfg.head_dim))
+    dims = (pages, page, cfg.n_kv_heads, cfg.head_dim)
+    pools = [_shape(one_chip, dims, jnp.int8 if kv_int8 else cfg.dtype)
+             ] * cfg.n_layers
+    scales = [_shape(one_chip, dims[:-1], jnp.float32) if kv_int8 else 0
+              ] * cfg.n_layers
     cos, sin = _on(one_chip, jax.eval_shape(
         lambda: rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)))
     i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
     f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
-    compiled = _paged_step.lower(
-        params, [pool] * cfg.n_layers, [pool] * cfg.n_layers,
-        [0] * cfg.n_layers, [0] * cfg.n_layers,
+    return _paged_step.lower(
+        params, pools, pools, scales, scales,
         i32((S, max_len // page)), i32((S,)), i32((S,)), f32((S,)),
         i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
-        cfg=cfg, cos=cos, sin=sin, page=page, kv_int8=False).compile()
+        cfg=cfg, cos=cos, sin=sin, page=page, kv_int8=kv_int8).compile(), dims
+
+
+def test_paged_step_llama3_1b_widths(one_chip):
+    """The serving decode step: 8 slots over 2048 pages of 16, depth 2."""
+    cfg = dataclasses.replace(LLAMA3_1B, n_layers=2)
+    S, pages, page, max_len = 8, 2048, 16, 2048
+    compiled, dims = _compile_paged_step(one_chip, cfg, S, pages, page,
+                                         max_len)
     rep = cfg.n_heads // cfg.n_kv_heads
     _assert_no_repeated_table(compiled, S, max_len, cfg.n_kv_heads, rep,
                               cfg.head_dim)
-    # All the step's temporaries together are smaller than ONE float32 copy
-    # of a layer's table repeated to every head: 90 MB against 134 MB at
-    # these widths (a step that repeats the table holds 341 MB).
+    # All that the step holds beyond its arguments (its temporaries, and
+    # what it returns that aliases no argument) is smaller than TWO float32
+    # copies of a layer's table repeated to every head: 153 MB against 268
+    # at these widths (a step that repeats the table holds 404 MB). The
+    # pools come back aliased to the donated arguments; the temporaries
+    # hold two pool-sized copies in other layouts, which a head of 64, half
+    # a lane, makes the compiler choose here and not at a head of 128.
+    m = compiled.memory_analysis()
     repeated = S * max_len * cfg.n_heads * cfg.head_dim * 4
-    assert compiled.memory_analysis().temp_size_in_bytes < repeated
+    assert m.alias_size_in_bytes >= 2 * cfg.n_layers * math.prod(dims) * 2
+    assert (m.temp_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes) < 2 * repeated
     _assert_vocabulary_sorted_under_a_conditional(compiled, S,
                                                   cfg.vocab_size)
 
@@ -310,6 +329,33 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
     text = compiled.as_text()
     assert f"{cfg.prefill_chunk},{max_len}]" not in text
     assert f"[{max_len},{cfg.n_heads}," not in text     # no expanded cache
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_paged_step_writes_the_pools_in_place(one_chip, kv_int8):
+    """The dense family's step at the benchmark's widths (Mistral-7B's; 16
+    slots, pages of 16 x 8 x 128), depth 1: every pool, and every scale of
+    int8 pages, comes back aliased to the donated argument, and the program
+    copies no pool whole (un-donated it copied each one for the one row a
+    slot writes: 32 copies of 67 MB a step at the benchmark's depth and
+    2048 pages). 4096 pages here, so that a pool's shape is not the shape
+    of the 16 slots' gathered tables, 16 x 128 pages."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    cfg = LlamaConfig(vocab_size=32768, n_layers=1, rope_theta=1e6)
+    S, pages, page, max_len = 16, 4096, 16, 2048
+    compiled, dims = _compile_paged_step(one_chip, cfg, S, pages, page,
+                                         max_len, kv_int8)
+    m = compiled.memory_analysis()
+    held = 2 * cfg.n_layers * pages * page * cfg.n_kv_heads * (
+        cfg.head_dim + 4 if kv_int8 else 2 * cfg.head_dim)
+    # beside the pools the step returns the slots' tokens and keys
+    assert held <= m.alias_size_in_bytes <= m.output_size_in_bytes \
+        < held + 65536
+    pool = "[" + ",".join(map(str, dims)) + "]"
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if (" copy(" in ln or " copy-start(" in ln)
+                and pool in ln.split(" copy", 1)[0]]
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
