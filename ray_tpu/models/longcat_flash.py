@@ -49,7 +49,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ..ops.layers import rms_norm, rope_rows as _rope_rows
+from ..ops.layers import (rms_norm, rope_interleaved as _rope,
+                          rope_rows as _rope_rows)
 from ..ops.quant import mm
 from ..parallel.moe import moe_ffn_zero, softmax_gates
 from .engine import _pick_tokens
@@ -259,17 +260,6 @@ def expert_share(params: Dict[str, Any], offset: int, held: int
 
 
 # ---------------------------------------------------------------- sublayers
-def _rope(x, cos, sin):
-    """Rotary embedding over interleaved pairs ``(2j, 2j + 1)``. x [N, d] or
-    [N, H, d]; cos, sin [N, d / 2]."""
-    xf = x.astype(F32).reshape(*x.shape[:-1], -1, 2)
-    if x.ndim == 3:
-        cos, sin = cos[:, None, :], sin[:, None, :]
-    a, b = xf[..., 0], xf[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
 def _latent_qkv(att, h, cos, sin, cfg: LongcatFlashConfig):
     """h [N, D] at the positions of cos / sin -> (q_nope [N, H, dn], rotated
     q_rope [N, H, dr], the position's cache row [N, C + dr]: the normed,

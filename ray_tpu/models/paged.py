@@ -36,8 +36,10 @@ from .engine import _pick_one, _pick_tokens, _prefill_one
 from .paged_ops import (_quant_kv, latent_pool_shape,  # noqa: F401
                         paged_attention)    # (re-exports)
 from .llama import LlamaConfig, _mlp_block
+from . import cohere2_moe as cohere
 from . import longcat_flash as longcat
 from . import minicpm_sala as sala
+from .cohere2_moe import Cohere2MoeConfig
 from .longcat_flash import LongcatFlashConfig
 from .minicpm_sala import MiniCPMSALAConfig
 from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
@@ -276,7 +278,7 @@ def _longcat_step(eng, scales, uploads):
     return toks, None, None, new_keys, (next_tok, routing)
 
 
-def _longcat_landed(eng, routing):
+def _routing_landed(eng, routing):
     eng.last_routing = routing
 
 
@@ -284,6 +286,59 @@ def _longcat_counts(eng, tail, sp):
     sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]),
            zero_picks=int(tail[2]), latent_positions=int(tail[3]),
            moe_rows=int(tail[4]), landed=int(tail[5]))
+
+
+def _cohere_state(eng):
+    cfg = eng.cfg
+    if eng.max_len % cfg.prefill_chunk or eng.kv_int8:
+        raise ValueError(
+            "this family's prefill fills max_len in whole chunks, and its "
+            "window layers' rings are kept in the model's dtype beside pages "
+            "of the same (kv_dtype='int8' would quantise one kind of layer "
+            "and not the other)")
+    # beside the full layers' pools, each window layer's K/V as a ring a
+    # slot: position p at index p mod window, sized by the slots and the
+    # model's window whatever a slot's context; no table, no allocator
+    ring = (eng.S, cfg.n_kv_heads, cfg.sliding_window, cfg.head_dim)
+    eng.rings_k = [jnp.zeros(ring, cfg.dtype)
+                   for _ in range(cfg.n_window_layers)]
+    eng.rings_v = [jnp.zeros(ring, cfg.dtype)
+                   for _ in range(cfg.n_window_layers)]
+    # the last step's chosen experts [layers, S, k]: left on the device, for
+    # a reference check to read
+    eng.last_routing = None
+
+
+def _cohere_prefill(eng, suffix, pad, n):
+    first, bufs = cohere.prefill(eng.params, suffix, eng.max_len, eng.cfg)
+    full, window = ([b for kind, b in zip(eng.cfg.kinds, bufs) if kind == k]
+                    for k in (cohere.FULL, cohere.WINDOW))
+    return first, full, window or None
+
+
+def _cohere_write_state(eng, rows, slot, n):
+    eng.rings_k, eng.rings_v = cohere._write_rings(
+        eng.rings_k, eng.rings_v, rows, np.int32(n), np.int32(slot))
+
+
+def _cohere_admit_fields(eng, n):
+    return ({"chunks": -(-n // eng.cfg.prefill_chunk)}, {},
+            {"ring_positions": min(n, eng.cfg.sliding_window)
+             * eng.cfg.n_window_layers})
+
+
+def _cohere_step(eng, scales, uploads):
+    (toks, eng.pools_k, eng.pools_v, eng.rings_k, eng.rings_v, new_keys,
+     routing, next_tok) = cohere._cohere_step(
+        eng.params, eng.pools_k, eng.pools_v, eng.rings_k, eng.rings_v,
+        *uploads, eng.cfg, eng.page)
+    return toks, None, None, new_keys, (next_tok, routing)
+
+
+def _cohere_counts(eng, tail, sp):
+    sp.set(moe_hit=int(tail[0]), moe_max=int(tail[1]), moe_rows=int(tail[2]),
+           context_positions=int(tail[3]), window_positions=int(tail[4]),
+           landed=int(tail[5]))
 
 
 @dataclass(frozen=True)
@@ -313,6 +368,12 @@ class _Family:
     #                         not (n_kv_heads, head_dim) of keys beside the
     #                         same of values: the layer then has ONE pool
     #                         and no V pool
+    write_state: object = None  # (engine, the prefill's state, slot, prompt
+    #                         length), where the per-slot state is not
+    #                         ``self.ssm`` / ``self.conv``
+    no_prefix_cache: str = (    # why ``enable_prefix_cache`` is refused
+        "snapshots of recurrent state at page boundaries; a prefill of the "
+        "suffix alone over latent pages")
 
 
 _FAMILIES = {
@@ -326,9 +387,15 @@ _FAMILIES = {
     LongcatFlashConfig: _Family(
         lambda cfg: cfg.n_sublayers, _longcat_state, _longcat_prefill,
         _longcat_step, _longcat_counts, _longcat_scatter,
-        _longcat_admit_fields, (), _longcat_landed,
+        _longcat_admit_fields, (), _routing_landed,
         lambda cfg, pages, page: latent_pool_shape(
             pages, page, cfg.latent_width)),
+    Cohere2MoeConfig: _Family(
+        lambda cfg: cfg.n_full_layers, _cohere_state, _cohere_prefill,
+        _cohere_step, _cohere_counts, admit_fields=_cohere_admit_fields,
+        buckets=(), landed=_routing_landed, write_state=_cohere_write_state,
+        no_prefix_cache="window layers whose K/V is a per-slot ring: a ring "
+                        "is not shareable by page"),
 }
 
 
@@ -395,7 +462,15 @@ class PagedEngine:
     compressed latent and the one rotary key all heads share
     (``self.pools_k``; there is no V pool and no per-slot state); its prompts
     are admitted in chunks through the expanded attention form and its step
-    attends in the absorbed form. Pages, tables, admission order, preemption by
+    attends in the absorbed form. A ``Cohere2MoeConfig`` mixes window and
+    full attention: K/V pools for its full-attention layers only, read in
+    blocks of table columns (no gather as wide as the table), and beside them
+    each window layer's K/V as a per-slot ring of the window's width
+    (``self.rings_k`` / ``self.rings_v``: position ``p`` at index ``p mod
+    window``), written whole at admission from the prefill's last positions
+    and advanced by the step for all slots, donated to both; a slot's window
+    memory is fixed whatever its context; its prompts are admitted in
+    chunks. Pages, tables, admission order, preemption by
     recompute and the spans are the same code (``_FAMILIES`` holds what
     differs).
 
@@ -415,7 +490,8 @@ class PagedEngine:
 
     def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig,
                                           MiniCPMSALAConfig,
-                                          LongcatFlashConfig], *,
+                                          LongcatFlashConfig,
+                                          Cohere2MoeConfig], *,
                  max_slots: int = 8,
                  num_pages: int = 64, page_size: int = 16,
                  max_len: int = 512, enable_prefix_cache: bool = False,
@@ -433,9 +509,8 @@ class PagedEngine:
             if enable_prefix_cache:
                 raise ValueError(
                     "enable_prefix_cache needs what this engine does not "
-                    "keep for this family (snapshots of recurrent state at "
-                    "page boundaries; a prefill of the suffix alone over "
-                    "latent pages): it runs without it")
+                    f"keep for this family ({self.family.no_prefix_cache}): "
+                    "it runs without it")
             self.n_kv = self.family.n_kv(cfg)
             if self.family.pool_shape:
                 shape = self.family.pool_shape(cfg, num_pages, page_size)
@@ -660,9 +735,9 @@ class PagedEngine:
                 self.prefix_hits += 1
             elif self.enable_prefix_cache:
                 self.prefix_misses += 1
-            more = ({}, {})
+            more = ({}, {}, {})     # of the prefill, scatter, state spans
             if self.family and self.family.admit_fields:
-                more = self.family.admit_fields(self, n)
+                more = self.family.admit_fields(self, n) + ({},)
             with plane_events.span("serve.admit.prefill", "serve",
                                    rid=rid8, **more[0]):
                 first_logits, seq_caches, state = self._prefill(
@@ -682,9 +757,12 @@ class PagedEngine:
             if state is not None:
                 with plane_events.span("serve.admit.state", "serve",
                                        rid=rid8, layers=len(state),
-                                       dispatches=1):
-                    self.ssm, self.conv = _write_state(
-                        self.ssm, self.conv, state, np.int32(idx))
+                                       dispatches=1, **more[2]):
+                    if self.family.write_state:
+                        self.family.write_state(self, state, idx, n)
+                    else:
+                        self.ssm, self.conv = _write_state(
+                            self.ssm, self.conv, state, np.int32(idx))
             with plane_events.span("serve.admit.sample", "serve",
                                    rid=rid8):
                 key = jnp.asarray(self.keys[idx], dtype=jnp.uint32)

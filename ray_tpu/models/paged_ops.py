@@ -9,7 +9,13 @@ over the chosen pages only (``attend_chosen``). And the read path of a
 latent (MLA) layer, whose cache is ONE pool of one row a position (the
 compressed latent and the one rotary key all heads share), two positions
 side by side: ``write_latent`` and ``attend_latent``, the absorbed form,
-which never expands a cached row to per-head keys and values."""
+which never expands a cached row to per-head keys and values. And the two
+reads of a model that mixes window and full attention: a window layer's K/V
+as a per-slot RING of the window's width (``write_ring``, ``ring_rows``,
+``attend_ring``: no table, no gather, no allocator), and a full layer's read
+over the page pool in BLOCKS of table columns with an online softmax, each
+slot's own blocks and no others (``attend_pages_blocked``: no array as wide
+as the table)."""
 
 from __future__ import annotations
 
@@ -314,3 +320,137 @@ def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
         o_lat = o2[:, :H, :C] + o2[:, H:, W:W + C]
         o = jnp.einsum("shc,chd->shd", o_lat.astype(dt), w_uv)
         return o.reshape(S, -1)
+
+
+# --------------------------------------- window layers as rings, the full read
+# A window layer's cache of a slot is a ring ``[kvh, W, d]``: position ``p``
+# lies at index ``p mod W`` and is overwritten by position ``p + W``, which
+# is the first that no longer sees it. Index ``r`` of a slot whose newest
+# position is ``q`` therefore holds position ``q - ((q - r) mod W)``; where
+# that is negative nothing has been written there yet. The K/V heads stand
+# BEFORE the positions (``[S, kvh, W, d]``, not the pools' ``[.., W, kvh,
+# d]``): a head's ``[W, d]`` is what the two products contract, and with the
+# positions outside the heads the chip's compiler kept the rings in that
+# layout anyway and copied every ring whole into it and back in each step
+# (AOT, PR 43: two copies of 268 MB a ring a step).
+def write_ring(k, v, ring_k, ring_v, lengths):
+    """Each slot's K/V row [S, kvh, d] of position ``lengths`` into its own
+    ring [S, kvh, W, d] at ``lengths mod W``. An inactive slot (length 0)
+    writes index 0 of its own ring, which its next admission rewrites. The
+    scatter runs over the rings seen as ``[S kvh, W, d]``, one row a (slot,
+    head): over ``[S, kvh, W, d]`` its indices would stand on both sides of
+    the heads, and the compiler then wants the heads inside the positions
+    for it, which is the layout the read does not want (a whole copy each
+    way again)."""
+    with jax.named_scope("ring_write"):
+        S, kvh, W, d = ring_k.shape
+        rows = jnp.arange(S * kvh)
+        at = jnp.repeat(lengths % W, kvh)
+
+        def put(ring, new):
+            flat = ring.reshape(S * kvh, W, d).at[rows, at].set(
+                new.reshape(S * kvh, d).astype(ring.dtype))
+            return flat.reshape(S, kvh, W, d)
+
+        return put(ring_k, k), put(ring_v, v)
+
+
+def ring_rows(rows, n, W):
+    """A sequence's rows [T, kvh, d] as its ring [kvh, W, d] after ``n``
+    positions: of those the last ``W`` (all of them where there are fewer),
+    position ``p`` at index ``p mod W``. An index no position has reached
+    keeps a clamped row: its position reads negative until the step that
+    writes it."""
+    r = jnp.arange(W)
+    src = jnp.maximum(n - 1 - (n - 1 - r) % W, 0)
+    return rows[src].swapaxes(0, 1)
+
+
+def attend_ring(q, ring_k, ring_v, lengths):
+    """Each slot's query [S, H, d] at position ``lengths`` over its ring
+    where it lies: every index whose position is not negative, which are the
+    last ``W`` positions up to the query's own (the window's bound ``q - k <
+    W`` is the ring's width). Grouped as ``attend_pages``: the ``H / kvh``
+    query heads of a K/V head against the ring in its own dtype, float32
+    accumulation. -> o [S, H * d]."""
+    with jax.named_scope("ring_attn"):
+        S, kvh, W, d = ring_k.shape
+        qg = q.reshape(S, kvh, -1, d)
+        s = jnp.einsum("sgrd,sgwd->sgrw", qg, ring_k,
+                       preferred_element_type=jnp.float32) * (d ** -0.5)
+        r = jnp.arange(W)[None, :]
+        pos = lengths[:, None] - (lengths[:, None] - r) % W         # [S, W]
+        s = jnp.where((pos >= 0)[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("sgrw,sgwd->sgrd", p.astype(ring_v.dtype), ring_v)
+        return o.reshape(S, -1)
+
+
+def attend_pages_blocked(q, pool_k, pool_v, tables, lengths, block_pages):
+    """``attend_pages``' result for pools in the model's dtype without its
+    table-wide gather, and without a slot paying for a longer one's context.
+    A slot's context is cut into blocks of ``block_pages`` table columns; the
+    blocks of all slots stand in ONE list (slot by slot: ``lengths // block +
+    1`` of each), and a ``fori_loop`` takes ``S`` of them at a time, as many
+    times as the list is long: it gathers those blocks' pages (``[S,
+    block_pages * page, kvh, d]`` of keys and of values, whatever the table's
+    width), gives each its own softmax statistics, and folds them into their
+    slots' running maximum, sum and weighted values (an online softmax whose
+    blocks arrive in no fixed number a slot). At 32 slots of 32 768 positions
+    the whole gather would be 2.1 GB of keys and as much of values in one
+    layer; this reads what is live, rounded up to a block a slot. The rest of
+    a slot's last block gathers its table's padding (page 0) and is masked.
+    q [S, 1, H, d] -> o [S, 1, H * d]."""
+    with jax.named_scope("attention"):
+        S, P = tables.shape
+        page, kvh, d = pool_k.shape[1:]
+        Bp = min(block_pages, P)
+        if P % Bp:
+            tables = jnp.pad(tables, ((0, 0), (0, -P % Bp)))
+        Bk = Bp * page
+        qg = q.reshape(S, kvh, -1, d)
+        rep = qg.shape[2]
+        need = lengths // Bk + 1            # blocks that hold 0 .. lengths
+        ends = jnp.cumsum(need)
+        firsts = ends - need
+        slots = jnp.arange(S)
+
+        def blocks(it, carry):
+            m, l, acc = carry
+            item = it * S + slots
+            live = item < ends[-1]
+            slot = jnp.minimum(jnp.searchsorted(ends, item, side="right"),
+                               S - 1)
+            blk = jnp.where(live, item - firsts[slot], 0)
+            cols = tables[slot[:, None],
+                          blk[:, None] * Bp + jnp.arange(Bp)[None, :]]
+            k = pool_k[cols].reshape(S, Bk, kvh, d)
+            v = pool_v[cols].reshape(S, Bk, kvh, d)
+            s = jnp.einsum("igrd,ikgd->igrk", qg[slot], k,
+                           preferred_element_type=jnp.float32) * (d ** -0.5)
+            pos = blk[:, None] * Bk + jnp.arange(Bk)[None, :]
+            ok = ((pos <= lengths[slot][:, None])
+                  & live[:, None])[:, None, None, :]
+            s = jnp.where(ok, s, -1e30)
+            m_i = s.max(axis=-1)                            # [items, kvh, rep]
+            p = jnp.where(ok, jnp.exp(s - m_i[..., None]), 0.0)
+            acc_i = jnp.einsum("igrk,ikgd->igrd", p.astype(v.dtype), v,
+                               preferred_element_type=jnp.float32)
+            # each item into its slot (several of one slot may stand here)
+            mine = ((slot[None, :] == slots[:, None])
+                    & live[None, :])[:, :, None, None]      # [S, items, 1, 1]
+            m_new = jnp.maximum(m, jnp.max(
+                jnp.where(mine, m_i[None], -1e30), axis=1))
+            w = jnp.where(mine, jnp.exp(m_i[None] - m_new[:, None]), 0.0)
+            fix = jnp.exp(m - m_new)
+            l = l * fix + jnp.sum(w * p.sum(axis=-1)[None], axis=1)
+            acc = acc * fix[..., None] + jnp.sum(
+                w[..., None] * acc_i[None], axis=1)
+            return m_new, l, acc
+
+        init = (jnp.full((S, kvh, rep), -1e30, jnp.float32),
+                jnp.zeros((S, kvh, rep), jnp.float32),
+                jnp.zeros((S, kvh, rep, d), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, (ends[-1] + S - 1) // S, blocks,
+                                      init)
+        return (acc / l[..., None]).astype(q.dtype).reshape(S, 1, -1)

@@ -22,6 +22,18 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (x * (1.0 + scale.astype(jnp.float32))).astype(orig_dtype)
 
 
+def layer_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """Mean-subtracting norm over the last axis, in float32, no bias:
+    ``(x - mean) * rsqrt(var + eps) * (1 + scale)`` (the weight is stored as
+    an offset from one, as ``rms_norm``'s)."""
+    orig_dtype = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(var + eps)
+    return (x * (1.0 + scale.astype(jnp.float32))).astype(orig_dtype)
+
+
 def rope_frequencies(head_dim: int, max_len: int,
                      theta: float = 500000.0) -> Tuple[jax.Array, jax.Array]:
     """Precompute cos/sin tables: [max_len, head_dim//2]."""
@@ -39,6 +51,18 @@ def rope_rows(positions: jax.Array, head_dim: int,
                                            dtype=jnp.float32) / head_dim))
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array
+                     ) -> jax.Array:
+    """Rotary embedding over interleaved pairs ``(2j, 2j + 1)`` (the GPT-J
+    convention). x [N, d] or [N, H, d]; cos, sin [N, d / 2] (``rope_rows``)."""
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    if x.ndim == 3:
+        cos, sin = cos[:, None, :], sin[:, None, :]
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,

@@ -170,7 +170,8 @@ def softmax_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
 
 def moe_ffn_grouped(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
                     experts_held: Dict[str, jax.Array], expert_offset: int,
-                    token_mask: jax.Array | None = None
+                    token_mask: jax.Array | None = None,
+                    cap: int | None = None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``moe_ffn_share``'s result by a product GROUPED by expert: the pairs
     (token, expert) are sorted with the held ones first, by expert, and each
@@ -195,7 +196,7 @@ def moe_ffn_grouped(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
     fixed order)."""
     T, k = gate_idx.shape
     Eh = experts_held["w_up"].shape[0]
-    cap = min(T * k, max(T, 16 * k))
+    cap = min(T * k, max(T, 16 * k) if cap is None else cap)
     _, _, group, sizes = _held_pairs(gate_idx, Eh, expert_offset, token_mask)
     n_held = jnp.sum(sizes)
 

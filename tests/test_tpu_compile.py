@@ -331,6 +331,59 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
     assert f"[{max_len},{cfg.n_heads}," not in text     # no expanded cache
 
 
+def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
+    """The window / full attention family's programs at the benchmark's
+    widths and one period of its layers (three window, one full), 32 slots of
+    512 pages: the decode step gives every pool and every ring back aliased
+    to the donated argument, copies none whole, and holds no array of
+    gathered keys or values as wide as the table (32 x 32 768 positions
+    would be 2.1 GB of keys and as much of values: the full layer is read in
+    blocks of 16 table columns); the prefill chunk builds no array of chunk x
+    ``max_len`` scores and gives its carried rows back aliased."""
+    from perfbench.aot_commanda import table_wide_shapes
+    from ray_tpu.models import cohere2_moe as cm
+
+    cfg = cm.Cohere2MoeConfig(vocab_size=32768, n_layers=4, experts_held=16)
+    S, pages, page, max_len = 32, 7168, 64, 32768
+    params = _on(one_chip, jax.eval_shape(
+        lambda: cm.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = _shape(one_chip, (pages, page, cfg.n_kv_heads, cfg.head_dim))
+    ring = _shape(one_chip, (S, cfg.n_kv_heads, cfg.sliding_window,
+                             cfg.head_dim))
+    held = [[pool], [pool], [ring] * 3, [ring] * 3]
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = cm._cohere_step.lower(
+        params, *held, i32((S, max_len // page)), i32((S,)), i32((S,)),
+        f32((S,)), i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
+        cfg=cfg, page=page).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(held))
+    text = compiled.as_text()
+    for whole in ("bf16[%d,%d,%d,%d]" % pool.shape,
+                  "bf16[%d,%d,%d,%d]" % ring.shape):
+        assert not [ln for ln in text.splitlines()
+                    if " copy(" in ln and f"= {whole}" in ln]
+    assert table_wide_shapes(text, S, max_len, cfg) == []
+    # the guard sees a table-wide gather where there is one
+    assert table_wide_shapes(f"bf16[{S},{max_len},8,128]", S, max_len, cfg)
+    assert m.temp_size_in_bytes < 1e9
+    carry = _on(one_chip, jax.eval_shape(
+        lambda: cm.prefill_carry(cfg, max_len)))
+    compiled = cm._cohere_prefill_chunk.lower(
+        params, i32((cfg.prefill_chunk,)), i32(()), i32(()), carry,
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
+    assert m.temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    assert f"{cfg.prefill_chunk},{max_len}]" not in text
+    # the held experts' products are grouped by expert at a chunk's rows
+    assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 def test_paged_step_writes_the_pools_in_place(one_chip, kv_int8):
     """The dense family's step at the benchmark's widths (Mistral-7B's; 16
