@@ -46,8 +46,8 @@ from ..ops.layers import layer_norm, rope_interleaved, rope_rows
 from ..ops.quant import mm
 from ..parallel.moe import moe_ffn_grouped, moe_ffn_share, sigmoid_gates
 from .engine import _pick_tokens
-from .paged_ops import (attend_pages_blocked, attend_ring, ring_rows,
-                        write_kv, write_ring)
+from .paged_ops import (attend_pages_blocked, attend_ring, block_pages_of,
+                        ring_rows, write_kv, write_ring)
 
 F32 = jnp.float32
 WINDOW, FULL = "sliding_attention", "full_attention"
@@ -76,7 +76,11 @@ class Cohere2MoeConfig:
     # how the programs cut their work (no effect on the result)
     prefill_chunk: int = 2048         # tokens a dispatch
     key_block: int = 256              # keys a step of a prompt's online softmax
-    page_block: int = 16              # table columns a block of the full read
+    page_block: int = 16              # not read since PR 44: the full read's
+    #                                   block follows ``paged_ops.
+    #                                   block_pages_of`` (these 16 columns at
+    #                                   the published shape); the benchmark's
+    #                                   configuration file names the field
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -122,7 +126,7 @@ COHERE2_MOE_DEBUG = Cohere2MoeConfig(
     vocab_size=96, d_model=64, n_layers=4, n_heads=8, n_kv_heads=2,
     head_dim=16, sliding_window=16, router_width=16, experts_held=16,
     top_k=3, n_shared=2, expert_d_ff=48, prefill_chunk=16, key_block=8,
-    page_block=2, dtype=jnp.float32)
+    dtype=jnp.float32)
 
 
 # ------------------------------------------------------------------ weights
@@ -433,8 +437,10 @@ def _decode_logits(params, pools_k, pools_v, rings_k, rings_v, tables, toks,
                 offs, False)
             new_k.append(pool_k)
             new_v.append(pool_v)
-            return attend_pages_blocked(q[:, None], pool_k, pool_v, tables,
-                                        lengths, cfg.page_block)[:, 0]
+            return attend_pages_blocked(
+                q[:, None], pool_k, pool_v, tables, lengths,
+                block_pages_of(*tables.shape, *pool_k.shape[1:], q.dtype)
+            )[:, 0]
 
         x, idx, counts = _block(layer, x, attend, active, cfg)
         routing.append(idx)

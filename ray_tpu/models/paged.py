@@ -33,8 +33,8 @@ from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..util import events as plane_events
 from .engine import _pick_one, _pick_tokens, _prefill_one
-from .paged_ops import (_quant_kv, latent_pool_shape,  # noqa: F401
-                        paged_attention)    # (re-exports)
+from .paged_ops import (_quant_kv, block_pages_of,  # noqa: F401
+                        latent_pool_shape, paged_attention)  # (re-exports)
 from .llama import LlamaConfig, _mlp_block
 from . import cohere2_moe as cohere
 from . import longcat_flash as longcat
@@ -59,8 +59,8 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
     scales are donated, as every other family's step donates its own, so
     the caller keeps the returned ones and no handle on those it gave
     (un-donated, each of a step's pools was copied whole for its one
-    row). Reads: gather each slot's pages into its [P*page, kvh, d]
-    view, mask by position.
+    row). Reads: each slot's live pages, block by block
+    (``paged_ops.paged_attention``); no array as wide as the table.
     """
     S = tables.shape[0]
     x = params["embedding"][toks].astype(cfg.dtype)[:, None, :]  # [S,1,D]
@@ -167,8 +167,18 @@ def _suffix_prefill(params, prefix_caches, suffix_padded, prefix_len,
 # reads); the prefill, the scatter where it has more or other than K/V to
 # scatter, and the step. Each takes the engine; the state write
 # (``_write_state``) is the same for all that have per-slot state.
+def _read_block(eng):
+    """The positions a block of ``paged_attention``'s read holds in ``eng``'s
+    step, by the rule the step itself takes its blocks from: what the step
+    row's ``kv_positions_read`` counts in."""
+    cfg = eng.cfg
+    return eng.page * block_pages_of(eng.S, eng.P, eng.page, cfg.n_kv_heads,
+                                     cfg.head_dim, cfg.dtype)
+
+
 def _nemotron_state(eng):
     eng.ssm, eng.conv = init_state(eng.cfg, eng.S)
+    eng._read_block = _read_block(eng)  # ``_hybrid_step``: paged_attention
     # the last step's chosen experts [expert layers, S, k]: left on the
     # device, for a reference check to read
     eng.last_routing = None
@@ -557,9 +567,16 @@ class PagedEngine:
         self.pending: List[tuple] = []
         self._admit_events: List[tuple] = []
         self._prefill_buckets = (16, 64, 256)
+        # the positions a block of the step's K/V read holds, set where a
+        # step that reads through ``paged_attention`` is bound (the dense
+        # family's here, the hybrid's in its ``state``); 0: no such read
+        self._read_block = 0
         if self.family:
             self._prefill_buckets = self.family.buckets
             self.family.state(self)
+        else:
+            self._read_block = _read_block(self)
+        self._kv_positions = None   # (read, live) of the step dispatched
         # what this step() did, for its ``serve.engine.step`` row
         self._steps = self._admitted = self._preempted = 0
         self._step_counts = None    # what rode with a recurrent step's tokens
@@ -849,6 +866,9 @@ class PagedEngine:
                    preempted=self._preempted)
             if self._step_counts is not None:
                 self.family.counts(self, self._step_counts, sp)
+            if self._kv_positions:
+                sp.set(kv_positions_read=self._kv_positions[0],
+                       kv_positions_live=self._kv_positions[1])
         return events
 
     def _step(self):
@@ -861,7 +881,7 @@ class PagedEngine:
         are the oldest's, after this one's dispatch; with steps in flight
         it cannot run ahead of, the call only lands the oldest, and the
         call that lands the last goes on as the synchronous one."""
-        self._step_counts = None
+        self._step_counts = self._kv_positions = None
         events: List[tuple] = []
         flights = self._flights
         if flights and not self._runs_ahead():
@@ -890,6 +910,14 @@ class PagedEngine:
                                 else 0 for i in range(self.S)],
                                dtype=np.int32)
             sampling = int(np.count_nonzero(self.temps[active] > 0.0))
+            if self._read_block:
+                # what ``paged_attention`` reads of a pool: the positions
+                # before each slot's own (that row comes from the step's
+                # arguments) in whole blocks, and how many of them are live
+                held = lengths[active]
+                self._kv_positions = (
+                    int(np.sum(-(-held // self._read_block)
+                               * self._read_block)), int(np.sum(held)))
             no_scales = [0] * self.n_kv
             # one batched transfer: seven small uploads one by one were
             # 2.0 ms of every step on the chip (PERF.md section 6, PR 32)

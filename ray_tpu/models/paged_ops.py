@@ -1,21 +1,25 @@
 """Device arithmetic of the page pool that every family's step shares:
 one K/V row of every slot written at its (page, offset), and each slot's
-query attended over its gathered pages (``paged_attention``: every page of
-the slot's table). Beside it the read path of a block-sparse layer, whose
-block is a page: a compressed-key pool written as windows of keys complete
+query attended over its LIVE pages (``paged_attention``: the row it writes,
+then the positions before it in blocks of table columns through
+``attend_pages_blocked``, each slot's own blocks and no others, an online
+softmax over them; no array as wide as the table, and an idle slot reads
+nothing). ``attend_pages`` is the same read as ONE gather of every slot's
+whole table: the dense branch of a block-sparse layer takes it over the
+table's first columns, and the tests hold the blocked read to it. Beside
+it the read path of a block-sparse layer, whose block is a page: a
+compressed-key pool written as windows of keys complete
 (``write_ckeys``: the indexer's cache), the choice of blocks from it
 (``choose_blocks``, shared with the prefill of such a family) and attention
 over the chosen pages only (``attend_chosen``). And the read path of a
 latent (MLA) layer, whose cache is ONE pool of one row a position (the
 compressed latent and the one rotary key all heads share), two positions
 side by side: ``write_latent`` and ``attend_latent``, the absorbed form,
-which never expands a cached row to per-head keys and values. And the two
-reads of a model that mixes window and full attention: a window layer's K/V
-as a per-slot RING of the window's width (``write_ring``, ``ring_rows``,
-``attend_ring``: no table, no gather, no allocator), and a full layer's read
-over the page pool in BLOCKS of table columns with an online softmax, each
-slot's own blocks and no others (``attend_pages_blocked``: no array as wide
-as the table)."""
+which never expands a cached row to per-head keys and values. And a window
+layer's K/V as a per-slot RING of the window's width (``write_ring``,
+``ring_rows``, ``attend_ring``: no table, no gather, no allocator), beside
+which such a model's full layers read their page pool through
+``attend_pages_blocked`` too."""
 
 from __future__ import annotations
 
@@ -37,21 +41,42 @@ def _quant_kv(vec, qmax=127.0):
     return q, scale[..., 0].astype(jnp.float32)
 
 
+def _lane_rows(pool):
+    """A K/V pool [num_pages, page, kvh, d] as a step's scatter and gather
+    index it. Where a head is narrower than the chip's 128 lanes (64 at
+    ``LLAMA3_1B``'s widths) that is with a position's heads side by side,
+    ``[num_pages, page, kvh * d]``: the chip keeps such a pool with the
+    PAGES as its minor axis, since a minor axis of half a lane would be
+    padded to twice the size, and must turn it whole into a layout a page
+    can be taken from, and back, in every step. Over the 4-D shape the
+    turned copies are padded, one for the scatter and one for the gather,
+    and with the read in loops all of them stood to the program's end (AOT,
+    PR 44: 3.19 GB of temporaries beside a 1.07 GB cache at depth 16; the
+    table-wide read's 0.37); a row of ``kvh * d`` fills whole lanes, one
+    turned copy serves both and is turned back when its layer's read ends
+    (0.08 GB). At a head of 128 the pool is indexed as it is: it lies page
+    by page already and no step copies it."""
+    pages, page, kvh, d = pool.shape
+    return pool.reshape(pages, page, kvh * d) if d % 128 else pool
+
+
 def write_kv(k, v, pool_k, pool_v, scale_k, scale_v, page_idx, offs, kv_int8):
     """Each slot's K/V row [S, 1, kvh, d] into its (page_idx, offs)."""
+    def put(pool, rows):
+        flat = _lane_rows(pool)
+        return flat.at[page_idx, offs].set(
+            rows.astype(pool.dtype).reshape(-1, *flat.shape[2:])
+        ).reshape(pool.shape)
+
     with jax.named_scope("kv_write"):
         if kv_int8:
             kq, ks = _quant_kv(k[:, 0])
             vq, vs = _quant_kv(v[:, 0])
-            pool_k = pool_k.at[page_idx, offs].set(kq)
-            pool_v = pool_v.at[page_idx, offs].set(vq)
+            pool_k, pool_v = put(pool_k, kq), put(pool_v, vq)
             scale_k = scale_k.at[page_idx, offs].set(ks)
             scale_v = scale_v.at[page_idx, offs].set(vs)
         else:
-            pool_k = pool_k.at[page_idx, offs].set(
-                k[:, 0].astype(pool_k.dtype))
-            pool_v = pool_v.at[page_idx, offs].set(
-                v[:, 0].astype(pool_v.dtype))
+            pool_k, pool_v = put(pool_k, k[:, 0]), put(pool_v, v[:, 0])
     return pool_k, pool_v, scale_k, scale_v
 
 
@@ -90,19 +115,148 @@ def attend_pages(q, pool_k, pool_v, scale_k, scale_v, tables, lengths,
     return o
 
 
+def block_pages_of(S: int, P: int, page: int, kvh: int, d: int, dtype) -> int:
+    """The table columns a block of the blocked read holds, from the shapes
+    alone (slots, a table's columns, a page's positions, a position's K/V
+    heads and their width as the queries' ``dtype`` holds them): the ONE
+    rule of every step that reads through ``attend_pages_blocked`` and of
+    the engine's count of what such a step reads. An eighth of the table's
+    width, so that a slot at a sixth of its table (what a decode mix holds on
+    average) is one or two items of the read's list and a full one eight;
+    and no more than 64 MiB of gathered keys a pass of ``S`` blocks, past
+    which a pass no longer stays on the chip (32 blocks of 2048 positions x
+    2 KiB took 2.3 times what twice as many of 1024 did). What a pass costs
+    is its gathered pages, ~55 ns each whatever a page holds, and ~10 us of
+    its own (PERF.md section 5, PR 44): narrower blocks round a slot up by
+    less and take more passes, and between an eighth and a sixteenth of a
+    2048-wide table the two cancel."""
+    row_bytes = kvh * d * jnp.dtype(dtype).itemsize
+    return max(1, min(P // 8, (64 << 20) // (S * page * row_bytes)))
+
+
+def attend_pages_blocked(q, pool_k, pool_v, tables, lengths, block_pages,
+                         scale_k=None, scale_v=None, own=None):
+    """``attend_pages``' result without its table-wide gather, and without a
+    slot paying for a longer one's context. A slot's context is cut into
+    blocks of ``block_pages`` table columns; the blocks of all slots stand
+    in ONE list (slot by slot: as many of each as hold its positions up to
+    the query's own), and a ``fori_loop`` takes ``S`` of them at a time, as
+    many times as the list is long: it gathers those blocks' pages (``[S, block_pages * page, kvh,
+    d]`` of keys and of values, whatever the table's width), gives each its
+    own softmax statistics, and folds them into their slots' running
+    maximum, sum and weighted values (an online softmax whose blocks arrive
+    in no fixed number a slot). At 32 slots of 32 768 positions the whole
+    gather would be 2.1 GB of keys and as much of values in one layer; this
+    reads what is live, rounded up to a block a slot. The rest of a slot's
+    last block gathers its table's padding (page 0) and is masked.
+
+    ``scale_k`` / ``scale_v``: the scales of int8 pools, gathered block by
+    block beside their pages and applied in the queries' dtype. ``own``:
+    each slot's K/V row ``(k, v)`` [S, kvh, d] at its query's own position
+    ``lengths``, as the pool holds it; given, the running softmax STARTS
+    from that row and the loop reads only the positions before it
+    (``ceil(lengths / block)`` blocks), so a slot of length 0 (an idle one)
+    gathers nothing and a slot at a block's edge does not open the next.
+    q [S, 1, H, d] -> o [S, 1, H * d]."""
+    with jax.named_scope("attention"):
+        S, P = tables.shape
+        page, kvh, d = pool_k.shape[1:]
+        Bp = min(block_pages, P)
+        if P % Bp:
+            tables = jnp.pad(tables, ((0, 0), (0, -P % Bp)))
+        Bk = Bp * page
+        qg = q.reshape(S, kvh, -1, d)
+        rep = qg.shape[2]
+        scale = d ** -0.5
+        n = lengths + (own is None)         # positions read from the pool
+        need = (n + Bk - 1) // Bk           # blocks that hold 0 .. n - 1
+        ends = jnp.cumsum(need)
+        # Every block the tables could hold, in the list's order, worked out
+        # once, before the loop and for every layer that shares the tables
+        # and lengths: an item's slot, its block of the slot's context, its
+        # table columns, and which slot it folds into. The loop only slices.
+        item = jnp.arange(-(-P // Bp) * S)
+        live = item < ends[-1]
+        slot = jnp.minimum(jnp.searchsorted(ends, item, side="right",
+                                            method="compare_all"), S - 1)
+        blk = jnp.where(live, item - (ends - need)[slot], 0)
+        cols = tables.reshape(S, -1, Bp)[slot, blk]     # a block's columns
+        left = jnp.where(live, n[slot] - blk * Bk, 0)   # positions to admit
+        mine = (slot[:, None] == jnp.arange(S)[None, :]) & live[:, None]
+
+        def pages(pool, scales, cols):
+            rows = _lane_rows(pool)[cols].reshape(S, Bk, kvh, d)
+            if scales is None:
+                return rows
+            return rows.astype(q.dtype) * scales[cols].reshape(
+                S, Bk, kvh, 1).astype(q.dtype)
+
+        def blocks(it, carry):
+            m, l, acc = carry
+            slot_i, cols_i, left_i, mine_i = (
+                jax.lax.dynamic_slice_in_dim(a, it * S, S)
+                for a in (slot, cols, left, mine))
+            k = pages(pool_k, scale_k, cols_i)
+            v = pages(pool_v, scale_v, cols_i)
+            s = jnp.einsum("igrd,ikgd->igrk", qg[slot_i], k,
+                           preferred_element_type=jnp.float32) * scale
+            ok = (jnp.arange(Bk)[None, :]
+                  < left_i[:, None])[:, None, None, :]
+            s = jnp.where(ok, s, -1e30)
+            m_i = s.max(axis=-1)                           # [items, kvh, rep]
+            p = jnp.where(ok, jnp.exp(s - m_i[..., None]), 0.0)
+            acc_i = jnp.einsum("igrk,ikgd->igrd", p.astype(v.dtype), v,
+                               preferred_element_type=jnp.float32)
+            # each item into its slot (several of one slot may stand here)
+            to = mine_i.T[:, :, None, None]                # [S, items, 1, 1]
+            m_new = jnp.maximum(m, jnp.max(
+                jnp.where(to, m_i[None], -1e30), axis=1))
+            w = jnp.where(to, jnp.exp(m_i[None] - m_new[:, None]), 0.0)
+            fix = jnp.exp(m - m_new)
+            l = l * fix + jnp.sum(w * p.sum(axis=-1)[None], axis=1)
+            acc = acc * fix[..., None] + jnp.sum(
+                w[..., None] * acc_i[None], axis=1)
+            return m_new, l, acc
+
+        if own is None:
+            init = (jnp.full((S, kvh, rep), -1e30, jnp.float32),
+                    jnp.zeros((S, kvh, rep), jnp.float32),
+                    jnp.zeros((S, kvh, rep, d), jnp.float32))
+        else:       # the query's own row: one key of weight 1 to start from
+            k_own, v_own = own
+            init = (jnp.einsum("sgrd,sgd->sgr", qg, k_own,
+                               preferred_element_type=jnp.float32) * scale,
+                    jnp.ones((S, kvh, rep), jnp.float32),
+                    jnp.broadcast_to(v_own.astype(jnp.float32)[:, :, None],
+                                     (S, kvh, rep, d)))
+        _, l, acc = jax.lax.fori_loop(0, (ends[-1] + S - 1) // S, blocks,
+                                      init)
+        return (acc / l[..., None]).astype(q.dtype).reshape(S, 1, -1)
+
+
 def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
                     lengths, page_idx, offs, kv_int8, dtype):
     """One layer's cache write and attention for every slot.
 
     q [S, 1, H, d], k and v [S, 1, kvh, d] (rotated already where the family
     rotates); pool_* [num_pages, page, kvh, d]; tables [S, P]. Writes each
-    slot's row at (page_idx, offs), gathers each slot's pages into its
-    [P*page, kvh, d] view and masks by position (keys <= the query's).
-    Returns (o [S, 1, H*d], pool_k, pool_v, scale_k, scale_v)."""
+    slot's row at (page_idx, offs), in place, and reads each slot's LIVE
+    pages through ``attend_pages_blocked``: the query's own row from the
+    arguments, the positions before it block by block, masked by position
+    (keys <= the query's); a slot of length 0 reads no page. No array as
+    wide as the table. Returns (o [S, 1, H*d], pool_k, pool_v, scale_k,
+    scale_v)."""
     pool_k, pool_v, scale_k, scale_v = write_kv(
         k, v, pool_k, pool_v, scale_k, scale_v, page_idx, offs, kv_int8)
-    o = attend_pages(q, pool_k, pool_v, scale_k, scale_v, tables, lengths,
-                     kv_int8, dtype)
+    if kv_int8:     # the row as the pool now holds it: quantised, and back
+        own = tuple(r.astype(dtype) * s[..., None].astype(dtype)
+                    for r, s in map(_quant_kv, (k[:, 0], v[:, 0])))
+    else:
+        own = k[:, 0].astype(pool_k.dtype), v[:, 0].astype(pool_v.dtype)
+    o = attend_pages_blocked(
+        q, pool_k, pool_v, tables, lengths,
+        block_pages_of(*tables.shape, *pool_k.shape[1:], q.dtype), scale_k,
+        scale_v, own)
     return o, pool_k, pool_v, scale_k, scale_v
 
 
@@ -322,7 +476,7 @@ def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
         return o.reshape(S, -1)
 
 
-# --------------------------------------- window layers as rings, the full read
+# ---------------------------------------------------- window layers as rings
 # A window layer's cache of a slot is a ring ``[kvh, W, d]``: position ``p``
 # lies at index ``p mod W`` and is overwritten by position ``p + W``, which
 # is the first that no longer sees it. Index ``r`` of a slot whose newest
@@ -384,73 +538,3 @@ def attend_ring(q, ring_k, ring_v, lengths):
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("sgrw,sgwd->sgrd", p.astype(ring_v.dtype), ring_v)
         return o.reshape(S, -1)
-
-
-def attend_pages_blocked(q, pool_k, pool_v, tables, lengths, block_pages):
-    """``attend_pages``' result for pools in the model's dtype without its
-    table-wide gather, and without a slot paying for a longer one's context.
-    A slot's context is cut into blocks of ``block_pages`` table columns; the
-    blocks of all slots stand in ONE list (slot by slot: ``lengths // block +
-    1`` of each), and a ``fori_loop`` takes ``S`` of them at a time, as many
-    times as the list is long: it gathers those blocks' pages (``[S,
-    block_pages * page, kvh, d]`` of keys and of values, whatever the table's
-    width), gives each its own softmax statistics, and folds them into their
-    slots' running maximum, sum and weighted values (an online softmax whose
-    blocks arrive in no fixed number a slot). At 32 slots of 32 768 positions
-    the whole gather would be 2.1 GB of keys and as much of values in one
-    layer; this reads what is live, rounded up to a block a slot. The rest of
-    a slot's last block gathers its table's padding (page 0) and is masked.
-    q [S, 1, H, d] -> o [S, 1, H * d]."""
-    with jax.named_scope("attention"):
-        S, P = tables.shape
-        page, kvh, d = pool_k.shape[1:]
-        Bp = min(block_pages, P)
-        if P % Bp:
-            tables = jnp.pad(tables, ((0, 0), (0, -P % Bp)))
-        Bk = Bp * page
-        qg = q.reshape(S, kvh, -1, d)
-        rep = qg.shape[2]
-        need = lengths // Bk + 1            # blocks that hold 0 .. lengths
-        ends = jnp.cumsum(need)
-        firsts = ends - need
-        slots = jnp.arange(S)
-
-        def blocks(it, carry):
-            m, l, acc = carry
-            item = it * S + slots
-            live = item < ends[-1]
-            slot = jnp.minimum(jnp.searchsorted(ends, item, side="right"),
-                               S - 1)
-            blk = jnp.where(live, item - firsts[slot], 0)
-            cols = tables[slot[:, None],
-                          blk[:, None] * Bp + jnp.arange(Bp)[None, :]]
-            k = pool_k[cols].reshape(S, Bk, kvh, d)
-            v = pool_v[cols].reshape(S, Bk, kvh, d)
-            s = jnp.einsum("igrd,ikgd->igrk", qg[slot], k,
-                           preferred_element_type=jnp.float32) * (d ** -0.5)
-            pos = blk[:, None] * Bk + jnp.arange(Bk)[None, :]
-            ok = ((pos <= lengths[slot][:, None])
-                  & live[:, None])[:, None, None, :]
-            s = jnp.where(ok, s, -1e30)
-            m_i = s.max(axis=-1)                            # [items, kvh, rep]
-            p = jnp.where(ok, jnp.exp(s - m_i[..., None]), 0.0)
-            acc_i = jnp.einsum("igrk,ikgd->igrd", p.astype(v.dtype), v,
-                               preferred_element_type=jnp.float32)
-            # each item into its slot (several of one slot may stand here)
-            mine = ((slot[None, :] == slots[:, None])
-                    & live[None, :])[:, :, None, None]      # [S, items, 1, 1]
-            m_new = jnp.maximum(m, jnp.max(
-                jnp.where(mine, m_i[None], -1e30), axis=1))
-            w = jnp.where(mine, jnp.exp(m_i[None] - m_new[:, None]), 0.0)
-            fix = jnp.exp(m - m_new)
-            l = l * fix + jnp.sum(w * p.sum(axis=-1)[None], axis=1)
-            acc = acc * fix[..., None] + jnp.sum(
-                w[..., None] * acc_i[None], axis=1)
-            return m_new, l, acc
-
-        init = (jnp.full((S, kvh, rep), -1e30, jnp.float32),
-                jnp.zeros((S, kvh, rep), jnp.float32),
-                jnp.zeros((S, kvh, rep, d), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, (ends[-1] + S - 1) // S, blocks,
-                                      init)
-        return (acc / l[..., None]).astype(q.dtype).reshape(S, 1, -1)

@@ -160,6 +160,42 @@ def test_step_rows_count_the_slots_that_sample(model):
     assert eng.slots == [None, None] and eng.temps[0] > 0   # left stale
 
 
+def test_step_rows_count_the_positions_the_read_gathers(model):
+    """``kv_positions_read`` / ``kv_positions_live`` on the step row: what
+    ``paged_attention`` gathers of the pools (each active slot's positions
+    before its query's own, in whole blocks of table columns) beside what of
+    it is live. One request crosses a block's edge while it decodes, a short
+    one beside it leaves: read >= live on every row, read is whole blocks and
+    at most the active slots' (padded) tables, and it grows by one block on
+    the row whose query is the first to see a block's worth of positions
+    before it, and by no other."""
+    cfg, params = model
+    cfg = type(cfg)(**{**cfg.__dict__, "max_seq_len": 512})
+    eng = PagedEngine(params, cfg, max_slots=2, num_pages=80, page_size=16,
+                      max_len=512)
+    block = eng._read_block
+    assert block < eng.max_len
+    eng.submit("req-long", [1 + i % 95 for i in range(block - 2)],
+               max_new_tokens=6)
+    eng.submit("req-short", [7, 8, 9], max_new_tokens=2)
+    eng.run_to_completion()
+    steps = [r["fields"] for r in _rows() if r["name"] == "serve.engine.step"]
+    decoded = [f for f in steps if f["active"]]
+    assert len(decoded) == 5 and all(
+        "kv_positions_read" not in f for f in steps if not f["active"])
+    width = -(-eng.max_len // block) * block
+    for f in decoded:
+        assert f["kv_positions_live"] <= f["kv_positions_read"] \
+            <= width * f["active"]
+        assert f["kv_positions_read"] % block == 0
+    # the long request's positions before its query: block - 2 .. block + 2;
+    # the short one's 3 beside it on the first row (its second token ends it)
+    assert [f["kv_positions_live"] for f in decoded] == [
+        block - 2 + 3, block - 1, block, block + 1, block + 2]
+    assert [f["kv_positions_read"] for f in decoded] == [
+        2 * block, block, block, 2 * block, 2 * block]
+
+
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
     _drive(_engine(model))
     rows = _rows()

@@ -234,6 +234,39 @@ def test_the_dense_family_still_takes_its_own_programs():
     assert "serve.admit.state" not in names
 
 
+def test_step_program_gathers_no_slots_whole_table(params):
+    """The jaxpr of the hybrid step at a toy shape whose table holds eight of
+    the read's blocks: no array of ``[S, P * page, kvh, d]`` (each slot's
+    whole table gathered), a ``while`` an attention layer and a gathered
+    block of ``[S, block, kvh, d]`` in it; and a request whose context
+    crosses a block's edge while it decodes streams what the model's
+    ``forward`` continues with."""
+    eng = _engine(params, max_slots=2, num_pages=40, page_size=16,
+                  max_len=512)
+    S, P = eng.S, eng.P
+    text = str(jax.make_jaxpr(
+        lambda *a: nh._hybrid_step(*a, CFG, eng.page, False))(
+        params, eng.pools_k, eng.pools_v, [0] * eng.n_kv, [0] * eng.n_kv,
+        eng.ssm, eng.conv, np.zeros((S, P), np.int32), np.zeros(S, np.int32),
+        np.zeros(S, np.int32), np.zeros(S, np.float32),
+        np.zeros(S, np.int32), np.ones(S, np.float32),
+        np.zeros((S, 2), np.uint32))).replace(" ", "")
+    block = eng._read_block
+    assert block < eng.max_len
+    assert f"[{S},{eng.max_len},{CFG.n_kv_heads}" not in text
+    assert f"[{S},{P},{eng.page}," not in text
+    assert f"[{S},{block},{CFG.n_kv_heads},{CFG.head_dim}]" in text
+    assert text.count("while[") == CFG.n_attn_layers
+
+    prompt = _tokens(block - 3, seed=12)
+    eng.submit("crosses", prompt, max_new_tokens=6)
+    seq = list(prompt)
+    for _ in range(6):
+        lg = nh.forward(params, jnp.asarray(seq, jnp.int32), CFG)
+        seq.append(int(jnp.argmax(lg[-1])))
+    assert eng.run_to_completion()["crosses"] == seq[len(prompt):]
+
+
 # ----------------------------------------------------------------- spans
 @pytest.fixture
 def _clean_ring():

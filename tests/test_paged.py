@@ -492,3 +492,57 @@ def test_prefix_hit_between_decode_steps_reads_the_rebound_pools(
         assert len(outs[0][rid]) == n
         assert outs[0][rid] == _ref(params, cfg, prompt, n) \
             or kv_dtype == "int8", rid
+
+
+# ------------------ the step reads each slot's live pages in blocks (ISSUE 44)
+
+def _wide_engine(model, kv_dtype="model", **kw):
+    """A table of eight of the read's blocks: 512 positions a slot."""
+    cfg, params = model
+    return PagedEngine(params, cfg, max_slots=3, num_pages=80, page_size=16,
+                       max_len=512, kv_dtype=kv_dtype, **kw)
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_step_program_gathers_no_slots_whole_table(model, kv_dtype):
+    """The jaxpr of the step at a toy shape whose table holds eight of
+    the read's blocks: no array of ``[S, P * page, kvh, d]`` (each slot's whole
+    table gathered, in the pool's dtype or dequantised) nor the int8 pages'
+    scales at that width, one ``while`` a layer, and a gathered block of
+    ``[S, block, kvh, d]`` in it."""
+    cfg, _ = model
+    eng = _wide_engine(model, kv_dtype)
+    S, P = eng.S, eng.P
+    scales = ((eng.scales_k, eng.scales_v) if eng.kv_int8
+              else ([0] * eng.n_kv,) * 2)
+    text = str(jax.make_jaxpr(
+        lambda *a: _paged_step(*a, cfg, eng.cos, eng.sin, eng.page,
+                               eng.kv_int8))(
+        eng.params, eng.pools_k, eng.pools_v, *scales,
+        np.zeros((S, P), np.int32), np.zeros(S, np.int32),
+        np.zeros(S, np.int32), np.zeros(S, np.float32),
+        np.zeros(S, np.int32), np.ones(S, np.float32),
+        np.zeros((S, 2), np.uint32))).replace(" ", "")
+    block = eng._read_block
+    assert block < eng.max_len
+    wide = f"[{S},{eng.max_len},{cfg.n_kv_heads}"
+    assert wide not in text and f"[{S},{P},{eng.page}," not in text
+    assert f"[{S},{block},{cfg.n_kv_heads},{cfg.head_dim}]" in text
+    assert text.count("while[") == cfg.n_layers
+
+
+def test_paged_matches_greedy_across_the_reads_blocks(model):
+    """Slots whose contexts end in the read's first and in its second block,
+    one of them crossing the edge while it decodes, beside a slot that stays
+    idle: each streams what ``generate_greedy`` does."""
+    cfg, params = model
+    cfg = type(cfg)(**{**cfg.__dict__, "max_seq_len": 512})
+    eng = _wide_engine((cfg, params))
+    rng = np.random.default_rng(0)
+    reqs = {"short": (rng.integers(1, 96, 7).tolist(), 6),
+            "crosses": (rng.integers(1, 96, 250).tolist(), 12)}
+    for rid, (p, n) in reqs.items():
+        eng.submit(rid, p, max_new_tokens=n)
+    got = eng.run_to_completion()
+    for rid, (p, n) in reqs.items():
+        assert got[rid] == _ref(params, cfg, p, n), rid

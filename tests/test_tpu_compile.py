@@ -60,6 +60,16 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _table_wide(text, S, max_len, page, cfg):
+    """The spellings of every slot's whole table gathered from a K/V pool
+    (``[S, max_len, kvh, d]`` or the gather's own ``[S * P, page, kvh, d]``)
+    that ``text`` holds."""
+    tail = f"{cfg.n_kv_heads},{cfg.head_dim}]"
+    return [w for w in (f"[{S},{max_len},{tail}",
+                        f"[{S * max_len // page},{page},{tail}")
+            if w in text]
+
+
 def _assert_no_repeated_table(compiled, S, cap, kvh, rep, d):
     """The decode attention contracts grouped heads against the gathered
     table at ``kvh`` heads in the pool's dtype: no float32 array of the
@@ -155,11 +165,16 @@ def test_paged_step_llama3_1b_widths(one_chip):
                               cfg.head_dim)
     # All that the step holds beyond its arguments (its temporaries, and
     # what it returns that aliases no argument) is smaller than TWO float32
-    # copies of a layer's table repeated to every head: 153 MB against 268
+    # copies of a layer's table repeated to every head: 45 MB against 268
     # at these widths (a step that repeats the table holds 404 MB). The
-    # pools come back aliased to the donated arguments; the temporaries
-    # hold two pool-sized copies in other layouts, which a head of 64, half
-    # a lane, makes the compiler choose here and not at a head of 128.
+    # pools come back aliased to the donated arguments. A head of 64, half
+    # a lane, makes the chip keep a pool with its pages as the minor axis,
+    # here and not at a head of 128, and turn it whole for a step's scatter
+    # and gather: the step indexes it a position a row
+    # (``paged_ops._lane_rows``), so one turned copy a pool serves both and
+    # goes when its layer's read ends (the table-wide read held 153 MB;
+    # the blocked read over the 4-D pool 408, every copy to the program's
+    # end, and 3.19 GB at depth 16 where this holds 0.08).
     m = compiled.memory_analysis()
     repeated = S * max_len * cfg.n_heads * cfg.head_dim * 4
     assert m.alias_size_in_bytes >= 2 * cfg.n_layers * math.prod(dims) * 2
@@ -200,6 +215,8 @@ def test_hybrid_step_and_prefill_nemotron_widths(one_chip):
     assert m.alias_size_in_bytes >= donated
     _assert_no_repeated_table(compiled, S, max_len, cfg.n_kv_heads,
                               cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
+    # nor every slot's whole table gathered (1.7 ms of the step until PR 44)
+    assert not _table_wide(compiled.as_text(), S, max_len, page, cfg)
     _assert_vocabulary_sorted_under_a_conditional(compiled, S,
                                                   cfg.vocab_size)
     Eh, D, F = cfg.experts_held, cfg.d_model, cfg.expert_d_ff
@@ -406,9 +423,15 @@ def test_paged_step_writes_the_pools_in_place(one_chip, kv_int8):
     assert held <= m.alias_size_in_bytes <= m.output_size_in_bytes \
         < held + 65536
     pool = "[" + ",".join(map(str, dims)) + "]"
-    assert not [ln for ln in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    assert not [ln for ln in text.splitlines()
                 if (" copy(" in ln or " copy-start(" in ln)
                 and pool in ln.split(" copy", 1)[0]]
+    # and it gathers no slot's whole table, in either spelling of its shape
+    # (3.6 ms of a 15.4 ms step until PR 44): the read goes block by block
+    assert not _table_wide(text, S, max_len, page, cfg)
+    assert _table_wide(f"bf16[{S * max_len // page},{page},8,128]", S,
+                       max_len, page, cfg)      # the guard sees one
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
