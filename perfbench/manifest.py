@@ -13,6 +13,8 @@ import json
 import os
 import re
 
+from perfbench import program_spans
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "perfbench")
 
@@ -86,10 +88,18 @@ def resolve(dotted: str):
 
 def layer_values(man: Manifest, cell: str, ctx: dict) -> dict:
     """Every per-layer metric of the cell whose reader found something.
-    A reader that finds nothing to read returns None and is left out."""
+    A reader that finds nothing to read returns None and is left out. Where
+    it asked ``program_spans`` / ``setup_spans`` for a span name that NO row
+    of the run carries, ``ctx["program_lacks"]`` says so (metric -> those
+    names): the program cannot write it, which is another thing than a
+    stretch that held none (``every_listed_metric`` tells them apart)."""
     out = {}
+    ctx["program_lacks"] = {}
     for m in man.metrics_for(cell, "per_layer"):
-        value = man.reader(m["name"])(ctx)
+        value, in_vain = program_spans.asked_in_vain(
+            ctx, man.reader(m["name"]))
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        elif in_vain:
+            ctx["program_lacks"][m["name"]] = in_vain
     return out

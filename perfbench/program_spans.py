@@ -10,7 +10,11 @@ device trace's clock.
 
 A program without these spans (the parent of the PR that added them) has no
 ``read_spill`` and writes no such rows: every function here then returns
-nothing, and the readers return None.
+nothing, and the readers return None. None keeps its meaning, "nothing to
+read"; that the run holds NO row of a name at all, which only a program that
+does not write the span explains, is noted beside it (``rows`` notes,
+``asked_in_vain`` collects), so that the harness can leave such a metric out
+of a parent-side line instead of ending the run.
 """
 
 from __future__ import annotations
@@ -43,10 +47,35 @@ def spans(ctx: dict) -> dict:
     return ctx["_program_spans"]
 
 
+_IN_VAIN = "_spans_asked_in_vain"
+
+
+def note_in_vain(ctx: dict, *names):
+    """A reader asked for these span names and no row of the run has one."""
+    ctx.setdefault(_IN_VAIN, set()).update(names)
+
+
+def asked_in_vain(ctx: dict, read):
+    """``(read(ctx), names)``: the reader's value and, sorted, the span names
+    it asked for that no row of the run carries."""
+    ctx[_IN_VAIN] = set()
+    value = read(ctx)
+    return value, sorted(ctx.pop(_IN_VAIN))
+
+
+def rows(ctx: dict, name: str) -> list:
+    """The ``name`` span rows of the run, whenever they were recorded. A name
+    that no row carries is noted: the program does not write it."""
+    by_name = spans(ctx)
+    if name not in by_name:
+        note_in_vain(ctx, name)
+    return by_name.get(name, [])
+
+
 def in_window(ctx: dict, name: str) -> list:
     """The ``name`` spans that began inside the measured window."""
     a, b = ctx["run"]["t_open"] * 1e9, ctx["run"]["t_close"] * 1e9
-    return [f for f in spans(ctx).get(name, []) if a <= f["t0_ns"] < b]
+    return [f for f in rows(ctx, name) if a <= f["t0_ns"] < b]
 
 
 def in_trace(ctx: dict, name: str) -> list:
@@ -59,7 +88,7 @@ def in_trace(ctx: dict, name: str) -> list:
     off = host["offset_ns"]
     a, b = host["window_ns"]
     out = [(f["t0_ns"] + off, f["t0_ns"] + f["dur_ns"] + off, f)
-           for f in spans(ctx).get(name, [])]
+           for f in rows(ctx, name)]
     return [s for s in out if s[0] < b and s[1] > a]
 
 
@@ -75,8 +104,8 @@ def ms_per_prompt_token(ctx: dict, phase: str):
     are those whose ``parent`` is an admission of the window."""
     admitted = {f["sid"]: f["prompt_len"] for f in in_window(ctx, ADMIT)}
     tokens = sum(admitted.values())
-    if not tokens:
+    of_phase = rows(ctx, phase)
+    if not tokens or not of_phase:    # no row of the phase at all is not 0 ms
         return None
-    spent = sum(f["dur_ns"] for f in spans(ctx).get(phase, [])
-                if f["parent"] in admitted)
+    spent = sum(f["dur_ns"] for f in of_phase if f["parent"] in admitted)
     return spent / 1e6 / tokens

@@ -163,7 +163,8 @@ class BenchLLMServer(LLMServer):
             if body.get("sample_to"):
                 xplane.write_sample(trace, body["sample_to"])
             red = xplane.reduce(trace)
-            red.pop("all_busy_intervals", None)
+            if red["n_devices"] < 2:   # the same list as ``busy_intervals``
+                red.pop("all_busy_intervals", None)
             out["trace"] = red
             shutil.rmtree(b["trace_dir"], ignore_errors=True)
         return out

@@ -19,14 +19,47 @@ def say_compared(lines):
         print(f"perfbench: compared: {line}", file=sys.stderr, flush=True)
 
 
-def every_listed_metric(man, cell: str, metrics: dict):
+def say_device_window(window: dict, stretch: str, clocks: str):
+    """A traced run's device line in words, the unclipped figure beside it:
+    ``busy_trace_s`` over ``window_s`` is an overrun seen and not only
+    survived."""
+    say(f"device line: busy {window['busy_s']:.6f}s inside {stretch} of "
+        f"{window['window_s']:.6f}s; the whole trace file holds "
+        f"{window['busy_trace_s']:.6f}s of device work ({clocks})")
+
+
+#: a share of a roofline or of a peak is never left out of a line: a later
+#: PR that takes a kernel off the path may not go unbounded for it
+NEVER_LEFT_OUT = ("roofline", "mfu")
+
+
+def every_listed_metric(man, cell: str, metrics: dict,
+                        program_lacks: dict | None = None):
     """A traced line of the chip carries every per-layer metric the manifest
-    lists for its cell, or the run ends here with another code than 0 and no
-    result line: the driver refuses a line that lacks one, so a reader that
-    came up empty (a traced stretch that held no admission, say) is said
-    aloud, by name, instead."""
+    lists for its cell, with one exception, or the run ends here with another
+    code than 0 and no result line: a reader that came up empty (a traced
+    stretch that held no admission, say) is said aloud, by name.
+
+    The exception is a metric in ``program_lacks`` (``manifest.layer_values``
+    fills it: metric -> the span names its reader asked for that NO row of
+    the run carries). The program does not write that span at all, as the
+    PARENT of the PR that adds the span and its reader does not, and the
+    driver runs the parent's traced runs with the PR's benchmark files. Such
+    a metric is named on standard error and left out of the line, and the
+    run goes on: a parent-side line may lack a metric whose span the program
+    cannot write. (On the change side the driver still refuses a line that
+    lacks a listed metric.) A span the program does write, with no row in the
+    stretch that was read, is an empty reading and ends the run as before; so
+    does any ``roofline`` or ``mfu`` share, whatever it lacks."""
     lacking = [m["name"] for m in man.metrics_for(cell, "per_layer")
                if m["name"] not in metrics]
+    left_out = [name for name in lacking if name in (program_lacks or {})
+                and not any(part in name for part in NEVER_LEFT_OUT)]
+    for name in left_out:
+        print(f"perfbench: {cell}: {name} is left out of the line: no row of "
+              f"this run carries {program_lacks[name]}, so the program does "
+              f"not write what its reader reads", file=sys.stderr, flush=True)
+    lacking = [name for name in lacking if name not in left_out]
     if lacking:
         raise SystemExit(
             f"perfbench: {cell}: the traced run has nothing to read for "
@@ -161,12 +194,18 @@ def check_device(device: dict | None, chips: int, rehearse: bool):
                      f"needs {chips} TPU chip(s)")
 
 
-def device_line(device: dict, trace_red: dict | None, window_s=None) -> dict:
+def device_line(device: dict, window: dict | None = None) -> dict:
+    """The result line's ``device``. ``window`` is a traced run's
+    ``xplane.device_window``: ``busy_s``, the seconds in which an operation
+    ran on the device INSIDE the traced window (averaged over the chips),
+    ``window_s``, that window's length on the same clock, so that
+    0 <= ``busy_s`` <= ``window_s`` by construction, and ``busy_trace_s``, the
+    unclipped union of the whole trace file, which the driver ignores: over
+    ``window_s`` it shows an engine that ran ahead of the device through the
+    profiler's start or stop."""
     peaks = [p for p in device.get("peak_bytes_in_use") or [] if p]
     out = {"platform": device["platform"], "kind": device["device_kind"],
            "count": device["device_count"],
            "memory_peak_bytes": max(peaks) if peaks else 0}
-    if trace_red and trace_red.get("busy_s"):
-        out["busy_s"] = sum(trace_red["busy_s"]) / len(trace_red["busy_s"])
-        out["window_s"] = window_s
+    out.update(window or {})
     return out

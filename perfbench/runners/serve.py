@@ -13,14 +13,14 @@ import os
 import sys
 import time
 
-from perfbench import stats, traffic as tg
+from perfbench import serve_spans, stats, traffic as tg, xplane
 from perfbench.manifest import Manifest, layer_values
 from perfbench.program import Factory, section, shape_of
 from perfbench.reference import REF_NEW, REF_PROMPT
 from perfbench.runners.common import (check_device, device_line,
                                       every_listed_metric, say, say_compared,
-                                      start_cluster, stop_cluster,
-                                      trace_sample_path)
+                                      say_device_window, start_cluster,
+                                      stop_cluster, trace_sample_path)
 
 now = time.perf_counter
 TRACE_SECONDS = 6.0
@@ -378,21 +378,23 @@ def run(man: Manifest, cell: dict, args, t_start: float) -> dict:
            "peaks": None if rehearse else man.peaks(
                info["device"]["device_kind"]),
            "rehearse": rehearse}
-    window_s = None
-    if collected.get("trace_window_ns") and collected["trace_window_ns"][1]:
-        a, b = collected["trace_window_ns"]
-        window_s = (b - a) / 1e9
+    # the traced window on the TRACE's clock: the device line and every
+    # trace reader are cut to it (None where nothing was traced)
+    host = ctx["host"] = serve_spans.align(collected, red)
+    window = xplane.device_window(red, host["window_ns"]) if host else None
+    if window:
+        say_device_window(window, "the traced window", "aligned by " + (
+            "the step annotations" if host["from_annotations"]
+            else "the wall clock"))
     line = {"correct": bool(correct), "attempted": s["attempted"],
             "failed": s["failed"],
-            "device": device_line(info["device"], red, window_s)}
+            "device": device_line(info["device"], window)}
     if trace:
-        from perfbench import serve_spans
-
-        ctx["host"] = serve_spans.align(collected, red)
         line["metrics"] = layer_values(man, cell["name"], ctx)
         if not rehearse:
-            every_listed_metric(man, cell["name"], line["metrics"])
-        if red and red.get("busy_s"):
+            every_listed_metric(man, cell["name"], line["metrics"],
+                                ctx["program_lacks"])
+        if window:
             line["breakdown"] = serve_spans.breakdown(ctx)
     else:
         line["metrics"] = {

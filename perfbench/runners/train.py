@@ -12,8 +12,8 @@ from perfbench.manifest import Manifest, layer_values
 from perfbench.program import shape_of
 from perfbench.runners.common import (NoChip, check_device, device_line,
                                       every_listed_metric, say, say_compared,
-                                      start_cluster, stop_cluster,
-                                      trace_sample_path)
+                                      say_device_window, start_cluster,
+                                      stop_cluster, trace_sample_path)
 
 TRACE_STEPS = 3
 
@@ -142,8 +142,14 @@ def run(man: Manifest, cell: dict, args, t_start: float) -> dict:
            m["steps"] * m["tokens_per_step"] / m["window_s"],
            "setup_s": m["t_open_wall"] - t_start}
     traced = m.get("trace")
+    if traced and traced.get("device_window"):
+        say_device_window(
+            traced["device_window"], "the traced steps' span",
+            f"the host read {m['trace_window_s']:.6f}s from start_trace's "
+            f"return to stop_trace's call")
     line = {"correct": bool(correct), "attempted": m["steps"], "failed": 0,
-            "device": device_line(device, traced, m.get("trace_window_s"))}
+            "device": device_line(device,
+                                  (traced or {}).get("device_window"))}
     if args.trace:
         ctx = {"cell": cell, "config": config, "mix": mix,
                "shape": shape_of(config, rehearse), "train": m,
@@ -153,7 +159,8 @@ def run(man: Manifest, cell: dict, args, t_start: float) -> dict:
                "peaks": None if rehearse else man.peaks(device["device_kind"])}
         line["metrics"] = layer_values(man, cell["name"], ctx)
         if not rehearse:
-            every_listed_metric(man, cell["name"], line["metrics"])
+            every_listed_metric(man, cell["name"], line["metrics"],
+                                ctx["program_lacks"])
         if traced and traced.get("n_devices"):
             line["breakdown"] = {
                 "device_ops": traced["top_ops"],

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import os
 
-from perfbench import stats
+from perfbench import program_spans, stats
 from perfbench.runners.common import say
 
 CLUSTER, PROBE = "gcs.cluster.start", "gcs.node.probe"
@@ -84,7 +84,13 @@ def spans(ctx: dict) -> list:
 
 
 def named(ctx: dict, *names) -> list:
-    return [s for s in spans(ctx) if s["name"] in names]
+    """The rows of any of ``names``; where the run holds none at all, that
+    is noted for the harness (``program_spans.asked_in_vain``): the program
+    does not write them, and a parent-side line may lack their metric."""
+    found = [s for s in spans(ctx) if s["name"] in names]
+    if not found:
+        program_spans.note_in_vain(ctx, *names)
+    return found
 
 
 def seconds(span: dict) -> float:

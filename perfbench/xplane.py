@@ -111,7 +111,12 @@ def _is_tensorcore(plane_name: str) -> bool:
 def reduce(trace: dict) -> dict:
     """All times in seconds, clock in nanoseconds as the trace has it.
 
-    ``busy_s``            per device: length of the union of its operations
+    ``busy_s``            per device: length of the union of EVERY operation
+                          in the file, clipped to nothing: the profiler
+                          records from inside ``start_trace()`` until
+                          somewhere inside ``stop_trace()``, so this is not
+                          the busy time of any window the host read
+                          (``device_window`` gives that)
     ``op_self_s``         name -> self seconds, summed over devices
     ``modules``           name -> per-execution seconds, every device's
     ``modules_by_device`` the same, one dict per device
@@ -167,6 +172,39 @@ def reduce(trace: dict) -> dict:
             span_ns=(min(d["span_ns"][0] for d in devices),
                      max(d["span_ns"][1] for d in devices)))
     return out
+
+
+def busy_inside_s(busy_intervals, window_ns) -> float:
+    """Seconds of one device's ``busy_intervals`` (sorted and disjoint, as
+    ``reduce`` gives them) that lie inside ``window_ns`` = (a_ns, b_ns) on
+    the trace's clock: never under 0 and never over the window's own length,
+    whatever ran before the window opened or after it closed."""
+    window = [tuple(window_ns)]
+    return sum(b - a for a, b in stats.intersect(busy_intervals, window)) / 1e9
+
+
+def device_window(reduced: dict | None, window_ns) -> dict | None:
+    """What a traced run's result line says of the device, read on ONE clock
+    over ONE stretch: ``busy_s``, the seconds in which an operation ran on
+    the device INSIDE ``window_ns`` (the trace's clock), averaged over the
+    devices; ``window_s``, that window's length; and ``busy_trace_s``, the
+    unclipped union of the whole file. An engine with steps in flight keeps
+    the device busy through ``start_trace()`` and ``stop_trace()``, so
+    ``busy_trace_s`` may exceed ``window_s``; ``busy_s`` cannot. A window in
+    which nothing ran reads 0: that is what is true of it. None where the
+    trace holds no device line."""
+    if not reduced or not reduced.get("busy_s"):
+        return None
+    per_device = reduced.get("all_busy_intervals")
+    if per_device is None:      # the replica ships one chip's list once
+        if reduced["n_devices"] != 1:
+            raise ValueError("the reduced trace of several devices came "
+                             "without all_busy_intervals")
+        per_device = [reduced["busy_intervals"]]
+    inside = [busy_inside_s(iv, window_ns) for iv in per_device]
+    return {"busy_s": sum(inside) / len(inside),
+            "window_s": (window_ns[1] - window_ns[0]) / 1e9,
+            "busy_trace_s": sum(reduced["busy_s"]) / len(reduced["busy_s"])}
 
 
 def top_ops(reduced: dict, k: int = 10):

@@ -1,5 +1,6 @@
 """The serving runner's end-to-end arithmetic, the train runner's window
-condition, and the refusal of a traced line that lacks a listed metric."""
+condition, and the refusal of a traced line that lacks a listed metric, but
+for one whose span the program does not write at all."""
 
 import pytest
 
@@ -127,3 +128,33 @@ def test_a_traced_chat_line_without_an_admission_is_no_result(lacking):
         every_listed_metric(MAN, "serve-chat-steady",
                             _line("serve-chat-steady", without=(lacking,)))
     assert lacking in str(e.value) and e.value.code not in (0, None)
+
+
+def test_a_metric_whose_span_the_program_lacks_is_left_out_by_name(capsys):
+    """What ``layer_values`` reports of the parent under a PR's new reader:
+    the line goes out without that metric, which standard error names."""
+    lacking, cell = "step_prepare_ms", "serve-decode-heavy"
+    every_listed_metric(MAN, cell, _line(cell, without=(lacking,)),
+                        {lacking: ["serve.step.prepare"]})
+    err = capsys.readouterr().err
+    assert f"{cell}: {lacking} is left out of the line" in err
+    assert "serve.step.prepare" in err
+    # the say-so covers that metric and no other
+    with pytest.raises(SystemExit) as e:
+        every_listed_metric(
+            MAN, cell, _line(cell, without=(lacking, "step_fetch_ms")),
+            {lacking: ["serve.step.prepare"]})
+    assert "step_fetch_ms" in str(e.value) and lacking not in str(e.value)
+
+
+@pytest.mark.parametrize("cell,share", [
+    ("serve-decode-heavy", "decode_hbm_roofline_pct"),
+    ("serve-commanda-mixed-ctx-decode", "commanda_decode_hbm_roofline_pct"),
+    ("train-fsdp2-tp2", "train_mfu_pct")])
+def test_a_share_of_a_roofline_or_of_the_peak_is_never_left_out(cell, share,
+                                                                capsys):
+    with pytest.raises(SystemExit) as e:
+        every_listed_metric(MAN, cell, _line(cell, without=(share,)),
+                            {share: ["a.span.the_program_lacks"]})
+    assert share in str(e.value) and e.value.code not in (0, None)
+    assert "left out" not in capsys.readouterr().err

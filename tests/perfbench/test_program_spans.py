@@ -1,17 +1,24 @@
 """The readers of the program's own spans (``perfbench/program_spans.py`` and
 the nine per-layer metrics that use it): each against a synthetic ``ctx`` and
 a small spill file in the recorder's row format, the expected value worked
-out by hand beside it; None where the program wrote no such rows; and one
-rehearsal of ``serve-decode-heavy`` whose traced line holds the new names."""
+out by hand beside it; None where the program wrote no such rows; a span name
+that NO row of the run carries (the program does not write it: its metric is
+left out of the line, by name) told apart from rows that exist and a traced
+stretch that held none (an empty reading: no result), and from a share of a
+roofline, which is never left out; and one rehearsal of
+``serve-decode-heavy`` whose traced line holds the new names."""
 
 import argparse
 import json
+import os
+import shutil
 import time
 
 import pytest
 
 from perfbench import program_spans as ps
-from perfbench.manifest import ROOT, Manifest
+from perfbench.manifest import BENCH_DIR, ROOT, Manifest, layer_values
+from perfbench.runners.common import every_listed_metric
 
 MAN = Manifest(ROOT)
 PID = 555
@@ -201,6 +208,126 @@ def test_spans_are_read_once_and_cut_on_both_clocks(session):
     assert MAN.reader("admit_scatter_device_idle_pct")(late) \
         == pytest.approx(100 * (1 - 0.12 / 0.32))
     assert ps.median_ms(ctx, "no.such_span") is None
+
+
+# ------------------------- a span the program lacks, and an empty reading
+#: what a later PR brings beside the spans it adds to the program: readers
+#: of span names that no row of ROWS carries (their first segment is no
+#: plane of the recorder's, so the repo's event-name lint passes them by)
+NEW_READERS = {
+    "step_newphase_ms": ("ms", "ps.median_ms(ctx, 'later.step.newphase')"),
+    "admit_newphase_ms_per_prompt_token": (
+        "ms", "ps.ms_per_prompt_token(ctx, 'later.admit.newphase')"),
+    "newkernel_roofline_pct": (
+        "%", "ps.median_ms(ctx, 'later.kernel.new', 'share')"),
+}
+
+
+def _tree_with(root, readers, cell="serve-chat-steady"):
+    """The benchmark's files with the nine program-span readers (which a
+    synthetic ``ctx`` can feed) and ``readers`` appended for ``cell``."""
+    shutil.copytree(os.path.join(BENCH_DIR, "layer_metrics"),
+                    os.path.join(root, "perfbench", "layer_metrics"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    doc["per_layer"] = [m for m in doc["per_layer"] if m["name"] in EXPECTED]
+    for name in readers:
+        unit, expr = NEW_READERS[name]
+        with open(os.path.join(root, "perfbench", "layer_metrics",
+                               name + ".py"), "w") as f:
+            f.write("from perfbench import program_spans as ps\n\n\n"
+                    f"def read(ctx):\n    return {expr}\n")
+        doc["per_layer"].append({
+            "name": name, "unit": unit, "better": "lower",
+            "source": "program_span", "layer": "engine",
+            "moves": "latency_ms_per_out_token", "workloads": [cell]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+    return Manifest(root)
+
+
+def test_a_span_the_program_lacks_leaves_its_metric_out_and_the_run_goes_on(
+        session, tmp_path_factory, capsys):
+    """The parent under a PR's new readers: no row of the run is named
+    ``later.step.newphase`` or ``later.admit.newphase``. Both metrics are
+    left out, each named on standard error with the span it lacks; every
+    other reader reads what it read before; nothing is raised (exit 0)."""
+    cell = "serve-chat-steady"
+    man = _tree_with(str(tmp_path_factory.mktemp("tree")),
+                     ["step_newphase_ms",
+                      "admit_newphase_ms_per_prompt_token"])
+    ctx = _ctx(session)
+    values = layer_values(man, cell, ctx)
+    assert ctx["program_lacks"] == {
+        "step_newphase_ms": ["later.step.newphase"],
+        "admit_newphase_ms_per_prompt_token": ["later.admit.newphase"]}
+    for name in list(EXPECTED)[:6]:
+        assert values[name]["value"] == pytest.approx(EXPECTED[name])
+    line = dict(values)
+    every_listed_metric(man, cell, line, ctx["program_lacks"])
+    err = capsys.readouterr().err
+    for name, span in (("step_newphase_ms", "later.step.newphase"),
+                       ("admit_newphase_ms_per_prompt_token",
+                        "later.admit.newphase")):
+        assert name not in line
+        assert f"{name} is left out of the line" in err and span in err
+    # without the harness's say-so the line is refused as before
+    with pytest.raises(SystemExit) as e:
+        every_listed_metric(man, cell, line)
+    assert "step_newphase_ms" in str(e.value)
+
+
+def test_rows_outside_the_traced_stretch_are_an_empty_reading_no_result(
+        session, tmp_path_factory, capsys):
+    """``serve.engine.admit`` rows exist and none began inside a trace that
+    opens after A2: the program writes the span, the stretch held none. That
+    ends the run by name, beside a lacking span that alone would not."""
+    cell = "serve-chat-steady"
+    man = _tree_with(str(tmp_path_factory.mktemp("tree")),
+                     ["step_newphase_ms"])
+    ctx = _ctx(session)
+    ctx["host"]["window_ns"] = (106 * S + OFF, 108 * S + OFF)
+    values = layer_values(man, cell, ctx)
+    assert "prefill_device_ms_per_prompt_token" not in values
+    assert ctx["program_lacks"] == {
+        "step_newphase_ms": ["later.step.newphase"]}
+    with pytest.raises(SystemExit) as e:
+        every_listed_metric(man, cell, values, ctx["program_lacks"])
+    assert e.value.code not in (0, None)
+    assert "prefill_device_ms_per_prompt_token" in str(e.value)
+    assert "step_newphase_ms" not in str(e.value)
+    assert "step_newphase_ms is left out" in capsys.readouterr().err
+
+
+def test_a_share_of_a_roofline_is_never_left_out(session, tmp_path_factory,
+                                                 capsys):
+    cell = "serve-chat-steady"
+    man = _tree_with(str(tmp_path_factory.mktemp("tree")),
+                     ["newkernel_roofline_pct"])
+    ctx = _ctx(session)
+    values = layer_values(man, cell, ctx)
+    assert ctx["program_lacks"] == {
+        "newkernel_roofline_pct": ["later.kernel.new"]}
+    with pytest.raises(SystemExit) as e:
+        every_listed_metric(man, cell, values, ctx["program_lacks"])
+    assert "newkernel_roofline_pct" in str(e.value)
+    assert "left out" not in capsys.readouterr().err
+
+
+def test_what_a_reader_asked_for_in_vain_is_collected_per_reader(session):
+    ctx = _ctx(session)
+    value, names = ps.asked_in_vain(
+        ctx, lambda c: ps.median_ms(c, "no.such_span"))
+    assert value is None and names == ["no.such_span"]
+    # rows exist, none in the window: nothing is noted, None means "empty"
+    far = {**_ctx(session), "run": {"t_open": 500.0, "t_close": 510.0}}
+    assert ps.asked_in_vain(far, lambda c: ps.median_ms(c, ps.PREPARE)) \
+        == (None, [])
+    # one reader's note is not the next one's
+    assert ps.asked_in_vain(ctx, lambda c: ps.median_ms(c, ps.PREPARE)) \
+        == (pytest.approx(2.0), [])
+    # a reader that is called outside the harness notes and harms nothing
+    assert ps.median_ms(_ctx(session), "no.such_span") is None
 
 
 def test_rehearsed_decode_cell_reports_the_program_span_metrics():

@@ -4,11 +4,14 @@ hand beside it, against no spill (None), against a program that wrote other
 rows but no set-up span (None: the parent of the PR that added them), and in
 a traced rehearsal of one serving cell and of the train cell.
 
-``BENCHMARK.json`` does not list the six yet (PERF.md section 7 says why: a
-traced run of the PARENT would end in ``every_listed_metric``), so the
-rehearsals run from a copy of the benchmark's files that does: the six
-one-line reader files and the six entries the next ``benchmark`` PR appends,
-built here from ``setup_spans.METRICS``."""
+``BENCHMARK.json`` does not list the six yet. PR 41 held them back because a
+traced run of its PARENT, which wrote no set-up span, would have ended in
+``every_listed_metric``; since PR 48 a span name that no row of a run
+carries is noted (``setup_spans.named``) and its metric is left out of a
+parent-side line, and the tree that stands writes the spans, so the next
+``benchmark`` issue can append the six. Until then the rehearsals run from a
+copy of the benchmark's files that lists them: the six one-line reader files
+and the six entries, built here from ``setup_spans.METRICS``."""
 
 import argparse
 import json
@@ -230,9 +233,30 @@ def test_reader_finds_nothing_in_a_program_without_set_up_spans(
         metric, tmp_path):
     """The parent of the PR that added the spans spills the engine's rows
     and no ``gcs.cluster.start``: nothing is read, nothing is raised."""
+    from perfbench import program_spans
+
     _write_spill(str(tmp_path), {REPLICA: [
         r for r in ROWS[REPLICA] if r[1].startswith("serve.engine.")]})
-    assert getattr(ss, metric)(_serve_ctx(str(tmp_path))) is None
+    value, in_vain = program_spans.asked_in_vain(
+        _serve_ctx(str(tmp_path)), getattr(ss, metric))
+    assert value is None
+    # ... and the harness is told which spans the program lacks, so that it
+    # leaves the metric out of the line and does not end the run
+    assert in_vain and set(in_vain) <= {
+        ss.CLUSTER, ss.PROBE, ss.PLACE, ss.BUILD, *ss.ENTRY, *ss.INIT}
+
+
+def test_a_span_that_is_there_is_not_asked_for_in_vain(session):
+    """Rows of ``jit.program.build`` exist and none ended before a window
+    that opens at 1 s: an empty reading, which ends a chip run by name."""
+    from perfbench import program_spans
+
+    early = {**_serve_ctx(session), "run": {"t_open": 1.0, "t_close": 2.0}}
+    assert program_spans.asked_in_vain(early, ss.setup_program_build_s) \
+        == (None, [])
+    value, in_vain = program_spans.asked_in_vain(_serve_ctx(session),
+                                                 ss.setup_cluster_s)
+    assert value == pytest.approx(2.0) and in_vain == []
 
 
 def test_every_metric_has_a_reader_and_a_layer():
