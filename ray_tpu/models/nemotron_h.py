@@ -478,7 +478,9 @@ def _hybrid_step(params, pools_k, pools_v, scales_k, scales_v, ssm_states,
     expert layers and the most tokens one expert got, so that one transfer
     fetches all; pools_k, pools_v, scales_k, scales_v, ssm_states,
     conv_tails, keys; the chosen experts [expert layers, S, k], which stay
-    on the device unless a reference check asks for them)."""
+    on the device unless a reference check asks for them; the tokens alone,
+    int32[S], as the next step takes them: with them the engine dispatches
+    that step before it has fetched this one's)."""
     (logits, new_k, new_v, new_sk, new_sv, new_ssm, new_conv,
      load, routing) = _decode_logits(params, pools_k, pools_v, scales_k, scales_v,
                             ssm_states, conv_tails, tables, toks, lengths,
@@ -486,6 +488,7 @@ def _hybrid_step(params, pools_k, pools_v, scales_k, scales_v, ssm_states,
     splits = jax.vmap(jax.random.split)(keys)
     picked = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
                           lengths)
-    out = jnp.concatenate([picked.astype(jnp.int32), load])
+    picked = picked.astype(jnp.int32)
+    out = jnp.concatenate([picked, load])
     return (out, new_k, new_v, new_sk, new_sv, new_ssm, new_conv,
-            splits[:, 0], routing)
+            splits[:, 0], routing, picked)

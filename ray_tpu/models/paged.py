@@ -192,15 +192,17 @@ def _nemotron_prefill(eng, suffix, pad, n):
 
 def _nemotron_step(eng, scales, uploads):
     (toks, eng.pools_k, eng.pools_v, sk, sv, eng.ssm, eng.conv, new_keys,
-     eng.last_routing) = _hybrid_step(
+     routing, next_tok) = _hybrid_step(
         eng.params, eng.pools_k, eng.pools_v, *scales, eng.ssm, eng.conv,
         *uploads, eng.cfg, eng.page, eng.kv_int8)
-    return toks, sk, sv, new_keys, None
+    return toks, sk, sv, new_keys, (next_tok, routing)
 
 
 def _nemotron_counts(eng, tail, sp):
-    # the expert layers' load rode with the tokens
-    sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]))
+    # the expert layers' load rode with the tokens; ONE step's figures (the
+    # mean, where the call landed two: the row's reader averages rows)
+    sp.set(experts_hit=int(tail[0]) // eng._landed,
+           expert_tokens_max=int(tail[1]) // eng._landed)
 
 
 def _sala_state(eng):
@@ -359,10 +361,11 @@ class _Family:
     prefill: object         # (engine, prompt, pad, n) -> (first logits,
     #                         caches, per-layer state tuples)
     step: object            # (engine, scales, uploads) -> (tokens and the
-    #                         step's counts, scales_k, scales_v, keys, and
-    #                         None or (the tokens alone as the next step
-    #                         takes them, what ``landed`` publishes): with
-    #                         them the engine runs ahead of the device, ``_step``)
+    #                         step's counts, scales_k, scales_v, keys, (the
+    #                         tokens alone as the next step takes them, what
+    #                         ``landed`` publishes)): with the tokens alone
+    #                         the engine runs ahead of the device, ``_step``
+    #                         (the dense family's, a branch there, does too)
     counts: object          # (engine, what rode with the tokens, the step's
     #                         span): the family's fields of the step row
     scatter: object = None  # (engine, caches, page_ids), where the prefill
@@ -389,7 +392,7 @@ class _Family:
 _FAMILIES = {
     NemotronHConfig: _Family(
         lambda cfg: cfg.n_attn_layers, _nemotron_state, _nemotron_prefill,
-        _nemotron_step, _nemotron_counts),
+        _nemotron_step, _nemotron_counts, landed=_routing_landed),
     MiniCPMSALAConfig: _Family(
         lambda cfg: cfg.n_sparse_layers, _sala_state, _sala_prefill,
         _sala_step, _sala_counts, _sala_scatter, _sala_admit_fields, (),
@@ -422,13 +425,17 @@ class _PagedSlot:
     done: bool = False
 
 
-#: Steps kept dispatched beyond the one whose tokens a call fetches, where
-#: the family's step can run ahead. One hides the host's part of a step;
-#: ten of ~16 ms ride out the ~0.1 s for which a shared host stops every
-#: process on it once or twice a minute (the chip goes on with what it was
-#: given: PERF.md section 6, PR 32). The cost: a request that finds a free
-#: slot waits for them to land before it is admitted.
+#: Steps kept dispatched beyond the one whose tokens a call fetches, with
+#: every slot held. One hides the host's part of a step; ten of 12-24 ms ride
+#: out the ~0.1 s for which a shared host stops every process on it once or
+#: twice a minute (the chip goes on with what it was given: PERF.md section
+#: 6, PR 32). They cost an arrival nothing: with no slot free it waits for a
+#: stream to end, and the steps in flight have landed by then.
 _STEPS_AHEAD = 10
+#: The same while a slot is free: a request that arrives then is admitted
+#: once the steps in flight have landed, one a call, so only as many are
+#: kept as hide the host's part of a call under the device's.
+_STEPS_FREE_SLOT = 2
 
 
 @dataclass
@@ -437,8 +444,8 @@ class _Flight:
     toks: object        # device: the tokens (a recurrent step's counts after)
     keys: object        # device: the slots' sampling keys after the step
     active: List[int]   # the slots that decoded in it
-    next_tok: object = None     # device int32[S]: the tokens alone
-    kept: object = None         # what the family publishes when it lands
+    next_tok: object    # device int32[S]: the tokens alone, the next step's
+    kept: object        # what the family publishes when it lands, or None
 
     def ended(self) -> bool:
         """Whether the device has finished the step (asks, never waits)."""
@@ -484,18 +491,24 @@ class PagedEngine:
     recompute and the spans are the same code (``_FAMILIES`` holds what
     differs).
 
-    Where the family's step program hands its tokens on as a device array
-    (``_Family.step``), the engine **runs ahead of the device**:
-    ``step()`` dispatches the next step on the tokens and keys the last
-    one left on the device, keeps up to ``_STEPS_AHEAD`` dispatched and
-    fetches the oldest's tokens as it ends. The host's part of a step
-    (uploads, dispatch, transfer, the pump between calls) then lies under
-    the device's, and a host that stands still for a tenth of a second
-    finds the device still at work. Events come some calls after their
-    step's dispatch, at the instant they would have come. It runs ahead
-    only while nothing but the count of tokens decides what the next step
-    holds (``_runs_ahead``); otherwise the steps in flight land, one a
-    call, and the call that lands the last is the synchronous one.
+    Every family's step program hands its tokens on as a device array
+    (``_Family.step``; ``_paged_step``'s tokens are that array), and the
+    engine **runs ahead of the device** for all five: ``step()`` dispatches
+    the next step on the tokens and keys the last one left on the device,
+    keeps up to ``_STEPS_AHEAD`` dispatched while every slot is held
+    (``_STEPS_FREE_SLOT`` while one is free) and fetches the oldest's
+    tokens as it ends. The host's part of a step (uploads, dispatch,
+    transfer, the pump between calls) then lies under the device's, and a
+    host that stands still for a tenth of a second finds the device still
+    at work. Events come some calls after their step's dispatch, at the
+    instant they would have come. It runs ahead only while nothing but the
+    count of tokens decides what the next step holds (``_runs_ahead``: no
+    request waits beside a free slot, no stream has an ``eos_id`` or its
+    last token dispatched, a page to spare for every held slot); otherwise
+    the steps in flight land, one a call, and the call that lands the last
+    is the synchronous one: it admits, so an arrival that finds a free slot
+    waits for at most ``_STEPS_FREE_SLOT`` steps, and the prefix cache is
+    read and written with nothing in flight.
     """
 
     def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig,
@@ -580,6 +593,7 @@ class PagedEngine:
         # what this step() did, for its ``serve.engine.step`` row
         self._steps = self._admitted = self._preempted = 0
         self._step_counts = None    # what rode with a recurrent step's tokens
+        self._landed = 0            # steps whose tokens this step() fetched
         self._flights: List[_Flight] = []   # dispatched, not fetched, in order
         # Prefix cache: full-prompt-page content hash -> (page id,
         # refcount). Pages with refcount 0 stay resident (reusable)
@@ -882,6 +896,7 @@ class PagedEngine:
         it cannot run ahead of, the call only lands the oldest, and the
         call that lands the last goes on as the synchronous one."""
         self._step_counts = self._kv_positions = None
+        self._landed = 0
         events: List[tuple] = []
         flights = self._flights
         if flights and not self._runs_ahead():
@@ -935,7 +950,6 @@ class PagedEngine:
         with plane_events.span("serve.step.dispatch", "serve"):
             scales = ((self.scales_k, self.scales_v) if self.kv_int8
                       else (no_scales, no_scales))
-            ahead = None
             if self.family:
                 toks, sk, sv, new_keys, ahead = self.family.step(
                     self, scales, uploads)
@@ -945,21 +959,28 @@ class PagedEngine:
                     self.params, self.pools_k, self.pools_v, *scales,
                     *uploads, self.cfg, self.cos, self.sin, self.page,
                     self.kv_int8)
+                ahead = (toks, None)    # the tokens alone; nothing to publish
             if self.kv_int8:
                 # model-dtype mode keeps scales stable at [None]*n_layers
                 self.scales_k, self.scales_v = sk, sv
             for i in active:    # positions written or on their way
                 self.slots[i].length += 1
-        now = _Flight(toks, new_keys, active, *(ahead or ()))
-        if ahead and all(self.slots[i].eos_id is None for i in active):
+        now = _Flight(toks, new_keys, active, *ahead)
+        if all(self.slots[i].eos_id is None for i in active):
             # no token's value ends a stream: later calls may dispatch
             # before this step's tokens are fetched
             flights.append(now)
             # the oldest lands when it has ended (while the steps in
             # flight are still few, after an admission, its tokens are
             # not kept waiting) or when enough are in flight (the call
-            # then waits for it, with the device at work on the others)
-            if len(flights) > _STEPS_AHEAD or flights[0].ended():
+            # then waits for it, with the device at work on the others).
+            # Enough: ``_STEPS_AHEAD`` with every slot held, when no
+            # arrival could be admitted before a stream ends anyway; two
+            # while a slot is free, which hide the host's part of a call
+            # and are all that an arrival then waits for
+            full = all(s is not None for s in self.slots)
+            if len(flights) > (_STEPS_AHEAD if full else _STEPS_FREE_SLOT) \
+                    or flights[0].ended():
                 self._land(flights.pop(0), events)
         else:
             self._land(now, events)
@@ -983,6 +1004,7 @@ class PagedEngine:
         with plane_events.span("serve.step.fetch", "serve"):
             toks, keys = jax.device_get((flight.toks, flight.keys))
             self.keys = np.array(keys)
+            self._landed += 1
             if self.family:  # the step's counts rode with the tokens
                 tail = toks[self.S:]
                 self._step_counts = (tail if self._step_counts is None

@@ -157,3 +157,41 @@ def cpu_mesh8():
     devices = jax.devices("cpu")
     assert len(devices) >= 8, "conftest must provide 8 virtual CPU devices"
     return devices[:8]
+
+
+@pytest.fixture
+def slow_device(monkeypatch):
+    """No step has ended when the engine asks: as on the chip, where a
+    step takes longer than the host's part of a call (the CPU ends a toy
+    step before the call returns, and nothing would stay in flight)."""
+    from ray_tpu.models import paged
+
+    monkeypatch.setattr(paged._Flight, "ended", lambda self: False)
+
+
+@pytest.fixture
+def prompt_device(monkeypatch):
+    """Every step has ended when the engine asks: each call fetches the
+    step it dispatched, so a ``serve.engine.step`` row is one step's (the
+    CPU ends most toy steps in time, not all)."""
+    from ray_tpu.models import paged
+
+    monkeypatch.setattr(paged._Flight, "ended", lambda self: True)
+
+
+@pytest.fixture
+def streams():
+    """-> f(engine, {rid: (prompt, max_new)}, **how): what each request
+    streams, and the most steps the engine ever left in flight when
+    ``step()`` returned."""
+    def drive(eng, reqs, **how):
+        for r, (prompt, n) in reqs.items():
+            eng.submit(r, prompt, max_new_tokens=n, **how)
+        got, deepest = {r: [] for r in reqs}, 0
+        while eng.has_work():
+            for rid, tok in eng.step():
+                if tok is not None:
+                    got[rid].append(tok)
+            deepest = max(deepest, len(eng._flights))
+        return got, deepest
+    return drive

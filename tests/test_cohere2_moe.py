@@ -541,14 +541,6 @@ def test_admission_and_release_leave_both_caches_as_they_found_them(params):
     assert all(s is None for s in eng.slots) and not eng._flights
 
 
-@pytest.fixture
-def slow_device(monkeypatch):
-    """No step has ended when the engine asks: as on the chip, where a
-    step takes longer than the host's part of a call (the CPU ends a toy
-    step before the call returns, and nothing would stay in flight)."""
-    monkeypatch.setattr(paged._Flight, "ended", lambda self: False)
-
-
 def test_requests_admitted_at_different_steps_stream_what_each_streams_alone(
         params, slow_device):
     reqs = {"a": (_tokens(40, 1), 12), "b": (_tokens(2, 2), 25),
@@ -581,7 +573,7 @@ def test_running_ahead_streams_what_the_synchronous_loop_streams(
     reqs = {"long": (_tokens(40, 1), 19), "short": (_tokens(9, 3), 13)}
 
     def streams(**more):
-        eng = _engine(params)
+        eng = _engine(params, max_slots=2)  # every slot held: the full depth
         for r, (prompt, n) in reqs.items():
             eng.submit(r, prompt, max_new_tokens=n, **how, **more)
         got, deepest = {r: [] for r in reqs}, 0

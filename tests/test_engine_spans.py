@@ -31,7 +31,9 @@ REQS = {"req-aaaa-long-id": ([1, 2, 3, 4], 6), "req-bbbb": ([7, 8], 3),
 
 
 @pytest.fixture(autouse=True)
-def _clean_ring():
+def _clean_ring(prompt_device):
+    # ``prompt_device``: each call fetches the step it dispatched, so a
+    # ``serve.engine.step`` row here is one step's, with its four phases
     events.reset()
     yield
     events._enabled = True
@@ -224,6 +226,31 @@ def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
         assert kids == (STEP_PHASES if s["fields"]["active"]
                         else STEP_PHASES[:1])
         assert "free_pages" in s["fields"] and "preempted" in s["fields"]
+
+
+def test_phases_of_a_call_with_steps_in_flight(model, slow_device):
+    """Ahead of the device a call that decodes has its prepare and its
+    dispatch, and the fetch and the emit of the OLDEST step once enough are
+    in flight; a call that only lands (a request waits beside a free slot)
+    has a fetch and an emit alone; ``active`` is what the call dispatched,
+    ``tokens`` what it landed."""
+    eng = _engine(model)
+    eng.submit("req-a", [1, 2, 3, 4], max_new_tokens=9)
+    for _ in range(3):
+        eng.step()
+    eng.submit("req-b", [7, 8], max_new_tokens=3)
+    eng.step()
+    rows = _rows()
+    steps = [r for r in rows if r["name"] == "serve.engine.step"]
+    kids = [[r["name"] for r in rows
+             if r["fields"].get("parent") == s["fields"]["sid"]
+             and r["name"] in STEP_PHASES] for s in steps]
+    assert kids == [STEP_PHASES[:2], STEP_PHASES[:2], STEP_PHASES,
+                    STEP_PHASES[2:]]
+    assert [(s["fields"]["active"], s["fields"]["tokens"])
+            for s in steps] == [(1, 1), (1, 0), (1, 1), (0, 1)]
+    while eng.has_work():
+        eng.step()
 
 
 def test_preemption_is_counted_on_the_step_row(model):

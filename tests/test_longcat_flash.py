@@ -376,14 +376,6 @@ def test_requests_admitted_at_different_steps_stream_what_each_streams_alone(
     assert eng._available_pages() == 63       # page 0 is reserved
 
 
-@pytest.fixture
-def slow_device(monkeypatch):
-    """No step has ended when the engine asks: as on the chip, where a
-    step takes longer than the host's part of a call (the CPU ends a toy
-    step before the call returns, and nothing would stay in flight)."""
-    monkeypatch.setattr(paged._Flight, "ended", lambda self: False)
-
-
 @pytest.mark.parametrize("how", [
     {}, {"temperature": 0.8, "top_k": 5, "seed": 3}],
     ids=["greedy", "top_k"])
@@ -392,7 +384,7 @@ def test_running_ahead_streams_what_the_synchronous_loop_streams(
     reqs = {"long": (_tokens(40, 1), 19), "short": (_tokens(21, 3), 13)}
 
     def streams(**more):
-        eng = _engine(params)
+        eng = _engine(params, max_slots=2)  # every slot held: the full depth
         for r, (prompt, n) in reqs.items():
             eng.submit(r, prompt, max_new_tokens=n, **how, **more)
         got, deepest = {r: [] for r in reqs}, 0

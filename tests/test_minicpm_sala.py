@@ -329,34 +329,12 @@ def test_requests_admitted_at_different_steps_stream_what_each_streams_alone(
     assert eng._available_pages() == 63       # page 0 is reserved
 
 
-def _streams(eng, reqs, **how):
-    """What each request streams, and the most steps the engine ever left
-    in flight when ``step()`` returned."""
-    for r, (prompt, n) in reqs.items():
-        eng.submit(r, prompt, max_new_tokens=n, **how)
-    got, deepest = {r: [] for r in reqs}, 0
-    while eng.has_work():
-        for rid, tok in eng.step():
-            if tok is not None:
-                got[rid].append(tok)
-        deepest = max(deepest, len(eng._flights))
-    return got, deepest
-
-
-@pytest.fixture
-def slow_device(monkeypatch):
-    """No step has ended when the engine asks: as on the chip, where a
-    step takes longer than the host's part of a call (the CPU ends a toy
-    step before the call returns, and nothing would stay in flight)."""
-    monkeypatch.setattr(paged._Flight, "ended", lambda self: False)
-
-
 @pytest.mark.parametrize("how", [
     {}, {"temperature": 0.8, "top_k": 5, "seed": 3},
     {"temperature": 1.0, "top_p": 0.9, "seed": 11}],
     ids=["greedy", "top_k", "top_p"])
 def test_running_ahead_streams_what_the_synchronous_loop_streams(
-        params, how, slow_device):
+        params, how, slow_device, streams):
     """Without an ``eos_id`` only the count of tokens ends a stream, so the
     engine dispatches each step on the tokens and keys the last one left on
     the device and fetches tokens ``_STEPS_AHEAD`` steps behind; with an
@@ -364,23 +342,24 @@ def test_running_ahead_streams_what_the_synchronous_loop_streams(
     dispatched it. Both stream the same tokens, sampled ones too: the keys
     are the same chain. Two requests of unequal lengths, one past
     ``dense_len`` from its first step, one crossing it; the shorter ends
-    while the other goes on."""
+    while the other goes on. Every slot is held, so the depth is
+    ``_STEPS_AHEAD``."""
     reqs = {"long": (_tokens(40, 1), 19), "short": (_tokens(21, 3), 13)}
-    ahead, deepest = _streams(_engine(params), reqs, **how)
-    sync, none = _streams(_engine(params), reqs, eos_id=CFG.vocab_size,
-                          **how)
+    ahead, deepest = streams(_engine(params, max_slots=2), reqs, **how)
+    sync, none = streams(_engine(params, max_slots=2), reqs,
+                         eos_id=CFG.vocab_size, **how)
     assert ahead == sync and [len(v) for v in ahead.values()] == [19, 13]
     assert deepest == paged._STEPS_AHEAD and none == 0
 
 
 def test_a_step_that_has_ended_lands_in_the_call_that_finds_it(
-        params, monkeypatch):
+        params, monkeypatch, streams):
     """Where every step has ended by the time the engine asks, each call
     fetches the step it dispatched: nothing stays in flight, the same
     stream."""
     monkeypatch.setattr(paged._Flight, "ended", lambda self: True)
     reqs = {"long": (_tokens(40, 1), 19)}
-    got, deepest = _streams(_engine(params), reqs)
+    got, deepest = streams(_engine(params), reqs)
     assert deepest == 0 and got["long"] == _alone(params, _tokens(40, 1), 19)
 
 
@@ -401,14 +380,18 @@ def test_steps_in_flight_land_before_an_admission_and_are_work(
     assert got["a"] == _alone(params, _tokens(40, 1), 1)
     assert len(eng._flights) == 1 and eng.has_work()
     assert eng.slots[0].length == 41 and len(eng.slots[0].emitted) == 1
-    assert step() == [] and step() == []     # steps 2 and 3, none fetched
-    assert len(eng._flights) == 3 and eng.slots[0].length == 43
+    assert step() == []                      # step 2, none fetched
+    assert len(eng._flights) == 2 and eng.slots[0].length == 42
+    # a slot is free: two in flight hide the host's part of a call, and a
+    # third dispatch lands the oldest
+    assert [rid for rid, _ in step()] == ["a"]
+    assert len(eng._flights) == 2 and eng.slots[0].length == 43
     eng.submit("b", _tokens(9, 2), max_new_tokens=4)
-    # a slot is free and b waits: no step is dispatched until the three in
+    # a slot is free and b waits: no step is dispatched until the two in
     # flight have landed, one a call; the call that lands the last admits
     # b and dispatches a step for both
-    assert [rid for rid, _ in step()] == ["a"] and len(eng._flights) == 2
-    assert [rid for rid, _ in step()] == ["a"] and eng.slots[0].length == 43
+    assert [rid for rid, _ in step()] == ["a"] and len(eng._flights) == 1
+    assert eng.slots[0].length == 43
     assert [rid for rid, _ in step()] == ["a", "b"]
     assert len(eng._flights) == 1 and eng._flights[0].active == [0, 1]
     assert len(got["a"]) == 4 and eng.slots[0].length == 44

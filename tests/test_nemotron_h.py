@@ -12,6 +12,7 @@ import pytest
 
 from perfbench.reference import nemotron_h as ref
 from ray_tpu.models import nemotron_h as nh
+from ray_tpu.models import paged
 from ray_tpu.models.paged import PagedEngine
 from ray_tpu.ops import ssm
 from ray_tpu.parallel import moe
@@ -276,8 +277,8 @@ def _clean_ring():
     events.reset()
 
 
-def test_state_write_span_and_expert_load_on_the_step_row(params,
-                                                          _clean_ring):
+def test_state_write_span_and_expert_load_on_the_step_row(
+        params, _clean_ring, prompt_device):
     eng = _engine(params)
     eng.submit("req-aaaa-long", _tokens(9, 2), max_new_tokens=4)
     eng.run_to_completion()
@@ -301,7 +302,7 @@ def test_state_write_span_and_expert_load_on_the_step_row(params,
 
 
 def test_a_sampled_request_streams_the_parents_tokens_and_is_counted(
-        params, _clean_ring):
+        params, _clean_ring, prompt_device):
     """The hybrid step through the same picker: the sampled tokens are
     the parent commit's for this seed, alone and beside a greedy
     neighbour, and ``sampling`` falls to 0 on the rows after the sampling
@@ -323,6 +324,145 @@ def test_a_sampled_request_streams_the_parents_tokens_and_is_counted(
     steps = [r["fields"] for r in rows if r["name"] == "serve.engine.step"]
     assert [f["sampling"] for f in steps] == [1, 1, 1, 0, 0, 0, 0, 0]
     assert [f["active"] for f in steps] == [2, 2, 2, 1, 1, 1, 1, 1]
+
+
+# ------------------- the engine runs ahead of the device (ISSUE 49: S6)
+AHEAD_REQS = {"long": (_tokens(19, 1), 19), "short": (_tokens(5, 3), 13)}
+
+
+@pytest.mark.parametrize("how", [
+    {}, {"temperature": 0.8, "top_k": 5, "seed": 3},
+    {"temperature": 1.0, "top_p": 0.9, "seed": 11}],
+    ids=["greedy", "top_k", "top_p"])
+def test_running_ahead_streams_what_the_synchronous_loop_streams(
+        params, how, slow_device, streams):
+    """``_hybrid_step`` hands its tokens on alone beside the tokens with
+    the two counts, so without an ``eos_id`` the engine dispatches each
+    step on the tokens and keys the last one left on the device, the
+    recurrent state and the pools going from step to step donated, and
+    fetches tokens ``_STEPS_AHEAD`` steps behind (both slots are held);
+    with an ``eos_id`` no token equals, every step is fetched in the call
+    that dispatched it. The same tokens, sampled ones too."""
+    ahead, deepest = streams(_engine(params, max_slots=2), AHEAD_REQS,
+                             **how)
+    sync, none = streams(_engine(params, max_slots=2), AHEAD_REQS,
+                         eos_id=CFG.vocab_size, **how)
+    assert ahead == sync and [len(v) for v in ahead.values()] == [19, 13]
+    assert deepest == paged._STEPS_AHEAD and none == 0
+
+
+@pytest.mark.parametrize("slots, depth", [
+    (2, paged._STEPS_AHEAD), (3, paged._STEPS_FREE_SLOT)],
+    ids=["every_slot_held", "a_slot_free"])
+def test_the_depth_follows_whether_a_slot_is_free(params, slots, depth,
+                                                  slow_device, streams):
+    got, deepest = streams(_engine(params, max_slots=slots), AHEAD_REQS)
+    assert deepest == depth
+    for r, (prompt, n) in AHEAD_REQS.items():
+        assert got[r] == _alone(params, prompt, n), r
+
+
+def test_a_step_that_has_ended_lands_in_the_call_that_finds_it(
+        params, prompt_device, streams):
+    reqs = {"long": AHEAD_REQS["long"]}
+    got, deepest = streams(_engine(params), reqs)
+    assert deepest == 0 and got["long"] == _alone(params, *reqs["long"])
+
+
+def test_steps_in_flight_land_before_an_admission_and_are_work(
+        params, slow_device):
+    eng = _engine(params, max_slots=2)
+    eng.submit("a", _tokens(11, 1), max_new_tokens=14)
+    got = {"a": [], "b": []}
+
+    def step():
+        events = eng.step()
+        for rid, tok in events:
+            if tok is not None:
+                got[rid].append(tok)
+        return events
+
+    step()                                   # admits, dispatches step 1
+    assert got["a"] == _alone(params, _tokens(11, 1), 1)
+    assert len(eng._flights) == 1 and eng.has_work()
+    assert eng.last_routing is None          # nothing has landed
+    assert step() == []                      # step 2, none fetched
+    # a slot is free: the third dispatch lands the oldest
+    assert [rid for rid, _ in step()] == ["a"] and len(eng._flights) == 2
+    assert eng.last_routing is not None      # step 1's
+    eng.submit("b", _tokens(9, 2), max_new_tokens=4)
+    # a slot is free and b waits: no step is dispatched until the two in
+    # flight have landed, one a call; the call that lands the last admits
+    # b and dispatches a step for both
+    assert [rid for rid, _ in step()] == ["a"] and len(eng._flights) == 1
+    assert eng.slots[0].length == 14 and eng.slots[1] is None
+    assert [rid for rid, _ in step()] == ["a", "b"]
+    assert len(eng._flights) == 1 and eng._flights[0].active == [0, 1]
+    assert len(got["a"]) == 4 and eng.slots[0].length == 15
+    while eng.has_work():
+        step()
+    assert got["a"] == _alone(params, _tokens(11, 1), 14)
+    assert got["b"] == _alone(params, _tokens(9, 2), 4)
+    assert not eng._flights and eng._available_pages() == 23
+
+
+def test_last_routing_is_the_landed_steps(params, monkeypatch):
+    """The reference check reads ``engine.last_routing`` after the call
+    that returned a decode token (``nemotron_h_check.program_out``): with
+    steps in flight that is the routing of the step whose token the call
+    returned, not of the newest dispatched, so the check collects what it
+    collects from an engine that fetches every step at once."""
+    from perfbench.reference.nemotron_h_check import program_out
+
+    prompt = _tokens(9, 4)
+    emitted = _alone(params, prompt, 9)
+    outs = []
+    for ended in (True, False):
+        monkeypatch.setattr(paged._Flight, "ended", lambda self: ended)
+        eng = _engine(params)
+        depths = []
+        step = eng.step
+        monkeypatch.setattr(
+            eng, "step", lambda: (step(), depths.append(len(eng._flights)))[0])
+        outs.append(program_out(eng, prompt, emitted))
+        assert max(depths) == (0 if ended else paged._STEPS_FREE_SLOT)
+    (row_a, toks_a, routes_a), (row_b, toks_b, routes_b) = outs
+    assert toks_a == toks_b == emitted and np.array_equal(row_a, row_b)
+    assert routes_a.shape == (CFG.n_moe_layers, 9 + 9 - 1, CFG.top_k)
+    assert np.array_equal(routes_a, routes_b)
+    # the decode steps' choices differ from step to step: a routing read
+    # one step late or early would not pass
+    assert len({routes_a[:, k].tobytes() for k in range(9, 17)}) > 1
+
+
+def test_a_row_that_lands_two_steps_carries_one_steps_expert_load(
+        params, _clean_ring, monkeypatch):
+    """``experts_hit`` is one step's figure on every ``serve.engine.step``
+    row (the benchmark's reader averages rows): the call that lands the
+    last step in flight, admits a stream with an ``eos_id`` and fetches the
+    step it dispatches lands two, and its row holds their mean."""
+    def rows(ended):
+        monkeypatch.setattr(paged._Flight, "ended", lambda self: ended)
+        events.reset()
+        eng = _engine(params, max_slots=2)
+        eng.submit("a", _tokens(11, 1), max_new_tokens=8)
+        landed = [(eng.step(), eng._landed)[1] for _ in range(2)]
+        eng.submit("b", _tokens(9, 2), max_new_tokens=4,
+                   eos_id=CFG.vocab_size)
+        landed += [(eng.step(), eng._landed)[1] for _ in range(2)]
+        steps = [r[6] for r in events.drain()[0]
+                 if r[1] == "serve.engine.step"]
+        return landed, [(f.get("experts_hit"), f.get("expert_tokens_max"))
+                        for f in steps]
+
+    landed, (s1, s2, s3, _) = rows(True)    # a step a row
+    assert landed == [1, 1, 1, 1]
+    landed, loads = rows(False)
+    # two calls dispatch s1 and s2; b waits beside a free slot: one call
+    # lands s1, the next lands s2, admits b and fetches s3 at once
+    assert landed == [0, 0, 1, 2]
+    assert loads == [(None, None), (None, None), s1,
+                     ((s2[0] + s3[0]) // 2, (s2[1] + s3[1]) // 2)]
 
 
 def test_greedy_identical_with_recorder_on_and_off(params, _clean_ring):

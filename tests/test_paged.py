@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import LlamaConfig, generate_greedy, init_params
+from ray_tpu.models import LlamaConfig, generate_greedy, init_params, paged
 from ray_tpu.models.paged import (PagedEngine, _paged_step, _quant_kv,
                                   _scatter_pages)
 
@@ -306,7 +306,10 @@ def test_scatter_leaves_shared_and_foreign_pages_alone(bf16_model, kv_dtype):
     prefix = list(range(50, 58))                 # two full pages
     for e in (eng, ref):
         _noise_pools(e, 9)
-        e.submit("x", prefix + [1, 2], max_new_tokens=12)
+        # an ``eos_id`` no token equals: every step is fetched in its own
+        # call, so ``_admit`` below finds nothing in flight
+        e.submit("x", prefix + [1, 2], max_new_tokens=12,
+                 eos_id=cfg.vocab_size)
         e.submit("z", [9, 8, 7], max_new_tokens=12)
         for _ in range(3):
             e.step()
@@ -388,7 +391,9 @@ def test_scatter_donates_the_pools_and_keeps_no_handle(model, kv_dtype):
                       page_size=8, max_len=64, kv_dtype=kv_dtype,
                       enable_prefix_cache=True)
     prefix = list(range(1, 17))
-    eng.submit("a", prefix + [20], max_new_tokens=3)
+    # an ``eos_id`` no token equals: the step is fetched in its own call,
+    # so ``_admit`` below finds nothing in flight
+    eng.submit("a", prefix + [20], max_new_tokens=3, eos_id=cfg.vocab_size)
     eng.step()
     eng.submit("b", prefix + [30], max_new_tokens=3)  # gathers its prefix
     given = jax.tree_util.tree_leaves(
@@ -546,3 +551,137 @@ def test_paged_matches_greedy_across_the_reads_blocks(model):
     got = eng.run_to_completion()
     for rid, (p, n) in reqs.items():
         assert got[rid] == _ref(params, cfg, p, n), rid
+
+
+# ------------------- the engine runs ahead of the device (ISSUE 49: S6)
+
+def _ahead_engine(model, **kw):
+    cfg, params = model
+    kw = {"max_slots": 2, "num_pages": 24, "page_size": 8, "max_len": 64,
+          **kw}
+    return PagedEngine(params, cfg, **kw)
+
+
+AHEAD_REQS = {"long": (list(range(1, 20)), 19), "short": ([7, 8, 9], 13)}
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+@pytest.mark.parametrize("how", [
+    {}, {"temperature": 0.8, "top_k": 5, "seed": 3},
+    {"temperature": 1.0, "top_p": 0.9, "seed": 11}],
+    ids=["greedy", "top_k", "top_p"])
+def test_running_ahead_streams_what_the_synchronous_loop_streams(
+        model, how, kv_dtype, slow_device, streams):
+    """``_paged_step``'s tokens are the array the next step takes, so
+    without an ``eos_id`` the dense family dispatches each step on the
+    tokens and keys the last one left on the device and fetches tokens
+    ``_STEPS_AHEAD`` steps behind (both slots are held); with an ``eos_id``
+    no token equals, every step is fetched in the call that dispatched it.
+    Both stream the same tokens, sampled ones too: the keys are the same
+    chain; the int8 scales are rebound at every dispatch."""
+    ahead, deepest = streams(_ahead_engine(model, kv_dtype=kv_dtype),
+                             AHEAD_REQS, **how)
+    sync, none = streams(_ahead_engine(model, kv_dtype=kv_dtype),
+                         AHEAD_REQS, eos_id=model[0].vocab_size, **how)
+    assert ahead == sync and [len(v) for v in ahead.values()] == [19, 13]
+    assert deepest == paged._STEPS_AHEAD and none == 0
+    if not how and kv_dtype == "model":
+        for r, (prompt, n) in AHEAD_REQS.items():
+            assert ahead[r] == _ref(model[1], model[0], prompt, n), r
+
+
+@pytest.mark.parametrize("slots, depth", [
+    (2, paged._STEPS_AHEAD), (3, paged._STEPS_FREE_SLOT)],
+    ids=["every_slot_held", "a_slot_free"])
+def test_the_depth_follows_whether_a_slot_is_free(model, slots, depth,
+                                                  slow_device, streams):
+    """With every slot held no arrival could be admitted before a stream
+    ends, and the engine keeps ``_STEPS_AHEAD`` steps dispatched; beside a
+    free slot it keeps the two that hide the host's part of a call, which
+    is all that an arrival then waits for."""
+    got, deepest = streams(_ahead_engine(model, max_slots=slots),
+                           AHEAD_REQS)
+    assert deepest == depth
+    for r, (prompt, n) in AHEAD_REQS.items():
+        assert got[r] == _ref(model[1], model[0], prompt, n), r
+
+
+def test_a_step_that_has_ended_lands_in_the_call_that_finds_it(
+        model, monkeypatch, streams):
+    monkeypatch.setattr(paged._Flight, "ended", lambda self: True)
+    reqs = {"long": AHEAD_REQS["long"]}
+    got, deepest = streams(_ahead_engine(model), reqs)
+    assert deepest == 0
+    assert got["long"] == _ref(model[1], model[0], *reqs["long"])
+
+
+def test_steps_in_flight_land_before_an_admission_and_are_work(
+        model, slow_device):
+    cfg, params = model
+    eng = _ahead_engine(model)
+    a, b = list(range(1, 12)), [30, 31, 32]
+    eng.submit("a", a, max_new_tokens=14)
+    got = {"a": [], "b": []}
+
+    def step():
+        events = eng.step()
+        for rid, tok in events:
+            if tok is not None:
+                got[rid].append(tok)
+        return events
+
+    step()                                   # admits, dispatches step 1
+    assert got["a"] == _ref(params, cfg, a, 1)
+    assert len(eng._flights) == 1 and eng.has_work()
+    assert eng.slots[0].length == 12 and len(eng.slots[0].emitted) == 1
+    assert step() == []                      # step 2, none fetched
+    # a slot is free: the third dispatch lands the oldest
+    assert [rid for rid, _ in step()] == ["a"] and len(eng._flights) == 2
+    eng.submit("b", b, max_new_tokens=4)
+    # a slot is free and b waits: no step is dispatched until the two in
+    # flight have landed, one a call; the call that lands the last admits
+    # b and dispatches a step for both
+    assert [rid for rid, _ in step()] == ["a"] and len(eng._flights) == 1
+    assert eng.slots[0].length == 14 and eng.slots[1] is None
+    assert [rid for rid, _ in step()] == ["a", "b"]
+    assert len(eng._flights) == 1 and eng._flights[0].active == [0, 1]
+    assert len(got["a"]) == 4 and eng.slots[0].length == 15
+    while eng.has_work():
+        step()
+    assert got["a"] == _ref(params, cfg, a, 14)
+    assert got["b"] == _ref(params, cfg, b, 4)
+    assert not eng._flights and sorted(eng.free_pages) == list(range(1, 24))
+
+
+@pytest.mark.parametrize("case", ["prefix_cache", "preemption"])
+def test_a_cache_hit_and_a_preemption_stream_what_they_streamed_before(
+        model, case, slow_device):
+    """Admissions read and write the prefix cache, and a slot is preempted,
+    only with nothing in flight (``_runs_ahead`` wants a page to spare for
+    every held slot): the streams are the synchronous loop's and the
+    reference's, the hits and the preemptions happen all the same."""
+    cfg, params = model
+    prefix = list(range(1, 17))             # two full pages of 8
+    if case == "prefix_cache":
+        kw = dict(max_slots=3, enable_prefix_cache=True)
+        first = ("a", prefix + [20], 14)
+        late = {3: ("b", prefix + [30, 31], 5), 6: ("c", prefix + [40], 4),
+                9: ("d", prefix[:8] + [50, 51, 52], 4)}
+    else:       # 7 pages for two sequences that grow to 5 each
+        kw = dict(num_pages=8)
+        first = ("x", [1, 2, 3, 4, 5, 6], 30)
+        late = {0: ("y", [9, 8, 7, 6, 5], 30)}
+    outs = []
+    for eos in (None, cfg.vocab_size):
+        eng = _ahead_engine(model, **kw)
+        eng.submit(first[0], first[1], max_new_tokens=first[2], eos_id=eos)
+        acc, preempted = _drive(eng, late)
+        outs.append(acc)
+        assert not eng._flights and not eng.tables.any()
+        if case == "prefix_cache":
+            assert eng.prefix_hits == 3 and eng.prefix_misses == 1
+        else:
+            assert preempted > 0
+    assert outs[0] == outs[1]
+    for rid, prompt, n in [first, *late.values()]:
+        assert outs[0][rid] == _ref(params, cfg, prompt, n), rid
