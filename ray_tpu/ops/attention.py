@@ -188,12 +188,37 @@ def make_flash_attention(mesh):
     return attn
 
 
-#: On-chip autotuned (block_q, block_k_major, block_k) per sequence length,
-#: loaded once from records/flash_autotune.json (written by
-#: benchmarks/tpu_kernels.py on a chip). Mosaic's own defaults are
-#: 128/128/128 at every size — conservative for v5e, where larger q/k blocks
-#: amortize the softmax rescale and keep the MXU busy; the sweep picks per-L
-#: winners empirically.
+#: Blocks of the three Mosaic kernels behind ``_tpu_flash`` (forward, ``dkv``,
+#: ``dq``). Mosaic's own defaults are 128 everywhere, and until PR 50 the
+#: seven backward fields stood at that 128 because only the forward kernel had
+#: ever been timed: at L 4096 a ``dkv`` call then walks a 32 x 32 grid of tiny
+#: products and runs at a tenth of the MXU's peak (6.8 ms an execution inside
+#: the train cell's step, where the blocks below take 1.1). PR 50 timed all
+#: three kernels alone on one TPU v5e (each call closed by
+#: ``block_until_ready``, median of 24), every block in 128..2048 that the
+#: kernel's own checks and the chip's compiler take, at ``[1, 16, 4096, 128]``
+#: (one chip's share of the train cell) and again at L 2048 and 8192 and at a
+#: head of 64 (``[2, 32, 2048, 64]``, ``[1, 16, 4096, 64]``): PERF.md section
+#: 6 has the tables. What they say: the forward kernel wants 1024 in every
+#: field (best or within 2 % of the best at all five shapes; a 2048-wide
+#: field loses 5-10 % or overruns the 16 MiB of scoped VMEM). The ``dkv``
+#: kernel wants major blocks of 1024 (2048 wins 4-7 % at L 8192 and loses
+#: 2-8 % at the four others); its minor blocks hardly matter from 256 up (2 %
+#: between them, inside the timing's noise), so they are 512: minors of 1024
+#: are refused for 0.9 MB of VMEM at a head of 256 and L 16384, and 512
+#: leaves a factor of two. The ``dq`` kernel wants 1024 rows of queries
+#: against K/V blocks of 512 (a 1024-wide ``block_k_major_dq`` costs 8-30 %:
+#: the library broadcasts ``di`` to that many lanes in HBM). The head's width
+#: moved no winner (64 against 128), so the caps depend on nothing.
+_FWD_CAP = 1024          # block_q, block_k_major, block_k
+_BWD_MAJOR_CAP = 1024    # block_q_major_dkv, block_k_major_dkv, block_q_dq
+_BWD_MINOR_CAP = 512     # block_q_dkv, block_k_dkv, block_k_major_dq,
+#                          block_k_dq
+
+#: Forward blocks (block_q, block_k_major, block_k) per sequence length from
+#: an on-chip record, where one exists: records/flash_autotune.json, which
+#: benchmarks/tpu_kernels.py writes (forward kernel only) and no run has yet.
+#: Loaded once; the backward fields come from the caps above either way.
 _AUTOTUNE_CACHE: Optional[dict] = None
 import os as _os
 _AUTOTUNE_PATH = _os.path.join(_os.path.dirname(_os.path.dirname(
@@ -217,19 +242,32 @@ def _autotune_table() -> dict:
                                               int(row["block_k_major"]),
                                               int(row["block_k"]))
         except FileNotFoundError:
-            pass  # no on-chip sweep recorded: the heuristic applies
+            pass  # no on-chip sweep recorded: the caps apply
         _AUTOTUNE_CACHE = table
     return _AUTOTUNE_CACHE
 
 
+def _block(cap: int, seq_len: int) -> int:
+    """The largest of 128, 256, 512, ... up to ``cap`` that divides
+    ``seq_len``: the blocks the sweep timed, and never one that does not
+    tile L (L 640 gets 128 and L 1536 gets 512; the kernel refuses a block
+    that leaves a remainder, at the caller's jit). A length that no multiple
+    of 128 divides is one block."""
+    b = cap
+    while b >= 128:
+        if seq_len % b == 0:
+            return b
+        b //= 2
+    return seq_len
+
+
 def flash_block_sizes(seq_len: int, head_dim: int = 128):
-    """BlockSizes for the Mosaic kernel: fwd blocks autotuned if an on-chip
-    record exists for this (L, head_dim), else a v5e-oriented heuristic
-    (512-wide where they tile). Backward blocks stay at a conservative 128
-    — the sweep only ever times the forward kernel, so copying tuned fwd
-    blocks into the never-validated dkv/dq fields risks a bwd compile
-    failure that surfaces at the *caller's* jit, where no fallback can
-    catch it."""
+    """BlockSizes for the three Mosaic kernels: every field is the largest
+    power-of-two multiple of 128 under its kernel's cap that divides
+    ``seq_len`` (the caps and the on-chip sweep behind them are above
+    ``_FWD_CAP``). Where an on-chip record holds forward blocks for this
+    (L, head_dim) and they tile L, the forward fields are the record's; the
+    backward fields are the rule's in both branches."""
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
     table = _autotune_table()
@@ -237,13 +275,14 @@ def flash_block_sizes(seq_len: int, head_dim: int = 128):
     if tuned is not None and all(seq_len % b == 0 for b in tuned):
         bq, bkm, bk = tuned
     else:
-        bq = bkm = bk = min(512, seq_len)
-    bwd = min(128, seq_len)
+        bq = bkm = bk = _block(_FWD_CAP, seq_len)
+    major = _block(_BWD_MAJOR_CAP, seq_len)
+    minor = _block(_BWD_MINOR_CAP, seq_len)
     return BlockSizes(
         block_q=bq, block_k_major=bkm, block_k=bk, block_b=1,
-        block_q_major_dkv=bwd, block_k_major_dkv=bwd,
-        block_k_dkv=bwd, block_q_dkv=bwd,
-        block_k_major_dq=bwd, block_k_dq=bwd, block_q_dq=bwd,
+        block_q_major_dkv=major, block_k_major_dkv=major,
+        block_k_dkv=minor, block_q_dkv=minor,
+        block_k_major_dq=minor, block_k_dq=minor, block_q_dq=major,
     )
 
 

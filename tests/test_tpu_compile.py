@@ -96,21 +96,31 @@ def _assert_vocabulary_sorted_under_a_conditional(compiled, S, V):
     assert f"f32[{S * V}]" in text and f"f32[{S * V}]" not in entry
 
 
-@pytest.mark.parametrize("heads,kv_heads,head_dim", [(32, 8, 64),
-                                                     (16, 8, 128)])
-def test_mosaic_flash_fwd_bwd_L2048(one_chip, heads, kv_heads, head_dim):
-    """The library kernel through ``_tpu_flash`` with the blocks
-    ``flash_block_sizes`` returns: forward blocks from the autotune table
-    or the 512 heuristic, backward blocks pinned at 128."""
-    L = 2048
+@pytest.mark.parametrize("B,L,heads,kv_heads,head_dim", [
+    (2, 2048, 32, 8, 64),      # chip_smoke.py's LLAMA3_1B
+    (2, 2048, 16, 8, 128),
+    (1, 4096, 16, 4, 128),     # one chip's share of train-fsdp2-tp2
+    (1, 8192, 16, 4, 128),
+    (1, 640, 8, 2, 128),       # only 128 tiles it (min(512, L) handed it 512)
+    (1, 1536, 8, 2, 64),       # 512 tiles it, 1024 does not
+    (1, 4096, 8, 2, 256),
+    (1, 16384, 8, 2, 256),     # dkv minors of 1024 overran scoped VMEM here
+])
+def test_mosaic_flash_fwd_bwd(one_chip, B, L, heads, kv_heads, head_dim):
+    """The library's three kernels through ``_tpu_flash`` with the blocks
+    ``flash_block_sizes`` returns from its rule of the length (PR 50; no
+    autotune record exists), at the shapes the chip sweep ran and at
+    lengths and heads it did not: a block that does not tile L, or a tile
+    too large for the 16 MiB of scoped VMEM, is refused here and not at a
+    trainer's jit."""
     scale = head_dim ** -0.5
 
     def loss(q, k, v):
         return attention._tpu_flash(q, k, v, True, scale).astype(
             jnp.float32).sum()
 
-    q = _shape(one_chip, (2, L, heads, head_dim))
-    kv = _shape(one_chip, (2, L, kv_heads, head_dim))
+    q = _shape(one_chip, (B, L, heads, head_dim))
+    kv = _shape(one_chip, (B, L, kv_heads, head_dim))
     _assert_kernel(jax.jit(loss).lower(q, kv, kv).compile())
     _assert_kernel(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile())
