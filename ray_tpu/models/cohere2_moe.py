@@ -44,8 +44,9 @@ import jax.numpy as jnp
 
 from ..ops.layers import layer_norm, rope_interleaved, rope_rows
 from ..ops.quant import mm
-from ..parallel.moe import moe_ffn_grouped, moe_ffn_share, sigmoid_gates
-from .engine import _pick_tokens
+from ..parallel.moe import (expert_share,  # noqa: F401 (re-export)
+                            moe_ffn_grouped, moe_ffn_share, sigmoid_gates)
+from .engine import _sample, prefill_in_chunks
 from .paged_ops import (attend_pages_blocked, attend_ring, block_pages_of,
                         ring_rows, write_kv, write_ring)
 
@@ -169,19 +170,6 @@ def init_params(cfg: Cohere2MoeConfig, key: jax.Array) -> Dict[str, Any]:
                     "w_down": _normal(k[11], (eh, f, d), dt)},
         })
     return params
-
-
-def expert_share(params: Dict[str, Any], offset: int, held: int
-                 ) -> Dict[str, Any]:
-    """The tree of one chip of a deployment that divides each layer's routed
-    experts: experts ``offset .. offset + held - 1`` of a tree that holds
-    them all; everything else (attention, the shared experts, the router
-    over all outputs, the norms) is on every chip alike."""
-    layers = [{**lyr, "moe": {
-        **lyr["moe"], **{w: lyr["moe"][w][offset:offset + held]
-                         for w in ("w_gate", "w_up", "w_down")}}}
-        for lyr in params["layers"]]
-    return {**params, "layers": layers}
 
 
 # ------------------------------------------------------------------- layers
@@ -367,29 +355,15 @@ def _cohere_prefill_chunk(params, tokens, start, n_valid, bufs, cfg):
 
 def prefill(params, prompt, total: int, cfg: Cohere2MoeConfig,
             keep_routing: bool = False):
-    """Prefill one request chunk by chunk (a host loop over ONE program, so
-    the work grows with the prompt in steps of ``prefill_chunk`` and nothing
-    compiles per length). -> (next-token logits, per layer the (K, V) rows
-    [total, kvh, d]: a full layer's for the page scatter, a window layer's
-    for its ring; with ``keep_routing`` also every prompt position's chosen
-    experts [layers, len(prompt), k])."""
-    import numpy as np
-
-    n, C = len(prompt), cfg.prefill_chunk
-    bufs = prefill_carry(cfg, total)
-    chunks = -(-n // C)
-    padded = np.zeros(chunks * C, np.int32)
-    padded[:n] = prompt
-    routing = []
-    for c in range(chunks):
-        first, bufs, idx = _cohere_prefill_chunk(
-            params, padded[c * C:(c + 1) * C], np.int32(c * C), np.int32(n),
-            bufs, cfg)
-        if keep_routing:
-            routing.append(idx)
-    if keep_routing:
-        return first, bufs, np.asarray(jnp.concatenate(routing, 1))[:, :n]
-    return first, bufs
+    """Prefill one request chunk by chunk (``engine.prefill_in_chunks`` over
+    ``_cohere_prefill_chunk``). -> (next-token logits, per layer the (K, V)
+    rows [total, kvh, d]: a full layer's for the page scatter, a window
+    layer's for its ring; with ``keep_routing`` also every prompt position's
+    chosen experts [layers, len(prompt), k])."""
+    first, (bufs,), routing = prefill_in_chunks(
+        _cohere_prefill_chunk, params, prompt, cfg.prefill_chunk,
+        (prefill_carry(cfg, total),), cfg, keep_routing)
+    return (first, bufs, routing) if keep_routing else (first, bufs)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -474,8 +448,6 @@ def _cohere_step(params, pools_k, pools_v, rings_k, rings_v, tables, toks,
     logits, new_k, new_v, new_rk, new_rv, counts, routing = _decode_logits(
         params, pools_k, pools_v, rings_k, rings_v, tables, toks, lengths,
         cfg, page)
-    splits = jax.vmap(jax.random.split)(keys)
-    picked = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
-                          lengths).astype(jnp.int32)
-    return (jnp.concatenate([picked, counts]), new_k, new_v, new_rk, new_rv,
-            splits[:, 0], routing, picked)
+    out, new_keys, picked = _sample(logits, temps, top_ks, top_ps, keys,
+                                    lengths, counts)
+    return out, new_k, new_v, new_rk, new_rv, new_keys, routing, picked

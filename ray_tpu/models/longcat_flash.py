@@ -52,8 +52,9 @@ import jax.numpy as jnp
 from ..ops.layers import (rms_norm, rope_interleaved as _rope,
                           rope_rows as _rope_rows)
 from ..ops.quant import mm
-from ..parallel.moe import moe_ffn_zero, softmax_gates
-from .engine import _pick_tokens
+from ..parallel.moe import (expert_share,  # noqa: F401 (re-export)
+                            moe_ffn_zero, softmax_gates)
+from .engine import _sample, prefill_in_chunks
 from .paged_ops import attend_latent, latent_pages, write_latent
 
 F32 = jnp.float32
@@ -246,19 +247,6 @@ def calibrate_router_bias(params, cfg: LongcatFlashConfig, key: jax.Array,
                                  in zip(params["layers"], layers)]}
 
 
-def expert_share(params: Dict[str, Any], offset: int, held: int
-                 ) -> Dict[str, Any]:
-    """The tree of one chip of a deployment that divides each layer's
-    computing experts: experts ``offset .. offset + held - 1`` of a tree that
-    holds them all; everything else (the router over all outputs, its zero
-    experts) is on every chip alike."""
-    layers = [{**lyr, "moe": {
-        **lyr["moe"], **{w: lyr["moe"][w][offset:offset + held]
-                         for w in ("w_gate", "w_up", "w_down")}}}
-        for lyr in params["layers"]]
-    return {**params, "layers": layers}
-
-
 # ---------------------------------------------------------------- sublayers
 def _latent_qkv(att, h, cos, sin, cfg: LongcatFlashConfig):
     """h [N, D] at the positions of cos / sin -> (q_nope [N, H, dn], rotated
@@ -428,28 +416,14 @@ def _longcat_prefill_chunk(params, tokens, start, n_valid, lats, cfg):
 
 def prefill(params, prompt, total: int, cfg: LongcatFlashConfig,
             keep_routing: bool = False):
-    """Prefill one request chunk by chunk (a host loop over ONE program, so
-    the work grows with the prompt in steps of ``prefill_chunk`` and nothing
-    compiles per length). -> (next-token logits, per sublayer the cache rows
-    [total, C + dr] for the page scatter; with ``keep_routing`` also every
-    prompt position's chosen experts [layers, len(prompt), k])."""
-    import numpy as np
-
-    n, C = len(prompt), cfg.prefill_chunk
-    lats = prefill_carry(cfg, total)
-    chunks = -(-n // C)
-    padded = np.zeros(chunks * C, np.int32)
-    padded[:n] = prompt
-    routing = []
-    for c in range(chunks):
-        first, lats, idx = _longcat_prefill_chunk(
-            params, padded[c * C:(c + 1) * C], np.int32(c * C), np.int32(n),
-            lats, cfg)
-        if keep_routing:
-            routing.append(idx)
-    if keep_routing:
-        return first, lats, np.asarray(jnp.concatenate(routing, 1))[:, :n]
-    return first, lats
+    """Prefill one request chunk by chunk (``engine.prefill_in_chunks`` over
+    ``_longcat_prefill_chunk``). -> (next-token logits, per sublayer the
+    cache rows [total, C + dr] for the page scatter; with ``keep_routing``
+    also every prompt position's chosen experts [layers, len(prompt), k])."""
+    first, (lats,), routing = prefill_in_chunks(
+        _longcat_prefill_chunk, params, prompt, cfg.prefill_chunk,
+        (prefill_carry(cfg, total),), cfg, keep_routing)
+    return (first, lats, routing) if keep_routing else (first, lats)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -518,8 +492,6 @@ def _longcat_step(params, pools, tables, toks, lengths, temps, top_ks,
     let the engine dispatch that step before it has fetched this one's)."""
     logits, new, counts, routing = _decode_logits(
         params, pools, tables, toks, lengths, cfg, page)
-    splits = jax.vmap(jax.random.split)(keys)
-    picked = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
-                          lengths).astype(jnp.int32)
-    return (jnp.concatenate([picked, counts]), new, splits[:, 0], routing,
-            picked)
+    out, new_keys, picked = _sample(logits, temps, top_ks, top_ps, keys,
+                                    lengths, counts)
+    return out, new, new_keys, routing, picked

@@ -37,11 +37,12 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import linear_attn
 from ..ops.layers import apply_rope, rms_norm, rope_rows as _rope_rows
 from ..ops.quant import mm
-from .engine import _pick_tokens
+from .engine import _sample, prefill_in_chunks
 from .llama import _mlp_block
 from .paged_ops import (attend_chosen, attend_pages, choose_block_mask,
                         select_pages, write_ckeys, write_kv)
@@ -416,30 +417,17 @@ def _sala_prefill_chunk(params, tokens, start, n_valid, states, kv, ckeys,
 
 def prefill(params, prompt, total: int, cfg: MiniCPMSALAConfig,
             keep_chosen: bool = False):
-    """Prefill one request chunk by chunk (a host loop over ONE program, so
-    the work grows with the prompt in steps of ``prefill_chunk`` and nothing
-    compiles per length). -> (next-token logits, per sparse layer the dense
-    (k, v) and the compressed keys [total / stride + 1, ...] for the page
-    scatter, per lightning layer the state at ``len(prompt)``; with
+    """Prefill one request chunk by chunk (``engine.prefill_in_chunks`` over
+    ``_sala_prefill_chunk``). -> (next-token logits, per sparse layer the
+    dense (k, v) and the compressed keys [total / stride + 1, ...] for the
+    page scatter, per lightning layer the state at ``len(prompt)``; with
     ``keep_chosen`` also every position's chosen blocks
     [sparse layers, len(prompt), kvh, topk])."""
-    import numpy as np
-
-    n, C = len(prompt), cfg.prefill_chunk
-    states, kv, ckeys = prefill_carry(cfg, total)
-    chunks = -(-n // C)
-    padded = np.zeros(chunks * C, np.int32)
-    padded[:n] = prompt
-    chosen = []
-    for c in range(chunks):
-        first, states, kv, ckeys, mask = _sala_prefill_chunk(
-            params, padded[c * C:(c + 1) * C], np.int32(c * C), np.int32(n),
-            states, kv, ckeys, cfg)
-        if keep_chosen:
-            chosen.append(mask)
+    first, (states, kv, ckeys), masks = prefill_in_chunks(
+        _sala_prefill_chunk, params, prompt, cfg.prefill_chunk,
+        prefill_carry(cfg, total), cfg, keep_chosen)
     out = (first, (kv, ckeys), states)
     if keep_chosen:     # a mask holds topk blocks: their indices, ascending
-        masks = np.asarray(jnp.concatenate(chosen, axis=1))[:, :n]
         out += (np.argsort(~masks, axis=-1, kind="stable")[..., :cfg.topk],)
     return out
 
@@ -555,9 +543,6 @@ def _sala_step(params, pools_k, pools_v, pools_c, states, tables, toks,
     (logits, new_k, new_v, new_c, new_states, counts,
      chosen) = _decode_logits(params, pools_k, pools_v, pools_c, states,
                               tables, toks, lengths, cfg, page)
-    splits = jax.vmap(jax.random.split)(keys)
-    picked = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
-                          lengths)
-    picked = picked.astype(jnp.int32)
-    out = jnp.concatenate([picked, counts])
-    return out, new_k, new_v, new_c, new_states, splits[:, 0], chosen, picked
+    out, new_keys, picked = _sample(logits, temps, top_ks, top_ps, keys,
+                                    lengths, counts)
+    return out, new_k, new_v, new_c, new_states, new_keys, chosen, picked

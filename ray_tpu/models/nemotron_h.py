@@ -37,7 +37,7 @@ from ..ops import ssm
 from ..ops.layers import rms_norm
 from ..ops.quant import mm
 from ..parallel.moe import moe_ffn_share, relu2, sigmoid_gates
-from .engine import _pick_tokens
+from .engine import _sample
 from .paged_ops import paged_attention
 
 F32 = jnp.float32
@@ -485,10 +485,7 @@ def _hybrid_step(params, pools_k, pools_v, scales_k, scales_v, ssm_states,
      load, routing) = _decode_logits(params, pools_k, pools_v, scales_k, scales_v,
                             ssm_states, conv_tails, tables, toks, lengths,
                             cfg, page, kv_int8)
-    splits = jax.vmap(jax.random.split)(keys)
-    picked = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
-                          lengths)
-    picked = picked.astype(jnp.int32)
-    out = jnp.concatenate([picked, load])
-    return (out, new_k, new_v, new_sk, new_sv, new_ssm, new_conv,
-            splits[:, 0], routing, picked)
+    out, new_keys, picked = _sample(logits, temps, top_ks, top_ps, keys,
+                                    lengths, load)
+    return (out, new_k, new_v, new_sk, new_sv, new_ssm, new_conv, new_keys,
+            routing, picked)

@@ -23,7 +23,7 @@ from __future__ import annotations
 import functools
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Union
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +32,7 @@ import numpy as np
 from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..util import events as plane_events
-from .engine import _pick_one, _pick_tokens, _prefill_one
+from .engine import _pick_one, _prefill_one, _sample
 from .paged_ops import (_quant_kv, block_pages_of,  # noqa: F401
                         latent_pool_shape, paged_attention)  # (re-exports)
 from .llama import LlamaConfig, _mlp_block
@@ -102,11 +102,9 @@ def _paged_step(params, pools_k, pools_v, scales_k, scales_v, tables,
     head = (params["embedding"].T if cfg.tie_embeddings
             else params["lm_head"])
     logits = mm(x[:, 0], head)                     # [S, V]
-    splits = jax.vmap(jax.random.split)(keys)
-    out = _pick_tokens(logits, temps, top_ks, top_ps, splits[:, 1],
-                       lengths)
+    out, new_keys, _ = _sample(logits, temps, top_ks, top_ps, keys, lengths)
     return (out, new_pools_k, new_pools_v, new_scales_k, new_scales_v,
-            splits[:, 0])
+            new_keys)
 
 
 @functools.partial(jax.jit, static_argnames=("page", "kv_int8"),
@@ -160,13 +158,10 @@ def _suffix_prefill(params, prefix_caches, suffix_padded, prefix_len,
     return first, [(kc[0], vc[0]) for kc, vc in new]
 
 
-# --------------------------------------------- families beside the dense one
-# What a family brings, by the type of its config: how many layers have a
-# pool and, where a position's row is not K beside V, its shape; what it
-# keeps beside the pools (per-slot state, a second pool, what a check
-# reads); the prefill, the scatter where it has more or other than K/V to
-# scatter, and the step. Each takes the engine; the state write
-# (``_write_state``) is the same for all that have per-slot state.
+# ------------------------------------------------------------ the families
+# What a family brings, by the type of its config: ``_Family``'s fields say
+# what each callable is given and returns. Each takes the engine; a family's
+# module knows nothing of it.
 def _read_block(eng):
     """The positions a block of ``paged_attention``'s read holds in ``eng``'s
     step, by the rule the step itself takes its blocks from: what the step
@@ -174,6 +169,71 @@ def _read_block(eng):
     cfg = eng.cfg
     return eng.page * block_pages_of(eng.S, eng.P, eng.page, cfg.n_kv_heads,
                                      cfg.head_dim, cfg.dtype)
+
+
+def _scales(eng):
+    """The int8 scales as ``_paged_step`` and ``_hybrid_step`` take them; in
+    the model's dtype there are none, and the programs take placeholders
+    (donated aliases of what they return)."""
+    return ((eng.scales_k, eng.scales_v) if eng.kv_int8
+            else (eng._no_scales, eng._no_scales))
+
+
+def _scatter_kv(eng, caches, page_ids):
+    """One dispatch of ``_scatter_pages``, which consumes the pools it is
+    given, as the step does: both leave ``eng.pools_*`` the only handles."""
+    (eng.pools_k, eng.pools_v, eng.scales_k,
+     eng.scales_v) = _scatter_pages(
+        eng.pools_k, eng.pools_v, eng.scales_k, eng.scales_v, caches,
+        page_ids, np.float32(127.0), eng.page, eng.kv_int8)
+
+
+def _write_slot_state(eng, state, slot, n):
+    eng.ssm, eng.conv = _write_state(eng.ssm, eng.conv, state,
+                                     np.int32(slot))
+
+
+def _dense_state(eng):
+    cfg = eng.cfg
+    eng.cos, eng.sin = rope_frequencies(cfg.head_dim, eng.max_len,
+                                        cfg.rope_theta)
+    eng._read_block = _read_block(eng)  # ``_paged_step``: paged_attention
+
+
+def _dense_prefill(eng, suffix, pad, n, shared):
+    """The whole prompt, or (seeded with the shared prefix's K/V gathered
+    from its cached pages) only the suffix, the compute the cache saves."""
+    cfg = eng.cfg
+    padded = jnp.asarray(suffix + [0] * (pad - len(suffix)), dtype=jnp.int32)
+    if not shared:
+        return _prefill_one(eng.params, padded, n, eng.max_len, cfg, eng.cos,
+                            eng.sin, pad) + (None,)
+    L0 = len(shared) * eng.page
+    tbl = jnp.asarray(shared, dtype=jnp.int32)
+
+    def prefix(pool, scale):
+        rows = pool[tbl].reshape(L0, cfg.n_kv_heads, cfg.head_dim)
+        if eng.kv_int8:     # dequantize borrowed pages
+            rows = rows.astype(cfg.dtype) * scale[tbl].reshape(
+                L0, cfg.n_kv_heads, 1).astype(cfg.dtype)
+        return jnp.concatenate([rows, jnp.zeros(
+            (eng.max_len - L0,) + rows.shape[1:], rows.dtype)])
+
+    prefix_caches = [(prefix(pk, sk), prefix(pv, sv)) for pk, pv, sk, sv
+                     in zip(eng.pools_k, eng.pools_v, eng.scales_k,
+                            eng.scales_v)]
+    return _suffix_prefill(
+        eng.params, prefix_caches, padded, jnp.int32(L0), jnp.int32(n),
+        eng.max_len, cfg, eng.cos, eng.sin, pad) + (None,)
+
+
+def _dense_step(eng, uploads):
+    toks, eng.pools_k, eng.pools_v, sk, sv, new_keys = _paged_step(
+        eng.params, eng.pools_k, eng.pools_v, *_scales(eng), *uploads,
+        eng.cfg, eng.cos, eng.sin, eng.page, eng.kv_int8)
+    if eng.kv_int8:
+        eng.scales_k, eng.scales_v = sk, sv
+    return toks, new_keys, toks, None   # the tokens alone; nothing to publish
 
 
 def _nemotron_state(eng):
@@ -184,18 +244,20 @@ def _nemotron_state(eng):
     eng.last_routing = None
 
 
-def _nemotron_prefill(eng, suffix, pad, n):
+def _nemotron_prefill(eng, suffix, pad, n, shared):
     padded = jnp.asarray(suffix + [0] * (pad - len(suffix)), dtype=jnp.int32)
     return _hybrid_prefill(eng.params, padded, n, eng.max_len, eng.cfg,
                            pad)[:3]
 
 
-def _nemotron_step(eng, scales, uploads):
+def _nemotron_step(eng, uploads):
     (toks, eng.pools_k, eng.pools_v, sk, sv, eng.ssm, eng.conv, new_keys,
      routing, next_tok) = _hybrid_step(
-        eng.params, eng.pools_k, eng.pools_v, *scales, eng.ssm, eng.conv,
-        *uploads, eng.cfg, eng.page, eng.kv_int8)
-    return toks, sk, sv, new_keys, (next_tok, routing)
+        eng.params, eng.pools_k, eng.pools_v, *_scales(eng), eng.ssm,
+        eng.conv, *uploads, eng.cfg, eng.page, eng.kv_int8)
+    if eng.kv_int8:
+        eng.scales_k, eng.scales_v = sk, sv
+    return toks, new_keys, next_tok, routing
 
 
 def _nemotron_counts(eng, tail, sp):
@@ -207,12 +269,10 @@ def _nemotron_counts(eng, tail, sp):
 
 def _sala_state(eng):
     cfg = eng.cfg
-    if eng.page != cfg.block or eng.max_len % cfg.prefill_chunk \
-            or eng.kv_int8 or cfg.topk > eng.P:
+    if eng.page != cfg.block or cfg.topk > eng.P:
         raise ValueError(
-            "this family's block is the page (page_size == cfg.block), its "
-            "prefill fills max_len in whole chunks, its table holds topk "
-            "pages and its pages are kept in the model's dtype")
+            "this family's block is the page (page_size == cfg.block) and "
+            "its table holds topk pages")
     eng.ssm, eng.conv = sala.init_state(cfg, eng.S), []
     # beside each sparse layer's K/V pool the indexer's cache: one
     # compressed key per ``stride`` positions, on the page of its first
@@ -224,7 +284,7 @@ def _sala_state(eng):
     eng.last_selection = None
 
 
-def _sala_prefill(eng, suffix, pad, n):
+def _sala_prefill(eng, suffix, pad, n, shared):
     first, caches, states = sala.prefill(
         eng.params, suffix, eng.max_len, eng.cfg)
     return first, caches, [(s,) for s in states]
@@ -237,18 +297,16 @@ def _sala_scatter(eng, caches, page_ids):
 
 
 def _sala_admit_fields(eng, n):
-    """What an admission's prefill and scatter spans say besides: the
-    chunk program's dispatches, the compressed-key rows scattered."""
-    return ({"chunks": -(-n // eng.cfg.prefill_chunk)},
-            {"ckeys": eng.max_len // eng.cfg.stride * eng.n_kv})
+    # the compressed-key rows scattered
+    return {"ckeys": eng.max_len // eng.cfg.stride * eng.n_kv}, {}
 
 
-def _sala_step(eng, scales, uploads):
+def _sala_step(eng, uploads):
     (toks, eng.pools_k, eng.pools_v, eng.pools_c, eng.ssm, new_keys,
      chosen, next_tok) = sala._sala_step(
         eng.params, eng.pools_k, eng.pools_v, eng.pools_c, eng.ssm,
         *uploads, eng.cfg, eng.page)
-    return toks, None, None, new_keys, (next_tok, chosen)
+    return toks, new_keys, next_tok, chosen
 
 
 def _sala_landed(eng, chosen):
@@ -261,16 +319,12 @@ def _sala_counts(eng, tail, sp):
 
 
 def _longcat_state(eng):
-    if eng.max_len % eng.cfg.prefill_chunk or eng.kv_int8:
-        raise ValueError(
-            "this family's prefill fills max_len in whole chunks and its "
-            "latent pages are kept in the model's dtype")
     # the last step's chosen experts [layers, S, k]: left on the device, for
     # a reference check to read
     eng.last_routing = None
 
 
-def _longcat_prefill(eng, suffix, pad, n):
+def _longcat_prefill(eng, suffix, pad, n, shared):
     first, lats = longcat.prefill(eng.params, suffix, eng.max_len, eng.cfg)
     return first, lats, None
 
@@ -280,14 +334,13 @@ def _longcat_scatter(eng, lats, page_ids):
 
 
 def _longcat_admit_fields(eng, n):
-    return ({"chunks": -(-n // eng.cfg.prefill_chunk)},
-            {"latent_rows": eng.max_len * eng.n_kv})
+    return {"latent_rows": eng.max_len * eng.n_kv}, {}
 
 
-def _longcat_step(eng, scales, uploads):
+def _longcat_step(eng, uploads):
     toks, eng.pools_k, new_keys, routing, next_tok = longcat._longcat_step(
         eng.params, eng.pools_k, *uploads, eng.cfg, eng.page)
-    return toks, None, None, new_keys, (next_tok, routing)
+    return toks, new_keys, next_tok, routing
 
 
 def _routing_landed(eng, routing):
@@ -302,12 +355,6 @@ def _longcat_counts(eng, tail, sp):
 
 def _cohere_state(eng):
     cfg = eng.cfg
-    if eng.max_len % cfg.prefill_chunk or eng.kv_int8:
-        raise ValueError(
-            "this family's prefill fills max_len in whole chunks, and its "
-            "window layers' rings are kept in the model's dtype beside pages "
-            "of the same (kv_dtype='int8' would quantise one kind of layer "
-            "and not the other)")
     # beside the full layers' pools, each window layer's K/V as a ring a
     # slot: position p at index p mod window, sized by the slots and the
     # model's window whatever a slot's context; no table, no allocator
@@ -321,7 +368,7 @@ def _cohere_state(eng):
     eng.last_routing = None
 
 
-def _cohere_prefill(eng, suffix, pad, n):
+def _cohere_prefill(eng, suffix, pad, n, shared):
     first, bufs = cohere.prefill(eng.params, suffix, eng.max_len, eng.cfg)
     full, window = ([b for kind, b in zip(eng.cfg.kinds, bufs) if kind == k]
                     for k in (cohere.FULL, cohere.WINDOW))
@@ -334,17 +381,16 @@ def _cohere_write_state(eng, rows, slot, n):
 
 
 def _cohere_admit_fields(eng, n):
-    return ({"chunks": -(-n // eng.cfg.prefill_chunk)}, {},
-            {"ring_positions": min(n, eng.cfg.sliding_window)
-             * eng.cfg.n_window_layers})
+    return {}, {"ring_positions": min(n, eng.cfg.sliding_window)
+                * eng.cfg.n_window_layers}
 
 
-def _cohere_step(eng, scales, uploads):
+def _cohere_step(eng, uploads):
     (toks, eng.pools_k, eng.pools_v, eng.rings_k, eng.rings_v, new_keys,
      routing, next_tok) = cohere._cohere_step(
         eng.params, eng.pools_k, eng.pools_v, eng.rings_k, eng.rings_v,
         *uploads, eng.cfg, eng.page)
-    return toks, None, None, new_keys, (next_tok, routing)
+    return toks, new_keys, next_tok, routing
 
 
 def _cohere_counts(eng, tail, sp):
@@ -358,22 +404,25 @@ class _Family:
     n_kv: object            # cfg -> layers (or sublayers) with a pool
     state: object           # engine -> None: allocates what the family
     #                         keeps beside the pools, refuses what it cannot
-    prefill: object         # (engine, prompt, pad, n) -> (first logits,
-    #                         caches, per-layer state tuples)
-    step: object            # (engine, scales, uploads) -> (tokens and the
-    #                         step's counts, scales_k, scales_v, keys, (the
-    #                         tokens alone as the next step takes them, what
-    #                         ``landed`` publishes)): with the tokens alone
-    #                         the engine runs ahead of the device, ``_step``
-    #                         (the dense family's, a branch there, does too)
-    counts: object          # (engine, what rode with the tokens, the step's
+    prefill: object         # (engine, the prompt less its shared prefix,
+    #                         pad, prompt length, the prefix's pages) ->
+    #                         (first logits, caches, per-layer state or None)
+    step: object            # (engine, uploads) -> (the tokens and the step's
+    #                         counts, the keys, the tokens alone as the next
+    #                         step takes them: with them the engine runs
+    #                         ahead of the device, ``_step``; what ``landed``
+    #                         publishes or None). It rebinds what the
+    #                         program consumed
+    counts: object = None   # (engine, what rode with the tokens, the step's
     #                         span): the family's fields of the step row
-    scatter: object = None  # (engine, caches, page_ids), where the prefill
-    #                         returns more than K/V
-    admit_fields: object = None     # (engine, prompt length) -> fields of
-    #                         the admission's prefill and scatter spans
-    buckets: tuple = (16, 64, 256)  # what a prompt is padded to under
-    #                         max_len; none where it is admitted in chunks
+    scatter: object = _scatter_kv   # (engine, caches, page_ids): its own
+    #                         where the prefill returns other than K/V
+    admit_fields: object = None     # (engine, prompt length) -> its fields
+    #                         of the admission's scatter and state spans
+    chunked: bool = False   # its prompts are admitted in chunks of
+    #                         ``cfg.prefill_chunk``, not padded to a bucket:
+    #                         ``max_len`` is a whole number of chunks and
+    #                         the prefill span says ``chunks``
     landed: object = None   # (engine, what the step kept on the device):
     #                         called when that step's tokens are fetched
     pool_shape: object = None   # (cfg, num_pages, page_size) -> the shape
@@ -381,34 +430,67 @@ class _Family:
     #                         not (n_kv_heads, head_dim) of keys beside the
     #                         same of values: the layer then has ONE pool
     #                         and no V pool
-    write_state: object = None  # (engine, the prefill's state, slot, prompt
-    #                         length), where the per-slot state is not
-    #                         ``self.ssm`` / ``self.conv``
-    no_prefix_cache: str = (    # why ``enable_prefix_cache`` is refused
+    write_state: object = _write_slot_state     # (engine, the prefill's
+    #                         state, slot, prompt length): its own where the
+    #                         state is not ``eng.ssm`` / ``eng.conv``
+    no_prefix_cache: Optional[str] = (  # why ``enable_prefix_cache`` is
+        #                     refused; None where the family has one
         "snapshots of recurrent state at page boundaries; a prefill of the "
         "suffix alone over latent pages")
+    no_int8: Optional[str] = None   # why ``kv_dtype="int8"`` is refused;
+    #                         None where the step reads int8 pages
+
+    @property
+    def buckets(self) -> tuple:
+        """What a prompt is padded to under ``max_len``."""
+        return () if self.chunked else (16, 64, 256)
 
 
 _FAMILIES = {
+    # a K/V pool for every layer; the one family with a prefix cache
+    LlamaConfig: _Family(
+        lambda cfg: cfg.n_layers, _dense_state, _dense_prefill, _dense_step,
+        no_prefix_cache=None),
+    # pools for the attention layers only, per-slot recurrent state of the
+    # Mamba layers beside them (``eng.ssm`` / ``eng.conv``): written whole at
+    # admission, advanced by the step for all slots, donated to both
     NemotronHConfig: _Family(
         lambda cfg: cfg.n_attn_layers, _nemotron_state, _nemotron_prefill,
         _nemotron_step, _nemotron_counts, landed=_routing_landed),
+    # pools for the sparse layers only, a compressed-key pool beside each
+    # (``eng.pools_c``: the cache of the layer's block selection, on the same
+    # pages) and per-slot lightning state (``eng.ssm``)
     MiniCPMSALAConfig: _Family(
         lambda cfg: cfg.n_sparse_layers, _sala_state, _sala_prefill,
-        _sala_step, _sala_counts, _sala_scatter, _sala_admit_fields, (),
-        _sala_landed),
+        _sala_step, _sala_counts, _sala_scatter, _sala_admit_fields,
+        chunked=True, landed=_sala_landed,
+        no_int8="its step reads its pages, and the compressed keys beside "
+                "them, in the model's dtype"),
+    # ONE pool for each of the ``2 x layers`` latent-attention sublayers, a
+    # position's row the compressed latent and the one rotary key all heads
+    # share (no V pool, no per-slot state); admitted through the expanded
+    # attention form, stepped in the absorbed form
     LongcatFlashConfig: _Family(
         lambda cfg: cfg.n_sublayers, _longcat_state, _longcat_prefill,
         _longcat_step, _longcat_counts, _longcat_scatter,
-        _longcat_admit_fields, (), _routing_landed,
-        lambda cfg, pages, page: latent_pool_shape(
-            pages, page, cfg.latent_width)),
+        _longcat_admit_fields, chunked=True, landed=_routing_landed,
+        pool_shape=lambda cfg, pages, page: latent_pool_shape(
+            pages, page, cfg.latent_width),
+        no_int8="its latent pages are kept in the model's dtype"),
+    # K/V pools for the full-attention layers only, read in blocks of table
+    # columns, and each window layer's K/V as a per-slot ring of the window's
+    # width (``eng.rings_k`` / ``eng.rings_v``), written and advanced as
+    # per-slot state is: a slot's window memory is fixed whatever its context
     Cohere2MoeConfig: _Family(
         lambda cfg: cfg.n_full_layers, _cohere_state, _cohere_prefill,
         _cohere_step, _cohere_counts, admit_fields=_cohere_admit_fields,
-        buckets=(), landed=_routing_landed, write_state=_cohere_write_state,
+        chunked=True, landed=_routing_landed,
+        write_state=_cohere_write_state,
         no_prefix_cache="window layers whose K/V is a per-slot ring: a ring "
-                        "is not shareable by page"),
+                        "is not shareable by page",
+        no_int8="its window layers' rings are kept in the model's dtype "
+                "beside pages of the same: int8 would quantise one kind of "
+                "layer and not the other"),
 }
 
 
@@ -465,35 +547,14 @@ class PagedEngine:
     the ones they are given: read them through the engine, between two
     calls, and keep no handle across one.
 
-    Which device programs run follows from the type of ``cfg``. A
-    ``LlamaConfig`` has a K/V pool for every layer. A ``NemotronHConfig``
-    has pools for its attention layers only and, beside them, per-slot
-    recurrent state of its Mamba layers (SSM state and convolution tail,
-    ``self.ssm`` / ``self.conv``): written whole at admission, advanced
-    by the step for all slots, donated to both. A ``MiniCPMSALAConfig``
-    has pools for its sparse layers only, a compressed-key pool beside each
-    (``self.pools_c``: the cache of the layer's block selection, on the
-    same pages) and per-slot lightning state (``self.ssm``); its prompts
-    are admitted in chunks. A ``LongcatFlashConfig`` has ONE pool for each of
-    its ``2 x layers`` latent-attention sublayers, a position's row the
-    compressed latent and the one rotary key all heads share
-    (``self.pools_k``; there is no V pool and no per-slot state); its prompts
-    are admitted in chunks through the expanded attention form and its step
-    attends in the absorbed form. A ``Cohere2MoeConfig`` mixes window and
-    full attention: K/V pools for its full-attention layers only, read in
-    blocks of table columns (no gather as wide as the table), and beside them
-    each window layer's K/V as a per-slot ring of the window's width
-    (``self.rings_k`` / ``self.rings_v``: position ``p`` at index ``p mod
-    window``), written whole at admission from the prefill's last positions
-    and advanced by the step for all slots, donated to both; a slot's window
-    memory is fixed whatever its context; its prompts are admitted in
-    chunks. Pages, tables, admission order, preemption by
-    recompute and the spans are the same code (``_FAMILIES`` holds what
-    differs).
+    Which device programs run follows from the type of ``cfg``: its row of
+    ``_FAMILIES`` holds what the family keeps beside the pools and how it
+    prefills, scatters and steps. Pages, tables, admission order, preemption
+    by recompute and the spans are the same code for every row.
 
     Every family's step program hands its tokens on as a device array
     (``_Family.step``; ``_paged_step``'s tokens are that array), and the
-    engine **runs ahead of the device** for all five: ``step()`` dispatches
+    engine **runs ahead of the device** for every row: ``step()`` dispatches
     the next step on the tokens and keys the last one left on the device,
     keeps up to ``_STEPS_AHEAD`` dispatched while every slot is held
     (``_STEPS_FREE_SLOT`` while one is free) and fetches the oldest's
@@ -511,11 +572,7 @@ class PagedEngine:
     read and written with nothing in flight.
     """
 
-    def __init__(self, params, cfg: Union[LlamaConfig, NemotronHConfig,
-                                          MiniCPMSALAConfig,
-                                          LongcatFlashConfig,
-                                          Cohere2MoeConfig], *,
-                 max_slots: int = 8,
+    def __init__(self, params, cfg, *, max_slots: int = 8,
                  num_pages: int = 64, page_size: int = 16,
                  max_len: int = 512, enable_prefix_cache: bool = False,
                  kv_dtype: str = "model"):
@@ -526,21 +583,17 @@ class PagedEngine:
         self.num_pages = num_pages
         self.P = max_len // page_size           # table width per slot
         self.max_len = self.P * page_size
-        self.family = _FAMILIES.get(type(cfg))
-        shape = None
-        if self.family:
-            if enable_prefix_cache:
-                raise ValueError(
-                    "enable_prefix_cache needs what this engine does not "
-                    f"keep for this family ({self.family.no_prefix_cache}): "
-                    "it runs without it")
-            self.n_kv = self.family.n_kv(cfg)
-            if self.family.pool_shape:
-                shape = self.family.pool_shape(cfg, num_pages, page_size)
-        else:
-            self.n_kv = cfg.n_layers
-            self.cos, self.sin = rope_frequencies(
-                cfg.head_dim, self.max_len, cfg.rope_theta)
+        # a subclass of a row's config (a configuration file's own class
+        # over ``LlamaConfig``) takes that row
+        fam = self.family = next((_FAMILIES[t] for t in type(cfg).__mro__
+                                  if t in _FAMILIES), None)
+        if fam is None:
+            raise TypeError(f"{type(cfg).__name__} has no row in _FAMILIES")
+        if enable_prefix_cache and fam.no_prefix_cache:
+            raise ValueError(
+                "enable_prefix_cache needs what this engine does not keep "
+                f"for this family ({fam.no_prefix_cache}): it runs without "
+                "it")
         if kv_dtype not in ("model", "int8"):
             raise ValueError("kv_dtype must be 'model' or 'int8'")
         # kv_dtype="int8": pages store per-head-vector-quantized K/V
@@ -548,9 +601,18 @@ class PagedEngine:
         # lever). Dequantize happens in the gather; outputs are CLOSE
         # to full precision, not bit-identical.
         self.kv_int8 = kv_dtype == "int8"
-        one_pool = shape is not None
-        if not one_pool:
-            shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        if self.kv_int8 and fam.no_int8:
+            raise ValueError(
+                f"kv_dtype='int8' is refused for this family: {fam.no_int8}")
+        if fam.chunked and self.max_len % cfg.prefill_chunk:
+            raise ValueError(
+                "this family's prefill fills max_len in whole chunks: "
+                f"max_len {self.max_len} is not a multiple of "
+                f"cfg.prefill_chunk {cfg.prefill_chunk}")
+        self.n_kv = fam.n_kv(cfg)
+        one_pool = fam.pool_shape is not None
+        shape = (fam.pool_shape(cfg, num_pages, page_size) if one_pool
+                 else (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim))
         pool_dt = jnp.int8 if self.kv_int8 else cfg.dtype
         self.pools_k = [jnp.zeros(shape, pool_dt)
                         for _ in range(self.n_kv)]
@@ -563,6 +625,8 @@ class PagedEngine:
         self.scales_v = [jnp.ones(sshape, jnp.float32)
                          for _ in range(self.n_kv)] \
             if self.kv_int8 else [None] * self.n_kv
+        # what a step program takes in their place in the model's dtype
+        self._no_scales = [0] * self.n_kv
         # Page 0 is a reserved scratch page: INACTIVE slots still flow
         # through the jitted step (static shapes) and their writes land
         # at tables[i,0]=0 / offset 0 — which must never be a page a
@@ -579,16 +643,12 @@ class PagedEngine:
                               for i in range(self.S)])
         self.pending: List[tuple] = []
         self._admit_events: List[tuple] = []
-        self._prefill_buckets = (16, 64, 256)
-        # the positions a block of the step's K/V read holds, set where a
-        # step that reads through ``paged_attention`` is bound (the dense
-        # family's here, the hybrid's in its ``state``); 0: no such read
+        self._prefill_buckets = fam.buckets
+        # the positions a block of the step's K/V read holds, set by the
+        # ``state`` of a family whose step reads through
+        # ``paged_attention``; 0: no such read
         self._read_block = 0
-        if self.family:
-            self._prefill_buckets = self.family.buckets
-            self.family.state(self)
-        else:
-            self._read_block = _read_block(self)
+        fam.state(self)
         self._kv_positions = None   # (read, live) of the step dispatched
         # what this step() did, for its ``serve.engine.step`` row
         self._steps = self._admitted = self._preempted = 0
@@ -766,18 +826,20 @@ class PagedEngine:
                 self.prefix_hits += 1
             elif self.enable_prefix_cache:
                 self.prefix_misses += 1
-            more = ({}, {}, {})     # of the prefill, scatter, state spans
-            if self.family and self.family.admit_fields:
-                more = self.family.admit_fields(self, n) + ({},)
+            fam = self.family
+            chunks = ({"chunks": -(-n // self.cfg.prefill_chunk)}
+                      if fam.chunked else {})
+            scattered, written = (fam.admit_fields(self, n)
+                                  if fam.admit_fields else ({}, {}))
             with plane_events.span("serve.admit.prefill", "serve",
-                                   rid=rid8, **more[0]):
-                first_logits, seq_caches, state = self._prefill(
-                    suffix, pad, shared, L0, n)
+                                   rid=rid8, **chunks):
+                first_logits, seq_caches, state = fam.prefill(
+                    self, suffix, pad, n, shared)
             self.tables[idx] = 0
             self.tables[idx, :len(slot.pages)] = slot.pages
             with plane_events.span("serve.admit.scatter", "serve",
                                    rid=rid8, pages=need, dispatches=1,
-                                   **more[1]):
+                                   **scattered):
                 self._scatter(seq_caches, slot.pages, len(shared))
             if self.enable_prefix_cache:
                 # off, no page is ever shared: the registry's keys (every
@@ -788,12 +850,8 @@ class PagedEngine:
             if state is not None:
                 with plane_events.span("serve.admit.state", "serve",
                                        rid=rid8, layers=len(state),
-                                       dispatches=1, **more[2]):
-                    if self.family.write_state:
-                        self.family.write_state(self, state, idx, n)
-                    else:
-                        self.ssm, self.conv = _write_state(
-                            self.ssm, self.conv, state, np.int32(idx))
+                                       dispatches=1, **written):
+                    fam.write_state(self, state, idx, n)
             with plane_events.span("serve.admit.sample", "serve",
                                    rid=rid8):
                 key = jnp.asarray(self.keys[idx], dtype=jnp.uint32)
@@ -810,60 +868,13 @@ class PagedEngine:
                 slot.done = True
             self.slots[idx] = slot
 
-    def _prefill(self, suffix: List[int], pad: int, shared: List[int],
-                 L0: int, n: int):
-        """Pad and dispatch the prefill program: the whole prompt, or —
-        seeded with the shared prefix's K/V gathered from its cached
-        pages — only the suffix, the compute the cache saves.
-        -> (first logits, per-layer dense K/V, recurrent state or None)"""
-        if self.family:
-            return self.family.prefill(self, suffix, pad, n)
-        padded = jnp.asarray(suffix + [0] * (pad - len(suffix)),
-                             dtype=jnp.int32)
-        if not shared:
-            return _prefill_one(
-                self.params, padded, n, self.max_len, self.cfg,
-                self.cos, self.sin, pad) + (None,)
-        tbl = jnp.asarray(shared, dtype=jnp.int32)
-        prefix_caches = []
-        zpad = self.max_len - L0
-        for li in range(self.cfg.n_layers):
-            pk = self.pools_k[li][tbl].reshape(
-                L0, self.cfg.n_kv_heads, self.cfg.head_dim)
-            pv = self.pools_v[li][tbl].reshape(
-                L0, self.cfg.n_kv_heads, self.cfg.head_dim)
-            if self.kv_int8:  # dequantize borrowed pages
-                pk = pk.astype(self.cfg.dtype) * \
-                    self.scales_k[li][tbl].reshape(
-                        L0, self.cfg.n_kv_heads, 1
-                    ).astype(self.cfg.dtype)
-                pv = pv.astype(self.cfg.dtype) * \
-                    self.scales_v[li][tbl].reshape(
-                        L0, self.cfg.n_kv_heads, 1
-                    ).astype(self.cfg.dtype)
-            z = jnp.zeros((zpad,) + pk.shape[1:], pk.dtype)
-            prefix_caches.append(
-                (jnp.concatenate([pk, z]),
-                 jnp.concatenate([pv, z])))
-        return _suffix_prefill(
-            self.params, prefix_caches, padded,
-            jnp.int32(L0), jnp.int32(n), self.max_len,
-            self.cfg, self.cos, self.sin, pad) + (None,)
-
     def _scatter(self, seq_caches, pages: List[int], n_shared: int):
         """The computed K/V into the slot's OWN pages only (shared
-        prefix pages already hold their content): one dispatch of
-        ``_scatter_pages``, which consumes the pools it is given, as the
-        step does: both leave ``self.pools_*`` the only handles."""
+        prefix pages already hold their content): one dispatch of the
+        family's scatter."""
         page_ids = np.full(self.P, self.num_pages, dtype=np.int32)
         page_ids[n_shared:len(pages)] = pages[n_shared:]
-        if self.family and self.family.scatter:
-            return self.family.scatter(self, seq_caches, page_ids)
-        (self.pools_k, self.pools_v, self.scales_k,
-         self.scales_v) = _scatter_pages(
-            self.pools_k, self.pools_v, self.scales_k, self.scales_v,
-            seq_caches, page_ids, np.float32(127.0), self.page,
-            self.kv_int8)
+        self.family.scatter(self, seq_caches, page_ids)
 
     # ----------------------------------------------------------- step
     def step(self) -> List[tuple]:
@@ -933,7 +944,6 @@ class PagedEngine:
                 self._kv_positions = (
                     int(np.sum(-(-held // self._read_block)
                                * self._read_block)), int(np.sum(held)))
-            no_scales = [0] * self.n_kv
             # one batched transfer: seven small uploads one by one were
             # 2.0 ms of every step on the chip (PERF.md section 6, PR 32)
             if not flights:
@@ -948,24 +958,10 @@ class PagedEngine:
                 uploads = (tables, flights[-1].next_tok, *rest,
                            flights[-1].keys)
         with plane_events.span("serve.step.dispatch", "serve"):
-            scales = ((self.scales_k, self.scales_v) if self.kv_int8
-                      else (no_scales, no_scales))
-            if self.family:
-                toks, sk, sv, new_keys, ahead = self.family.step(
-                    self, scales, uploads)
-            else:
-                (toks, self.pools_k, self.pools_v, sk, sv,
-                 new_keys) = _paged_step(
-                    self.params, self.pools_k, self.pools_v, *scales,
-                    *uploads, self.cfg, self.cos, self.sin, self.page,
-                    self.kv_int8)
-                ahead = (toks, None)    # the tokens alone; nothing to publish
-            if self.kv_int8:
-                # model-dtype mode keeps scales stable at [None]*n_layers
-                self.scales_k, self.scales_v = sk, sv
+            toks, new_keys, next_tok, kept = self.family.step(self, uploads)
             for i in active:    # positions written or on their way
                 self.slots[i].length += 1
-        now = _Flight(toks, new_keys, active, *ahead)
+        now = _Flight(toks, new_keys, active, next_tok, kept)
         if all(self.slots[i].eos_id is None for i in active):
             # no token's value ends a stream: later calls may dispatch
             # before this step's tokens are fetched
@@ -1005,12 +1001,12 @@ class PagedEngine:
             toks, keys = jax.device_get((flight.toks, flight.keys))
             self.keys = np.array(keys)
             self._landed += 1
-            if self.family:  # the step's counts rode with the tokens
+            if self.family.counts:  # they rode with the tokens
                 tail = toks[self.S:]
                 self._step_counts = (tail if self._step_counts is None
                                      else self._step_counts + tail)
-                if flight.kept is not None:
-                    self.family.landed(self, flight.kept)
+            if flight.kept is not None:
+                self.family.landed(self, flight.kept)
         with plane_events.span("serve.step.emit", "serve",
                                tokens=len(flight.active)):
             for i in flight.active:

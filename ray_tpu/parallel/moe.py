@@ -391,6 +391,21 @@ def make_ep_moe_ffn(mesh, k: int, capacity_factor: float = 2.0,
     return fn
 
 
+def expert_share(params: Dict[str, Any], offset: int, held: int
+                 ) -> Dict[str, Any]:
+    """The tree of one chip of a deployment that divides each layer's
+    computing experts: experts ``offset .. offset + held - 1`` of a tree
+    whose layers each hold them all under ``"moe"`` (``w_gate`` / ``w_up`` /
+    ``w_down``, the expert axis first); everything else (attention, shared
+    and zero experts, the router over all outputs, the norms) is on every
+    chip alike."""
+    layers = [{**lyr, "moe": {
+        **lyr["moe"], **{w: lyr["moe"][w][offset:offset + held]
+                         for w in ("w_gate", "w_up", "w_down")}}}
+        for lyr in params["layers"]]
+    return {**params, "layers": layers}
+
+
 def expert_shardings(experts: Any, mesh) -> Any:
     """NamedShardings for a stacked expert tree: dim 0 -> ep, ffn dims tp."""
     from jax.sharding import NamedSharding

@@ -5,6 +5,7 @@ recycle."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.models import LlamaConfig, generate_greedy, init_params
@@ -225,3 +226,109 @@ def test_the_sort_lives_under_one_cond_on_the_batch(picker):
                              cond.params["branches"]), key=len)
     assert not greedy                       # hands on the argmax
     assert {"sort", "cumsum", "gather"} <= set(sample)
+
+
+# ------------------------------------------------- the one chunked admission
+def _toy_chunk_program(seen):
+    """A chunk program as ``prefill_in_chunks`` calls one: (params, tokens
+    [chunk], start, n_valid, *carry, cfg) -> (logits, *carry, extra). Its
+    logits are the chunk's tokens, its carry counts calls and sums tokens,
+    its extra is [layers = 2, chunk] of the tokens' positions."""
+    def program(params, tokens, start, n_valid, calls, total, cfg):
+        seen.append((tokens.copy(), start, n_valid, cfg))
+        extra = np.stack([start + np.arange(len(tokens))] * 2)
+        return tokens * params, calls + 1, total + tokens.sum(), extra
+    return program
+
+
+@pytest.mark.parametrize("n, chunks", [(1, 1), (7, 1), (8, 1), (9, 2),
+                                       (24, 3)],
+                         ids=["one-token", "short", "exactly-one-chunk",
+                              "one-token-over", "three-chunks"])
+@pytest.mark.parametrize("keep", [False, True], ids=["plain", "keep"])
+def test_prefill_in_chunks_is_one_loop_over_the_chunk_program(n, chunks,
+                                                              keep):
+    from ray_tpu.models.engine import prefill_in_chunks
+
+    prompt, seen = list(range(1, n + 1)), []
+    first, carry, kept = prefill_in_chunks(
+        _toy_chunk_program(seen), 3, prompt, 8, (0, 0), "cfg", keep)
+    # padded to whole chunks of 8, one call a chunk, in order
+    assert [s[1] for s in seen] == [8 * c for c in range(chunks)]
+    padded = np.concatenate([s[0] for s in seen])
+    assert padded.dtype == np.int32 and len(padded) == 8 * chunks
+    assert padded[:n].tolist() == prompt and not padded[n:].any()
+    for _, start, n_valid, cfg in seen:     # as the jitted programs take them
+        assert type(start) is np.int32 and type(n_valid) is np.int32
+        assert n_valid == n and cfg == "cfg"
+    # the LAST chunk's logits, the carry after it
+    assert first.tolist() == (3 * seen[-1][0]).tolist()
+    assert carry == [chunks, sum(prompt)]
+    if keep:    # every prompt position's extra, the padded tail cut
+        assert kept.shape == (2, n) and kept[0].tolist() == list(range(n))
+    else:
+        assert kept is None
+
+
+def _debug_configs():
+    from ray_tpu.models import LLAMA_DEBUG
+    from ray_tpu.models.cohere2_moe import COHERE2_MOE_DEBUG
+    from ray_tpu.models.longcat_flash import LONGCAT_FLASH_DEBUG
+    from ray_tpu.models.minicpm_sala import MINICPM_SALA_DEBUG
+    from ray_tpu.models.nemotron_h import NEMOTRON_H_DEBUG
+
+    return {"dense": LLAMA_DEBUG, "hybrid": NEMOTRON_H_DEBUG,
+            "sparse": MINICPM_SALA_DEBUG, "latent": LONGCAT_FLASH_DEBUG,
+            "window-full": COHERE2_MOE_DEBUG}
+
+
+@pytest.mark.parametrize("name", ["dense", "hybrid", "sparse", "latent",
+                                  "window-full"])
+def test_every_family_is_a_whole_row_of_the_one_engine(name):
+    from ray_tpu.models import paged
+
+    cfg = _debug_configs()[name]
+    assert len(paged._FAMILIES) == 5
+    eng = PagedEngine(None, cfg, max_slots=2, num_pages=24, page_size=8,
+                      max_len=96)
+    row = eng.family
+    assert row is paged._FAMILIES[type(cfg)]
+    for must in (row.n_kv, row.state, row.prefill, row.step, row.scatter,
+                 row.write_state):
+        assert callable(must)
+    for may in (row.counts, row.landed, row.admit_fields, row.pool_shape):
+        assert may is None or callable(may)
+    # chunked: no buckets, and the config says the chunk
+    chunked = name in ("sparse", "latent", "window-full")
+    assert row.chunked is chunked and hasattr(cfg, "prefill_chunk") is chunked
+    assert eng._prefill_buckets == row.buckets == (
+        () if chunked else (16, 64, 256))
+    # int8 pages where the step reads them, a prefix cache for the dense row
+    assert (row.no_int8 is None) is (name in ("dense", "hybrid"))
+    assert (row.no_prefix_cache is None) is (name == "dense")
+    # a step's counts ride with its tokens for every row but the dense one
+    assert (row.counts is None) is (name == "dense")
+    assert eng.n_kv == row.n_kv(cfg) == len(eng.pools_k)
+    assert len(eng.pools_v) == (0 if row.pool_shape else eng.n_kv)
+
+
+def test_a_subclass_of_a_rows_config_takes_that_row():
+    """What a configuration file's own class over ``LlamaConfig`` relies on
+    (``tests/perfbench/test_extensibility.py`` serves one)."""
+    import dataclasses
+
+    from ray_tpu.models import LLAMA_DEBUG, paged
+
+    @dataclasses.dataclass(frozen=True)
+    class OwnConfig(LlamaConfig):
+        layer_pattern: str = "AM"
+
+    cfg = OwnConfig(**dataclasses.asdict(LLAMA_DEBUG))
+    eng = PagedEngine(None, cfg, max_slots=2, num_pages=24, page_size=8,
+                      max_len=96)
+    assert eng.family is paged._FAMILIES[LlamaConfig]
+
+
+def test_a_config_without_a_row_is_refused():
+    with pytest.raises(TypeError, match="object has no row in _FAMILIES"):
+        PagedEngine(None, object())

@@ -426,9 +426,9 @@ def test_what_the_engine_refuses_for_this_family(params):
         _engine(params, enable_prefix_cache=True)
     with pytest.raises(ValueError, match="block is the page"):
         _engine(params, page_size=16, num_pages=32)
-    with pytest.raises(ValueError, match="block is the page"):
+    with pytest.raises(ValueError, match="int8.*in the model's dtype"):
         _engine(params, kv_dtype="int8")
-    with pytest.raises(ValueError, match="block is the page"):
+    with pytest.raises(ValueError, match="whole chunks"):
         _engine(params, max_len=88)           # not whole chunks of 16
 
 
@@ -440,8 +440,10 @@ def test_the_other_families_hold_no_compressed_keys():
                       dtype=jnp.float32)
     eng = PagedEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg,
                       max_slots=2, num_pages=24, page_size=8, max_len=64)
-    assert not eng.family and not hasattr(eng, "pools_c")
-    assert eng._prefill_buckets == (16, 64, 256)
+    assert eng.family is paged._FAMILIES[LlamaConfig]
+    assert eng.family.no_prefix_cache is None   # the dense row: a prefix
+    assert not hasattr(eng, "pools_c")          # cache, no compressed keys
+    assert eng._prefill_buckets == eng.family.buckets == (16, 64, 256)
 
 
 # --------------------------------------------------------------------- spans

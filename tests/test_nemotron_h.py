@@ -226,7 +226,11 @@ def test_the_dense_family_still_takes_its_own_programs():
                       dtype=jnp.float32)
     eng = PagedEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg,
                       max_slots=2, num_pages=24, page_size=8, max_len=64)
-    assert not eng.family and eng.n_kv == 2
+    row = eng.family
+    assert row is paged._FAMILIES[LlamaConfig] and eng.n_kv == 2
+    # a prefix cache (the one family that has one), int8 pages, buckets
+    assert row.no_prefix_cache is None and row.no_int8 is None
+    assert not row.chunked and row.buckets == (16, 64, 256)
     assert not hasattr(eng, "ssm")
     eng.submit("d", [1, 2, 3], max_new_tokens=4)
     events.reset()
