@@ -52,7 +52,8 @@ import jax.numpy as jnp
 from ..ops.layers import (rms_norm, rope_interleaved as _rope,
                           rope_rows as _rope_rows)
 from ..ops.quant import mm
-from ..parallel.moe import (expert_share,  # noqa: F401 (re-export)
+from ..parallel.moe import (balanced_bias,
+                            expert_share,  # noqa: F401 (re-export)
                             moe_ffn_zero, softmax_gates)
 from .engine import _sample, prefill_in_chunks
 from .paged_ops import attend_latent, latent_pages, write_latent
@@ -236,8 +237,7 @@ def calibrate_router_bias(params, cfg: LongcatFlashConfig, key: jax.Array,
 
     def calibrated(moe, u):
         scores = jax.nn.softmax(jnp.dot(u.astype(F32), moe["w_router"]), -1)
-        cut = jnp.quantile(scores, 1.0 - cfg.top_k / cfg.router_width, axis=0)
-        moe = {**moe, "router_bias": jnp.mean(cut) - cut}
+        moe = {**moe, "router_bias": balanced_bias(scores, cfg.top_k)}
         layers.append(moe)
         return moe
 

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from ..ops import ssm
 from ..ops.layers import rms_norm
 from ..ops.quant import mm
-from ..parallel.moe import moe_ffn_share, relu2, sigmoid_gates
+from ..parallel.moe import (balanced_bias, moe_ffn_share, relu2,
+                            sigmoid_gates)
 from .engine import _sample
 from .paged_ops import paged_attention
 
@@ -170,9 +171,8 @@ def calibrate_router_bias(params, cfg: NemotronHConfig, key: jax.Array,
         else:
             scores = jax.nn.sigmoid(jnp.dot(h.astype(F32),
                                             layer["w_router"]))
-            cut = jnp.quantile(scores, 1.0 - cfg.top_k / cfg.n_experts,
-                               axis=0)
-            layer = {**layer, "router_bias": jnp.mean(cut) - cut}
+            layer = {**layer,
+                     "router_bias": balanced_bias(scores, cfg.top_k)}
             out = _moe(layer, h, everyone, cfg)[0]
         layers.append(layer)
         x = x + out

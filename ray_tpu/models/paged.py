@@ -34,12 +34,15 @@ from ..ops.quant import mm
 from ..util import events as plane_events
 from .engine import _pick_one, _prefill_one, _sample
 from .paged_ops import (_quant_kv, block_pages_of,  # noqa: F401
-                        latent_pool_shape, paged_attention)  # (re-exports)
+                        lane_pool_shape, latent_pool_shape,
+                        paged_attention)  # (re-exports)
 from .llama import LlamaConfig, _mlp_block
 from . import cohere2_moe as cohere
+from . import lfm2_moe as lfm2
 from . import longcat_flash as longcat
 from . import minicpm_sala as sala
 from .cohere2_moe import Cohere2MoeConfig
+from .lfm2_moe import Lfm2MoeConfig
 from .longcat_flash import LongcatFlashConfig
 from .minicpm_sala import MiniCPMSALAConfig
 from .nemotron_h import (NemotronHConfig, _hybrid_prefill, _hybrid_step,
@@ -399,6 +402,43 @@ def _cohere_counts(eng, tail, sp):
            landed=int(tail[5]))
 
 
+def _lfm2_state(eng):
+    # per slot a convolution tail for every conv layer and nothing else (no
+    # ``eng.ssm``): the last two gated inputs of each short convolution
+    eng.conv = lfm2.init_state(eng.cfg, eng.S)
+    eng._read_block = _read_block(eng)  # ``_lfm2_step``: paged_attention
+    # the last step's chosen experts [expert layers, S, k]: left on the
+    # device, for a reference check to read
+    eng.last_routing = None
+
+
+def _lfm2_prefill(eng, suffix, pad, n, shared):
+    return lfm2.prefill(eng.params, suffix, eng.max_len, eng.cfg)
+
+
+def _lfm2_write_state(eng, tails, slot, n):
+    eng.conv = lfm2._write_tails(eng.conv, tails, np.int32(slot))
+
+
+def _lfm2_admit_fields(eng, n):
+    return {}, {"conv_rows": (eng.cfg.conv_kernel - 1)
+                * eng.cfg.n_conv_layers}
+
+
+def _lfm2_step(eng, uploads):
+    (toks, eng.pools_k, eng.pools_v, eng.conv, new_keys, routing,
+     next_tok) = lfm2._lfm2_step(
+        eng.params, eng.pools_k, eng.pools_v, eng.conv, *uploads, eng.cfg,
+        eng.page)
+    return toks, new_keys, next_tok, routing
+
+
+def _lfm2_counts(eng, tail, sp):
+    sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]),
+           moe_rows=int(tail[2]), context_positions=int(tail[3]),
+           landed=int(tail[4]))
+
+
 @dataclass(frozen=True)
 class _Family:
     n_kv: object            # cfg -> layers (or sublayers) with a pool
@@ -427,9 +467,10 @@ class _Family:
     #                         called when that step's tokens are fetched
     pool_shape: object = None   # (cfg, num_pages, page_size) -> the shape
     #                         of a layer's pool where a position's row is
-    #                         not (n_kv_heads, head_dim) of keys beside the
-    #                         same of values: the layer then has ONE pool
-    #                         and no V pool
+    #                         not laid out as (n_kv_heads, head_dim)
+    one_pool: bool = False  # a position's row is not keys beside values of
+    #                         the same shape: the layer has ONE pool (of
+    #                         ``pool_shape``) and no V pool
     write_state: object = _write_slot_state     # (engine, the prefill's
     #                         state, slot, prompt length): its own where the
     #                         state is not ``eng.ssm`` / ``eng.conv``
@@ -475,7 +516,7 @@ _FAMILIES = {
         _longcat_step, _longcat_counts, _longcat_scatter,
         _longcat_admit_fields, chunked=True, landed=_routing_landed,
         pool_shape=lambda cfg, pages, page: latent_pool_shape(
-            pages, page, cfg.latent_width),
+            pages, page, cfg.latent_width), one_pool=True,
         no_int8="its latent pages are kept in the model's dtype"),
     # K/V pools for the full-attention layers only, read in blocks of table
     # columns, and each window layer's K/V as a per-slot ring of the window's
@@ -491,6 +532,20 @@ _FAMILIES = {
         no_int8="its window layers' rings are kept in the model's dtype "
                 "beside pages of the same: int8 would quantise one kind of "
                 "layer and not the other"),
+    # K/V pools for the attention layers only (one layer in four), a
+    # position's 8 heads of 64 side by side as four whole lanes (a 4-D pool of
+    # such heads is turned whole twice a step); per-slot state a convolution
+    # tail for every conv layer and nothing else (``eng.conv``)
+    Lfm2MoeConfig: _Family(
+        lambda cfg: cfg.n_attn_layers, _lfm2_state, _lfm2_prefill,
+        _lfm2_step, _lfm2_counts, admit_fields=_lfm2_admit_fields,
+        chunked=True, landed=_routing_landed,
+        pool_shape=lambda cfg, pages, page: lane_pool_shape(
+            pages, page, cfg.n_kv_heads, cfg.head_dim),
+        write_state=_lfm2_write_state,
+        no_prefix_cache="snapshots of a conv layer's two-row tail at page "
+                        "boundaries beside the shared pages",
+        no_int8="its step reads its lane pools in the model's dtype"),
 }
 
 
@@ -610,14 +665,13 @@ class PagedEngine:
                 f"max_len {self.max_len} is not a multiple of "
                 f"cfg.prefill_chunk {cfg.prefill_chunk}")
         self.n_kv = fam.n_kv(cfg)
-        one_pool = fam.pool_shape is not None
-        shape = (fam.pool_shape(cfg, num_pages, page_size) if one_pool
+        shape = (fam.pool_shape(cfg, num_pages, page_size) if fam.pool_shape
                  else (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim))
         pool_dt = jnp.int8 if self.kv_int8 else cfg.dtype
         self.pools_k = [jnp.zeros(shape, pool_dt)
                         for _ in range(self.n_kv)]
         self.pools_v = [jnp.zeros(shape, pool_dt)
-                        for _ in range(0 if one_pool else self.n_kv)]
+                        for _ in range(0 if fam.one_pool else self.n_kv)]
         sshape = shape[:-1]
         self.scales_k = [jnp.ones(sshape, jnp.float32)
                          for _ in range(self.n_kv)] \

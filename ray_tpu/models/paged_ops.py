@@ -23,6 +23,8 @@ which such a model's full layers read their page pool through
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -55,13 +57,36 @@ def _lane_rows(pool):
     table-wide read's 0.37); a row of ``kvh * d`` fills whole lanes, one
     turned copy serves both and is turned back when its layer's read ends
     (0.08 GB). At a head of 128 the pool is indexed as it is: it lies page
-    by page already and no step copies it."""
+    by page already and no step copies it. A pool that IS ``[num_pages, page,
+    kvh * d]`` (``lane_pool_shape``: a family whose engine keeps a narrow
+    head's positions as whole lanes from the start) is indexed as it is too:
+    nothing is turned, in no step."""
+    if pool.ndim == 3:
+        return pool
     pages, page, kvh, d = pool.shape
     return pool.reshape(pages, page, kvh * d) if d % 128 else pool
 
 
+def lane_pool_shape(num_pages: int, page: int, kvh: int, d: int):
+    """The shape of a K or V pool whose head is narrower than a lane, kept
+    with a position's heads side by side: ``[num_pages, page, kvh * d]`` (8
+    heads of 64 are four whole lanes; a toy's row is what it is).
+    ``_lane_rows`` turns a 4-D pool of such heads into this view twice a
+    step, each time a copy as wide as the pool; a pool that has this shape
+    lies page by page as it is. ``write_kv`` and ``attend_pages_blocked``
+    take either."""
+    return (num_pages, page, kvh * d)
+
+
+def _pool_heads(pool, d: int):
+    """(positions a page, K/V heads a position) of a pool of either shape,
+    given the head's width."""
+    return pool.shape[1], math.prod(pool.shape[2:]) // d
+
+
 def write_kv(k, v, pool_k, pool_v, scale_k, scale_v, page_idx, offs, kv_int8):
-    """Each slot's K/V row [S, 1, kvh, d] into its (page_idx, offs)."""
+    """Each slot's K/V row [S, 1, kvh, d] into its (page_idx, offs) of a pool
+    ``[num_pages, page, kvh, d]`` or ``[num_pages, page, kvh * d]``."""
     def put(pool, rows):
         flat = _lane_rows(pool)
         return flat.at[page_idx, offs].set(
@@ -160,7 +185,8 @@ def attend_pages_blocked(q, pool_k, pool_v, tables, lengths, block_pages,
     q [S, 1, H, d] -> o [S, 1, H * d]."""
     with jax.named_scope("attention"):
         S, P = tables.shape
-        page, kvh, d = pool_k.shape[1:]
+        d = q.shape[-1]
+        page, kvh = _pool_heads(pool_k, d)
         Bp = min(block_pages, P)
         if P % Bp:
             tables = jnp.pad(tables, ((0, 0), (0, -P % Bp)))
@@ -239,8 +265,9 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
     """One layer's cache write and attention for every slot.
 
     q [S, 1, H, d], k and v [S, 1, kvh, d] (rotated already where the family
-    rotates); pool_* [num_pages, page, kvh, d]; tables [S, P]. Writes each
-    slot's row at (page_idx, offs), in place, and reads each slot's LIVE
+    rotates); pool_* [num_pages, page, kvh, d] (or ``lane_pool_shape``'s);
+    tables [S, P]. Writes each slot's row at (page_idx, offs), in place, and
+    reads each slot's LIVE
     pages through ``attend_pages_blocked``: the query's own row from the
     arguments, the positions before it block by block, masked by position
     (keys <= the query's); a slot of length 0 reads no page. No array as
@@ -255,8 +282,8 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
         own = k[:, 0].astype(pool_k.dtype), v[:, 0].astype(pool_v.dtype)
     o = attend_pages_blocked(
         q, pool_k, pool_v, tables, lengths,
-        block_pages_of(*tables.shape, *pool_k.shape[1:], q.dtype), scale_k,
-        scale_v, own)
+        block_pages_of(*tables.shape, *_pool_heads(pool_k, q.shape[-1]),
+                       q.shape[-1], q.dtype), scale_k, scale_v, own)
     return o, pool_k, pool_v, scale_k, scale_v
 
 

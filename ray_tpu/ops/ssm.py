@@ -18,7 +18,8 @@ the oracle the chunked form is tested against. Plain XLA, no kernel.
 
 The depthwise causal convolution that precedes the scan lives here too
 (``causal_conv`` for a prompt, ``conv_step`` for one token over the kept
-tail of ``K - 1`` inputs).
+tail of ``K - 1`` inputs); a gated short convolution (``models/lfm2_moe.py``)
+takes the same three with no bias, and a prompt's chunk its left edge.
 """
 
 from __future__ import annotations
@@ -129,31 +130,46 @@ def ssm_step(state: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
     return y, new
 
 
-def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
+                left: Optional[jax.Array] = None) -> jax.Array:
     """Depthwise causal convolution over time. x [L, C], w [K, C] (``w[K-1]``
-    multiplies the current input), b [C] -> [L, C] in float32."""
+    multiplies the current input), b [C] or None (no bias) -> [L, C] in
+    float32. ``left`` [K-1, C]: the inputs before ``x[0]``, oldest first (a
+    chunk's left edge is the tail of the chunk before it); zeros where None."""
     K = w.shape[0]
     L = x.shape[0]
-    xp = jnp.pad(x.astype(F32), ((K - 1, 0), (0, 0)))
-    out = b.astype(F32)[None, :]
+    xp = (jnp.pad(x.astype(F32), ((K - 1, 0), (0, 0))) if left is None
+          else jnp.concatenate([left.astype(F32), x.astype(F32)]))
+    out = 0.0 if b is None else b.astype(F32)[None, :]
     for k in range(K):
         out = out + xp[k:k + L] * w[k].astype(F32)[None, :]
     return out
 
 
-def conv_tail(x: jax.Array, n_valid, K: int) -> jax.Array:
+def conv_tail(x: jax.Array, n_valid, K: int,
+              left: Optional[jax.Array] = None) -> jax.Array:
     """The last ``K - 1`` VALID inputs of a padded prompt, oldest first
     (zeros where the prompt is shorter): what ``conv_step`` continues from.
-    x [L, C], n_valid traced -> [K-1, C]."""
-    xp = jnp.pad(x, ((K - 1, 0), (0, 0)))
+    x [L, C], n_valid traced -> [K-1, C]. With ``left`` [K-1, C] (the inputs
+    before ``x[0]``) ``x`` is one chunk of a prompt and ``n_valid`` counts
+    from the chunk's first position: clipped to the chunk, so a chunk the
+    prompt runs through hands on its own last inputs, and one that holds
+    fewer than ``K - 1`` valid ones the rest from ``left``."""
+    if left is None:
+        xp = jnp.pad(x, ((K - 1, 0), (0, 0)))
+    else:
+        xp = jnp.concatenate([left.astype(x.dtype), x])
+        n_valid = jnp.clip(n_valid, 0, x.shape[0])
     return jax.lax.dynamic_slice_in_dim(xp, n_valid, K - 1, axis=0)
 
 
-def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array, b: jax.Array
+def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array,
+              b: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
     """One token of every slot. tail [S, K-1, C] (oldest first), x [S, C] ->
     (out [S, C] float32, new tail)."""
     window = jnp.concatenate([tail, x[:, None, :].astype(tail.dtype)], axis=1)
-    out = (jnp.einsum("skc,kc->sc", window.astype(F32), w.astype(F32))
-           + b.astype(F32)[None, :])
+    out = jnp.einsum("skc,kc->sc", window.astype(F32), w.astype(F32))
+    if b is not None:
+        out = out + b.astype(F32)[None, :]
     return out, window[:, 1:]

@@ -86,20 +86,33 @@ def _expert_ffn(h: jax.Array, experts: Dict[str, jax.Array],
 
 
 def sigmoid_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
-                  k: int, scale: float, norm: bool = True
-                  ) -> Tuple[jax.Array, jax.Array]:
+                  k: int, scale: float, norm: bool = True,
+                  eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid router with a selection bias, in float32. x: [T, D],
     w_router: [D, E], bias: [E] -> (weights [T, k], experts [T, k]). The
     top k are chosen by ``score + bias``; the weights are the chosen
     experts' scores WITHOUT the bias, normalised to sum to one where
-    ``norm``, times ``scale``."""
+    ``norm`` (over their sum ``+ eps``: a family's own constant), times
+    ``scale``."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                     w_router.astype(jnp.float32)))
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
     vals = jnp.take_along_axis(scores, idx, axis=-1)
     if norm:
-        vals = vals / (vals.sum(-1, keepdims=True) + 1e-20)
+        vals = vals / (vals.sum(-1, keepdims=True) + eps)
     return vals * scale, idx
+
+
+def balanced_bias(scores: jax.Array, k: int) -> jax.Array:
+    """The selection bias that makes a router's outputs be chosen about
+    equally often over a sample. scores [T, E]: each output's score over
+    ``T`` sample tokens -> [E]: the offset that puts the ``1 - k / E``
+    quantile of every output's score where the others' is, so that each is
+    over the common threshold for ``k / E`` of the tokens, as load balancing
+    leaves a trained router. What a family's ``calibrate_router_bias`` sets
+    at each expert layer of its seeded weights; the bias only selects."""
+    cut = jnp.quantile(scores, 1.0 - k / scores.shape[-1], axis=0)
+    return jnp.mean(cut) - cut
 
 
 def _held_pairs(gate_idx: jax.Array, Eh: int, expert_offset: int,
@@ -402,6 +415,7 @@ def expert_share(params: Dict[str, Any], offset: int, held: int
     layers = [{**lyr, "moe": {
         **lyr["moe"], **{w: lyr["moe"][w][offset:offset + held]
                          for w in ("w_gate", "w_up", "w_down")}}}
+        if "moe" in lyr else lyr    # a leading dense layer has no experts
         for lyr in params["layers"]]
     return {**params, "layers": layers}
 

@@ -273,22 +273,23 @@ def test_prefill_in_chunks_is_one_loop_over_the_chunk_program(n, chunks,
 def _debug_configs():
     from ray_tpu.models import LLAMA_DEBUG
     from ray_tpu.models.cohere2_moe import COHERE2_MOE_DEBUG
+    from ray_tpu.models.lfm2_moe import LFM2_MOE_DEBUG
     from ray_tpu.models.longcat_flash import LONGCAT_FLASH_DEBUG
     from ray_tpu.models.minicpm_sala import MINICPM_SALA_DEBUG
     from ray_tpu.models.nemotron_h import NEMOTRON_H_DEBUG
 
     return {"dense": LLAMA_DEBUG, "hybrid": NEMOTRON_H_DEBUG,
             "sparse": MINICPM_SALA_DEBUG, "latent": LONGCAT_FLASH_DEBUG,
-            "window-full": COHERE2_MOE_DEBUG}
+            "window-full": COHERE2_MOE_DEBUG, "conv-attention": LFM2_MOE_DEBUG}
 
 
 @pytest.mark.parametrize("name", ["dense", "hybrid", "sparse", "latent",
-                                  "window-full"])
+                                  "window-full", "conv-attention"])
 def test_every_family_is_a_whole_row_of_the_one_engine(name):
     from ray_tpu.models import paged
 
     cfg = _debug_configs()[name]
-    assert len(paged._FAMILIES) == 5
+    assert len(paged._FAMILIES) == 6
     eng = PagedEngine(None, cfg, max_slots=2, num_pages=24, page_size=8,
                       max_len=96)
     row = eng.family
@@ -299,7 +300,7 @@ def test_every_family_is_a_whole_row_of_the_one_engine(name):
     for may in (row.counts, row.landed, row.admit_fields, row.pool_shape):
         assert may is None or callable(may)
     # chunked: no buckets, and the config says the chunk
-    chunked = name in ("sparse", "latent", "window-full")
+    chunked = name in ("sparse", "latent", "window-full", "conv-attention")
     assert row.chunked is chunked and hasattr(cfg, "prefill_chunk") is chunked
     assert eng._prefill_buckets == row.buckets == (
         () if chunked else (16, 64, 256))
@@ -309,7 +310,12 @@ def test_every_family_is_a_whole_row_of_the_one_engine(name):
     # a step's counts ride with its tokens for every row but the dense one
     assert (row.counts is None) is (name == "dense")
     assert eng.n_kv == row.n_kv(cfg) == len(eng.pools_k)
-    assert len(eng.pools_v) == (0 if row.pool_shape else eng.n_kv)
+    # a pool of the family's own shape may still be K beside V (a head
+    # narrower than a lane kept as whole-lane rows): ``one_pool`` says
+    assert row.one_pool is (name == "latent")
+    assert len(eng.pools_v) == (0 if row.one_pool else eng.n_kv)
+    assert (row.pool_shape is not None) is (name in ("latent",
+                                                     "conv-attention"))
 
 
 def test_a_subclass_of_a_rows_config_takes_that_row():

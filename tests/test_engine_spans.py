@@ -198,6 +198,80 @@ def test_step_rows_count_the_positions_the_read_gathers(model):
         2 * block, block, block, 2 * block, 2 * block]
 
 
+@pytest.fixture(scope="module")
+def conv_model():
+    from ray_tpu.models import lfm2_moe as lm
+
+    cfg = lm.LFM2_MOE_DEBUG     # conv conv attn conv conv conv; chunk 16
+    return cfg, jax.jit(lambda k: lm.init_params(cfg, k))(
+        jax.random.PRNGKey(0))
+
+
+def _conv_rows(conv_model, reqs):
+    cfg, params = conv_model
+    eng = PagedEngine(params, cfg, max_slots=3, num_pages=64, page_size=4,
+                      max_len=96)
+    for rid, (prompt, n) in reqs.items():
+        eng.submit(rid, prompt, max_new_tokens=n)
+    eng.run_to_completion()
+    by = {}
+    for r in _rows():
+        by.setdefault(r["name"], []).append(r["fields"])
+    return eng, by
+
+
+def test_conv_family_step_rows_carry_the_expert_and_context_counters(
+        conv_model):
+    """The sixth family's fields of the step row ride with the tokens in the
+    one transfer: ``experts_hit`` (summed over the expert layers),
+    ``expert_tokens_max``, ``moe_rows`` (the active rows), ``context_
+    positions`` (what the active slots' queries attend in an attention
+    layer) and ``landed``; a row on which no step landed has none."""
+    cfg = conv_model[0]
+    eng, by = _conv_rows(conv_model, {
+        "req-aaaa-long": ([1 + i % 90 for i in range(45)], 6),
+        "req-bbbb-short": ([7, 8, 9, 10, 11, 12, 13, 14, 15], 6)})
+    steps = by["serve.engine.step"]
+    landed = [f for f in steps if "context_positions" in f]
+    assert len(landed) == 5 == len([f for f in steps if f["active"]])
+    assert steps[0]["admitted"] == 2 and all(
+        "experts_hit" not in f for f in steps if not f["active"])
+    for k, f in enumerate(landed):
+        assert f["landed"] == 1 and f["moe_rows"] == f["active"] == 2
+        # positions 45 + k and 9 + k, and the row the step wrote
+        assert f["context_positions"] == 45 + 9 + 2 * (k + 1)
+        # two rows x top-3 over sixteen experts, four expert layers
+        assert 3 * cfg.n_moe_layers <= f["experts_hit"] \
+            <= 6 * cfg.n_moe_layers
+        assert 1 <= f["expert_tokens_max"] <= 2
+        # the dense family's count of what the blocked read gathers stands
+        # beside them: this family's step reads through the same read
+        assert f["kv_positions_live"] == 45 + 9 + 2 * k
+    assert eng.last_routing.shape == (cfg.n_moe_layers, 3, cfg.top_k)
+
+
+def test_conv_family_admit_rows_count_chunks_and_conv_rows(conv_model):
+    """``serve.admit.prefill`` says ``chunks`` (the prompt over the chunk of
+    16, rounded up), ``serve.admit.state`` the conv layers written and their
+    ``conv_rows`` (two rows a layer), each one dispatch, all inside their
+    admission."""
+    cfg = conv_model[0]
+    _, by = _conv_rows(conv_model, {
+        "req-aaaa-long": ([1 + i % 90 for i in range(45)], 3),
+        "req-bbbb-short": ([7, 8, 9], 3), "req-cccc": ([5] * 16, 3)})
+    admits, prefill = by["serve.engine.admit"], by["serve.admit.prefill"]
+    assert [p["chunks"] for p in prefill] == [3, 1, 1]
+    assert [a["bucket"] for a in admits] == [96] * 3    # no bucket: max_len
+    state = by["serve.admit.state"]
+    assert [(s["layers"], s["conv_rows"], s["dispatches"]) for s in state] \
+        == [(cfg.n_conv_layers, 2 * cfg.n_conv_layers, 1)] * 3
+    assert cfg.n_conv_layers == 5
+    scatter = by["serve.admit.scatter"]
+    assert [s["pages"] for s in scatter] == [12, 1, 5]
+    assert [p["parent"] for p in prefill] == [a["sid"] for a in admits] \
+        == [s["parent"] for s in state] == [s["parent"] for s in scatter]
+
+
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
     _drive(_engine(model))
     rows = _rows()

@@ -411,6 +411,63 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
     assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
 
 
+def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip):
+    """The gated-short-convolution / attention family's programs at the
+    benchmark's widths and its first four layers (conv, conv, attention,
+    conv: both dense layers, then two expert layers of all 64 experts), 64
+    slots of 384 pages: the decode step gives every pool and conv tail back
+    aliased to the donated argument and holds NO COPY AS WIDE AS A POOL (a
+    head of 64 is half a lane: a 4-D pool of such heads is turned whole twice
+    a step, this family's pools are ``lane_pool_shape``'s), no array as wide
+    as the table, and no ``ragged-dot`` (a step's 64 rows take every expert
+    on every row); the prefill chunk groups its rows by expert and gives its
+    carried rows back aliased."""
+    from perfbench.aot_lfm2 import pool_wide_copies, table_wide_shapes
+    from ray_tpu.models import lfm2_moe as lm
+    from ray_tpu.models.paged_ops import lane_pool_shape
+
+    cfg = lm.Lfm2MoeConfig(n_layers=4)
+    S, pages, page, max_len = 64, 24576, 16, 6144
+    params = _on(one_chip, jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = _shape(one_chip, lane_pool_shape(pages, page, cfg.n_kv_heads,
+                                            cfg.head_dim))
+    tails = _on(one_chip, jax.eval_shape(lambda: lm.init_state(cfg, S)))
+    held = [[pool], [pool], tails]
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = lm._lfm2_step.lower(
+        params, *held, i32((S, max_len // page)), i32((S,)), i32((S,)),
+        f32((S,)), i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
+        cfg=cfg, page=page).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(held))
+    text = compiled.as_text()
+    elems = math.prod(pool.shape)
+    assert pool_wide_copies(text, elems) == []
+    # the guard sees such a copy where there is one
+    assert pool_wide_copies(
+        "  %c = bf16[24576,16,512]{2,1,0} copy(%p)", elems)
+    row = cfg.n_kv_heads * cfg.head_dim
+    assert table_wide_shapes(text, S, max_len, row) == []
+    assert table_wide_shapes(f"bf16[{S},{max_len},{row}]", S, max_len, row)
+    assert "ragged_dot_tiling=" not in text
+    assert m.temp_size_in_bytes < 0.5e9
+    carry = _on(one_chip, jax.eval_shape(
+        lambda: lm.prefill_carry(cfg, max_len)))
+    compiled = lm._lfm2_prefill_chunk.lower(
+        params, i32((cfg.prefill_chunk,)), i32(()), i32(()), *carry,
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
+    assert m.temp_size_in_bytes < 0.5e9     # no chunk x max_len scores
+    # the held experts' products are grouped by expert at a chunk's rows
+    assert compiled.as_text().count("ragged_dot_tiling=") == \
+        3 * cfg.n_moe_layers
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 def test_paged_step_writes_the_pools_in_place(one_chip, kv_int8):
     """The dense family's step at the benchmark's widths (Mistral-7B's; 16
