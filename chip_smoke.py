@@ -284,7 +284,9 @@ def sharded_train(cfg, mesh, steps: int):
     jax.block_until_ready((params, opt_state, tokens))
     t0 = time.perf_counter()
     # XLA cannot partition a Mosaic kernel: under a mesh the flash kernel
-    # runs per device, on that device's batch rows and heads
+    # runs per device, on that device's batch rows and heads. The mesh-bound
+    # attention also tells forward_hidden that the step is sharded, which
+    # then holds the residual stream to the batch axes (ZeRO-3 x Megatron)
     step = make_train_step(cfg, opt, make_flash_attention(mesh))
     lowered = jax.jit(step, donate_argnums=(0, 1),
                       out_shardings=(param_sh, opt_sh, None)).lower(

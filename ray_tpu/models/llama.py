@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from ..ops.quant import mm
 from ..ops.attention import dense_attention, flash_attention
 from ..ops.layers import apply_rope, cross_entropy_loss, rms_norm, rope_frequencies
+from ..parallel.sharding import activation_sharding
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,17 +145,32 @@ def _mlp_block(layer, x, cfg: LlamaConfig):
 def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
                    cfg: LlamaConfig, attn_impl=None,
                    remat: bool = True) -> jax.Array:
-    """Final-norm hidden states [B, L, D] (no lm_head projection)."""
+    """Final-norm hidden states [B, L, D] (no lm_head projection).
+
+    An ``attn_impl`` that is bound to a mesh
+    (``ops.attention.make_flash_attention(mesh)``) says that the step is
+    sharded, and the residual stream is then held to the batch layout
+    (``parallel.sharding.activation_sharding``) after the embedding and after
+    each residual sum: GSPMD gathers weights over ``fsdp`` and all-reduces the
+    two row-parallel products of a layer over ``tp``, where unpinned it
+    splits ``d_model`` over ``fsdp`` as the embedding is split. With any
+    other ``attn_impl`` nothing is pinned."""
+    mesh = getattr(attn_impl, "mesh", None)
+    layout = None if mesh is None else activation_sharding(mesh)
     if attn_impl is None:
         attn_impl = flash_attention
+
+    def pin(x):
+        return (x if layout is None
+                else jax.lax.with_sharding_constraint(x, layout))
+
     cos, sin = rope_frequencies(cfg.head_dim, tokens.shape[1], cfg.rope_theta)
-    x = params["embedding"][tokens].astype(cfg.dtype)
+    x = pin(params["embedding"][tokens].astype(cfg.dtype))
 
     def layer_fn(x, layer):
         a, _ = _attention_block(layer, x, cos, sin, cfg, attn_impl)
-        x = x + a
-        x = x + _mlp_block(layer, x, cfg)
-        return x
+        x = pin(x + a)
+        return pin(x + _mlp_block(layer, x, cfg))
 
     if remat:
         layer_fn = jax.checkpoint(layer_fn)

@@ -174,7 +174,15 @@ def make_flash_attention(mesh):
     batch) and heads (over ``tp``; GQA groups stay aligned because q and
     kv heads split the same way). The sequence stays whole — shard that
     with ``parallel.ring_attention``. Pass the result as a model's
-    ``attn_impl``."""
+    ``attn_impl``.
+
+    The result says which mesh it is bound to (``.mesh``), and that is how
+    a model learns that its step is sharded: ``llama.forward_hidden`` then
+    holds its residual stream to the layout this wrapper already assumes of
+    q/k/v (batch over the data axes), and GSPMD gathers weights over
+    ``fsdp`` and all-reduces the stream over ``tp`` where, unpinned, it
+    split ``d_model`` over ``fsdp`` and paid ~12 all-to-alls a layer around
+    the norms and this call (``parallel/sharding.py`` has the counts)."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -185,6 +193,7 @@ def make_flash_attention(mesh):
         return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
+    attn.mesh = mesh
     return attn
 
 
