@@ -33,9 +33,9 @@ from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..util import events as plane_events
 from .engine import _pick_one, _prefill_one, _sample
-from .paged_ops import (_quant_kv, block_pages_of,  # noqa: F401
-                        lane_pool_shape, latent_pool_shape,
-                        paged_attention)  # (re-exports)
+from .paged_ops import (_quant_kv, lane_pool_shape,  # noqa: F401
+                        latent_pool_shape, paged_attention,
+                        read_block_pages)  # (re-exports)
 from .llama import LlamaConfig, _mlp_block
 from . import cohere2_moe as cohere
 from . import lfm2_moe as lfm2
@@ -167,11 +167,12 @@ def _suffix_prefill(params, prefix_caches, suffix_padded, prefix_len,
 # module knows nothing of it.
 def _read_block(eng):
     """The positions a block of ``paged_attention``'s read holds in ``eng``'s
-    step, by the rule the step itself takes its blocks from: what the step
+    step, by the rule the step itself takes its blocks from (the XLA read's,
+    or the kernel's where the step reads lane pools on a TPU): what the step
     row's ``kv_positions_read`` counts in."""
     cfg = eng.cfg
-    return eng.page * block_pages_of(eng.S, eng.P, eng.page, cfg.n_kv_heads,
-                                     cfg.head_dim, cfg.dtype)
+    return eng.page * read_block_pages(eng.pools_k[0], eng.S, eng.P,
+                                       cfg.head_dim, cfg.dtype)
 
 
 def _scales(eng):

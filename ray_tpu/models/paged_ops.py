@@ -4,7 +4,9 @@ query attended over its LIVE pages (``paged_attention``: the row it writes,
 then the positions before it in blocks of table columns through
 ``attend_pages_blocked``, each slot's own blocks and no others, an online
 softmax over them; no array as wide as the table, and an idle slot reads
-nothing). ``attend_pages`` is the same read as ONE gather of every slot's
+nothing; on a TPU a pool kept as ``lane_pool_shape`` is read by the Pallas
+kernel ``ops.paged_decode.kv_decode`` instead, which gathers nothing at
+all). ``attend_pages`` is the same read as ONE gather of every slot's
 whole table: the dense branch of a block-sparse layer takes it over the
 table's first columns, and the tests hold the blocked read to it. Beside
 it the read path of a block-sparse layer, whose block is a page: a
@@ -27,6 +29,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from ..ops import attention
+from ..ops.paged_decode import kv_block_pages, kv_decode
 
 
 def _quant_kv(vec, qmax=127.0):
@@ -260,6 +265,33 @@ def attend_pages_blocked(q, pool_k, pool_v, tables, lengths, block_pages,
         return (acc / l[..., None]).astype(q.dtype).reshape(S, 1, -1)
 
 
+def _kernel_reads(pool) -> bool:
+    """Whether ``ops.paged_decode.kv_decode`` reads this K or V pool: a pool
+    the caller keeps as ``lane_pool_shape`` (rank 3 as it is passed, before
+    ``_lane_rows``: a 4-D pool of narrow heads that is only VIEWED so stays
+    on the XLA read), in a floating dtype (int8 pages have scales, which the
+    kernel does not take), of rows that are whole lanes and pages that are
+    whole sublane tiles of them, which is what the kernel's copies and
+    products tile; on a TPU (the platform answers through
+    ``ops.attention._on_tpu``, the one function a test replaces). Every other
+    pool, and every pool on any other platform, is read by
+    ``attend_pages_blocked``."""
+    return (pool.ndim == 3 and jnp.issubdtype(pool.dtype, jnp.floating)
+            and pool.shape[2] % 128 == 0
+            and pool.shape[1] % (32 // jnp.dtype(pool.dtype).itemsize) == 0
+            and attention._on_tpu())
+
+
+def read_block_pages(pool, S: int, P: int, d: int, dtype) -> int:
+    """The table columns a block of ``paged_attention``'s read of ``pool``
+    holds, whichever form reads it (``S`` slots of ``P`` columns, queries of
+    heads ``d`` wide in ``dtype``): what a slot's read is rounded up to, for
+    the step and for the engine's count of what the step reads."""
+    if _kernel_reads(pool):
+        return kv_block_pages(P, pool.shape[1])
+    return block_pages_of(S, P, *_pool_heads(pool, d), d, dtype)
+
+
 def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
                     lengths, page_idx, offs, kv_int8, dtype):
     """One layer's cache write and attention for every slot.
@@ -267,12 +299,20 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
     q [S, 1, H, d], k and v [S, 1, kvh, d] (rotated already where the family
     rotates); pool_* [num_pages, page, kvh, d] (or ``lane_pool_shape``'s);
     tables [S, P]. Writes each slot's row at (page_idx, offs), in place, and
-    reads each slot's LIVE
-    pages through ``attend_pages_blocked``: the query's own row from the
-    arguments, the positions before it block by block, masked by position
-    (keys <= the query's); a slot of length 0 reads no page. No array as
-    wide as the table. Returns (o [S, 1, H*d], pool_k, pool_v, scale_k,
-    scale_v)."""
+    reads each slot's LIVE pages: the query's own row from the arguments, the
+    positions before it block by block, masked by position (keys <= the
+    query's); a slot of length 0 reads no page. No array as wide as the
+    table. What the pools show picks the read (``_kernel_reads``): on a TPU
+    a lane pool goes through the Pallas kernel ``ops.paged_decode.kv_decode``
+    (each live page copied once into VMEM and contracted as stored, where
+    the gathered blocks, their reshape to heads of half a lane and the
+    float32 scores in HBM were 4.7 of a step's 18.9 ms at 64 slots of
+    118 600 positions and the kernel is 0.9: PERF.md section 5, PR 55);
+    every other pool through
+    ``attend_pages_blocked``, the form that runs wherever there is no Mosaic
+    compiler and the plain statement of the arithmetic that the kernel is
+    tested against (``tests/test_paged_decode.py``). Returns (o [S, 1,
+    H*d], pool_k, pool_v, scale_k, scale_v)."""
     pool_k, pool_v, scale_k, scale_v = write_kv(
         k, v, pool_k, pool_v, scale_k, scale_v, page_idx, offs, kv_int8)
     if kv_int8:     # the row as the pool now holds it: quantised, and back
@@ -280,10 +320,15 @@ def paged_attention(q, k, v, pool_k, pool_v, scale_k, scale_v, tables,
                     for r, s in map(_quant_kv, (k[:, 0], v[:, 0])))
     else:
         own = k[:, 0].astype(pool_k.dtype), v[:, 0].astype(pool_v.dtype)
-    o = attend_pages_blocked(
-        q, pool_k, pool_v, tables, lengths,
-        block_pages_of(*tables.shape, *_pool_heads(pool_k, q.shape[-1]),
-                       q.shape[-1], q.dtype), scale_k, scale_v, own)
+    if _kernel_reads(pool_k):
+        with jax.named_scope("attention"):
+            o = kv_decode(q[:, 0], *own, pool_k, pool_v, tables, lengths)
+            o = o.astype(q.dtype).reshape(q.shape[0], 1, -1)
+    else:
+        o = attend_pages_blocked(
+            q, pool_k, pool_v, tables, lengths,
+            read_block_pages(pool_k, *tables.shape, q.shape[-1], q.dtype),
+            scale_k, scale_v, own)
     return o, pool_k, pool_v, scale_k, scale_v
 
 

@@ -14,6 +14,7 @@ process may load libtpu, and every xdist worker imports this file.
 import dataclasses
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -411,7 +412,8 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
     assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
 
 
-def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip):
+def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
+                                                         monkeypatch):
     """The gated-short-convolution / attention family's programs at the
     benchmark's widths and its first four layers (conv, conv, attention,
     conv: both dense layers, then two expert layers of all 64 experts), 64
@@ -420,11 +422,19 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip):
     head of 64 is half a lane: a 4-D pool of such heads is turned whole twice
     a step, this family's pools are ``lane_pool_shape``'s), no array as wide
     as the table, and no ``ragged-dot`` (a step's 64 rows take every expert
-    on every row); the prefill chunk groups its rows by expert and gives its
-    carried rows back aliased."""
+    on every row); on the chip (the platform answered as one: a described
+    device is not ``jax.devices()``'s) the step reads its pools through the
+    Pallas kernel ``ops.paged_decode.kv_decode``, whose buffers fit the
+    scoped VMEM at these widths (Mosaic refuses what does not), and holds no
+    gathered block list of the XLA read (``bf16[3072,16,512]``: 64 blocks of
+    48 table columns) nor any array of its width; the prefill chunk groups
+    its rows by expert and gives its carried rows back aliased."""
     from perfbench.aot_lfm2 import pool_wide_copies, table_wide_shapes
     from ray_tpu.models import lfm2_moe as lm
     from ray_tpu.models.paged_ops import lane_pool_shape
+    from ray_tpu.ops import paged_decode
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
 
     cfg = lm.Lfm2MoeConfig(n_layers=4)
     S, pages, page, max_len = 64, 24576, 16, 6144
@@ -454,6 +464,21 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip):
     assert table_wide_shapes(f"bf16[{S},{max_len},{row}]", S, max_len, row)
     assert "ragged_dot_tiling=" not in text
     assert m.temp_size_in_bytes < 0.5e9
+    # one kernel an attention layer, and nothing as wide as the XLA read's
+    # list of gathered blocks (64 items of 48 pages of [16, 512])
+    assert text.count("tpu_custom_call") == cfg.n_attn_layers == 1
+    assert "ray_tpu_kv_decode" in text
+    gathered = [dims for dims in re.findall(r"bf16\[([0-9,]+)\]", text)
+                if dims.endswith(f",{page},{row}")
+                and S <= math.prod(map(int, dims.split(","))) // (page * row)
+                < pages]
+    assert gathered == [], gathered
+    # a turn's blocks of both pools, KV_DEPTH times, are what the kernel
+    # holds of VMEM beside the queries and the output: under the 16 MiB a
+    # kernel may scope (Mosaic refused the compile above otherwise)
+    ring = (2 * paged_decode.KV_DEPTH * paged_decode.KV_TURN * page * row * 2
+            * paged_decode.kv_block_pages(max_len // page, page))
+    assert ring + 3 * S * cfg.n_heads * row * 2 < 16 << 20
     carry = _on(one_chip, jax.eval_shape(
         lambda: lm.prefill_carry(cfg, max_len)))
     compiled = lm._lfm2_prefill_chunk.lower(
