@@ -11,7 +11,7 @@ from .llama import (
     loss_fn,
 )
 
-from . import mixtral, vit
+from . import granite_moe_hybrid, mixtral, vit
 from .paged import PagedEngine
 from .speculative import generate_speculative
 from .mixtral import (
@@ -21,6 +21,7 @@ from .mixtral import (
     mixtral_shardings,
 )
 from .mixtral import generate_greedy as mixtral_generate_greedy
+from .granite_moe_hybrid import GraniteMoeHybridConfig
 
 __all__ = [
     "LlamaConfig", "LLAMA3_8B", "LLAMA3_1B", "LLAMA_DEBUG", "init_params",
@@ -28,4 +29,5 @@ __all__ = [
     "mixtral", "MixtralConfig", "MIXTRAL_8X7B", "MIXTRAL_DEBUG",
     "generate_speculative", "PagedEngine",
     "mixtral_shardings", "mixtral_generate_greedy",
+    "granite_moe_hybrid", "GraniteMoeHybridConfig",
 ]

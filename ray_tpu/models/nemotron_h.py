@@ -179,6 +179,30 @@ def calibrate_router_bias(params, cfg: NemotronHConfig, key: jax.Array,
     return {**params, "layers": layers}
 
 
+def seeded_mamba(cfg, k) -> Dict[str, Any]:
+    """One Mamba-2 mixer's seeded weights from the keys ``k[1] .. k[8]``:
+    what every family with this mixer seeds (``cfg``: its ``d_model`` and
+    Mamba-2 sizes)."""
+    d, dt = cfg.d_model, cfg.dtype
+    H, di = cfg.mamba_heads, cfg.d_inner
+    step = jnp.exp(jax.random.uniform(k[3], (H,), F32)
+                   * (math.log(0.1) - math.log(0.001))
+                   + math.log(0.001))
+    return {
+        "w_in": _normal(k[1], (d, 2 * di + 2 * cfg.n_groups
+                               * cfg.ssm_state + H), dt),
+        "conv_w": _normal(k[2], (cfg.conv_kernel, cfg.conv_dim), dt,
+                          1.0 / math.sqrt(cfg.conv_kernel)),
+        "conv_b": _normal(k[8], (cfg.conv_dim,), dt, 0.05),
+        # softplus(dt_bias) = step
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(k[4], (H,), F32, 1.0, 16.0)),
+        "D": 1.0 + 0.1 * jax.random.normal(k[5], (H,), F32),
+        "gate_norm": _normal(k[6], (di,), dt, 0.05),
+        "w_out": _normal(k[7], (di, d), dt),
+    }
+
+
 def _seeded_params(cfg: NemotronHConfig, key: jax.Array) -> Dict[str, Any]:
     d, dt = cfg.d_model, cfg.dtype
     keys = jax.random.split(key, cfg.n_layers + 2)
@@ -192,24 +216,7 @@ def _seeded_params(cfg: NemotronHConfig, key: jax.Array) -> Dict[str, Any]:
         k = jax.random.split(keys[i + 2], 9)
         layer = {"norm": _normal(k[0], (d,), dt, 0.05)}
         if kind == "M":
-            H, di = cfg.mamba_heads, cfg.d_inner
-            step = jnp.exp(jax.random.uniform(k[3], (H,), F32)
-                           * (math.log(0.1) - math.log(0.001))
-                           + math.log(0.001))
-            layer.update({
-                "w_in": _normal(k[1], (d, 2 * di + 2 * cfg.n_groups
-                                       * cfg.ssm_state + H), dt),
-                "conv_w": _normal(k[2], (cfg.conv_kernel, cfg.conv_dim), dt,
-                                  1.0 / math.sqrt(cfg.conv_kernel)),
-                "conv_b": _normal(k[8], (cfg.conv_dim,), dt, 0.05),
-                # softplus(dt_bias) = step
-                "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-                "A_log": jnp.log(jax.random.uniform(k[4], (H,), F32, 1.0,
-                                                    16.0)),
-                "D": 1.0 + 0.1 * jax.random.normal(k[5], (H,), F32),
-                "gate_norm": _normal(k[6], (di,), dt, 0.05),
-                "w_out": _normal(k[7], (di, d), dt),
-            })
+            layer.update(seeded_mamba(cfg, k))
         elif kind == "*":
             qd, kvd = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
             layer.update({"wq": _normal(k[1], (d, qd), dt),
@@ -242,13 +249,13 @@ def expert_share(params: Dict[str, Any], offset: int, held: int
 
 
 # ------------------------------------------------------------------- mixers
-def _split_in(zxbcdt, cfg: NemotronHConfig):
+def _split_in(zxbcdt, cfg):
     di = cfg.d_inner
     return (zxbcdt[..., :di], zxbcdt[..., di:di + cfg.conv_dim],
             zxbcdt[..., di + cfg.conv_dim:])
 
 
-def _split_xbc(xbc, cfg: NemotronHConfig):
+def _split_xbc(xbc, cfg):
     di, gn = cfg.d_inner, cfg.n_groups * cfg.ssm_state
     lead = xbc.shape[:-1]
     return (xbc[..., :di].reshape(*lead, cfg.mamba_heads, cfg.mamba_head_dim),
@@ -256,7 +263,7 @@ def _split_xbc(xbc, cfg: NemotronHConfig):
             xbc[..., di + gn:].reshape(*lead, cfg.n_groups, cfg.ssm_state))
 
 
-def _gated_norm(y, z, scale, cfg: NemotronHConfig):
+def _gated_norm(y, z, scale, cfg):
     """RMSNorm of ``y * silu(z)`` over groups of ``d_inner / n_groups``."""
     g = y.astype(F32) * jax.nn.silu(z.astype(F32))
     lead = g.shape[:-1]
@@ -267,26 +274,29 @@ def _gated_norm(y, z, scale, cfg: NemotronHConfig):
             * (1.0 + scale.astype(F32))).astype(cfg.dtype)
 
 
-def _mamba_prompt(layer, u, n_valid, cfg: NemotronHConfig):
+def _mamba_prompt(layer, u, n_valid, cfg, state=None, left=None):
     """u [L, D] -> (out [L, D], state [H, P, N] float32 and the convolution
     tail [K-1, C], both AS OF position ``n_valid``: a padded tail has
-    ``dt = 0`` and is not among the tail's inputs)."""
+    ``dt = 0`` and is not among the tail's inputs). ``cfg``: any family's
+    config with this mixer's sizes. Given ``state`` and ``left`` (what this
+    call returned for the positions before ``u[0]``), ``u`` is one chunk of a
+    prompt that goes on from them, and ``n_valid`` counts from ``u[0]``."""
     L = u.shape[0]
     z, xbc, dt = _split_in(mm(u, layer["w_in"]), cfg)
-    tail = ssm.conv_tail(xbc, n_valid, cfg.conv_kernel)
-    xbc = jax.nn.silu(ssm.causal_conv(xbc, layer["conv_w"],
-                                      layer["conv_b"])).astype(cfg.dtype)
+    tail = ssm.conv_tail(xbc, n_valid, cfg.conv_kernel, left)
+    xbc = jax.nn.silu(ssm.causal_conv(xbc, layer["conv_w"], layer["conv_b"],
+                                      left)).astype(cfg.dtype)
     x, B, C = _split_xbc(xbc, cfg)
     dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
     dt = jnp.where((jnp.arange(L) < n_valid)[:, None], dt, 0.0)
     y, state = ssm.ssd_chunked(x, dt, -jnp.exp(layer["A_log"]), B, C,
-                               cfg.chunk_size)
+                               cfg.chunk_size, state)
     y = y + layer["D"][None, :, None] * x.astype(F32)
     y = _gated_norm(y.reshape(L, cfg.d_inner), z, layer["gate_norm"], cfg)
     return mm(y, layer["w_out"]), state, tail
 
 
-def _mamba_token(layer, u, state, tail, active, cfg: NemotronHConfig):
+def _mamba_token(layer, u, state, tail, active, cfg):
     """One token of every slot. u [S, D], state [S, H, P, N], tail
     [S, K-1, C]; an inactive lane's state stands still."""
     S = u.shape[0]

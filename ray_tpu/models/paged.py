@@ -38,10 +38,12 @@ from .paged_ops import (_quant_kv, lane_pool_shape,  # noqa: F401
                         read_block_pages)  # (re-exports)
 from .llama import LlamaConfig, _mlp_block
 from . import cohere2_moe as cohere
+from . import granite_moe_hybrid as granite
 from . import lfm2_moe as lfm2
 from . import longcat_flash as longcat
 from . import minicpm_sala as sala
 from .cohere2_moe import Cohere2MoeConfig
+from .granite_moe_hybrid import GraniteMoeHybridConfig
 from .lfm2_moe import Lfm2MoeConfig
 from .longcat_flash import LongcatFlashConfig
 from .minicpm_sala import MiniCPMSALAConfig
@@ -241,8 +243,10 @@ def _dense_step(eng, uploads):
 
 
 def _nemotron_state(eng):
+    # per slot the SSM state (float32) and the convolution tail of every
+    # Mamba layer: both rows with a Mamba-2 mixer keep them so
     eng.ssm, eng.conv = init_state(eng.cfg, eng.S)
-    eng._read_block = _read_block(eng)  # ``_hybrid_step``: paged_attention
+    eng._read_block = _read_block(eng)  # the step reads: paged_attention
     # the last step's chosen experts [expert layers, S, k]: left on the
     # device, for a reference check to read
     eng.last_routing = None
@@ -440,6 +444,33 @@ def _lfm2_counts(eng, tail, sp):
            landed=int(tail[4]))
 
 
+def _granite_prefill(eng, suffix, pad, n, shared):
+    return granite.prefill(eng.params, suffix, eng.max_len, eng.cfg)
+
+
+def _granite_admit_fields(eng, n):
+    # rows of the slot's state the admission writes: a Mamba layer's SSM
+    # state is heads x head_dim rows of ``ssm_state``, its tail K - 1 rows
+    cfg = eng.cfg
+    return {}, {"state_rows": cfg.n_mamba_layers
+                * (cfg.d_inner + cfg.conv_kernel - 1)}
+
+
+def _granite_step(eng, uploads):
+    (toks, eng.pools_k, eng.pools_v, eng.ssm, eng.conv, new_keys, routing,
+     next_tok) = granite._granite_step(
+        eng.params, eng.pools_k, eng.pools_v, eng.ssm, eng.conv, *uploads,
+        eng.cfg, eng.page)
+    return toks, new_keys, next_tok, routing
+
+
+def _granite_counts(eng, tail, sp):
+    _lfm2_counts(eng, tail, sp)     # the same five ride with the tokens
+    # the recurrences read and write each active row's whole state: from
+    # shapes and the rows, on the host (4.8 GB a step is past an int32)
+    sp.set(ssm_state_bytes=2 * int(tail[2]) * eng.cfg.slot_state_bytes)
+
+
 @dataclass(frozen=True)
 class _Family:
     n_kv: object            # cfg -> layers (or sublayers) with a pool
@@ -547,6 +578,19 @@ _FAMILIES = {
         no_prefix_cache="snapshots of a conv layer's two-row tail at page "
                         "boundaries beside the shared pages",
         no_int8="its step reads its lane pools in the model's dtype"),
+    # K/V pools for the attention layers only (one layer in ten, 4-D, a head
+    # of 128), per-slot SSM state and convolution tails of the Mamba layers
+    # beside them as the older Mamba-2 row keeps them; unlike that row's, its
+    # prompts are admitted in chunks, the recurrent state carried from chunk
+    # to chunk beside the attention layers' K/V
+    GraniteMoeHybridConfig: _Family(
+        lambda cfg: cfg.n_attn_layers, _nemotron_state, _granite_prefill,
+        _granite_step, _granite_counts, admit_fields=_granite_admit_fields,
+        chunked=True, landed=_routing_landed,
+        no_prefix_cache="snapshots of every Mamba layer's SSM state and "
+                        "tail at page boundaries beside the shared pages",
+        no_int8="a float32 SSM state beside int8 pages: the step reads its "
+                "pools in the model's dtype"),
 }
 
 

@@ -273,6 +273,7 @@ def test_prefill_in_chunks_is_one_loop_over_the_chunk_program(n, chunks,
 def _debug_configs():
     from ray_tpu.models import LLAMA_DEBUG
     from ray_tpu.models.cohere2_moe import COHERE2_MOE_DEBUG
+    from ray_tpu.models.granite_moe_hybrid import GRANITE_MOE_HYBRID_DEBUG
     from ray_tpu.models.lfm2_moe import LFM2_MOE_DEBUG
     from ray_tpu.models.longcat_flash import LONGCAT_FLASH_DEBUG
     from ray_tpu.models.minicpm_sala import MINICPM_SALA_DEBUG
@@ -280,16 +281,18 @@ def _debug_configs():
 
     return {"dense": LLAMA_DEBUG, "hybrid": NEMOTRON_H_DEBUG,
             "sparse": MINICPM_SALA_DEBUG, "latent": LONGCAT_FLASH_DEBUG,
-            "window-full": COHERE2_MOE_DEBUG, "conv-attention": LFM2_MOE_DEBUG}
+            "window-full": COHERE2_MOE_DEBUG, "conv-attention": LFM2_MOE_DEBUG,
+            "mamba-moe": GRANITE_MOE_HYBRID_DEBUG}
 
 
 @pytest.mark.parametrize("name", ["dense", "hybrid", "sparse", "latent",
-                                  "window-full", "conv-attention"])
+                                  "window-full", "conv-attention",
+                                  "mamba-moe"])
 def test_every_family_is_a_whole_row_of_the_one_engine(name):
     from ray_tpu.models import paged
 
     cfg = _debug_configs()[name]
-    assert len(paged._FAMILIES) == 6
+    assert len(paged._FAMILIES) == 7
     eng = PagedEngine(None, cfg, max_slots=2, num_pages=24, page_size=8,
                       max_len=96)
     row = eng.family
@@ -300,7 +303,8 @@ def test_every_family_is_a_whole_row_of_the_one_engine(name):
     for may in (row.counts, row.landed, row.admit_fields, row.pool_shape):
         assert may is None or callable(may)
     # chunked: no buckets, and the config says the chunk
-    chunked = name in ("sparse", "latent", "window-full", "conv-attention")
+    chunked = name in ("sparse", "latent", "window-full", "conv-attention",
+                       "mamba-moe")
     assert row.chunked is chunked and hasattr(cfg, "prefill_chunk") is chunked
     assert eng._prefill_buckets == row.buckets == (
         () if chunked else (16, 64, 256))

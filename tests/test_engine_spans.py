@@ -8,6 +8,7 @@ the GCS (``drain`` -> ``spill`` -> ``read_spill``).
 """
 
 import asyncio
+import dataclasses
 import glob
 import json
 import os
@@ -270,6 +271,54 @@ def test_conv_family_admit_rows_count_chunks_and_conv_rows(conv_model):
     assert [s["pages"] for s in scatter] == [12, 1, 5]
     assert [p["parent"] for p in prefill] == [a["sid"] for a in admits] \
         == [s["parent"] for s in state] == [s["parent"] for s in scatter]
+
+
+@pytest.fixture(scope="module")
+def mamba_moe_model():
+    from ray_tpu.models import granite_moe_hybrid as gm
+
+    # layers 3-6 of the period (mamba, mamba, attention, mamba); chunk 16
+    cfg = dataclasses.replace(gm.GRANITE_MOE_HYBRID_DEBUG, n_layers=4,
+                              layer_types=("mamba", "mamba", "attention",
+                                           "mamba"))
+    return cfg, jax.jit(lambda k: gm.init_params(cfg, k))(
+        jax.random.PRNGKey(0))
+
+
+def test_mamba_moe_family_rows_carry_state_bytes_chunks_and_state_rows(
+        mamba_moe_model):
+    """The seventh family's fields: on the step row ``experts_hit`` (summed
+    over the layers: every layer has experts), ``expert_tokens_max``,
+    ``moe_rows``, ``context_positions``, ``ssm_state_bytes`` (every active
+    row's recurrent state read and written, from shapes, on the host: at the
+    benchmark's size it is past an int32) and ``landed``; on the admission
+    ``chunks``, and on its state span the Mamba layers written and their
+    ``state_rows``."""
+    cfg = mamba_moe_model[0]
+    eng, by = _conv_rows(mamba_moe_model, {
+        "req-aaaa-long": ([1 + i % 90 for i in range(45)], 6),
+        "req-bbbb-short": ([7, 8, 9, 10, 11, 12, 13, 14, 15], 6)})
+    steps = by["serve.engine.step"]
+    landed = [f for f in steps if "ssm_state_bytes" in f]
+    assert len(landed) == 5 == len([f for f in steps if f["active"]])
+    per_slot = 3 * (4 * cfg.d_inner * cfg.ssm_state
+                    + 4 * (cfg.conv_kernel - 1) * cfg.conv_dim)  # float32 toy
+    assert cfg.slot_state_bytes == per_slot
+    for k, f in enumerate(landed):
+        assert f["landed"] == 1 and f["moe_rows"] == f["active"] == 2
+        assert f["ssm_state_bytes"] == 2 * 2 * per_slot
+        assert f["context_positions"] == 45 + 9 + 2 * (k + 1)
+        # two rows x top-3 over twelve held experts, four layers
+        assert 3 * cfg.n_layers <= f["experts_hit"] <= 6 * cfg.n_layers
+        assert 1 <= f["expert_tokens_max"] <= 2
+        assert f["kv_positions_live"] == 45 + 9 + 2 * k
+    assert eng.last_routing.shape == (cfg.n_layers, 3, cfg.top_k)
+    assert [p["chunks"] for p in by["serve.admit.prefill"]] == [3, 1]
+    assert [(s["layers"], s["state_rows"], s["dispatches"])
+            for s in by["serve.admit.state"]] == [
+        (3, 3 * (cfg.d_inner + cfg.conv_kernel - 1), 1)] * 2
+    assert [s["parent"] for s in by["serve.admit.state"]] == \
+        [a["sid"] for a in by["serve.engine.admit"]]
 
 
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):

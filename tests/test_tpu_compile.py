@@ -493,6 +493,60 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
         3 * cfg.n_moe_layers
 
 
+def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(one_chip):
+    """The Granite-MoE-hybrid family's programs at the benchmark's widths and
+    layers 4-6 of its period (mamba, attention, mamba; each with 36 of the 72
+    experts and the shared expert), 64 slots of 640 pages: the decode step
+    gives the pool, every SSM state (float32, 268 MB a layer) and every tail
+    back aliased to the donated argument, holds no array as wide as the table
+    and no ``ragged-dot`` (a step's 64 rows take every held expert on every
+    row), and its temporaries stay far under one layer's state (a copied
+    state would be 0.27 GB); the prefill chunk gives its carried K/V rows,
+    SSM states and tails back aliased, groups its rows by expert, and its
+    temporaries (the SSD form's float32 blocks at 2048 positions x 128 heads)
+    stay under the 1.3 GB the engine leaves beside its resident 13.6 GB."""
+    from perfbench.aot_lfm2 import table_wide_shapes
+    from ray_tpu.models import granite_moe_hybrid as gm
+
+    cfg = gm.GraniteMoeHybridConfig(
+        n_layers=3, layer_types=("mamba", "attention", "mamba"),
+        experts_held=36, vocab_size=50176)
+    S, pages, page, max_len = 64, 24576, 16, 10240
+    params = _on(one_chip, jax.eval_shape(
+        lambda: gm.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = _shape(one_chip, (pages, page, cfg.n_kv_heads, cfg.head_dim))
+    ssm, tails = _on(one_chip, jax.eval_shape(
+        lambda: gm.init_state(cfg, S)))
+    assert ssm[0].dtype == jnp.float32 and tails[0].dtype == jnp.bfloat16
+    held = [[pool], [pool], ssm, tails]
+    i32 = functools.partial(_shape, one_chip, dtype=jnp.int32)
+    f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
+    compiled = gm._granite_step.lower(
+        params, *held, i32((S, max_len // page)), i32((S,)), i32((S,)),
+        f32((S,)), i32((S,)), f32((S,)), _shape(one_chip, (S, 2), jnp.uint32),
+        cfg=cfg, page=page).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(held))
+    text = compiled.as_text()
+    row = cfg.n_kv_heads * cfg.head_dim
+    assert table_wide_shapes(text, S, max_len, row) == []
+    assert "ragged_dot_tiling=" not in text
+    assert m.temp_size_in_bytes < 0.2e9
+    carry = _on(one_chip, jax.eval_shape(
+        lambda: gm.prefill_carry(cfg, max_len)))
+    compiled = gm._granite_prefill_chunk.lower(
+        params, i32((cfg.prefill_chunk,)), i32(()), i32(()), *carry,
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
+    assert m.temp_size_in_bytes < 1.3e9
+    text = compiled.as_text()
+    assert f"{cfg.prefill_chunk},{max_len}]" not in text    # no L x T scores
+    assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
 def test_paged_step_writes_the_pools_in_place(one_chip, kv_int8):
     """The dense family's step at the benchmark's widths (Mistral-7B's; 16
