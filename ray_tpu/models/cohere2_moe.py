@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from ..ops.layers import layer_norm, rope_interleaved, rope_rows
 from ..ops.quant import mm
 from ..parallel.moe import (expert_share,  # noqa: F401 (re-export)
-                            moe_ffn_grouped, moe_ffn_share, sigmoid_gates)
+                            moe_ffn_held, sigmoid_gates)
 from .engine import _sample, prefill_in_chunks
 from .paged_ops import (attend_pages_blocked, attend_ring, block_pages_of,
                         ring_rows, write_kv, write_ring)
@@ -246,24 +246,20 @@ def _moe(layer, h, token_mask, cfg: Cohere2MoeConfig):
     is grouped by expert where the rows are a prompt's chunk
     (``moe_ffn_grouped`` with room for twice the pairs a router that spreads
     its picks sends here: 8.0 against 20.1 ms a layer at 2048 rows, and 9.7
-    with room for four times) and multiplies every row by every held expert
-    where they are a decode step's few (``moe_ffn_share``: 2.9 against 3.6 ms
-    at 32 rows): 16 gated experts of 4096 x 4096 on a TPU v5e, PERF.md
-    section 5."""
+    with room for four times; 4.4 since PR 57, its products a Pallas kernel
+    on the chip) and multiplies every row by every held expert where they
+    are a decode step's few (``moe_ffn_share``: 2.9 against 3.6 ms at 32
+    rows): 16 gated experts of 4096 x 4096 on a TPU v5e, PERF.md section
+    5."""
     moe = layer["moe"]
     with jax.named_scope("router"):
         vals, idx = sigmoid_gates(
             h, moe["w_router"], jnp.zeros((cfg.router_width,), F32),
             cfg.top_k, 1.0)
     held = {w: moe[w] for w in ("w_gate", "w_up", "w_down")}
-    T = h.shape[0]
-    if T >= GROUPED_FROM_ROWS:
-        mean = -(-T * cfg.top_k * cfg.experts_held // cfg.router_width)
-        routed, hit, most = moe_ffn_grouped(
-            h, vals, idx, held, cfg.expert_offset, token_mask, cap=2 * mean)
-    else:
-        routed, hit, most = moe_ffn_share(
-            h, vals, idx, held, cfg.expert_offset, token_mask)
+    routed, hit, most = moe_ffn_held(
+        h, vals, idx, held, cfg.expert_offset, token_mask, cfg.router_width,
+        GROUPED_FROM_ROWS)
     with jax.named_scope("shared_experts"):
         out = routed + _shared(layer["shared"], h, cfg)
     return out, idx, jnp.stack([hit, most]).astype(jnp.int32)

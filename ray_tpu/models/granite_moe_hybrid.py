@@ -46,8 +46,7 @@ import jax.numpy as jnp
 from ..ops.layers import rms_norm
 from ..ops.quant import mm
 from ..parallel.moe import (expert_share,  # noqa: F401 (re-export)
-                            moe_ffn_grouped, moe_ffn_share, router_probs,
-                            top_k_gates)
+                            moe_ffn_held, router_probs, top_k_gates)
 from .cohere2_moe import _prompt_attention
 from .engine import _sample, prefill_in_chunks
 # the Mamba-2 mixer, its seeded weights and its per-slot state (allocation and
@@ -277,7 +276,13 @@ def _qkv(layer, h, cfg: GraniteMoeHybridConfig):
 #: GB/s: all 36 are hit), 256 rows 1.15 against 2.70, 512 rows 2.38 against
 #: 3.24; 1024 rows 4.75 against 4.40, 2048 rows 10.67 against 7.48, where
 #: every expert on every row is 1.39 TFLOP a layer. The two cross between 512
-#: and 1024 rows; the engine runs 64 (a step) and ``prefill_chunk`` (a chunk)
+#: and 1024 rows; the engine runs 64 (a step) and ``prefill_chunk`` (a chunk).
+#: Since PR 57 the grouped products are a Pallas kernel on the chip
+#: (``parallel.moe.grouped_product_form``) and the same table reads (my chip
+#: run, PR 57; share / grouped, kernel form): 64 rows 1.02 / 1.05, 128 rows
+#: 1.04 / 1.10, 256 rows 1.24 / 1.22, 512 rows 2.24 / 1.50, 1024 rows 4.45 /
+#: 1.99, 2048 rows grouped 4.03 (was 7.74): they now cross at ~256 rows, which
+#: no program of the engine runs; the value stands
 GROUPED_FROM_ROWS = 1024
 
 
@@ -290,20 +295,13 @@ def _ffn(layer, u, token_mask, cfg: GraniteMoeHybridConfig):
         # the softmax over the ten chosen logits
         vals, idx = top_k_gates(router_probs(u, moe["w_router"]), cfg.top_k)
     held = {w: moe[w] for w in ("w_gate", "w_up", "w_down")}
-    T = u.shape[0]
     with jax.named_scope("moe"):
-        if T >= GROUPED_FROM_ROWS:
-            # room for twice the pairs a router that spreads its picks sends
-            # to the held experts: every pair where half of them are held,
-            # so no fallback is compiled beside it (room for 1.25 times was
-            # 7.10 against 7.48 ms a layer at 2048 rows)
-            mean = -(-T * cfg.top_k * cfg.experts_held // cfg.n_experts)
-            routed, hit, most = moe_ffn_grouped(
-                u, vals, idx, held, cfg.expert_offset, token_mask,
-                cap=min(T * cfg.top_k, 2 * mean))
-        else:
-            routed, hit, most = moe_ffn_share(u, vals, idx, held,
-                                              cfg.expert_offset, token_mask)
+        # grouped with room for every pair (half the experts are held), so no
+        # fallback is compiled beside it (room for 1.25 times the spread
+        # router's pairs was 7.10 against 7.48 ms a layer at 2048 rows, PR 56)
+        routed, hit, most = moe_ffn_held(
+            u, vals, idx, held, cfg.expert_offset, token_mask, cfg.n_experts,
+            GROUPED_FROM_ROWS)
     with jax.named_scope("shared_expert"):
         sh = layer["shared"]
         shared = mm(jax.nn.silu(mm(u, sh["w_gate"])) * mm(u, sh["w_up"]),

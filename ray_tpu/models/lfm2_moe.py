@@ -45,7 +45,7 @@ from ..ops.layers import apply_rope, rms_norm, rope_rows
 from ..ops.quant import mm
 from ..parallel.moe import (balanced_bias,
                             expert_share,  # noqa: F401 (re-export)
-                            moe_ffn_grouped, moe_ffn_share, sigmoid_gates)
+                            moe_ffn_held, sigmoid_gates)
 from .cohere2_moe import _prompt_attention
 from .engine import _sample, prefill_in_chunks
 from .paged_ops import paged_attention
@@ -258,7 +258,13 @@ def _qkv(layer, h, positions, cfg: Lfm2MoeConfig):
 #: 2.36 (1.47 ms is the layer's 1.21 GB at 819 GB/s), 256 rows 1.94 against
 #: 3.88; 2048 rows grouped 4.95, where every expert on every row is 2.5 TFLOP
 #: a layer (12.6 ms at the chip's peak). The two cross between 512 and 1024
-#: rows; the engine runs 64 (a step) and ``prefill_chunk`` (a chunk)
+#: rows; the engine runs 64 (a step) and ``prefill_chunk`` (a chunk). Since
+#: PR 57 the grouped products are a Pallas kernel on the chip
+#: (``parallel.moe.grouped_product_form``) and the same table reads (my chip
+#: run, PR 57; share / grouped, kernel form): 64 rows 1.68 / 1.71, 128 rows
+#: 1.68 / 1.75, 256 rows 2.01 / 1.84, 512 rows 3.57 / 1.94, 1024 rows 7.09 /
+#: 2.24, 2048 rows grouped 2.79 (was 5.00): they now cross between 128 and
+#: 256 rows, which no program of the engine runs; the value stands
 GROUPED_FROM_ROWS = 512
 
 
@@ -276,18 +282,11 @@ def _ffn(layer, h, token_mask, cfg: Lfm2MoeConfig, before_moe=None):
                                   cfg.top_k, cfg.routed_scale, cfg.norm_topk,
                                   GATE_EPS)
     held = {w: moe[w] for w in ("w_gate", "w_up", "w_down")}
-    T = h.shape[0]
     with jax.named_scope("experts"):
-        if T >= GROUPED_FROM_ROWS:
-            # room for twice the pairs a router that spreads its picks sends
-            # to the held experts: every pair where all experts are held
-            mean = -(-T * cfg.top_k * cfg.experts_held // cfg.n_experts)
-            out, hit, most = moe_ffn_grouped(
-                h, vals, idx, held, cfg.expert_offset, token_mask,
-                cap=min(T * cfg.top_k, 2 * mean))
-        else:
-            out, hit, most = moe_ffn_share(h, vals, idx, held,
-                                           cfg.expert_offset, token_mask)
+        # grouped with room for every pair: all experts are held
+        out, hit, most = moe_ffn_held(
+            h, vals, idx, held, cfg.expert_offset, token_mask, cfg.n_experts,
+            GROUPED_FROM_ROWS)
     return out, idx, jnp.stack([hit, most]).astype(jnp.int32)
 
 

@@ -31,6 +31,8 @@ import numpy as np
 
 from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
+from ..parallel.moe import (grouped_pairs, grouped_product_form,
+                            held_experts_form)
 from ..util import events as plane_events
 from .engine import _pick_one, _prefill_one, _sample
 from .paged_ops import (_quant_kv, lane_pool_shape,  # noqa: F401
@@ -497,6 +499,11 @@ class _Family:
     #                         the prefill span says ``chunks``
     landed: object = None   # (engine, what the step kept on the device):
     #                         called when that step's tokens are fetched
+    experts_form: object = None     # cfg -> the form a prompt chunk's
+    #                         grouped expert products take (``ragged`` or
+    #                         ``kernel``; None where its rows are not
+    #                         grouped): asked once, when the engine is built,
+    #                         and said by every prefill span
     pool_shape: object = None   # (cfg, num_pages, page_size) -> the shape
     #                         of a layer's pool where a position's row is
     #                         not laid out as (n_kv_heads, head_dim)
@@ -517,6 +524,18 @@ class _Family:
     def buckets(self) -> tuple:
         """What a prompt is padded to under ``max_len``."""
         return () if self.chunked else (16, 64, 256)
+
+
+def _held_form(family, width: str = "n_experts"):
+    """``_Family.experts_form`` of a family whose routed layer is
+    ``parallel.moe.moe_ffn_held``: that function's own answer
+    (``held_experts_form``) at a chunk's rows, the configuration's sizes and
+    the module's ``GROUPED_FROM_ROWS`` as it stands when the engine is
+    built."""
+    return lambda cfg: held_experts_form(
+        cfg.prefill_chunk, cfg.top_k, cfg.experts_held, cfg.d_model,
+        cfg.expert_d_ff, getattr(cfg, width), cfg.dtype,
+        family.GROUPED_FROM_ROWS)[0]
 
 
 _FAMILIES = {
@@ -547,6 +566,9 @@ _FAMILIES = {
         lambda cfg: cfg.n_sublayers, _longcat_state, _longcat_prefill,
         _longcat_step, _longcat_counts, _longcat_scatter,
         _longcat_admit_fields, chunked=True, landed=_routing_landed,
+        experts_form=lambda cfg: grouped_product_form(
+            grouped_pairs(cfg.prefill_chunk, cfg.top_k), cfg.d_model,
+            cfg.expert_d_ff, cfg.dtype),    # as ``moe_ffn_zero`` groups them
         pool_shape=lambda cfg, pages, page: latent_pool_shape(
             pages, page, cfg.latent_width), one_pool=True,
         no_int8="its latent pages are kept in the model's dtype"),
@@ -558,6 +580,7 @@ _FAMILIES = {
         lambda cfg: cfg.n_full_layers, _cohere_state, _cohere_prefill,
         _cohere_step, _cohere_counts, admit_fields=_cohere_admit_fields,
         chunked=True, landed=_routing_landed,
+        experts_form=_held_form(cohere, "router_width"),
         write_state=_cohere_write_state,
         no_prefix_cache="window layers whose K/V is a per-slot ring: a ring "
                         "is not shareable by page",
@@ -572,6 +595,7 @@ _FAMILIES = {
         lambda cfg: cfg.n_attn_layers, _lfm2_state, _lfm2_prefill,
         _lfm2_step, _lfm2_counts, admit_fields=_lfm2_admit_fields,
         chunked=True, landed=_routing_landed,
+        experts_form=_held_form(lfm2),
         pool_shape=lambda cfg, pages, page: lane_pool_shape(
             pages, page, cfg.n_kv_heads, cfg.head_dim),
         write_state=_lfm2_write_state,
@@ -587,6 +611,7 @@ _FAMILIES = {
         lambda cfg: cfg.n_attn_layers, _nemotron_state, _granite_prefill,
         _granite_step, _granite_counts, admit_fields=_granite_admit_fields,
         chunked=True, landed=_routing_landed,
+        experts_form=_held_form(granite),
         no_prefix_cache="snapshots of every Mamba layer's SSM state and "
                         "tail at page boundaries beside the shared pages",
         no_int8="a float32 SSM state beside int8 pages: the step reads its "
@@ -709,6 +734,8 @@ class PagedEngine:
                 "this family's prefill fills max_len in whole chunks: "
                 f"max_len {self.max_len} is not a multiple of "
                 f"cfg.prefill_chunk {cfg.prefill_chunk}")
+        form = fam.experts_form(cfg) if fam.experts_form else None
+        self._prefill_fields = {"experts_form": form} if form else {}
         self.n_kv = fam.n_kv(cfg)
         shape = (fam.pool_shape(cfg, num_pages, page_size) if fam.pool_shape
                  else (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim))
@@ -926,8 +953,8 @@ class PagedEngine:
             elif self.enable_prefix_cache:
                 self.prefix_misses += 1
             fam = self.family
-            chunks = ({"chunks": -(-n // self.cfg.prefill_chunk)}
-                      if fam.chunked else {})
+            chunks = ({"chunks": -(-n // self.cfg.prefill_chunk),
+                       **self._prefill_fields} if fam.chunked else {})
             scattered, written = (fam.admit_fields(self, n)
                                   if fam.admit_fields else ({}, {}))
             with plane_events.span("serve.admit.prefill", "serve",

@@ -14,9 +14,12 @@ Four implementations:
     expert on every row, then each row's own picked). On one chip it runs
     without its exchange. ``moe_ffn_grouped`` gives the same result by a
     product grouped by expert (each held expert on its own rows), faster
-    where few pairs are held and the widths are multiples of 128;
-    ``moe_ffn_zero`` is that layer where the router's last outputs are
-    zero-compute (identity) experts.
+    where few pairs are held and the widths are multiples of 128; its three
+    products are a Pallas grouped matmul (jax's ``megablox.gmm``) where
+    ``grouped_product_form`` sees whole tiles of sorted pairs on a TPU (a
+    prompt's chunk), ``lax.ragged_dot`` everywhere else (every CPU run, a
+    decode step's few pairs). ``moe_ffn_zero`` is that layer where the
+    router's last outputs are zero-compute (identity) experts.
   * ``moe_ffn_dense`` — computes every expert on every token and weights
     by the top-k gates. O(E) FLOPs; the correctness oracle and the
     single-device path.
@@ -38,8 +41,11 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
+from jax.experimental.pallas.ops.tpu.megablox import gmm
 from jax.lax import axis_size as _axis_size
 from jax.sharding import PartitionSpec as P
+
+from ..ops import attention
 
 
 def router_probs(x: jax.Array, w_router: jax.Array) -> jax.Array:
@@ -181,6 +187,94 @@ def softmax_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
     return jnp.take_along_axis(scores, idx, axis=-1) * scale, idx
 
 
+#: rows of a tile of the kernel's sorted pairs: the MXU's width. A group's
+#: two boundary tiles are multiplied whole, so a taller tile multiplies more
+#: rows of other groups: at 128 / 256 / 512 rows a tile the first product of
+#: 64 groups of ~128 rows took 0.95 / 0.99 / 1.40 ms and of 36 groups of
+#: ~284 rows 0.76 / 0.78 / (no VMEM) (chip, PR 57). Whole tiles of it are
+#: all the kernel asks of the pairs' count: alone on a TPU v5e (my chip run,
+#: PR 57; PERF.md section 5), a layer in ms, this against the parent's layer:
+#: 64 gated experts of 2048 x 1536 all held, top-4, 256 pairs 1.71 / 2.47,
+#: 1024 pairs 1.84 / 3.95, 8192 (a chunk) 2.79 / 5.00; 36 of 72 of 4096 x
+#: 768, top-10, 640 pairs 1.05 / 1.66, 20 480 (a chunk) 4.03 / 7.74; 16 of
+#: 128 of 4096 x 4096, top-8, 128 pairs 2.25 / 2.72, 4096 (a chunk) 4.43 /
+#: 7.68; 16 of 768 of 6144 x 2048, top-12, 256 pairs 1.94 / 2.62, 2048 (a
+#: chunk) 3.37 / 6.35: it won at every whole-tile count, one tile included.
+#: The one engine program that groups no whole tile (LongCat's decode step:
+#: 16 rows, 192 pairs, 0.49 ms a layer for its four hit experts, near their
+#: bytes) stays ``lax.ragged_dot``
+KERNEL_ROW_TILE = 128
+#: what a call of the kernel may take of the 16 MiB of scoped VMEM a Mosaic
+#: call gets on a v5e, counted as ``_kernel_tiling`` does: the weight tile
+#: and the output tile twice (double-buffered), the row tile twice and once
+#: more on the kernel's stack. Against the sizes Mosaic reports where it
+#: refuses a tile (AOT for a described v5e, PR 57: bfloat16 and float32,
+#: contractions of 128 to 6144) the count read from 0.7 % under to 5 % over
+#: at nine refused tiles, hence the quarter MiB left; twelve tiles it admits
+#: compiled, the eight the four families' widths give among them
+KERNEL_VMEM_BYTES = (16 << 20) - (256 << 10)
+
+
+def _kernel_tiling(K: int, N: int, itemsize: int) -> Tuple[int, int, int]:
+    """(row, contraction, column) tile of one grouped product [cap, K] x
+    [Eh, K, N], from the widths alone. The contraction is ONE tile: an
+    expert's weight tile then changes only where the group does, so each
+    held expert is read once a column tile however many row tiles its group
+    touches (split in two, the weights of 36 experts of 4096 x 768 were
+    read at every visit: 1.23 against 0.76 ms). The column tile is the
+    widest whole-lane divisor of ``N`` whose call fits
+    ``KERNEL_VMEM_BYTES``: the rows are read once a column tile, and a grid
+    step costs ~0.35 us (0.76 / 0.80 / 0.85 / 1.12 ms at 768 / 384 / 256 /
+    128 columns); 0 where not even one lane-wide tile fits."""
+    tm = KERNEL_ROW_TILE
+    tn = max((t for t in range(128, N + 1, 128) if N % t == 0
+              and itemsize * (3 * tm * K + 2 * K * t + 2 * tm * t)
+              <= KERNEL_VMEM_BYTES), default=0)
+    return tm, K, tn
+
+
+def grouped_product_form(cap: int, D: int, F: int, dtype) -> str:
+    """The implementation of ``moe_ffn_grouped``'s three products, from what
+    the call sees of its input and nothing else: ``"kernel"``, the Pallas
+    grouped matmul, where the ``cap`` sorted pairs are whole row tiles, both
+    widths of the experts ``[D, F]`` are whole lanes and a tile of either
+    whole contraction fits (one test: a column tile is a whole-lane divisor
+    of the other width), the dtype is floating, and the platform is a TPU
+    (it answers through ``ops.attention._on_tpu``, the one function a test
+    replaces); ``"ragged"``, ``lax.ragged_dot``, everywhere else: every CPU
+    run, a decode step's few pairs, a width that is no multiple of 128. The
+    number of experts does not enter: the one row tile serves groups of 32
+    to 284 rows (``KERNEL_ROW_TILE``)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    tiles = (cap % KERNEL_ROW_TILE == 0
+             and _kernel_tiling(D, F, itemsize)[2] > 0
+             and _kernel_tiling(F, D, itemsize)[2] > 0)
+    return "kernel" if (tiles and jnp.issubdtype(dtype, jnp.floating)
+                        and attention._on_tpu()) else "ragged"
+
+
+def grouped_pairs(T: int, k: int, cap: int | None = None) -> int:
+    """The sorted pairs ``moe_ffn_grouped`` gathers and multiplies at ``T``
+    rows of ``k`` picks when called with ``cap``."""
+    return min(T * k, max(T, 16 * k) if cap is None else cap)
+
+
+def _kernel_dot(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array
+                ) -> jax.Array:
+    """``lax.ragged_dot(lhs, rhs, sizes)`` by the Pallas grouped matmul that
+    jax ships (``megablox.gmm``): row tiles of the sorted pairs, each tile's
+    expert (and a boundary tile's second one, its rows masked at the store)
+    found by scalar prefetch from the group sizes, the expert's weight tile
+    read from ``rhs`` as stored, float32 accumulation, ``lhs``'s dtype out.
+    The tiles past the last group are not visited: their rows of the result
+    are whatever the buffer held. Interpreted where there is no Mosaic
+    compiler (a test that forces the form on the CPU)."""
+    return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+               tiling=_kernel_tiling(*rhs.shape[1:],
+                                     jnp.dtype(rhs.dtype).itemsize),
+               interpret=not attention._on_tpu())
+
+
 def moe_ffn_grouped(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
                     experts_held: Dict[str, jax.Array], expert_offset: int,
                     token_mask: jax.Array | None = None,
@@ -188,50 +282,70 @@ def moe_ffn_grouped(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``moe_ffn_share``'s result by a product GROUPED by expert: the pairs
     (token, expert) are sorted with the held ones first, by expert, and each
-    held expert multiplies exactly its rows (``lax.ragged_dot``), so an
-    expert no token picked is not read and no row meets an expert it did
-    not pick. Only the first ``cap`` sorted pairs are gathered and
-    multiplied: all ``T k`` of them at a decode step's few rows, ``T`` of
-    them at a prompt's (a router that spreads its picks sends ``T k Eh / E``
-    pairs here, a fortieth of them at 16 of 768); should more pairs than
-    ``cap`` be held, the call takes ``moe_ffn_share`` instead (one
-    ``lax.cond``), so it stays dropless and exact.
+    held expert multiplies exactly its rows, so an expert no token picked is
+    not read and no row meets an expert it did not pick. Only the first
+    ``cap`` sorted pairs are gathered and multiplied: all ``T k`` of them at
+    a decode step's few rows, ``T`` of them at a prompt's (a router that
+    spreads its picks sends ``T k Eh / E`` pairs here, a fortieth of them at
+    16 of 768); should more pairs than ``cap`` be held, the call takes
+    ``moe_ffn_share`` instead (one ``lax.cond``), so it stays dropless and
+    exact.
 
-    On a TPU v5e at 16 gated experts of 6144 x 2048 this was 0.48 against
-    ``moe_ffn_share``'s 1.69 ms a layer at 16 rows (4 of the 16 experts hit:
-    a quarter of the bytes) and 5.7 against 17.1 at 2048 (PERF.md section 5,
-    PR 34); at 16 experts of 2688 x 1856 it lost at every row count (a
-    width that is no multiple of 128 costs a layout copy of every expert:
-    PR 33), which is why ``moe_ffn_share`` is the other form. The rows
-    ``lax.ragged_dot`` leaves past its last group are UNINITIALISED on a TPU
-    (inf and NaN among them): they are selected away, never multiplied by a
-    zero weight. The un-sort is a gather (a scatter-add would sum in no
-    fixed order)."""
+    The three products take the form ``grouped_product_form`` picks from the
+    shapes, the dtype and the platform: the Pallas grouped matmul
+    (``_kernel_dot``) at a chunk's sorted pairs on a TPU, ``lax.ragged_dot``
+    otherwise; same pairs, same operands (the weights as the tree stores
+    them), float32 accumulation, the input's dtype out. A layer alone on a
+    TPU v5e at a chunk's 2048 rows, this against the parent's layer (my chip
+    run, PR 57; PERF.md section 5): 36 of 72 gated experts of 4096 x 768,
+    top-10, 4.03 against 7.74 ms; 64 of 2048 x 1536 all held, top-4, 2.79
+    against 5.00; 16 of 128 of 4096 x 4096, top-8, 4.43 against 7.68; 16 of
+    768 of 6144 x 2048, top-12, 3.37 against 6.35. The two forms were equal
+    on that sweep's data and, on one model's weights, in every calibrated
+    bias, row and token (PERF.md section 6); nothing holds them to one order
+    of a float32 sum, so no caller may count on equal bits. Before it
+    (PR 34, ``ragged_dot`` alone) 16 gated experts of 6144 x 2048 were 0.48
+    against ``moe_ffn_share``'s 1.69 ms a layer at 16 rows (4 of the 16
+    experts hit: a quarter of the bytes), which stands, and 5.7 against 17.1
+    at 2048; at 16 experts of 2688 x 1856 the grouped product lost at every
+    row count (a width that is no multiple of 128 costs a layout copy of
+    every expert: PR 33), which is why ``moe_ffn_share`` is the other form
+    and why such widths never take the kernel.
+
+    The rows past the last group are UNINITIALISED in BOTH forms on a TPU
+    (``lax.ragged_dot`` leaves them, the kernel never visits their tiles;
+    inf and NaN among them): they are selected away, never multiplied by a
+    zero weight. The un-sort is ONE gather (a scatter-add would sum in no
+    fixed order) of the last product's rows in their own dtype, the gate
+    applied after it: pair (t, j) lies at ``argsort(order)[t k + j]`` and
+    its gate is ``gate_vals[t, j]``. Until PR 57 the gate was applied before
+    it, to a float32 array of every sorted pair that was then gathered from
+    ``k`` times: 2.66 ms of a layer at 20 480 pairs of 4096 against 1.4 so;
+    the same sum, compiled otherwise: rare bf16 steps apart (chip, PR 57)."""
     T, k = gate_idx.shape
-    Eh = experts_held["w_up"].shape[0]
-    cap = min(T * k, max(T, 16 * k) if cap is None else cap)
+    Eh, D, F = experts_held["w_up"].shape
+    cap = grouped_pairs(T, k, cap)
     _, _, group, sizes = _held_pairs(gate_idx, Eh, expert_offset, token_mask)
     n_held = jnp.sum(sizes)
+    dot = (_kernel_dot if grouped_product_form(cap, D, F, x.dtype) == "kernel"
+           else lax.ragged_dot)
 
     def grouped():
         order = jnp.argsort(group, stable=True)
-        top = order[:cap]
-        rows = x[top // k]                                   # [cap, D]
-        u = lax.ragged_dot(rows, experts_held["w_up"], sizes)
+        rows = x[order[:cap] // k]                           # [cap, D]
+        u = dot(rows, experts_held["w_up"], sizes)
         if "w_gate" in experts_held:
-            u = jax.nn.silu(lax.ragged_dot(rows, experts_held["w_gate"],
-                                           sizes)) * u
+            u = jax.nn.silu(dot(rows, experts_held["w_gate"], sizes)) * u
         else:
             u = relu2(u)
-        y = lax.ragged_dot(u, experts_held["w_down"], sizes)
-        w = gate_vals.reshape(T * k)[top]
-        y = jnp.where((jnp.arange(cap) < n_held)[:, None],
-                      y.astype(jnp.float32) * w[:, None], 0.0)
+        y = dot(u, experts_held["w_down"], sizes)
         at = jnp.argsort(order).reshape(T, k)   # a pair's place when sorted
-        out = jnp.zeros((T, y.shape[1]), jnp.float32)
+        picked = y[jnp.minimum(at, cap - 1).T]                  # [k, T, D]
+        out = jnp.zeros((T, D), jnp.float32)
         for j in range(k):      # a token's k rows, summed in gate order
-            out = out + jnp.where((at[:, j] < cap)[:, None],
-                                  y[jnp.minimum(at[:, j], cap - 1)], 0.0)
+            out = out + jnp.where(
+                (at[:, j] < n_held)[:, None],
+                picked[j].astype(jnp.float32) * gate_vals[:, j, None], 0.0)
         return out.astype(x.dtype)
 
     if cap == T * k:
@@ -242,6 +356,40 @@ def moe_ffn_grouped(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
             lambda: moe_ffn_share(x, gate_vals, gate_idx, experts_held,
                                   expert_offset, token_mask)[0])
     return out, jnp.sum(sizes > 0), jnp.max(sizes)
+
+
+def held_experts_form(T: int, k: int, Eh: int, D: int, F: int, E: int, dtype,
+                      grouped_from: int) -> Tuple[str | None, int]:
+    """How ``moe_ffn_held`` multiplies ``T`` rows of ``k`` picks by ``Eh``
+    held experts ``[D, F]`` of a router ``E`` wide: (the form of the grouped
+    products, ``grouped_product_form``'s answer, and the pairs they are given
+    room for), or (None, 0) under ``grouped_from`` rows, where every row
+    meets every held expert. The room is twice the pairs a router that
+    spreads its picks sends here: every pair where half the experts or more
+    are held, so no fallback is compiled beside such a product. The engine
+    asks it with a family's chunk for its ``serve.admit.prefill`` rows."""
+    if T < grouped_from:
+        return None, 0
+    cap = min(T * k, 2 * -(-T * k * Eh // E))
+    return grouped_product_form(cap, D, F, dtype), cap
+
+
+def moe_ffn_held(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,
+                 experts_held: Dict[str, jax.Array], expert_offset: int,
+                 token_mask: jax.Array | None, router_width: int,
+                 grouped_from: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One chip's routed experts of a family that measured both forms alone:
+    ``moe_ffn_share`` under ``grouped_from`` rows (a decode step's),
+    ``moe_ffn_grouped`` from there (a prompt's chunk), as
+    ``held_experts_form`` says."""
+    form, cap = held_experts_form(
+        *gate_idx.shape, *experts_held["w_up"].shape, router_width, x.dtype,
+        grouped_from)
+    if form is None:
+        return moe_ffn_share(x, gate_vals, gate_idx, experts_held,
+                             expert_offset, token_mask)
+    return moe_ffn_grouped(x, gate_vals, gate_idx, experts_held,
+                           expert_offset, token_mask, cap)
 
 
 def moe_ffn_zero(x: jax.Array, gate_vals: jax.Array, gate_idx: jax.Array,

@@ -262,6 +262,9 @@ def test_conv_family_admit_rows_count_chunks_and_conv_rows(conv_model):
         "req-bbbb-short": ([7, 8, 9], 3), "req-cccc": ([5] * 16, 3)})
     admits, prefill = by["serve.engine.admit"], by["serve.admit.prefill"]
     assert [p["chunks"] for p in prefill] == [3, 1, 1]
+    # a chunk of 16 rows is under the family's GROUPED_FROM_ROWS: no row is
+    # grouped by expert, so there is no form of the grouped products to name
+    assert not any("experts_form" in p for p in prefill)
     assert [a["bucket"] for a in admits] == [96] * 3    # no bucket: max_len
     state = by["serve.admit.state"]
     assert [(s["layers"], s["conv_rows"], s["dispatches"]) for s in state] \
@@ -313,12 +316,62 @@ def test_mamba_moe_family_rows_carry_state_bytes_chunks_and_state_rows(
         assert 1 <= f["expert_tokens_max"] <= 2
         assert f["kv_positions_live"] == 45 + 9 + 2 * k
     assert eng.last_routing.shape == (cfg.n_layers, 3, cfg.top_k)
-    assert [p["chunks"] for p in by["serve.admit.prefill"]] == [3, 1]
+    assert [(p["chunks"], p.get("experts_form"))
+            for p in by["serve.admit.prefill"]] == [(3, None), (1, None)]
     assert [(s["layers"], s["state_rows"], s["dispatches"])
             for s in by["serve.admit.state"]] == [
         (3, 3 * (cfg.d_inner + cfg.conv_kernel - 1), 1)] * 2
     assert [s["parent"] for s in by["serve.admit.state"]] == \
         [a["sid"] for a in by["serve.engine.admit"]]
+
+
+@pytest.mark.parametrize("family, config, from_rows, on_tpu, form", [
+    ("lfm2_moe", "Lfm2MoeConfig", None, True, "kernel"),
+    ("lfm2_moe", "Lfm2MoeConfig", None, False, "ragged"),
+    ("granite_moe_hybrid", "GraniteMoeHybridConfig", None, True, "kernel"),
+    ("cohere2_moe", "Cohere2MoeConfig", None, True, "kernel"),
+    ("longcat_flash", "LongcatFlashConfig", None, True, "kernel"),
+    ("longcat_flash", "LongcatFlashConfig", None, False, "ragged"),
+    ("granite_moe_hybrid", "GraniteMoeHybridConfig", 4096, True, None)])
+def test_a_chunks_experts_form_follows_widths_and_platform(
+        family, config, from_rows, on_tpu, form, monkeypatch):
+    """``_Family.experts_form`` of the four families whose chunks group their
+    rows by expert, at their published widths (each config class's own
+    defaults: a chunk of 2048 rows): the kernel on a TPU, ``ragged`` off it,
+    none where a chunk has fewer rows than ``GROUPED_FROM_ROWS``. It is what
+    the engine asks once and every ``serve.admit.prefill`` row says."""
+    import importlib
+
+    from ray_tpu.models import paged
+    from ray_tpu.ops import attention
+
+    mod = importlib.import_module(f"ray_tpu.models.{family}")
+    cfg = getattr(mod, config)()
+    assert cfg.prefill_chunk == 2048 and cfg.dtype == jnp.bfloat16
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_tpu)
+    if from_rows:
+        monkeypatch.setattr(mod, "GROUPED_FROM_ROWS", from_rows)
+    assert paged._FAMILIES[type(cfg)].experts_form(cfg) == form
+
+
+def test_the_prefill_row_names_the_form_only_where_experts_are_grouped(
+        model, conv_model, monkeypatch):
+    """The field is the family's answer at the engine's own configuration
+    (toy rows grouped by ``lax.ragged_dot`` once ``GROUPED_FROM_ROWS`` lets
+    a chunk of 16 group), and a family without routed experts has none."""
+    from ray_tpu.models import lfm2_moe as lm, paged
+
+    monkeypatch.setattr(lm, "GROUPED_FROM_ROWS", 16)
+    _, by = _conv_rows(conv_model, {"req-aaaa": ([1 + i for i in range(20)],
+                                                 2)})
+    assert [p["experts_form"] for p in by["serve.admit.prefill"]] \
+        == ["ragged"] == [paged._FAMILIES[lm.Lfm2MoeConfig].experts_form(
+            conv_model[0])]
+    events.reset()
+    _drive(_engine(model))
+    assert all("experts_form" not in r["fields"] and "chunks"
+               not in r["fields"] for r in _rows()
+               if r["name"] == "serve.admit.prefill")
 
 
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):

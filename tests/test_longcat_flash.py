@@ -512,3 +512,144 @@ def test_the_grouped_product_is_the_held_experts_on_every_row(T, overflow,
                                atol=2e-5)
     assert (int(got[1]), int(got[2])) == (int(want[1]), int(want[2]))
     assert float(jnp.abs(got[0][3]).max()) == 0.0
+
+
+def _held_experts_f32(x, vals, idx, held, offset, mask):
+    """The plain statement in float32: every pair whose expert is held and
+    whose row is not masked adds ``gate x expert(row)``."""
+    f32 = {n: np.asarray(w, np.float32) for n, w in held.items()}
+    x, vals, idx = (np.asarray(a, np.float32) for a in (x, vals, idx))
+    out = np.zeros_like(x)
+    for e in range(f32["w_up"].shape[0]):
+        u = x @ f32["w_up"][e]
+        if "w_gate" in f32:
+            g = x @ f32["w_gate"][e]
+            u = g / (1 + np.exp(-g)) * u
+        else:
+            u = np.square(np.maximum(u, 0))
+        gate = ((idx == e + offset) * vals).sum(-1) * np.asarray(mask)
+        out += gate[:, None] * (u @ f32["w_down"][e])
+    return out
+
+
+def _kernel_case(routing, all_held, gated, T=128, k=2, D=128, F=256):
+    """bf16 rows, gates, picks, four held experts (all the router has, or
+    experts 2-5 of its 8), their offset and the mask of one case of the
+    kernel form's tests: toy widths of whole lanes, ``T k`` two row tiles."""
+    E, offset = (4, 0) if all_held else (8, 2)
+    keys = jax.random.split(jax.random.PRNGKey(len(routing) + 2 * gated), 6)
+    x = jax.random.normal(keys[0], (T, D)).astype(jnp.bfloat16)
+    held = {"w_up": jax.random.normal(keys[1], (4, D, F)) / D ** 0.5,
+            "w_down": jax.random.normal(keys[2], (4, F, D)) / F ** 0.5}
+    if gated:
+        held["w_gate"] = jax.random.normal(keys[3], (4, D, F)) / D ** 0.5
+    held = {n: w.astype(jnp.bfloat16) for n, w in held.items()}
+    vals = jax.random.uniform(keys[5], (T, k), minval=0.1)
+    mask = jnp.ones((T,), bool)
+    if routing in ("one-expert", "more-than-cap"):
+        idx = jnp.full((T, k), offset + 1)  # every pair to one held expert
+    elif routing == "empty-experts":    # held experts 1 and 2 get no pair
+        idx = jnp.stack([jnp.full((T,), offset),
+                         jnp.where(jnp.arange(T) % 3 == 0, offset + 3,
+                                   (offset + 4) % E)], axis=1)
+    else:   # distinct picks spread over the router's width
+        idx = jnp.argsort(jax.random.uniform(keys[4], (T, E)), axis=-1)[:, :k]
+    if routing == "masked-tail":    # a chunk's tail is no token
+        mask = jnp.arange(T) < T - 37
+    return x, vals, idx.astype(jnp.int32), held, offset, mask
+
+
+def _forced_kernel_form(monkeypatch, offset, cap):
+    """``moe_ffn_grouped`` jitted afresh (a trace of the other form is not
+    found again) with its products forced to the kernel; what the predicate
+    was asked."""
+    asked = []
+    monkeypatch.setattr(moe, "grouped_product_form",
+                        lambda *a: asked.append(a) or "kernel")
+    return jax.jit(lambda x, vals, idx, held, mask: moe.moe_ffn_grouped(
+        x, vals, idx, held, offset, mask, cap)), asked
+
+
+@pytest.mark.parametrize("routing", ["even", "one-expert", "empty-experts",
+                                     "masked-tail", "more-than-cap"])
+@pytest.mark.parametrize("all_held", [True, False],
+                         ids=["all-held", "held-range"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+def test_the_kernel_form_is_the_held_experts_on_every_row(
+        routing, all_held, gated, monkeypatch):
+    """``moe_ffn_grouped`` with its products as the Pallas grouped matmul
+    (interpreted here; the form forced, since the predicate picks it on a
+    TPU alone) against ``moe_ffn_share`` and the float32 statement: the same
+    result within bf16 and the same counts, whether every expert is held or
+    a range from ``expert_offset``, with even groups, one group of every
+    pair, empty groups, a chunk's tail masked out, and more pairs held than
+    ``cap`` takes (the ``cond``'s other side)."""
+    x, vals, idx, held, offset, mask = _kernel_case(routing, all_held, gated)
+    T, k = idx.shape
+    cap = 128 if routing == "more-than-cap" else T * k
+    run, asked = _forced_kernel_form(monkeypatch, offset, cap)
+    got = run(x, vals, idx, held, mask)
+    assert asked == [(cap, 128, 256, jnp.bfloat16)]
+    want = moe.moe_ffn_share(x, vals, idx, held, offset, mask)
+    plain = _held_experts_f32(x, vals, idx, held, offset, mask)
+    for other in (np.asarray(want[0], np.float32), plain):
+        np.testing.assert_allclose(np.asarray(got[0], np.float32), other,
+                                   atol=0.02 * np.abs(plain).max())
+    assert (int(got[1]), int(got[2])) == (int(want[1]), int(want[2]))
+    if routing == "empty-experts":
+        assert int(got[1]) == 2
+    if routing == "more-than-cap":
+        assert int(got[2]) == T * k > cap
+    if routing == "masked-tail":
+        assert float(jnp.abs(got[0][T - 37:]).max()) == 0.0
+
+
+def test_rows_past_the_last_group_never_reach_the_kernel_forms_result(
+        monkeypatch):
+    """What lies past the last held pair is never multiplied into a result:
+    the rows of a masked tail hold inf and NaN (they sort past the last
+    group, into the boundary tile, whose other rows the kernel leaves as it
+    found them, and into the tiles it skips), and the result is finite, the
+    masked rows exactly zero, the others bit for bit what the same call
+    gives with zeros there."""
+    x, vals, idx, held, offset, mask = _kernel_case("masked-tail", False,
+                                                    True)
+    T, k = idx.shape
+    bad = jnp.where(jnp.arange(T)[:, None] % 2 == 0, jnp.inf, jnp.nan)
+    planted = jnp.where(mask[:, None], x, bad.astype(x.dtype))
+    run, _ = _forced_kernel_form(monkeypatch, offset, T * k)
+    got = run(planted, vals, idx, held, mask)
+    want = run(jnp.where(mask[:, None], x, 0), vals, idx, held, mask)
+    assert bool(jnp.isfinite(got[0].astype(jnp.float32)).all())
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(want[0], np.float32))
+    assert float(jnp.abs(got[0][T - 37:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("cap, D, F, dtype, on_tpu, form", [
+    (20480, 4096, 768, jnp.bfloat16, True, "kernel"),   # granite's chunk
+    (8192, 2048, 1536, jnp.bfloat16, True, "kernel"),   # lfm2's
+    (4096, 4096, 4096, jnp.bfloat16, True, "kernel"),   # Command A+'s
+    (2048, 6144, 2048, jnp.bfloat16, True, "kernel"),   # LongCat's
+    (192, 6144, 2048, jnp.bfloat16, True, "ragged"),    # LongCat's step
+    (8192, 2048, 1536, jnp.bfloat16, False, "ragged"),  # no TPU
+    (8192, 2048, 1536, jnp.float32, True, "kernel"),
+    (8192, 2048, 1536, jnp.int8, True, "ragged"),       # no float
+    (4096, 2688, 1856, jnp.bfloat16, True, "ragged"),   # no whole lanes
+    (4096, 2048, 1856, jnp.bfloat16, True, "ragged"),
+    (1100, 2048, 1536, jnp.bfloat16, True, "ragged"),   # no whole row tile
+    (128, 2048, 1536, jnp.bfloat16, True, "kernel"),    # one row tile
+    (4096, 32768, 1536, jnp.bfloat16, True, "ragged"),  # a tile past VMEM
+    (4096, 256, 8192, jnp.bfloat16, True, "kernel"),    # narrow and wide
+    (4096, 128, 16384, jnp.bfloat16, True, "ragged"),   # its way back past it
+])
+def test_the_grouped_products_form_follows_shapes_dtype_and_platform(
+        cap, D, F, dtype, on_tpu, form, monkeypatch):
+    """``grouped_product_form``'s table: the kernel where the sorted pairs
+    are whole row tiles, both widths whole lanes, the
+    dtype floating, a tile of the whole contraction fits, and the platform
+    is a TPU; ``lax.ragged_dot`` everywhere else."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: on_tpu)
+    assert moe.grouped_product_form(cap, D, F, dtype) == form

@@ -61,6 +61,25 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _grouped_products(text):
+    """(grouped-matmul kernel calls, ``ragged-dot`` calls) in a compiled
+    program's text: the two forms of ``parallel.moe.moe_ffn_grouped``'s
+    products (``grouped_product_form``). The kernel is jax's ``megablox.gmm``,
+    whose custom call carries its name."""
+    return (len(re.findall(r"^\s*(?:ROOT )?%gmm[.\d]* = [^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text,
+                           re.M)),
+            text.count("ragged_dot_tiling="))
+
+
+def _chunk_form(cfg):
+    """What the engine's ``serve.admit.prefill`` rows say of this family's
+    chunk (``_Family.experts_form``), held to the compiled chunk's text."""
+    from ray_tpu.models.paged import _FAMILIES
+
+    return _FAMILIES[type(cfg)].experts_form(cfg)
+
+
 def _table_wide(text, S, max_len, page, cfg):
     """The spellings of every slot's whole table gathered from a K/V pool
     (``[S, max_len, kvh, d]`` or the gather's own ``[S * P, page, kvh, d]``)
@@ -304,7 +323,8 @@ def test_sala_step_and_prefill_chunk_minicpm_sala_widths(one_chip):
     assert f"{cfg.prefill_chunk},{max_len}]" not in compiled.as_text()
 
 
-def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
+def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip,
+                                                             monkeypatch):
     """The latent-attention / zero-expert family's programs at the
     benchmark's widths and one of its double layers, 16 slots of 288 pages:
     the decode step gives its pools back aliased to the donated arguments,
@@ -313,11 +333,16 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
     two positions a row are not) and never expands a cached latent row (no
     array of the gathered positions x heads x a per-head key or value); the
     prefill chunk builds no array of chunk x ``max_len`` scores and no
-    expanded cache."""
+    expanded cache. The held experts' products, three an expert layer, are
+    ``ragged-dot`` in the step (16 rows: 192 sorted pairs, no whole row
+    tile) and the grouped-matmul kernel in the chunk (2048 sorted pairs), as
+    ``grouped_product_form`` picks on the chip (the platform answered as
+    one: a described device is not ``jax.devices()``'s)."""
     from perfbench.aot_longcat import expanded_shapes
     from ray_tpu.models import longcat_flash as lc
     from ray_tpu.models.paged_ops import latent_pool_shape
 
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = lc.LongcatFlashConfig(vocab_size=16384, n_layers=1,
                                 experts_held=16)
     S, pages, page, max_len = 16, 4096, 64, 18432
@@ -343,7 +368,7 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
     # the guard sees an expansion where there is one
     assert expanded_shapes(f"bf16[{S},{max_len},64,128]", S * max_len, cfg)
     # the held experts' products are grouped by expert (PR 34): three a layer
-    assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
+    assert _grouped_products(text) == (0, 3 * cfg.n_layers)
     assert m.temp_size_in_bytes < 0.6e9
     carry = _on(one_chip, jax.eval_shape(
         lambda: lc.prefill_carry(cfg, max_len)))
@@ -357,9 +382,12 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip):
     text = compiled.as_text()
     assert f"{cfg.prefill_chunk},{max_len}]" not in text
     assert f"[{max_len},{cfg.n_heads}," not in text     # no expanded cache
+    assert _chunk_form(cfg) == "kernel"
+    assert _grouped_products(text) == (3 * cfg.n_layers, 0)
 
 
-def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
+def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip,
+                                                            monkeypatch):
     """The window / full attention family's programs at the benchmark's
     widths and one period of its layers (three window, one full), 32 slots of
     512 pages: the decode step gives every pool and every ring back aliased
@@ -367,10 +395,13 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
     gathered keys or values as wide as the table (32 x 32 768 positions
     would be 2.1 GB of keys and as much of values: the full layer is read in
     blocks of 16 table columns); the prefill chunk builds no array of chunk x
-    ``max_len`` scores and gives its carried rows back aliased."""
+    ``max_len`` scores, gives its carried rows back aliased, and multiplies
+    its rows grouped by expert in the grouped-matmul kernel, three calls a
+    layer (the platform answered as the chip)."""
     from perfbench.aot_commanda import table_wide_shapes
     from ray_tpu.models import cohere2_moe as cm
 
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = cm.Cohere2MoeConfig(vocab_size=32768, n_layers=4, experts_held=16)
     S, pages, page, max_len = 32, 7168, 64, 32768
     params = _on(one_chip, jax.eval_shape(
@@ -396,6 +427,7 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
     assert table_wide_shapes(text, S, max_len, cfg) == []
     # the guard sees a table-wide gather where there is one
     assert table_wide_shapes(f"bf16[{S},{max_len},8,128]", S, max_len, cfg)
+    assert _grouped_products(text) == (0, 0)    # 32 rows: every held expert
     assert m.temp_size_in_bytes < 1e9
     carry = _on(one_chip, jax.eval_shape(
         lambda: cm.prefill_carry(cfg, max_len)))
@@ -409,7 +441,8 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip):
     text = compiled.as_text()
     assert f"{cfg.prefill_chunk},{max_len}]" not in text
     # the held experts' products are grouped by expert at a chunk's rows
-    assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
+    assert _chunk_form(cfg) == "kernel"
+    assert _grouped_products(text) == (3 * cfg.n_layers, 0)
 
 
 def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
@@ -428,7 +461,8 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
     scoped VMEM at these widths (Mosaic refuses what does not), and holds no
     gathered block list of the XLA read (``bf16[3072,16,512]``: 64 blocks of
     48 table columns) nor any array of its width; the prefill chunk groups
-    its rows by expert and gives its carried rows back aliased."""
+    its rows by expert, three calls of the grouped-matmul kernel an expert
+    layer, and gives its carried rows back aliased."""
     from perfbench.aot_lfm2 import pool_wide_copies, table_wide_shapes
     from ray_tpu.models import lfm2_moe as lm
     from ray_tpu.models.paged_ops import lane_pool_shape
@@ -462,7 +496,7 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
     row = cfg.n_kv_heads * cfg.head_dim
     assert table_wide_shapes(text, S, max_len, row) == []
     assert table_wide_shapes(f"bf16[{S},{max_len},{row}]", S, max_len, row)
-    assert "ragged_dot_tiling=" not in text
+    assert _grouped_products(text) == (0, 0)
     assert m.temp_size_in_bytes < 0.5e9
     # one kernel an attention layer, and nothing as wide as the XLA read's
     # list of gathered blocks (64 items of 48 pages of [16, 512])
@@ -489,11 +523,12 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
         a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
     assert m.temp_size_in_bytes < 0.5e9     # no chunk x max_len scores
     # the held experts' products are grouped by expert at a chunk's rows
-    assert compiled.as_text().count("ragged_dot_tiling=") == \
-        3 * cfg.n_moe_layers
+    assert _chunk_form(cfg) == "kernel"
+    assert _grouped_products(compiled.as_text()) == (3 * cfg.n_moe_layers, 0)
 
 
-def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(one_chip):
+def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(
+        one_chip, monkeypatch):
     """The Granite-MoE-hybrid family's programs at the benchmark's widths and
     layers 4-6 of its period (mamba, attention, mamba; each with 36 of the 72
     experts and the shared expert), 64 slots of 640 pages: the decode step
@@ -502,12 +537,14 @@ def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(one_chip):
     and no ``ragged-dot`` (a step's 64 rows take every held expert on every
     row), and its temporaries stay far under one layer's state (a copied
     state would be 0.27 GB); the prefill chunk gives its carried K/V rows,
-    SSM states and tails back aliased, groups its rows by expert, and its
-    temporaries (the SSD form's float32 blocks at 2048 positions x 128 heads)
+    SSM states and tails back aliased, groups its rows by expert (three
+    calls of the grouped-matmul kernel a layer, the platform answered as the
+    chip), and its temporaries (the SSD form's float32 blocks at 2048 positions x 128 heads)
     stay under the 1.3 GB the engine leaves beside its resident 13.6 GB."""
     from perfbench.aot_lfm2 import table_wide_shapes
     from ray_tpu.models import granite_moe_hybrid as gm
 
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = gm.GraniteMoeHybridConfig(
         n_layers=3, layer_types=("mamba", "attention", "mamba"),
         experts_held=36, vocab_size=50176)
@@ -531,7 +568,7 @@ def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(one_chip):
     text = compiled.as_text()
     row = cfg.n_kv_heads * cfg.head_dim
     assert table_wide_shapes(text, S, max_len, row) == []
-    assert "ragged_dot_tiling=" not in text
+    assert _grouped_products(text) == (0, 0)
     assert m.temp_size_in_bytes < 0.2e9
     carry = _on(one_chip, jax.eval_shape(
         lambda: gm.prefill_carry(cfg, max_len)))
@@ -544,7 +581,8 @@ def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(one_chip):
     assert m.temp_size_in_bytes < 1.3e9
     text = compiled.as_text()
     assert f"{cfg.prefill_chunk},{max_len}]" not in text    # no L x T scores
-    assert text.count("ragged_dot_tiling=") == 3 * cfg.n_layers
+    assert _chunk_form(cfg) == "kernel"
+    assert _grouped_products(text) == (3 * cfg.n_layers, 0)
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
