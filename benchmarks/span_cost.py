@@ -4,8 +4,10 @@
 
 Nanoseconds per ``events.span`` with the recorder on, off, and on under a
 live ``jax.profiler`` session; and per ``step()``-shaped group (one outer
-span, four phases inside it, one ``span_done``: the six rows a decode step
-writes). Host code only: pin the process to the CPU backend
+span, four phases inside it, the step's own ``serve.step.flight`` row through
+``span_done`` with its seven fields and the clock read behind its ``wait_ns``,
+and the pump's ``span_done``: the seven rows a decode step writes since
+PR 58, six before). Host code only: pin the process to the CPU backend
 (``JAX_PLATFORMS=cpu``) so it takes no chip.
 """
 
@@ -30,15 +32,25 @@ def one_span():
 
 
 def one_step():
+    """The rows of a call that dispatches one step and lands an older one,
+    with the fields ``PagedEngine.step`` / ``_step`` / ``_land`` give them."""
     with events.span("serve.engine.step", "serve", k=1) as sp:
-        for name in ("serve.step.prepare", "serve.step.dispatch",
-                     "serve.step.fetch"):
-            with events.span(name, "serve"):
-                pass
-        with events.span("serve.step.emit", "serve", tokens=16):
+        with events.span("serve.step.prepare", "serve"):
+            pass
+        with events.span("serve.step.dispatch", "serve", step=11,
+                         depth=10) as sent:
+            pass
+        with events.span("serve.step.fetch", "serve", step=1) as got:
+            if got.sid and sent.t0_ns:
+                events.span_done(
+                    "serve.step.flight", "serve", sent.t0_ns, step=1, depth=1,
+                    active=16, admitted=0,
+                    wait_ns=time.perf_counter_ns() - got.t0_ns, call=sp.sid,
+                    landed_by=sp.sid)
+        with events.span("serve.step.emit", "serve", tokens=16, step=1):
             pass
         sp.set(active=16, admitted=0, tokens=16, pending=0, free_pages=1500,
-               preempted=0)
+               preempted=0, flights=10)
     events.span_done("serve.pump.deliver", "serve", sp.t0_ns, tokens=16,
                      lock_wait_ns=1)
 

@@ -16,6 +16,12 @@ wrapped to look at the ``ctx`` after the readers have run.
   wall time, on steps that decoded and admitted nothing (ratio of sums, and
   the least and the largest single step).
 - the phases' shares, rows a second and spill bytes a second in the window.
+- ``flight_metrics``: the four readers of ``serve.step.flight``
+  (``perfbench/flight_spans.py``) on the same ``ctx``, whichever of them the
+  cell lists: the chat cell lists none (its landing intervals are arrivals,
+  not steps), so this is the only way to its readings. Under the run-ahead a
+  call dispatches one step and lands an older one: ``step_cover`` still tiles
+  a CALL by its four phases, whichever steps they belong to.
 """
 
 from __future__ import annotations
@@ -28,13 +34,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def crosscheck(ctx: dict, values: dict) -> dict:
+FLIGHT_METRICS = ("step_host_slack_ms", "step_flights_ahead",
+                  "admit_stall_share_pct", "device_idle_restart_pct")
+
+
+def crosscheck(ctx: dict, values: dict, man=None) -> dict:
     from perfbench import program_spans as ps, stats
     from ray_tpu.util import events
 
     a, b = ctx["run"]["t_open"] * 1e9, ctx["run"]["t_close"] * 1e9
     rows = ps.spans(ctx)
-    out = {"window_s": (b - a) / 1e9}
+    out = {"window_s": (b - a) / 1e9, "window_ns": [a, b],
+           "profiler_calls_s": ctx["run"].get("trace_ctl") or []}
 
     def kids(parent_sid, name):
         return [f for f in rows.get(name, []) if f["parent"] == parent_sid]
@@ -116,6 +127,11 @@ def crosscheck(ctx: dict, values: dict) -> dict:
                  for fs in rows.values())
     out["span_rows_per_s_in_window"] = in_win / out["window_s"]
     out["metrics"] = {k: v["value"] for k, v in values.items()}
+    if man is not None:
+        # a cell that lists one has read it already (and said its lines)
+        out["flight_metrics"] = {
+            name: out["metrics"][name] if name in out["metrics"]
+            else man.reader(name)(ctx) for name in FLIGHT_METRICS}
     return out
 
 
@@ -128,7 +144,7 @@ def main():
     def keep(man, cell, ctx):
         values = readers(man, cell, ctx)
         try:
-            found = crosscheck(ctx, values)
+            found = crosscheck(ctx, values, man)
         except Exception as e:  # noqa: BLE001 — never cost the run its line
             found = {"error": repr(e)}
         print("[crosscheck] " + json.dumps(found), flush=True)

@@ -653,6 +653,12 @@ class _Flight:
     active: List[int]   # the slots that decoded in it
     next_tok: object    # device int32[S]: the tokens alone, the next step's
     kept: object        # what the family publishes when it lands, or None
+    # what its ``serve.step.flight`` row says when it lands
+    step: int = 0       # its number among the step programs dispatched
+    depth: int = 0      # steps already in flight when it was dispatched
+    admitted: int = 0   # admissions since the dispatch before it
+    call: int = 0       # sid of the ``serve.engine.step`` that dispatched it
+    t0_ns: int = 0      # start of its ``serve.step.dispatch`` (0: recorder off)
 
     def ended(self) -> bool:
         """Whether the device has finished the step (asks, never waits)."""
@@ -687,7 +693,9 @@ class PagedEngine:
     transfer, the pump between calls) then lies under the device's, and a
     host that stands still for a tenth of a second finds the device still
     at work. Events come some calls after their step's dispatch, at the
-    instant they would have come. It runs ahead only while nothing but the
+    instant they would have come; each dispatched step has a number of its
+    own and, as it lands, a ``serve.step.flight`` row that joins its dispatch
+    to its landing (``_land``). It runs ahead only while nothing but the
     count of tokens decides what the next step holds (``_runs_ahead``: no
     request waits beside a free slot, no stream has an ``eos_id`` or its
     last token dispatched, a page to spare for every held slot); otherwise
@@ -781,6 +789,11 @@ class PagedEngine:
         self._step_counts = None    # what rode with a recurrent step's tokens
         self._landed = 0            # steps whose tokens this step() fetched
         self._flights: List[_Flight] = []   # dispatched, not fetched, in order
+        # a dispatched step's identity (``k`` above numbers the CALL, which
+        # under the run-ahead dispatches one step and lands another)
+        self._dispatched = 0        # step programs dispatched so far
+        self._undispatched_admits = 0   # admissions since the last dispatch
+        self._call_sid = 0          # sid of the ``serve.engine.step`` under way
         # Prefix cache: full-prompt-page content hash -> (page id,
         # refcount). Pages with refcount 0 stay resident (reusable)
         # until pool pressure evicts them LRU (``_reclaim``).
@@ -936,6 +949,7 @@ class PagedEngine:
             if sp.sid:      # recorder on: submit() to this span's start
                 sp.set(waited_ns=sp.t0_ns - submitted_ns)
             self._admitted += 1
+            self._undispatched_admits += 1
             idx = self.slots.index(None)
             self.temps[idx] = temp
             self.top_ks[idx] = top_k
@@ -1008,13 +1022,14 @@ class PagedEngine:
         with plane_events.span("serve.engine.step", "serve",
                                k=self._steps) as sp:
             self._steps += 1
+            self._call_sid = sp.sid
             events, active, sampling = self._step()
             sp.set(active=active, sampling=sampling,
                    admitted=self._admitted,
                    tokens=sum(1 for _, tok in events if tok is not None),
                    pending=len(self.pending),
                    free_pages=len(self.free_pages),
-                   preempted=self._preempted)
+                   preempted=self._preempted, flights=len(self._flights))
             if self._step_counts is not None:
                 self.family.counts(self, self._step_counts, sp)
             if self._kv_positions:
@@ -1083,11 +1098,18 @@ class PagedEngine:
                     self.top_ps))
                 uploads = (tables, flights[-1].next_tok, *rest,
                            flights[-1].keys)
-        with plane_events.span("serve.step.dispatch", "serve"):
+        with plane_events.span("serve.step.dispatch", "serve",
+                               step=self._dispatched,
+                               depth=len(flights)) as sp:
             toks, new_keys, next_tok, kept = self.family.step(self, uploads)
             for i in active:    # positions written or on their way
                 self.slots[i].length += 1
-        now = _Flight(toks, new_keys, active, next_tok, kept)
+        now = _Flight(toks, new_keys, active, next_tok, kept,
+                      step=self._dispatched, depth=len(flights),
+                      admitted=self._undispatched_admits,
+                      call=self._call_sid, t0_ns=sp.t0_ns)
+        self._dispatched += 1
+        self._undispatched_admits = 0
         if all(self.slots[i].eos_id is None for i in active):
             # no token's value ends a stream: later calls may dispatch
             # before this step's tokens are fetched
@@ -1122,9 +1144,20 @@ class PagedEngine:
                         < self.slots[i].max_new for i in held))
 
     def _land(self, flight: _Flight, events: List[tuple]) -> None:
-        """Fetch a dispatched step's tokens and emit them."""
-        with plane_events.span("serve.step.fetch", "serve"):
+        """Fetch a dispatched step's tokens and emit them. The step's
+        ``serve.step.flight`` row is written as the tokens reach the host:
+        from its dispatch span's start to here is its time in flight, and
+        ``wait_ns`` of it the host stood blocked for the device."""
+        with plane_events.span("serve.step.fetch", "serve",
+                               step=flight.step) as sp:
             toks, keys = jax.device_get((flight.toks, flight.keys))
+            if sp.sid and flight.t0_ns:     # recorder on, then and now
+                plane_events.span_done(
+                    "serve.step.flight", "serve", flight.t0_ns,
+                    step=flight.step, depth=flight.depth,
+                    active=len(flight.active), admitted=flight.admitted,
+                    wait_ns=time.perf_counter_ns() - sp.t0_ns,
+                    call=flight.call, landed_by=self._call_sid)
             self.keys = np.array(keys)
             self._landed += 1
             if self.family.counts:  # they rode with the tokens
@@ -1134,7 +1167,7 @@ class PagedEngine:
             if flight.kept is not None:
                 self.family.landed(self, flight.kept)
         with plane_events.span("serve.step.emit", "serve",
-                               tokens=len(flight.active)):
+                               tokens=len(flight.active), step=flight.step):
             for i in flight.active:
                 s = self.slots[i]
                 tok = int(toks[i])

@@ -75,6 +75,13 @@ def _rows():
     return [events.row_to_dict(r) for r in rows]
 
 
+def _by_name(rows):
+    by = {}
+    for r in rows:
+        by.setdefault(r["name"], []).append(r["fields"])
+    return by
+
+
 def _inside(child, parent):
     c, p = child["fields"], parent["fields"]
     return (p["t0_ns"] <= c["t0_ns"]
@@ -215,10 +222,7 @@ def _conv_rows(conv_model, reqs):
     for rid, (prompt, n) in reqs.items():
         eng.submit(rid, prompt, max_new_tokens=n)
     eng.run_to_completion()
-    by = {}
-    for r in _rows():
-        by.setdefault(r["name"], []).append(r["fields"])
-    return eng, by
+    return eng, _by_name(_rows())
 
 
 def test_conv_family_step_rows_carry_the_expert_and_context_counters(
@@ -470,6 +474,146 @@ def test_spans_are_profiler_annotations_under_a_trace(model, tmp_path):
     assert "ray_tpu/serve.engine.admit" in names
 
 
+# ------------------------------------------- a dispatched step's own row
+
+def _two_held_slots(model, third=True):
+    """Two streams hold both slots (the cap is ``_STEPS_AHEAD``), a third
+    waits for the first to end: -> (engine, flights left after each call)."""
+    eng = _engine(model)
+    eng.submit("req-a", [1, 2, 3, 4], max_new_tokens=16)
+    eng.submit("req-b", [7, 8], max_new_tokens=40)
+    if third:
+        eng.submit("req-c", [5, 6, 9], max_new_tokens=6)
+    left = []
+    while eng.has_work():
+        eng.step()
+        left.append(len(eng._flights))
+    return eng, left
+
+
+def test_the_flights_of_a_run_join_dispatch_to_landing(model, slow_device):
+    """One ``serve.step.flight`` row a dispatched step: ``step`` runs from 0
+    without a hole, each has one dispatch, one fetch and one emit row of its
+    ``step``, begins where its dispatch span began and ends inside its fetch
+    span; ``call`` and ``landed_by`` are ``serve.engine.step`` rows, the one
+    that holds its dispatch and the one that holds its fetch."""
+    eng, _ = _two_held_slots(model)
+    by = _by_name(_rows())
+    flights = by["serve.step.flight"]
+    assert [f["step"] for f in flights] == list(range(eng._dispatched))
+    assert eng._dispatched == 39     # req-b's 40 tokens less its first
+    calls = {f["sid"]: f for f in by["serve.engine.step"]}
+    for name in ("serve.step.dispatch", "serve.step.fetch",
+                 "serve.step.emit"):
+        assert sorted(f["step"] for f in by[name]) \
+            == list(range(eng._dispatched))
+    dispatch = {f["step"]: f for f in by["serve.step.dispatch"]}
+    fetch = {f["step"]: f for f in by["serve.step.fetch"]}
+    for f in flights:
+        d, got = dispatch[f["step"]], fetch[f["step"]]
+        assert f["call"] in calls and f["landed_by"] in calls
+        assert d["parent"] == f["call"] and got["parent"] == f["landed_by"]
+        assert f["parent"] == got["sid"]    # written inside its fetch span
+        assert f["t0_ns"] == d["t0_ns"] and f["depth"] == d["depth"]
+        landing = f["t0_ns"] + f["dur_ns"]
+        assert got["t0_ns"] + f["wait_ns"] <= landing \
+            <= got["t0_ns"] + got["dur_ns"]
+        assert 0 <= f["wait_ns"] <= got["dur_ns"] and f["active"] in (1, 2)
+    # steps stay in flight: from the second on, another call lands a step
+    # than the one that dispatched it
+    assert flights[0]["landed_by"] != flights[0]["call"]
+    assert all(f["landed_by"] > f["call"] for f in flights)
+    landings = [f["t0_ns"] + f["dur_ns"] for f in flights]
+    assert landings == sorted(landings)
+
+
+def test_depth_climbs_to_the_cap_and_falls_to_0_after_an_admission(
+        model, slow_device):
+    from ray_tpu.models import paged
+
+    _two_held_slots(model)
+    flights = _by_name(_rows())["serve.step.flight"]
+    cap = paged._STEPS_AHEAD
+    depths = [f["depth"] for f in flights]
+    assert depths[:cap + 3] == list(range(cap + 1)) + [cap, cap]
+    assert max(depths) == cap
+    assert flights[0]["admitted"] == 2      # both before the first dispatch
+    later = [f for f in flights[1:] if f["admitted"]]
+    # req-c is admitted when req-a has ended, with nothing in flight
+    assert [(f["admitted"], f["depth"], f["active"]) for f in later] \
+        == [(1, 0, 2)]
+    at = flights.index(later[0])
+    assert depths[at - 1] > 0 and depths[at:at + 4] == [0, 1, 2, 3]
+    assert sum(f["admitted"] for f in flights) == 3
+
+
+def test_the_step_row_counts_the_flights_a_call_leaves(model, slow_device):
+    eng, left = _two_held_slots(model, third=False)
+    steps = _by_name(_rows())["serve.engine.step"]
+    assert [f["flights"] for f in steps] == left
+    assert max(left) == 10 and left[-1] == 0 == len(eng._flights)
+
+
+def test_a_step_that_has_ended_lands_in_the_call_that_dispatched_it(model):
+    """``prompt_device`` (this file's default): nothing stays in flight."""
+    _drive(_engine(model))
+    by = _by_name(_rows())
+    flights = by["serve.step.flight"]
+    assert flights and len(flights) == len(by["serve.step.dispatch"])
+    assert all(f["depth"] == 0 and f["landed_by"] == f["call"]
+               for f in flights)
+    assert all(f["flights"] == 0 for f in by["serve.engine.step"])
+
+
+def test_a_stream_with_an_eos_id_is_stepped_with_nothing_in_flight(
+        model, slow_device):
+    eng = _engine(model)
+    eng.submit("req-eos", [1, 2, 3, 4], max_new_tokens=7, eos_id=10**6)
+    eng.run_to_completion()
+    flights = _by_name(_rows())["serve.step.flight"]
+    assert [f["step"] for f in flights] == list(range(6))
+    assert all(f["depth"] == 0 and f["landed_by"] == f["call"]
+               and f["active"] == 1 for f in flights)
+    assert [f["admitted"] for f in flights] == [1, 0, 0, 0, 0, 0]
+
+
+def test_the_recorder_off_writes_no_flight_row_and_reads_no_clock(
+        model, slow_device, monkeypatch):
+    from ray_tpu.models import paged
+
+    events._enabled = False
+    eng = _engine(model)
+    eng.submit("req-a", [1, 2, 3, 4], max_new_tokens=9)   # stamps its arrival
+    eng.submit("req-b", [7, 8], max_new_tokens=9)
+    reads = []
+    clock = time.perf_counter_ns
+    monkeypatch.setattr(paged.time, "perf_counter_ns",
+                        lambda: reads.append(1) or clock())
+    for _ in range(4):
+        eng.step()
+    assert len(eng._flights) == 4 and not reads
+    assert all(f.t0_ns == 0 == f.call for f in eng._flights)
+    assert [f.step for f in eng._flights] == [0, 1, 2, 3]
+    # switched on with steps in flight: those have no dispatch instant and
+    # write no row; the steps dispatched from here on do
+    events._enabled = True
+    while eng.has_work():
+        eng.step()
+    flights = _by_name(_rows())["serve.step.flight"]
+    assert [f["step"] for f in flights] == list(range(4, eng._dispatched))
+    assert reads
+
+
+def test_greedy_identical_with_recorder_on_and_off_ahead_of_the_device(
+        model, slow_device):
+    on, calls_on, _ = _drive(_engine(model))
+    assert any(r["name"] == "serve.step.flight" for r in _rows())
+    events._enabled = False
+    off, calls_off, _ = _drive(_engine(model))
+    events._enabled = True
+    assert _rows() == [] and on == off and calls_on == calls_off
+
+
 # ------------------------------------------------------------ the pump
 
 def test_pump_and_request_rows_share_the_engine_clock(model):
@@ -523,10 +667,11 @@ def test_pump_and_request_rows_share_the_engine_clock(model):
                    for s in steps)
 
 
-def test_one_admission_and_one_step_emit_all_ten_span_names(model):
+def test_one_admission_and_one_step_emit_all_eleven_span_names(model):
     """The names the benchmark's per-layer metrics read. Two new tokens:
     the first comes from the admission, the second from the one step
-    that decodes (a single token would show no step phase)."""
+    that decodes (a single token would show no step phase), whose own
+    row, ``serve.step.flight``, is the eleventh name."""
     from ray_tpu.serve.llm import LLMServer
 
     cfg, params = model
@@ -539,7 +684,7 @@ def test_one_admission_and_one_step_emit_all_ten_span_names(model):
     assert {r["name"] for r in rows if r["name"].startswith(
         ("serve.engine.", "serve.step.", "serve.admit.", "serve.pump."))} \
         == {"serve.engine.step", "serve.engine.admit", "serve.pump.deliver",
-            *STEP_PHASES, *ADMIT_PHASES}
+            "serve.step.flight", *STEP_PHASES, *ADMIT_PHASES}
     admit, = [r for r in rows if r["name"] == "serve.engine.admit"]
     scatter, = [r for r in rows if r["name"] == "serve.admit.scatter"]
     assert scatter["fields"]["parent"] == admit["fields"]["sid"]
