@@ -28,8 +28,11 @@ blocks of table columns (``paged_ops.attend_pages_blocked``), a window
 layer's K/V in a **per-slot ring** of the window's width
 (``paged_ops.write_ring`` / ``attend_ring``), whose memory is fixed whatever
 the slot's context. A prompt is admitted ``prefill_chunk`` tokens at a time
-through ONE program that carries every layer's K/V; a window layer's
-attention visits only the key blocks that can hold a visible key.
+through ONE program that carries every layer's K/V; its attention
+(``_prompt_attention``) is a flash kernel on the chip, in which a tile of rows
+visits only the key tiles that hold a key visible to it, and a loop over key
+blocks elsewhere, in which a window layer starts at the block of the first
+key its first query sees.
 """
 
 from __future__ import annotations
@@ -42,6 +45,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import chunk_attention, chunk_attention_form
 from ..ops.layers import layer_norm, rope_interleaved, rope_rows
 from ..ops.quant import mm
 from ..parallel.moe import (expert_share,  # noqa: F401 (re-export)
@@ -187,11 +191,26 @@ def _qkv(layer, h, cos, sin, kind, cfg: Cohere2MoeConfig):
 
 def _prompt_attention(q, buf_k, buf_v, start, window, cfg: Cohere2MoeConfig):
     """A chunk's queries [N, H, d] at positions ``start ..`` over the
-    positions so far, key block by key block with an online softmax: no ``L x
-    L`` array. buf_k, buf_v [T, kvh, d] hold every position's row up to the
-    chunk's end. Key ``j`` is visible to query ``i`` iff ``j <= i`` and, with
-    a ``window``, ``i - j < window``: such a layer starts at the block of the
-    first key its first query sees. -> o [N, H * d]."""
+    positions so far, with an online softmax: no ``L x L`` array. buf_k,
+    buf_v [T, kvh, d] hold every position's row up to the chunk's end. Key
+    ``j`` is visible to query ``i`` iff ``j <= i`` and, with a ``window``,
+    ``i - j < window``. -> o [N, H * d].
+
+    Which form runs follows from what the call sees
+    (``ops.attention.chunk_attention_form``): on a TPU, at heads of whole
+    lanes and whole tiles of rows, the flash kernel
+    ``ops.attention.chunk_attention`` (scores and accumulator in VMEM, a row
+    tile visiting the key tiles it can see and no other); everywhere else
+    (every CPU run, lfm2's heads of 64) the loop below over blocks of
+    ``cfg.key_block`` keys, whose float32 maximum, sum and accumulator are
+    carried through HBM and whose every row visits every block from the
+    first one its first query sees to the chunk's end. One arithmetic: bf16
+    operands, float32 scores and sums, the weights cast to V's dtype, one
+    division at the end."""
+    if chunk_attention_form(q.shape[0], buf_k.shape[0], q.shape[1],
+                            cfg.n_kv_heads, cfg.head_dim,
+                            q.dtype) == "kernel":
+        return chunk_attention(q, buf_k, buf_v, start, window)
     N, kvh, d = q.shape[0], cfg.n_kv_heads, cfg.head_dim
     Kb = cfg.key_block
     qg = q.reshape(N, kvh, -1, d)

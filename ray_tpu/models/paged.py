@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.attention import chunk_attention_form
 from ..ops.layers import apply_rope, rms_norm, rope_frequencies
 from ..ops.quant import mm
 from ..parallel.moe import (grouped_pairs, grouped_product_form,
@@ -504,6 +505,9 @@ class _Family:
     #                         ``kernel``; None where its rows are not
     #                         grouped): asked once, when the engine is built,
     #                         and said by every prefill span
+    prompt_attn: bool = False   # its chunk's attention is ``cohere2_moe.
+    #                         _prompt_attention``: every prefill span says
+    #                         the form it takes (``_prompt_attn_form``)
     pool_shape: object = None   # (cfg, num_pages, page_size) -> the shape
     #                         of a layer's pool where a position's row is
     #                         not laid out as (n_kv_heads, head_dim)
@@ -536,6 +540,14 @@ def _held_form(family, width: str = "n_experts"):
         cfg.prefill_chunk, cfg.top_k, cfg.experts_held, cfg.d_model,
         cfg.expert_d_ff, getattr(cfg, width), cfg.dtype,
         family.GROUPED_FROM_ROWS)[0]
+
+
+def _prompt_attn_form(cfg, max_len: int) -> str:
+    """The form ``cohere2_moe._prompt_attention`` takes (``kernel`` or
+    ``loop``) at a chunk's queries over an engine's ``max_len`` buffered
+    rows: the answer of the function it asks itself."""
+    return chunk_attention_form(cfg.prefill_chunk, max_len, cfg.n_heads,
+                                cfg.n_kv_heads, cfg.head_dim, cfg.dtype)
 
 
 _FAMILIES = {
@@ -580,7 +592,7 @@ _FAMILIES = {
         lambda cfg: cfg.n_full_layers, _cohere_state, _cohere_prefill,
         _cohere_step, _cohere_counts, admit_fields=_cohere_admit_fields,
         chunked=True, landed=_routing_landed,
-        experts_form=_held_form(cohere, "router_width"),
+        experts_form=_held_form(cohere, "router_width"), prompt_attn=True,
         write_state=_cohere_write_state,
         no_prefix_cache="window layers whose K/V is a per-slot ring: a ring "
                         "is not shareable by page",
@@ -595,7 +607,7 @@ _FAMILIES = {
         lambda cfg: cfg.n_attn_layers, _lfm2_state, _lfm2_prefill,
         _lfm2_step, _lfm2_counts, admit_fields=_lfm2_admit_fields,
         chunked=True, landed=_routing_landed,
-        experts_form=_held_form(lfm2),
+        experts_form=_held_form(lfm2), prompt_attn=True,
         pool_shape=lambda cfg, pages, page: lane_pool_shape(
             pages, page, cfg.n_kv_heads, cfg.head_dim),
         write_state=_lfm2_write_state,
@@ -611,7 +623,7 @@ _FAMILIES = {
         lambda cfg: cfg.n_attn_layers, _nemotron_state, _granite_prefill,
         _granite_step, _granite_counts, admit_fields=_granite_admit_fields,
         chunked=True, landed=_routing_landed,
-        experts_form=_held_form(granite),
+        experts_form=_held_form(granite), prompt_attn=True,
         no_prefix_cache="snapshots of every Mamba layer's SSM state and "
                         "tail at page boundaries beside the shared pages",
         no_int8="a float32 SSM state beside int8 pages: the step reads its "
@@ -744,6 +756,9 @@ class PagedEngine:
                 f"cfg.prefill_chunk {cfg.prefill_chunk}")
         form = fam.experts_form(cfg) if fam.experts_form else None
         self._prefill_fields = {"experts_form": form} if form else {}
+        if fam.prompt_attn:
+            self._prefill_fields["prompt_attn_form"] = _prompt_attn_form(
+                cfg, self.max_len)
         self.n_kv = fam.n_kv(cfg)
         shape = (fam.pool_shape(cfg, num_pages, page_size) if fam.pool_shape
                  else (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim))

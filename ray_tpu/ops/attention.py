@@ -9,6 +9,11 @@ Two implementations:
     matmuls, causal-block skipping. Falls back transparently off-TPU.
   * ``dense_attention`` — pure-jax reference (XLA already fuses this well on
     short sequences; also the correctness oracle in tests).
+
+And, for serving, ``chunk_attention`` (end of file): a prompt chunk's queries
+at a traced offset over a buffer of every position so far, causal with an
+optional window, [N, H, D] over [T, Hk, D]: the one kernel here that takes an
+offset and a window.
 """
 
 from __future__ import annotations
@@ -477,3 +482,229 @@ def pallas_flash_reference(q, k, v, causal: bool = False,
     of = _flash_attention_bhld(qf, kf, vf, causal, scale,
                                min(block_q, L), min(block_k, L), interpret)
     return of.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+
+
+# ------------------------------------------- a prompt chunk over its prefix
+def _chunk_tile_range(row0, tq: int, tk: int, window: int, tiles: int):
+    """(first, last) key tile that holds a key visible to one of the ``tq``
+    queries at positions ``row0 ..``."""
+    first = jnp.maximum(row0 - window + 1, 0) // tk if window else 0
+    return first, jnp.minimum((row0 + tq - 1) // tk, tiles - 1)
+
+
+def _chunk_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, qs_ref, m_ref, l_ref,
+                  acc_ref, *, scale, window, tiles):
+    """One (K/V head, query tile, key step) of ``chunk_attention``. q_ref
+    [tq, rep * d]: the tile's rows of the ``rep`` heads that share the K/V
+    head, side by side; k_ref, v_ref [tk, d]: the key tile the index map
+    picked (the tile range's ``first + step``, held at its last once past
+    it); o_ref as q_ref. The heads are stacked ``heads`` to a product: qs_ref
+    [rep / heads, heads * tq, d] holds the queries so, acc_ref the weighted
+    values in float32, m_ref and l_ref [.., heads * tq, 1] the running
+    maximum and sum, all across the key steps."""
+    from jax.experimental import pallas as pl
+
+    tq, (tk, d) = q_ref.shape[0], k_ref.shape
+    products, rows = qs_ref.shape[:2]
+    heads = rows // tq
+    i, step = pl.program_id(1), pl.program_id(2)
+    row0 = start_ref[0] + i * tq
+    first, last = _chunk_tile_range(row0, tq, tk, window, tiles)
+    tile = first + step
+
+    @pl.when(step == 0)
+    def _():
+        for r in range(products * heads):
+            qs_ref[r // heads, pl.ds((r % heads) * tq, tq), :] = \
+                q_ref[:, r * d:(r + 1) * d]
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def visit(masked: bool):
+        k, v = k_ref[...], v_ref[...]
+
+        def product(c, _):
+            s = jax.lax.dot_general(
+                qs_ref[c], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if masked:
+                t = row0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (heads, tq, tk), 1).reshape(rows, tk)
+                j = tile * tk + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, tk), 1)
+                ok = j <= t
+                if window:
+                    ok = ok & (t - j < window)
+                s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[c]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # a row no key of this tile is visible to: exp(0) of every key
+            # if none was before (a later tile's alpha of 0 wipes it: its own
+            # position is visible to every row), 0 if one was
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[c] = alpha * l_ref[c] + p.sum(axis=-1, keepdims=True)
+            acc_ref[c] = alpha * acc_ref[c] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[c] = m_new
+
+        jax.lax.fori_loop(0, products, product, None)
+
+    # the diagonal crosses the tile, or the window's edge does
+    crossed = (tile + 1) * tk - 1 > row0
+    if window:
+        crossed = crossed | (row0 + tq - 1 - tile * tk >= window)
+    live = tile <= last
+    pl.when(live & crossed)(lambda: visit(True))
+    pl.when(live & jnp.logical_not(crossed))(lambda: visit(False))
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        for r in range(products * heads):
+            at = pl.ds((r % heads) * tq, tq)
+            o = acc_ref[r // heads, at, :] / l_ref[r // heads, at, :]
+            o_ref[:, r * d:(r + 1) * d] = o.astype(o_ref.dtype)
+
+
+#: Tiles of ``chunk_attention``, timed alone on one TPU v5e at Command A+'s
+#: and granite's shapes (a chunk of 2048 rows of 128 / 32 heads of 128 over 8
+#: K/V heads, behind 0 to 24 576 positions; PR 59, PERF.md section 5). Keys a
+#: tile: 1024 everywhere (a full layer behind 24 576 positions 26.4 ms,
+#: where 512 keys take 41-50, 256 take 71-97 and 2048 take 28-29: what a key
+#: step costs beside its two products, the accumulator read, scaled and
+#: written back, does not shrink with the tile). Rows of queries a tile and
+#: heads a product hardly matter from 512 stacked rows up (26.1-27.4 ms over
+#: 128-512 rows x 512-2048 stacked), so they are the most that fit the VMEM
+#: every kernel gets unasked.
+_CHUNK_Q_CAP = 256          # rows of queries a tile
+_CHUNK_K_CAP = 1024         # keys a tile
+_CHUNK_ROWS_CAP = 1024      # rows a product: whole heads of a group, stacked
+#: What a call may hold in VMEM: the compiler's own limit for a kernel's
+#: scope. A kernel that asks for more (``vmem_limit_bytes``) is given it out
+#: of what XLA keeps resident for the fusions AROUND it: with 64 MiB asked,
+#: granite's chunk program lost the prefetches of its nine Mamba layers'
+#: in-projections and ran 6.7 ms longer for 0.6 ms of attention saved.
+_CHUNK_VMEM_BYTES = 16 * 2 ** 20
+
+
+def chunk_attention_tiles(N: int, T: int, rep: int, d: int, itemsize: int):
+    """(query rows a tile, keys a tile, heads a product) of
+    ``chunk_attention`` from the shapes alone: the keys' tile the largest
+    under its cap that tiles ``T``, then the most query rows and the most
+    heads a product whose call fits ``_CHUNK_VMEM_BYTES`` by the count below
+    (Mosaic accepted and refused as it says at every tile tried: AOT, PR 59);
+    None where ``N`` queries over ``T`` buffered rows are not whole tiles."""
+    if N % 128 or T % 128:
+        return None
+    tk = _block(_CHUNK_K_CAP, T)
+    tq = _block(_CHUNK_Q_CAP, N)
+    while tq >= 128:
+        group = rep * tq    # rows of every head of a K/V head
+        held = (group * d * (5 * itemsize + 4)      # q and o twice, qs; acc
+                + 2 * group * 128 * 4               # maximum and sum, a lane
+                + 4 * tk * d * itemsize)            # K and V, twice
+        fits = [h for h in range(1, rep + 1) if rep % h == 0
+                and h * tq <= _CHUNK_ROWS_CAP
+                and held + h * tq * tk * (4 + itemsize) <= _CHUNK_VMEM_BYTES]
+        if fits:
+            return tq, tk, max(fits)
+        tq //= 2
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=("window", "tiles", "interpret"))
+def _chunk_attention(q, buf_k, buf_v, start, window, tiles, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, H, d = q.shape
+    T, kvh, _ = buf_k.shape
+    rep = H // kvh
+    tq, tk, heads = tiles
+    if N % tq or T % tk or rep % heads:
+        raise ValueError(f"tiles {tiles} do not divide q{q.shape} over "
+                         f"{buf_k.shape}")
+    n_tiles = T // tk
+    # the key tiles one query tile can need: a window layer's span of
+    # window + tq - 1 keys, a full layer's whole buffer
+    steps = min(n_tiles, (window + tq - 3) // tk + 2) if window else n_tiles
+
+    def kv_map(g, i, step, start_ref):
+        first, last = _chunk_tile_range(start_ref[0] + i * tq, tq, tk,
+                                        window, n_tiles)
+        return jnp.minimum(first + step, last), g
+
+    rows = heads * tq
+    kernel = functools.partial(_chunk_kernel, scale=d ** -0.5, window=window,
+                               tiles=n_tiles)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kvh, N // tq, steps),
+            in_specs=[pl.BlockSpec((tq, rep * d), lambda g, i, s, _: (i, g)),
+                      pl.BlockSpec((tk, d), kv_map),
+                      pl.BlockSpec((tk, d), kv_map)],
+            out_specs=pl.BlockSpec((tq, rep * d), lambda g, i, s, _: (i, g)),
+            scratch_shapes=[
+                pltpu.VMEM((rep // heads, rows, d), q.dtype),
+                pltpu.VMEM((rep // heads, rows, 1), jnp.float32),
+                pltpu.VMEM((rep // heads, rows, 1), jnp.float32),
+                pltpu.VMEM((rep // heads, rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((N, H * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="ray_tpu_chunk_attention",     # the kernel's name in a profile
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), q.reshape(N, H * d),
+      buf_k.reshape(T, kvh * d), buf_v.reshape(T, kvh * d))
+
+
+def chunk_attention_form(N: int, T: int, n_heads: int, n_kv_heads: int,
+                         head_dim: int, dtype) -> str:
+    """The implementation of a prompt chunk's attention over its prefix
+    (``models/cohere2_moe._prompt_attention``), from what the call sees of
+    its input and nothing else: ``"kernel"``, ``chunk_attention``, where a
+    head is whole lanes, the ``N`` queries and the ``T`` buffered rows are
+    whole tiles, the dtype is floating and the platform is a TPU
+    (``_on_tpu``, the one function a test replaces); ``"loop"``, the XLA loop
+    over key blocks, everywhere else: every CPU run, a head of 64."""
+    takes = head_dim % 128 == 0 and jnp.issubdtype(dtype, jnp.floating)
+    return "kernel" if (takes and chunk_attention_tiles(
+        N, T, n_heads // n_kv_heads, head_dim, jnp.dtype(dtype).itemsize)
+        and _on_tpu()) else "loop"
+
+
+def chunk_attention(q, buf_k, buf_v, start, window: int = 0,
+                    interpret: bool = False) -> jax.Array:
+    """A chunk's queries over a buffer of every position so far, as a Pallas
+    flash kernel. q [N, H, d] at positions ``start ..`` (``start`` traced: one
+    program serves every chunk); buf_k, buf_v [T, kvh, d] hold every
+    position's row up to the chunk's end. Key ``j`` is visible to query ``i``
+    iff ``j <= i`` and, with a ``window``, ``i - j < window``. -> o [N, H *
+    d] in q's dtype.
+
+    The grid is (K/V head, query tile, key step). The ``H / kvh`` query heads
+    of a K/V head share each key tile: q is read as ``[N, H * d]`` and the
+    buffers as ``[T, kvh * d]``, a block one K/V head's lanes, so K and V are
+    read once a group. ``start`` comes by scalar prefetch: a query tile's
+    first and last key tile follow from it, the tile's rows and ``window``;
+    the key steps walk that range and then stay on its last tile (a block
+    that is named again is not copied again) with their arithmetic skipped,
+    so the masked work of the loop, whose every row visits every key block up
+    to the chunk's end, is not done. The mask is applied on the tiles the
+    diagonal or the window's edge crosses and on no other. Scores are float32
+    from operands in their own dtype, times ``d ** -0.5``; the running
+    maximum, sum and weighted values stay in VMEM in float32 across the key
+    steps; the weights are cast to V's dtype for the second product and
+    divided once, exactly: the loop's arithmetic, in tiles
+    (``chunk_attention_tiles``)."""
+    N, H, d = q.shape
+    tiles = chunk_attention_tiles(N, buf_k.shape[0], H // buf_k.shape[1], d,
+                                  q.dtype.itemsize)
+    if tiles is None:
+        raise ValueError(f"chunk_attention takes whole tiles of 128 rows; "
+                         f"got q{tuple(q.shape)} over {tuple(buf_k.shape)}")
+    return _chunk_attention(q, buf_k, buf_v, start, window, tiles, interpret)

@@ -378,6 +378,29 @@ def test_the_prefill_row_names_the_form_only_where_experts_are_grouped(
                if r["name"] == "serve.admit.prefill")
 
 
+def test_a_chunked_admissions_prefill_row_names_its_attentions_form(
+        conv_model, mamba_moe_model, model):
+    """``serve.admit.prefill`` of a family whose chunk calls ``cohere2_moe.
+    _prompt_attention`` says ``prompt_attn_form``, the answer of the function
+    that picks the form (``ops.attention.chunk_attention_form``) at the
+    engine's chunk and ``max_len``: ``loop`` on the CPU, for every chunk of
+    the admission's row; the dense family's row has no such field."""
+    from ray_tpu.models import paged
+
+    for family in (conv_model, mamba_moe_model):
+        events.reset()
+        eng, by = _conv_rows(family, {
+            "req-aaaa-long": ([1 + i % 90 for i in range(45)], 2),
+            "req-bbbb": ([7, 8, 9], 2)})
+        assert [(p["chunks"], p["prompt_attn_form"])
+                for p in by["serve.admit.prefill"]] \
+            == [(3, "loop"), (1, "loop")]
+        assert paged._prompt_attn_form(family[0], eng.max_len) == "loop"
+    events.reset()
+    _drive(_engine(model))
+    assert not any("prompt_attn_form" in r["fields"] for r in _rows())
+
+
 def test_paged_admit_phases_nest_in_order_and_carry_the_rid(model):
     _drive(_engine(model))
     rows = _rows()

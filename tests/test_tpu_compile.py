@@ -72,6 +72,15 @@ def _grouped_products(text):
             text.count("ragged_dot_tiling="))
 
 
+def _chunk_attention_calls(text):
+    """Calls of the flash kernel ``ops.attention.chunk_attention`` (the
+    kernel form of ``cohere2_moe._prompt_attention``) in a compiled
+    program's text; its custom call carries its name."""
+    return len(re.findall(r"^\s*(?:ROOT )?%ray_tpu_chunk_attention[.\d]* = "
+                          r'[^\n]*custom_call_target="tpu_custom_call"', text,
+                          re.M))
+
+
 def _chunk_form(cfg):
     """What the engine's ``serve.admit.prefill`` rows say of this family's
     chunk (``_Family.experts_form``), held to the compiled chunk's text."""
@@ -395,10 +404,15 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip,
     gathered keys or values as wide as the table (32 x 32 768 positions
     would be 2.1 GB of keys and as much of values: the full layer is read in
     blocks of 16 table columns); the prefill chunk builds no array of chunk x
-    ``max_len`` scores, gives its carried rows back aliased, and multiplies
+    ``max_len`` scores, gives its carried rows back aliased, multiplies
     its rows grouped by expert in the grouped-matmul kernel, three calls a
-    layer (the platform answered as the chip)."""
+    layer, and attends in the flash kernel, one call a layer (the platform
+    answered as the chip): no float32 accumulator ``[kvh 8, rep 16, 2048
+    rows, 128]`` and no block of float32 scores of the loop form, which
+    carried both through HBM at every key block (1.09 s of a traced 6 in the
+    cell before PR 59), comes back."""
     from perfbench.aot_commanda import table_wide_shapes
+    from ray_tpu.models.paged import _prompt_attn_form
     from ray_tpu.models import cohere2_moe as cm
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
@@ -437,12 +451,19 @@ def test_cohere_step_and_prefill_chunk_command_a_plus_widths(one_chip,
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= sum(
         a.size * a.dtype.itemsize for a in jax.tree.leaves(carry))
-    assert m.temp_size_in_bytes < 1.5e9
+    assert m.temp_size_in_bytes < 0.8e9
     text = compiled.as_text()
     assert f"{cfg.prefill_chunk},{max_len}]" not in text
     # the held experts' products are grouped by expert at a chunk's rows
     assert _chunk_form(cfg) == "kernel"
     assert _grouped_products(text) == (3 * cfg.n_layers, 0)
+    # a chunk's queries meet their keys in the flash kernel
+    assert _prompt_attn_form(cfg, max_len) == "kernel"
+    assert _chunk_attention_calls(text) == cfg.n_layers
+    rows = f"{cfg.n_kv_heads},{cfg.n_heads // cfg.n_kv_heads},2048"
+    for carried in (f"f32[{rows},{cfg.head_dim}]",
+                    f"f32[{rows},{cfg.key_block}]", f"f32[{rows}]"):
+        assert carried not in text, carried
 
 
 def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
@@ -525,6 +546,10 @@ def test_lfm2_step_and_prefill_chunk_lfm2_24b_a2b_widths(one_chip,
     # the held experts' products are grouped by expert at a chunk's rows
     assert _chunk_form(cfg) == "kernel"
     assert _grouped_products(compiled.as_text()) == (3 * cfg.n_moe_layers, 0)
+    # heads of 64 are half a lane: its chunk's attention stays the loop
+    from ray_tpu.models.paged import _prompt_attn_form
+    assert _prompt_attn_form(cfg, max_len) == "loop"
+    assert _chunk_attention_calls(compiled.as_text()) == 0
 
 
 def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(
@@ -539,10 +564,12 @@ def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(
     state would be 0.27 GB); the prefill chunk gives its carried K/V rows,
     SSM states and tails back aliased, groups its rows by expert (three
     calls of the grouped-matmul kernel a layer, the platform answered as the
-    chip), and its temporaries (the SSD form's float32 blocks at 2048 positions x 128 heads)
+    chip), attends in the flash kernel (one call an attention layer, no
+    float32 accumulator of the loop form), and its temporaries (the SSD form's float32 blocks at 2048 positions x 128 heads)
     stay under the 1.3 GB the engine leaves beside its resident 13.6 GB."""
     from perfbench.aot_lfm2 import table_wide_shapes
     from ray_tpu.models import granite_moe_hybrid as gm
+    from ray_tpu.models.paged import _prompt_attn_form
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = gm.GraniteMoeHybridConfig(
@@ -583,6 +610,10 @@ def test_granite_step_and_prefill_chunk_granite_4_0_h_small_widths(
     assert f"{cfg.prefill_chunk},{max_len}]" not in text    # no L x T scores
     assert _chunk_form(cfg) == "kernel"
     assert _grouped_products(text) == (3 * cfg.n_layers, 0)
+    assert _prompt_attn_form(cfg, max_len) == "kernel"
+    assert _chunk_attention_calls(text) == cfg.n_attn_layers == 1
+    assert f"f32[{cfg.n_kv_heads},{cfg.n_heads // cfg.n_kv_heads},2048," \
+        not in text
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
