@@ -11,7 +11,7 @@ from .llama import (
     loss_fn,
 )
 
-from . import granite_moe_hybrid, mixtral, vit
+from . import deepseek_v3, granite_moe_hybrid, mixtral, vit
 from .paged import PagedEngine
 from .speculative import generate_speculative
 from .mixtral import (
@@ -21,6 +21,7 @@ from .mixtral import (
     mixtral_shardings,
 )
 from .mixtral import generate_greedy as mixtral_generate_greedy
+from .deepseek_v3 import DeepseekV3Config
 from .granite_moe_hybrid import GraniteMoeHybridConfig
 
 __all__ = [
@@ -30,4 +31,5 @@ __all__ = [
     "generate_speculative", "PagedEngine",
     "mixtral_shardings", "mixtral_generate_greedy",
     "granite_moe_hybrid", "GraniteMoeHybridConfig",
+    "deepseek_v3", "DeepseekV3Config",
 ]

@@ -458,10 +458,10 @@ def _decode_logits(params, pools, tables, toks, lengths,
                 w = _kvb(att, cfg)
             pool = write_latent(row, pools[2 * i + j], page_idx, offs)
             new.append(pool)
-            return attend_latent(
-                q_nope, q_rope, w[..., :cfg.qk_nope_head_dim],
-                w[..., cfg.qk_nope_head_dim:], pool, tables, lengths,
-                cfg.attn_scale)
+            return attend_latent(      # one query row a slot
+                q_nope[:, None], q_rope[:, None],
+                w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:],
+                pool, tables, lengths, cfg.attn_scale)[:, 0]
 
         x, idx, counts = _double_layer(layer, x, attend, active, cfg)
         routing.append(idx)

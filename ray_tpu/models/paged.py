@@ -41,11 +41,13 @@ from .paged_ops import (_quant_kv, lane_pool_shape,  # noqa: F401
                         read_block_pages)  # (re-exports)
 from .llama import LlamaConfig, _mlp_block
 from . import cohere2_moe as cohere
+from . import deepseek_v3 as deepseek
 from . import granite_moe_hybrid as granite
 from . import lfm2_moe as lfm2
 from . import longcat_flash as longcat
 from . import minicpm_sala as sala
 from .cohere2_moe import Cohere2MoeConfig
+from .deepseek_v3 import DeepseekV3Config
 from .granite_moe_hybrid import GraniteMoeHybridConfig
 from .lfm2_moe import Lfm2MoeConfig
 from .longcat_flash import LongcatFlashConfig
@@ -474,6 +476,67 @@ def _granite_counts(eng, tail, sp):
     sp.set(ssm_state_bytes=2 * int(tail[2]) * eng.cfg.slot_state_bytes)
 
 
+def _deepseek_state(eng):
+    # the last landed step's chosen experts, logits of its rows ([S, rows,
+    # V]) and, where it drafts, the MTP block's logits of the next draft and
+    # which drafts it accepted: left on the device, for a reference check
+    eng.last_routing = eng.last_logits = None
+    eng.last_draft_logits = eng.last_accepted = None
+    if eng.cfg.n_nextn:
+        # every slot's draft of the token after its last committed one (-1:
+        # none) and the distribution it was drawn from, on the device: a step
+        # hands them to the next as it hands on the pools
+        eng.drafts = jnp.full((eng.S,), -1, jnp.int32)
+        eng.draft_q = jnp.zeros((eng.S, eng.cfg.vocab_size), jnp.float32)
+
+
+def _deepseek_prefill(eng, suffix, pad, n, shared):
+    # the main model's output at the prompt's end is kept for the first draft
+    first, eng._prompt_end, lats = deepseek.prefill(
+        eng.params, suffix, eng.max_len, eng.cfg)
+    return first, lats, None
+
+
+def _deepseek_first_draft(eng, slot, tok, n):
+    """The admission's first draft, from the pair (the prompt's last output,
+    the first token): one dispatch, nothing fetched."""
+    eng.pools_k[-1], eng.drafts, eng.draft_q = \
+        deepseek._deepseek_first_draft(
+            eng.params, eng.pools_k[-1], eng.tables[slot][None],
+            eng._prompt_end, np.int32(tok), np.int32(n),
+            np.float32(eng.temps[slot]), np.int32(eng.top_ks[slot]),
+            np.float32(eng.top_ps[slot]), eng.drafts, eng.draft_q,
+            eng.keys[slot].astype(np.uint32, copy=False), np.int32(slot),
+            eng.cfg)
+
+
+def _deepseek_step(eng, uploads):
+    if not eng.cfg.n_nextn:     # no MTP module: one token a slot, as the rest
+        toks, eng.pools_k, new_keys, kept, next_tok = \
+            deepseek._deepseek_step_one(eng.params, eng.pools_k, *uploads,
+                                        eng.cfg)
+        return toks, new_keys, next_tok, kept
+    (toks, eng.pools_k, new_keys, kept, next_tok, lengths, eng.drafts,
+     eng.draft_q) = deepseek._deepseek_step(
+        eng.params, eng.pools_k, *uploads, eng.draft_q, eng.drafts, eng.cfg)
+    return toks, new_keys, next_tok, kept, lengths
+
+
+def _deepseek_landed(eng, kept):
+    eng.last_routing, eng.last_logits, *drafted = kept
+    if drafted:
+        eng.last_draft_logits, eng.last_accepted = drafted
+
+
+def _deepseek_counts(eng, tail, sp):
+    # ``attend_latent`` gathers every page of every slot's table, live or not
+    sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]),
+           latent_positions=int(tail[2]),
+           latent_positions_read=int(tail[6]) * eng.S * eng.max_len,
+           drafted=int(tail[3]), accepted=int(tail[4]),
+           moe_rows=int(tail[5]), landed=int(tail[6]))
+
+
 @dataclass(frozen=True)
 class _Family:
     n_kv: object            # cfg -> layers (or sublayers) with a pool
@@ -486,7 +549,9 @@ class _Family:
     #                         counts, the keys, the tokens alone as the next
     #                         step takes them: with them the engine runs
     #                         ahead of the device, ``_step``; what ``landed``
-    #                         publishes or None). It rebinds what the
+    #                         publishes or None; and, from a step that may
+    #                         commit two tokens a slot, the lengths as the
+    #                         next step takes them). It rebinds what the
     #                         program consumed
     counts: object = None   # (engine, what rode with the tokens, the step's
     #                         span): the family's fields of the step row
@@ -517,6 +582,11 @@ class _Family:
     write_state: object = _write_slot_state     # (engine, the prefill's
     #                         state, slot, prompt length): its own where the
     #                         state is not ``eng.ssm`` / ``eng.conv``
+    first_draft: object = None  # (engine, slot, the first token, prompt
+    #                         length): the draft a slot enters its first
+    #                         step with, of a family whose model has an MTP
+    #                         module (``cfg.n_nextn``): its step then verifies
+    #                         a draft and commits one or two tokens a slot
     no_prefix_cache: Optional[str] = (  # why ``enable_prefix_cache`` is
         #                     refused; None where the family has one
         "snapshots of recurrent state at page boundaries; a prefill of the "
@@ -628,6 +698,19 @@ _FAMILIES = {
                         "tail at page boundaries beside the shared pages",
         no_int8="a float32 SSM state beside int8 pages: the step reads its "
                 "pools in the model's dtype"),
+    # ONE latent pool a layer that has attention, the MTP block's the last
+    # (``longcat_flash``'s pool, scatter and chunked admission); where the
+    # model holds an MTP module the step verifies a draft with two query rows
+    # a slot and commits one or two tokens, and the drafts stay on the device
+    DeepseekV3Config: _Family(
+        lambda cfg: cfg.n_sublayers, _deepseek_state, _deepseek_prefill,
+        _deepseek_step, _deepseek_counts, _longcat_scatter,
+        _longcat_admit_fields, chunked=True, landed=_deepseek_landed,
+        experts_form=_held_form(deepseek),
+        pool_shape=lambda cfg, pages, page: latent_pool_shape(
+            pages, page, cfg.latent_width), one_pool=True,
+        first_draft=_deepseek_first_draft,
+        no_int8="its latent pages are kept in the model's dtype"),
 }
 
 
@@ -665,6 +748,9 @@ class _Flight:
     active: List[int]   # the slots that decoded in it
     next_tok: object    # device int32[S]: the tokens alone, the next step's
     kept: object        # what the family publishes when it lands, or None
+    lengths: object = None  # device int32[S]: the next step's lengths, where
+    #                     a step may commit two tokens a slot (else the host
+    #                     knows them)
     # what its ``serve.step.flight`` row says when it lands
     step: int = 0       # its number among the step programs dispatched
     depth: int = 0      # steps already in flight when it was dispatched
@@ -797,6 +883,11 @@ class PagedEngine:
         # ``state`` of a family whose step reads through
         # ``paged_attention``; 0: no such read
         self._read_block = 0
+        # the most tokens a step commits a slot: two where the model drafts
+        # (its own MTP module and a family that steps with it), and then how
+        # far a slot came is known on the device alone until a step lands:
+        # ``slot.length`` is an upper bound while steps are in flight
+        self._reach = 2 if fam.first_draft and cfg.n_nextn else 1
         fam.state(self)
         self._kv_positions = None   # (read, live) of the step dispatched
         # what this step() did, for its ``serve.engine.step`` row
@@ -984,6 +1075,8 @@ class PagedEngine:
             fam = self.family
             chunks = ({"chunks": -(-n // self.cfg.prefill_chunk),
                        **self._prefill_fields} if fam.chunked else {})
+            if self._reach > 1:     # the MTP block ran one token on
+                chunks["mtp_rows"] = n - 1
             scattered, written = (fam.admit_fields(self, n)
                                   if fam.admit_fields else ({}, {}))
             with plane_events.span("serve.admit.prefill", "serve",
@@ -1021,6 +1114,10 @@ class PagedEngine:
             if (eos_id is not None and tok == eos_id) or \
                     len(slot.emitted) >= max_new:
                 slot.done = True
+            elif self._reach > 1:
+                with plane_events.span("serve.admit.draft", "serve",
+                                       rid=rid8, dispatches=1):
+                    fam.first_draft(self, idx, tok, n)
             self.slots[idx] = slot
 
     def _scatter(self, seq_caches, pages: List[int], n_shared: int):
@@ -1108,18 +1205,23 @@ class PagedEngine:
                     self.top_ks, self.top_ps,
                     self.keys.astype(np.uint32, copy=False)))
             else:   # the last step dispatched hands on its tokens and keys
-                tables, *rest = jax.device_put((
+                last = flights[-1]
+                tables, at, *rest = jax.device_put((
                     self.tables, lengths, self.temps, self.top_ks,
                     self.top_ps))
-                uploads = (tables, flights[-1].next_tok, *rest,
-                           flights[-1].keys)
+                if last.lengths is not None:
+                    # ... and how far each slot came, which it alone knows
+                    # where a step may commit two tokens a slot
+                    at = last.lengths
+                uploads = (tables, last.next_tok, at, *rest, last.keys)
         with plane_events.span("serve.step.dispatch", "serve",
                                step=self._dispatched,
                                depth=len(flights)) as sp:
-            toks, new_keys, next_tok, kept = self.family.step(self, uploads)
-            for i in active:    # positions written or on their way
-                self.slots[i].length += 1
-        now = _Flight(toks, new_keys, active, next_tok, kept,
+            toks, new_keys, next_tok, kept, *lengths = self.family.step(
+                self, uploads)
+            for i in active:    # positions written or on their way, at most
+                self.slots[i].length += self._reach
+        now = _Flight(toks, new_keys, active, next_tok, kept, *lengths,
                       step=self._dispatched, depth=len(flights),
                       admitted=self._undispatched_admits,
                       call=self._call_sid, t0_ns=sp.t0_ns)
@@ -1148,14 +1250,16 @@ class PagedEngine:
     def _runs_ahead(self) -> bool:
         """Whether one more step can be dispatched before the tokens in
         flight are fetched: nothing waits for a free slot, no stream ends
-        with a token in flight (the count says so: a stream with an
-        ``eos_id`` is never left in flight), and every slot can take one
-        more page, so the step holds the slots the last one held and no
-        admission, no release and no preemption comes between them."""
+        with a token in flight (the count says so, at the most tokens a
+        flight may commit: a stream with an ``eos_id`` is never left in
+        flight), and every slot can take one more page, so the step holds the
+        slots the last one held and no admission, no release and no
+        preemption comes between them."""
         held = self._flights[-1].active
+        ahead = self._reach * len(self._flights)
         return (not (self.pending and any(s is None for s in self.slots))
                 and len(self.free_pages) >= len(held)
-                and all(len(self.slots[i].emitted) + len(self._flights)
+                and all(len(self.slots[i].emitted) + ahead
                         < self.slots[i].max_new for i in held))
 
     def _land(self, flight: _Flight, events: List[tuple]) -> None:
@@ -1166,45 +1270,57 @@ class PagedEngine:
         with plane_events.span("serve.step.fetch", "serve",
                                step=flight.step) as sp:
             toks, keys = jax.device_get((flight.toks, flight.keys))
+            # a slot's tokens of this step: one, or where the step may commit
+            # two, its two and how many of them count
+            S, reach = self.S, self._reach
+            count = (np.ones(S, np.int32) if reach == 1
+                     else toks[reach * S:(reach + 1) * S])
             if sp.sid and flight.t0_ns:     # recorder on, then and now
                 plane_events.span_done(
                     "serve.step.flight", "serve", flight.t0_ns,
                     step=flight.step, depth=flight.depth,
                     active=len(flight.active), admitted=flight.admitted,
+                    tokens=int(count[flight.active].sum()),
                     wait_ns=time.perf_counter_ns() - sp.t0_ns,
                     call=flight.call, landed_by=self._call_sid)
             self.keys = np.array(keys)
             self._landed += 1
             if self.family.counts:  # they rode with the tokens
-                tail = toks[self.S:]
+                tail = toks[S if reach == 1 else (reach + 1) * S:]
                 self._step_counts = (tail if self._step_counts is None
                                      else self._step_counts + tail)
             if flight.kept is not None:
                 self.family.landed(self, flight.kept)
         with plane_events.span("serve.step.emit", "serve",
-                               tokens=len(flight.active), step=flight.step):
+                               tokens=int(count[flight.active].sum()),
+                               step=flight.step):
             for i in flight.active:
                 s = self.slots[i]
-                tok = int(toks[i])
-                s.emitted.append(tok)
-                self.last_tok[i] = tok
-                events.append((s.request_id, tok))
-                if (s.eos_id is not None and tok == s.eos_id) or \
-                        len(s.emitted) >= s.max_new:
-                    s.done = True
-                    events.append((s.request_id, None))
-                    self._free(s)
-                    self.slots[i] = None
-                    self.tables[i] = 0
+                # the dispatch counted the most the step could commit
+                s.length -= reach - int(count[i])
+                for tok in toks[reach * i:reach * i + int(count[i])]:
+                    tok = int(tok)
+                    s.emitted.append(tok)
+                    self.last_tok[i] = tok
+                    events.append((s.request_id, tok))
+                    if (s.eos_id is not None and tok == s.eos_id) or \
+                            len(s.emitted) >= s.max_new:
+                        # a second token past the end is dropped
+                        s.done = True
+                        events.append((s.request_id, None))
+                        self._free(s)
+                        self.slots[i] = None
+                        self.tables[i] = 0
+                        break
 
     def _grow_tables(self, active: List[int]) -> List[int]:
         """Grow page tables BEFORE the step for slots crossing a page
-        boundary (the write this step lands at position ``length``);
-        -> the slots still active."""
+        boundary (the writes this step lands at positions ``length ..
+        length + _reach - 1``, ``length`` the host's upper bound while steps
+        are in flight); -> the slots still active."""
         for i in active:
             s = self.slots[i]
-            if s.length % self.page == 0 and \
-                    self._pages_needed(s.length + 1) > len(s.pages):
+            if self._pages_needed(s.length + self._reach) > len(s.pages):
                 if not self.free_pages:
                     self._reclaim(1)  # evict idle prefix pages first
                 if not self.free_pages:

@@ -501,51 +501,93 @@ def write_latent(row, pool, page_idx, offs):
         return pool.at[page_idx, offs // 2].set(new)
 
 
-def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
-    """Each slot's query over every page of its table, in the ABSORBED form.
+def write_latent_rows(rows, pool, tables, lengths):
+    """``write_latent`` for ``R`` rows a slot, rows [S, R, W] at positions
+    ``lengths .. lengths + R - 1`` of each slot's table, one after another
+    (two positions share a pool row, so the second write reads what the
+    first left)."""
+    page = 2 * pool.shape[1]
+    for r in range(rows.shape[1]):
+        at = lengths + r
+        page_idx = jnp.take_along_axis(tables, (at // page)[:, None],
+                                       axis=1)[:, 0]
+        pool = write_latent(rows[:, r], pool, page_idx, at % page)
+    return pool
 
-    q_nope [S, H, dn], q_rope [S, H, dr] (rotated); w_uk [C, H, dn] and w_uv
-    [C, H, dv]: the up-projection of the latent to each head's keys and
-    values; pool [num_pages, page / 2, 2 (C + dr)]; tables [S, P]. Per head
-    ``q~ = W_UK q_nope`` in the latent's C dims, the score of a cached row
-    is ``(q~ . c + q_rope . k_r) * scale``, rows past the query's position
-    are masked, the softmax weights sum the LATENTS, and ``W_UV`` is applied
-    once a head. The same mathematics as attention over ``k = [c W_UK |
-    k_r]``, ``v = c W_UV``, at ``2 H (2 C + dr)`` operations a cached
-    position where expanding one would cost ``2 C H (dn + dv)``.
+
+#: slots whose pages ``attend_latent`` gathers at once: the gathered rows of
+#: a block (``block x max_len x 1152 B``) and its scores are temporaries of
+#: the step, 1.7 GB for 64 slots of 14 336 positions at once (AOT, PR 60)
+#: and an eighth of it so
+LATENT_SLOT_BLOCK = 8
+
+
+def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
+    """Each slot's ``R`` query rows over every page of its table, in the
+    ABSORBED form.
+
+    q_nope [S, R, H, dn], q_rope [S, R, H, dr] (rotated): row ``r`` stands at
+    position ``lengths + r`` and sees the cached rows up to its own, those of
+    the rows before it among them (the caller has written all ``R``; a step
+    of one token a slot brings ``R = 1``, one that verifies a draft 2); w_uk
+    [C, H, dn] and w_uv [C, H, dv]: the up-projection of the latent to each
+    head's keys and values; pool [num_pages, page / 2, 2 (C + dr)]; tables
+    [S, P]. Per head ``q~ = W_UK q_nope`` in the latent's C dims, the score
+    of a cached row is ``(q~ . c + q_rope . k_r) * scale``, rows past the
+    query's position are masked, the softmax weights sum the LATENTS, and
+    ``W_UV`` is applied once a head. The same mathematics as attention over
+    ``k = [c W_UK | k_r]``, ``v = c W_UV``, at ``2 H (2 C + dr)`` operations
+    a cached position and query row where expanding one would cost ``2 C H
+    (dn + dv)``.
 
     The gathered pages are contracted as the pool stores them, two positions
     a row: the queries stand twice, ``[q, 0]`` against a row's first half and
-    ``[0, q]`` against its second (2 H rows fill the 128-wide unit that H =
-    64 would leave half empty), and the weighted sum of rows is read from the
-    matching halves. No copy of the gathered rows is sliced or reshaped.
-    -> o [S, H * dv]."""
+    ``[0, q]`` against its second (at ``R = 1`` the 2 H rows fill the
+    128-wide unit that H = 64 would leave half empty), and the weighted sum
+    of rows is read from the matching halves. No copy of the gathered rows is
+    sliced or reshaped, and the ``2 R H`` rows of a slot meet its pages in
+    ONE product each way, so the pages are read once whatever ``R``. The
+    slots go ``LATENT_SLOT_BLOCK`` at a time (the largest divisor of ``S``
+    not over it), one block after another. -> o [S, R, H * dv]."""
     with jax.named_scope("latent_attn"):
         S, P = tables.shape
-        H, C = q_nope.shape[1], w_uk.shape[0]
+        R, H, C = q_nope.shape[1], q_nope.shape[2], w_uk.shape[0]
         W = pool.shape[2] // 2
-        R = P * pool.shape[1]               # rows of two positions a slot
+        N = P * pool.shape[1]               # rows of two positions a slot
         dt = pool.dtype
-        q_lat = jnp.einsum("shd,chd->shc", q_nope, w_uk,
+        q_lat = jnp.einsum("srhd,chd->srhc", q_nope, w_uk,
                            preferred_element_type=jnp.float32)
         q = jnp.concatenate([q_lat.astype(dt), q_rope.astype(dt)], axis=-1)
         z = jnp.zeros_like(q)
-        q2 = jnp.concatenate([jnp.concatenate([q, z], axis=-1),
-                              jnp.concatenate([z, q], axis=-1)], axis=1)
-        rows = pool[tables].reshape(S, R, 2 * W)
-        s = jnp.einsum("sgw,srw->sgr", q2, rows,
-                       preferred_element_type=jnp.float32) * scale
-        s = s.reshape(S, 2, H, R)
-        pos = 2 * jnp.arange(R)[None, :] + jnp.arange(2)[:, None]   # [2, R]
-        admit = pos[None] <= lengths[:, None, None]
-        s = jnp.where(admit[:, :, None, :], s, -1e30)
-        e = jnp.exp(s - s.max(axis=(1, 3), keepdims=True))
-        p = e / e.sum(axis=(1, 3), keepdims=True)
-        o2 = jnp.einsum("sgr,srw->sgw", p.reshape(S, 2 * H, R).astype(dt),
-                        rows, preferred_element_type=jnp.float32)
-        o_lat = o2[:, :H, :C] + o2[:, H:, W:W + C]
-        o = jnp.einsum("shc,chd->shd", o_lat.astype(dt), w_uv)
-        return o.reshape(S, -1)
+        q2 = jnp.stack([jnp.concatenate([q, z], axis=-1),
+                        jnp.concatenate([z, q], axis=-1)], axis=1)
+        pos = 2 * jnp.arange(N)[None, :] + jnp.arange(2)[:, None]   # [2, N]
+
+        def block(args):
+            q2, tables, lengths = args      # [B, 2, R, H, 2 W], [B, P], [B]
+            B = tables.shape[0]
+            rows = pool[tables].reshape(B, N, 2 * W)
+            s = jnp.einsum("sgw,snw->sgn", q2.reshape(B, 2 * R * H, 2 * W),
+                           rows, preferred_element_type=jnp.float32) * scale
+            s = s.reshape(B, 2, R, H, N)
+            last = lengths[:, None] + jnp.arange(R)[None, :]        # [B, R]
+            admit = pos[None, :, None, :] <= last[:, None, :, None]
+            s = jnp.where(admit[:, :, :, None, :], s, -1e30)
+            e = jnp.exp(s - s.max(axis=(1, 4), keepdims=True))
+            p = e / e.sum(axis=(1, 4), keepdims=True)
+            o2 = jnp.einsum("sgn,snw->sgw",
+                            p.reshape(B, 2 * R * H, N).astype(dt), rows,
+                            preferred_element_type=jnp.float32)
+            o2 = o2.reshape(B, 2, R, H, 2 * W)
+            return o2[:, 0, :, :, :C] + o2[:, 1, :, :, W:W + C]
+
+        B = max(b for b in range(1, LATENT_SLOT_BLOCK + 1) if S % b == 0)
+        o_lat = jax.lax.map(block, (
+            q2.reshape(S // B, B, *q2.shape[1:]),
+            tables.reshape(S // B, B, P), lengths.reshape(S // B, B)))
+        o = jnp.einsum("srhc,chd->srhd",
+                       o_lat.reshape(S, R, H, C).astype(dt), w_uv)
+        return o.reshape(S, R, -1)
 
 
 # ---------------------------------------------------- window layers as rings

@@ -8,10 +8,12 @@ lives in ``attention.py``).
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -51,6 +53,52 @@ def rope_rows(positions: jax.Array, head_dim: int,
                                            dtype=jnp.float32) / head_dim))
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_len: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's rotary frequencies [head_dim / 2] (float64, a constant of the
+    program): pair ``j`` turns at ``f_j = theta ** (-2j / head_dim)`` where it
+    makes more than ``beta_fast`` turns over the ``original_len`` positions
+    the model was trained on, at ``f_j / factor`` where it makes fewer than
+    ``beta_slow``, and a linear blend of the two between. ``cd(n) = head_dim
+    ln(original_len / (2 pi n)) / (2 ln theta)`` is the pair that makes ``n``
+    turns; the ramp runs from ``floor(cd(beta_fast))`` to
+    ``ceil(cd(beta_slow))``, both clipped to the pairs there are."""
+    f = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+
+    def cd(turns):
+        return (head_dim * math.log(original_len / (2 * math.pi * turns))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(cd(beta_fast)), 0)
+    high = min(math.ceil(cd(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1`` (1 at a
+    factor of 1 or under)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_rows(positions: jax.Array, head_dim: int, theta: float,
+              factor: float, original_len: int, beta_fast: float,
+              beta_slow: float, mscale: float, mscale_all_dim: float
+              ) -> Tuple[jax.Array, jax.Array]:
+    """``rope_rows`` at YaRN's frequencies: cos, sin [N, head_dim / 2] of the
+    given positions, times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)`` (1 where the two are equal, as DeepSeek-V3's are: the
+    temperature then lies in the softmax scale alone)."""
+    inv_freq = jnp.asarray(yarn_inv_freq(
+        head_dim, theta, factor, original_len, beta_fast, beta_slow),
+        jnp.float32)
+    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return jnp.cos(freqs) * m, jnp.sin(freqs) * m
 
 
 def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array

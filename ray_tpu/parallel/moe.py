@@ -91,18 +91,39 @@ def _expert_ffn(h: jax.Array, experts: Dict[str, jax.Array],
     return y
 
 
+def _keep_groups(biased: jax.Array, n_group: int, topk_group: int
+                 ) -> jax.Array:
+    """``sigmoid_gates``' group-limited choice (DeepSeek-V3's ``noaux_tc``):
+    biased [T, E] stands in ``n_group`` equal groups, a group's score is the
+    sum of its two largest entries, the ``topk_group`` best groups are kept
+    and every other group's entries are set to 0, so that the top k are
+    chosen among what is left. One group is no limit: the array as it came."""
+    if n_group == 1:
+        return biased
+    T, E = biased.shape
+    groups = biased.reshape(T, n_group, E // n_group)
+    score = lax.top_k(groups, 2)[0].sum(-1)                     # [T, n_group]
+    _, best = lax.top_k(score, topk_group)
+    keep = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], groups, 0.0).reshape(T, E)
+
+
 def sigmoid_gates(x: jax.Array, w_router: jax.Array, bias: jax.Array,
                   k: int, scale: float, norm: bool = True,
-                  eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
+                  eps: float = 1e-20, n_group: int = 1, topk_group: int = 1
+                  ) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid router with a selection bias, in float32. x: [T, D],
     w_router: [D, E], bias: [E] -> (weights [T, k], experts [T, k]). The
-    top k are chosen by ``score + bias``; the weights are the chosen
-    experts' scores WITHOUT the bias, normalised to sum to one where
-    ``norm`` (over their sum ``+ eps``: a family's own constant), times
-    ``scale``."""
+    top k are chosen by ``score + bias``, among the ``topk_group`` best of
+    ``n_group`` groups where there is more than one (``_keep_groups``); the
+    weights are the chosen experts' scores WITHOUT the bias, normalised to
+    sum to one where ``norm`` (over their sum ``+ eps``: a family's own
+    constant), times ``scale``."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                     w_router.astype(jnp.float32)))
-    _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+    biased = _keep_groups(scores + bias.astype(jnp.float32), n_group,
+                          topk_group)
+    _, idx = lax.top_k(biased, k)
     vals = jnp.take_along_axis(scores, idx, axis=-1)
     if norm:
         vals = vals / (vals.sum(-1, keepdims=True) + eps)
@@ -585,3 +606,4 @@ def expert_shardings(experts: Any, mesh) -> Any:
             mesh, clean_spec(specs.get(name, P("ep")), leaf.shape, mesh))
 
     return {name: one(name, leaf) for name, leaf in experts.items()}
+

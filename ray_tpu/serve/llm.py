@@ -34,6 +34,17 @@ class LLMServer:
     ``num_pages=None`` sizes the pool so that ``max_slots`` sequences of
     ``max_len`` fit at once and no request waits for memory; a smaller
     pool admits by pages and preempts by recompute when it runs dry.
+
+    ``generation_defaults`` (``{"temperature": ...}``, the one field a
+    configuration has needed) is the deployment's sampling for a request that
+    does not say: what a model's ``generation_config.json`` is to a serving
+    system. A request's own field wins; without the keyword a silent request
+    is greedy, as before.
+
+    Whether a step drafts is the model's own: a family whose tree holds an
+    MTP module (``DeepseekV3Config.n_nextn``) is stepped with its drafts
+    inside the engine, one or two tokens a slot a step, with no keyword here
+    (``draft_factory`` is the older batch-1 path beside the engine).
     """
 
     def __init__(self, model_factory, *, max_slots: int = 4,
@@ -41,7 +52,13 @@ class LLMServer:
                  num_pages: Optional[int] = None, page_size: int = 16,
                  enable_prefix_cache: bool = False,
                  kv_dtype: str = "model",
-                 draft_factory=None, draft_k: int = 4):
+                 draft_factory=None, draft_k: int = 4,
+                 generation_defaults: Optional[Dict[str, Any]] = None):
+        unknown = set(generation_defaults or ()) - {"temperature"}
+        if unknown:
+            raise ValueError(f"generation_defaults: unknown {sorted(unknown)}")
+        self._default_temperature = float(
+            (generation_defaults or {}).get("temperature", 0.0))
         # Selects nothing: accepted for callers that still pass the one
         # value left (the benchmark's configuration files).
         if kv_cache != "paged":
@@ -152,8 +169,9 @@ class LLMServer:
                                max_new_tokens=int(
                                    body.get("max_new_tokens", 32)),
                                eos_id=body.get("eos_id"),
-                               temperature=float(
-                                   body.get("temperature", 0.0)),
+                               temperature=float(body.get(
+                                   "temperature",
+                                   self._default_temperature)),
                                top_k=int(body.get("top_k", 0)),
                                top_p=float(body.get("top_p", 1.0)),
                                seed=body.get("seed"))
@@ -366,7 +384,8 @@ def build_llm_app(model_factory, *, max_slots: int = 4,
                   num_pages: Optional[int] = None, page_size: int = 16,
                   enable_prefix_cache: bool = False,
                   kv_dtype: str = "model",
-                  draft_factory=None, draft_k: int = 4):
+                  draft_factory=None, draft_k: int = 4,
+                  generation_defaults: Optional[Dict[str, Any]] = None):
     """Bind an LLM serving app (reference shape: ``serve.llm``
     builders): ``serve.run(build_llm_app(factory))`` serves from
     ``models/paged.py``'s engine, its page pool sized so that no request
@@ -390,4 +409,5 @@ def build_llm_app(model_factory, *, max_slots: int = 4,
                     num_pages=num_pages, page_size=page_size,
                     enable_prefix_cache=enable_prefix_cache,
                     kv_dtype=kv_dtype,
-                    draft_factory=draft_factory, draft_k=draft_k)
+                    draft_factory=draft_factory, draft_k=draft_k,
+                    generation_defaults=generation_defaults)

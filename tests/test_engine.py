@@ -273,6 +273,7 @@ def test_prefill_in_chunks_is_one_loop_over_the_chunk_program(n, chunks,
 def _debug_configs():
     from ray_tpu.models import LLAMA_DEBUG
     from ray_tpu.models.cohere2_moe import COHERE2_MOE_DEBUG
+    from ray_tpu.models.deepseek_v3 import DEEPSEEK_V3_DEBUG
     from ray_tpu.models.granite_moe_hybrid import GRANITE_MOE_HYBRID_DEBUG
     from ray_tpu.models.lfm2_moe import LFM2_MOE_DEBUG
     from ray_tpu.models.longcat_flash import LONGCAT_FLASH_DEBUG
@@ -282,17 +283,18 @@ def _debug_configs():
     return {"dense": LLAMA_DEBUG, "hybrid": NEMOTRON_H_DEBUG,
             "sparse": MINICPM_SALA_DEBUG, "latent": LONGCAT_FLASH_DEBUG,
             "window-full": COHERE2_MOE_DEBUG, "conv-attention": LFM2_MOE_DEBUG,
-            "mamba-moe": GRANITE_MOE_HYBRID_DEBUG}
+            "mamba-moe": GRANITE_MOE_HYBRID_DEBUG,
+            "latent-mtp": DEEPSEEK_V3_DEBUG}
 
 
 @pytest.mark.parametrize("name", ["dense", "hybrid", "sparse", "latent",
                                   "window-full", "conv-attention",
-                                  "mamba-moe"])
+                                  "mamba-moe", "latent-mtp"])
 def test_every_family_is_a_whole_row_of_the_one_engine(name):
     from ray_tpu.models import paged
 
     cfg = _debug_configs()[name]
-    assert len(paged._FAMILIES) == 7
+    assert len(paged._FAMILIES) == 8
     eng = PagedEngine(None, cfg, max_slots=2, num_pages=24, page_size=8,
                       max_len=96)
     row = eng.family
@@ -304,7 +306,7 @@ def test_every_family_is_a_whole_row_of_the_one_engine(name):
         assert may is None or callable(may)
     # chunked: no buckets, and the config says the chunk
     chunked = name in ("sparse", "latent", "window-full", "conv-attention",
-                       "mamba-moe")
+                       "mamba-moe", "latent-mtp")
     assert row.chunked is chunked and hasattr(cfg, "prefill_chunk") is chunked
     assert eng._prefill_buckets == row.buckets == (
         () if chunked else (16, 64, 256))
@@ -316,10 +318,21 @@ def test_every_family_is_a_whole_row_of_the_one_engine(name):
     assert eng.n_kv == row.n_kv(cfg) == len(eng.pools_k)
     # a pool of the family's own shape may still be K beside V (a head
     # narrower than a lane kept as whole-lane rows): ``one_pool`` says
-    assert row.one_pool is (name == "latent")
+    assert row.one_pool is (name in ("latent", "latent-mtp"))
     assert len(eng.pools_v) == (0 if row.one_pool else eng.n_kv)
-    assert (row.pool_shape is not None) is (name in ("latent",
-                                                     "conv-attention"))
+    assert (row.pool_shape is not None) is (name in (
+        "latent", "conv-attention", "latent-mtp"))
+    # a step commits one token a slot, or up to two where the row drafts AND
+    # the model holds an MTP module: no engine keyword says so
+    assert (row.first_draft is not None) is (name == "latent-mtp")
+    assert eng._reach == (2 if name == "latent-mtp" else 1)
+    if name == "latent-mtp":
+        import dataclasses
+
+        plain = PagedEngine(None, dataclasses.replace(cfg, n_nextn=0),
+                            max_slots=2, num_pages=24, page_size=8,
+                            max_len=96)
+        assert plain._reach == 1 and plain.n_kv == eng.n_kv - 1
 
 
 def test_a_subclass_of_a_rows_config_takes_that_row():
@@ -342,3 +355,40 @@ def test_a_subclass_of_a_rows_config_takes_that_row():
 def test_a_config_without_a_row_is_refused():
     with pytest.raises(TypeError, match="object has no row in _FAMILIES"):
         PagedEngine(None, object())
+
+
+@pytest.mark.parametrize("body, want", [
+    ({}, (0.7, 0, 1.0)), ({"temperature": 0.0}, (0.0, 0, 1.0)),
+    ({"top_k": 5, "top_p": 0.9}, (0.7, 5, 0.9)),
+    ({"temperature": 1.3, "top_k": 2, "top_p": 0.5}, (1.3, 2, 0.5))],
+    ids=["silent", "greedy-asked", "cuts-asked", "all-asked"])
+def test_generation_defaults_apply_to_the_fields_a_request_lacks(model, body,
+                                                                  want):
+    """A deployment's default sampling (a model's ``generation_config.json``)
+    reaches a request that says nothing; a request's own field wins."""
+    import asyncio
+
+    from ray_tpu.serve.llm import LLMServer
+
+    cfg, params = model
+    server = LLMServer(lambda: (params, cfg), max_slots=2, max_len=64,
+                       generation_defaults={"temperature": 0.7})
+    seen = []
+    server.engine.submit = lambda rid, prompt, **kw: seen.append(kw)
+    server._ensure_loop = lambda: None
+
+    async def one():
+        server._submit({"prompt": [1, 2, 3], "max_new_tokens": 4, **body})
+
+    asyncio.run(one())
+    assert (seen[0]["temperature"], seen[0]["top_k"], seen[0]["top_p"]) == want
+
+
+def test_generation_defaults_refuse_a_field_they_do_not_know(model):
+    from ray_tpu.serve.llm import LLMServer
+
+    cfg, params = model
+    with pytest.raises(ValueError, match="top_k"):   # no configuration's yet
+        LLMServer(lambda: (params, cfg), generation_defaults={"top_k": 5})
+    silent = LLMServer(lambda: (params, cfg), max_slots=2, max_len=64)
+    assert silent._default_temperature == 0.0

@@ -550,6 +550,39 @@ def test_the_flights_of_a_run_join_dispatch_to_landing(model, slow_device):
     assert landings == sorted(landings)
 
 
+def test_a_flight_row_says_how_many_tokens_it_landed(model, slow_device):
+    """One a slot from a step that commits one token a slot; up to two from a
+    family whose model drafts (``tests/test_deepseek_v3.py`` holds the
+    counters that ride with them)."""
+    import dataclasses
+
+    from ray_tpu.models import deepseek_v3 as ds
+
+    _two_held_slots(model)
+    flights = _by_name(_rows())["serve.step.flight"]
+    assert flights and all(f["tokens"] == f["active"] for f in flights)
+    cfg = dataclasses.replace(ds.DEEPSEEK_V3_DEBUG, vocab_size=6)
+    eng = PagedEngine(ds.init_params(cfg, jax.random.PRNGKey(1)), cfg,
+                      max_slots=2, num_pages=64, page_size=4, max_len=96)
+    for rid, n in (("a", 9), ("b", 30)):
+        eng.submit(rid, [1, 2, 3, 4, 5], max_new_tokens=n, temperature=1.0,
+                   seed=n)
+    got = eng.run_to_completion()
+    assert [len(v) for v in got.values()] == [9, 30]
+    by = _by_name(_rows())
+    flights = by["serve.step.flight"]
+    assert all(f["active"] <= f["tokens"] <= 2 * f["active"]
+               for f in flights)
+    assert any(f["tokens"] > f["active"] for f in flights)
+    emits = {f["step"]: f["tokens"] for f in by["serve.step.emit"]}
+    assert all(emits[f["step"]] == f["tokens"] for f in flights)
+    # what the calls' rows count is what reached the streams: a token past a
+    # stream's budget is landed and dropped
+    streamed = sum(f["tokens"] for f in by["serve.engine.step"])
+    assert streamed == 9 + 30
+    assert streamed - 2 <= sum(f["tokens"] for f in flights) <= streamed
+
+
 def test_depth_climbs_to_the_cap_and_falls_to_0_after_an_admission(
         model, slow_device):
     from ray_tpu.models import paged

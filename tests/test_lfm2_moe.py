@@ -567,10 +567,11 @@ def test_the_engines_own_code_names_no_family():
     import inspect
 
     body = inspect.getsource(PagedEngine)
-    for word in ("lfm2", "Lfm2", "granite", "Granite", "cohere", "longcat",
-                 "sala", "nemotron", "llama"):
+    for word in ("lfm2", "Lfm2", "granite", "Granite", "deepseek",
+                 "Deepseek", "cohere", "longcat", "sala", "nemotron",
+                 "llama"):
         assert word not in body, word
-    assert len(paged._FAMILIES) == 7
+    assert len(paged._FAMILIES) == 8
 
 
 @pytest.fixture

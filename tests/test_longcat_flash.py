@@ -163,8 +163,9 @@ def test_the_absorbed_form_is_the_expanded_form_row_by_row(seed, lengths):
         seed, lengths)
     C, dn = CFG.kv_lora_rank, CFG.qk_nope_head_dim
     got = np.asarray(paged_ops.attend_latent(
-        jnp.asarray(q_nope), jnp.asarray(q_rope), jnp.asarray(w[..., :dn]),
-        jnp.asarray(w[..., dn:]), pool, tables, lens, CFG.attn_scale))
+        jnp.asarray(q_nope)[:, None], jnp.asarray(q_rope)[:, None],
+        jnp.asarray(w[..., :dn]), jnp.asarray(w[..., dn:]), pool, tables,
+        lens, CFG.attn_scale))[:, 0]
     for s, n in enumerate(lengths):
         rows = dense[s, :n + 1]                         # keys <= the query's
         kv = np.einsum("kc,chd->khd", rows[:, :C], w)

@@ -144,3 +144,64 @@ def test_mixtral_shardings_specs(cpu_mesh8):
     assert sh["layers"][0]["experts"]["w_gate"].spec == P("ep", "fsdp", "tp")
     assert sh["layers"][0]["experts"]["w_down"].spec == P("ep", "tp", "fsdp")
     assert sh["layers"][0]["wq"].spec == P("fsdp", "tp")
+
+
+# ------------------------------------------------- the sigmoid router's groups
+def _router_case(seed, T=50, D=24, E=32):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(T, D), jnp.float32),
+            jnp.asarray(rng.randn(D, E) / 3, jnp.float32),
+            jnp.asarray(rng.randn(E) * 0.1, jnp.float32))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("norm", [True, False], ids=["norm", "plain"])
+def test_sigmoid_gates_with_one_group_are_the_router_without_groups(seed,
+                                                                    norm):
+    """``n_group`` 1 (the default, the hybrid family's router) is the top k of
+    score + bias over all outputs, as before the groups: written out."""
+    from ray_tpu.parallel.moe import sigmoid_gates
+
+    x, w, b = _router_case(seed)
+    vals, idx = sigmoid_gates(x, w, b, 4, 2.5, norm=norm)
+    same = sigmoid_gates(x, w, b, 4, 2.5, norm=norm, n_group=1, topk_group=1)
+    np.testing.assert_array_equal(idx, same[1])
+    np.testing.assert_array_equal(vals, same[0])
+    s = 1 / (1 + np.exp(-(np.asarray(x) @ np.asarray(w))))
+    want = np.argsort(-(s + np.asarray(b)), axis=-1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(idx, want)
+    g = np.take_along_axis(s, want, -1)
+    if norm:
+        g = g / (g.sum(-1, keepdims=True) + 1e-20)
+    np.testing.assert_allclose(vals, 2.5 * g, rtol=2e-6)
+    # all groups kept is no limit either
+    every = sigmoid_gates(x, w, b, 4, 2.5, norm=norm, n_group=4, topk_group=4)
+    np.testing.assert_array_equal(idx, every[1])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("n_group, topk_group, k", [(8, 4, 8), (4, 2, 3),
+                                                    (4, 1, 5)])
+def test_group_limited_routing_is_the_written_out_loop(seed, n_group,
+                                                       topk_group, k):
+    from ray_tpu.parallel.moe import sigmoid_gates
+
+    x, w, b = _router_case(seed)
+    vals, idx = sigmoid_gates(x, w, b, k, 2.5, n_group=n_group,
+                              topk_group=topk_group)
+    s = 1 / (1 + np.exp(-(np.asarray(x, np.float64) @ np.asarray(w))))
+    size = w.shape[1] // n_group
+    for t in range(x.shape[0]):
+        biased = s[t] + np.asarray(b)
+        score = [np.sort(biased[g * size:(g + 1) * size])[-2:].sum()
+                 for g in range(n_group)]
+        kept = np.argsort(-np.asarray(score), kind="stable")[:topk_group]
+        masked = np.zeros_like(biased)
+        for g in kept:
+            masked[g * size:(g + 1) * size] = biased[g * size:(g + 1) * size]
+        want = np.argsort(-masked, kind="stable")[:k]
+        assert sorted(np.asarray(idx[t])) == sorted(want), t
+        assert {int(e) // size for e in np.asarray(idx[t])} <= set(kept)
+        gate = s[t][np.asarray(idx[t])]
+        np.testing.assert_allclose(vals[t], 2.5 * gate / (gate.sum() + 1e-20),
+                                   rtol=1e-5)
