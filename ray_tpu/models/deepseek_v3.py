@@ -57,7 +57,8 @@ from ..parallel.moe import balanced_bias, moe_ffn_held, sigmoid_gates
 from .engine import _sample, prefill_in_chunks
 from .longcat_flash import (_ffn, _kvb, _latent_prompt, _latent_qkv, _normal,
                             prefill_carry)
-from .paged_ops import attend_latent, write_latent_rows
+from .paged_ops import (attend_latent, latent_positions_read,
+                        write_latent_rows)
 
 F32 = jnp.float32
 
@@ -616,16 +617,20 @@ def _step_rows(params, pools, tables, rows, lengths, cfg: DeepseekV3Config):
     return g, logits, new, routing, load, at
 
 
-def _step_counts(load, lengths, rows: int, drafted, accepted):
+def _step_counts(load, pool, tables, lengths, rows: int, drafted, accepted,
+                 cfg):
     """A step's counts, as they ride behind its tokens: held experts hit
     summed over the expert layers, most tokens of one expert, the cached
     positions the active slots hold after the step's rows, drafts offered,
-    drafts accepted, active slots, and 1 (summed over the steps a call lands,
-    they count them)."""
+    drafts accepted, active slots, 1 (summed over the steps a call lands,
+    they count them), and the positions one of the step's reads gathers
+    (every slot's blocks, whole: ``attend_latent``'s own rule)."""
     active = lengths > 0
     return jnp.concatenate([load, jnp.stack([
         jnp.sum(jnp.where(active, lengths + rows, 0)), drafted, accepted,
-        jnp.sum(active), 1]).astype(jnp.int32)])
+        jnp.sum(active), 1,
+        latent_positions_read(pool, tables, lengths, rows, cfg.n_heads)
+    ]).astype(jnp.int32)])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -633,14 +638,14 @@ def _deepseek_step_one(params, pools, tables, toks, lengths, temps, top_ks,
                        top_ps, keys, cfg):
     """One token for every slot of a tree without an MTP module (``n_nextn``
     0): the step every other family's engine row runs, on this family's
-    layers. -> (int32[S + 7]: the tokens, then ``_step_counts``; pools; keys;
+    layers. -> (int32[S + 8]: the tokens, then ``_step_counts``; pools; keys;
     (the chosen experts [expert layers, S, k], the logits [S, 1, V]); the
     tokens alone)."""
     _, logits, new, routing, load, _ = _step_rows(
         params, pools, tables, toks[:, None], lengths, cfg)
     out, new_keys, picked = _sample(
         logits[:, 0], temps, top_ks, top_ps, keys, lengths,
-        _step_counts(load, lengths, 1, 0, 0))
+        _step_counts(load, pools[0], tables, lengths, 1, 0, 0, cfg))
     return out, new, new_keys, (jnp.stack(routing), logits), picked
 
 
@@ -661,7 +666,7 @@ def _deepseek_step(params, pools, tables, toks, lengths, temps, top_ks,
     flows through (static shapes), its rows land on page 0, and it is routed
     to no expert. Pools and the drafts' distributions are donated.
 
-    -> (int32[3 S + 7]: every slot's two tokens, how many of them count,
+    -> (int32[3 S + 8]: every slot's two tokens, how many of them count,
     then ``_step_counts``, so that one transfer fetches all; pools; keys;
     what a reference check reads and the engine publishes as the step lands
     (the chosen experts [expert layers, 2 S, k], the two rows' logits [S, 2,
@@ -689,8 +694,8 @@ def _deepseek_step(params, pools, tables, toks, lengths, temps, top_ks,
     new_drafts, new_q = _draft(q_logits, temps, top_ks, top_ps, u, active)
     n = jnp.where(active, 1 + accepted.astype(jnp.int32), 0)
     out = jnp.concatenate([committed, n, _step_counts(
-        load, lengths, 2, jnp.sum(active & (drafts >= 0)),
-        jnp.sum(accepted))])
+        load, pool, tables, lengths, 2, jnp.sum(active & (drafts >= 0)),
+        jnp.sum(accepted), cfg)])
     kept = (jnp.stack(routing + [idx]), logits, q_logits, accepted)
     return (out, new + [pool], new_keys, kept,
             jnp.where(accepted, second, first), lengths + n, new_drafts,

@@ -56,7 +56,8 @@ from ..parallel.moe import (balanced_bias,
                             expert_share,  # noqa: F401 (re-export)
                             moe_ffn_zero, softmax_gates)
 from .engine import _sample, prefill_in_chunks
-from .paged_ops import attend_latent, latent_pages, write_latent
+from .paged_ops import (attend_latent, latent_pages, latent_positions_read,
+                        write_latent)
 
 F32 = jnp.float32
 
@@ -438,11 +439,13 @@ def _scatter_latent(pools, lats, page_ids):
 
 def _decode_logits(params, pools, tables, toks, lengths,
                    cfg: LongcatFlashConfig, page: int):
-    """The decode step up to its logits [S, V]; the new pools; int32[6]: held
+    """The decode step up to its logits [S, V]; the new pools; int32[7]: held
     experts hit summed over the expert layers, most tokens of one expert,
     pairs routed to zero experts, the cached positions the active slots hold
-    (each read once a sublayer), the active rows, and 1 (summed over the
-    steps a call lands, they count them); the chosen experts [layers, S, k]."""
+    (each read once a sublayer), the active rows, 1 (summed over the steps a
+    call lands, they count them), and the positions a sublayer's read
+    gathers (every slot's blocks, whole: ``attend_latent``'s own rule); the
+    chosen experts [layers, S, k]."""
     x = params["embedding"][toks].astype(cfg.dtype)             # [S, D]
     active = lengths > 0
     page_idx = jnp.take_along_axis(
@@ -469,8 +472,10 @@ def _decode_logits(params, pools, tables, toks, lengths,
                           jnp.maximum(load[1], counts[1]),
                           load[2] + counts[2]])
     held = jnp.sum(jnp.where(active, lengths + 1, 0))
-    counts = jnp.concatenate([load, jnp.stack(
-        [held, jnp.sum(active), 1]).astype(jnp.int32)])
+    counts = jnp.concatenate([load, jnp.stack([
+        held, jnp.sum(active), 1,
+        latent_positions_read(pools[0], tables, lengths, 1, cfg.n_heads)
+    ]).astype(jnp.int32)])
     return _head(params, x, cfg), new, counts, jnp.stack(routing)
 
 
@@ -485,7 +490,7 @@ def _longcat_step(params, pools, tables, toks, lengths, temps, top_ks,
     donated. A slot of length 0 is inactive: it flows through (static
     shapes), its row lands on page 0, and it is routed to no expert.
 
-    -> (int32[S + 6]: the tokens, then ``_decode_logits``' counts, so that
+    -> (int32[S + 7]: the tokens, then ``_decode_logits``' counts, so that
     one transfer fetches all; pools; keys; the chosen experts [layers, S, k],
     which stay on the device unless a reference check asks for them; the
     tokens alone, int32[S], as the next step takes them: with the keys they

@@ -361,8 +361,11 @@ def _routing_landed(eng, routing):
 
 
 def _longcat_counts(eng, tail, sp):
+    # ``latent_positions_read``: what ``attend_latent`` gathered a sublayer,
+    # every slot's blocks whole, against the live ``latent_positions``
     sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]),
            zero_picks=int(tail[2]), latent_positions=int(tail[3]),
+           latent_positions_read=int(tail[6]),
            moe_rows=int(tail[4]), landed=int(tail[5]))
 
 
@@ -529,10 +532,11 @@ def _deepseek_landed(eng, kept):
 
 
 def _deepseek_counts(eng, tail, sp):
-    # ``attend_latent`` gathers every page of every slot's table, live or not
+    # ``latent_positions_read``: what ``attend_latent`` gathered a layer,
+    # every slot's blocks whole, against the live ``latent_positions``
     sp.set(experts_hit=int(tail[0]), expert_tokens_max=int(tail[1]),
            latent_positions=int(tail[2]),
-           latent_positions_read=int(tail[6]) * eng.S * eng.max_len,
+           latent_positions_read=int(tail[7]),
            drafted=int(tail[3]), accepted=int(tail[4]),
            moe_rows=int(tail[5]), landed=int(tail[6]))
 

@@ -17,7 +17,8 @@ over the chosen pages only (``attend_chosen``). And the read path of a
 latent (MLA) layer, whose cache is ONE pool of one row a position (the
 compressed latent and the one rotary key all heads share), two positions
 side by side: ``write_latent`` and ``attend_latent``, the absorbed form,
-which never expands a cached row to per-head keys and values. And a window
+which never expands a cached row to per-head keys and values and walks each
+slot's live pages in blocks, as the K/V read does. And a window
 layer's K/V as a per-slot RING of the window's width (``write_ring``,
 ``ring_rows``, ``attend_ring``: no table, no gather, no allocator), beside
 which such a model's full layers read their page pool through
@@ -515,16 +516,52 @@ def write_latent_rows(rows, pool, tables, lengths):
     return pool
 
 
-#: slots whose pages ``attend_latent`` gathers at once: the gathered rows of
-#: a block (``block x max_len x 1152 B``) and its scores are temporaries of
-#: the step, 1.7 GB for 64 slots of 14 336 positions at once (AOT, PR 60)
-#: and an eighth of it so
-LATENT_SLOT_BLOCK = 8
+#: what a pass of ``attend_latent`` may hold in float32 scores and weighted
+#: rows (``[items, score rows, a block's pool rows + 2 W]``). Under it the
+#: compiler keeps both in VMEM between the two products, beside the pass's
+#: gathered rows; GigaChat's read at 11 blocks a pass (23.1 MB) took 2.49 ms a
+#: layer and at 12 (25.2 MB) 3.46 (chip, PR 61: PERF.md section 5)
+_LATENT_PASS_BYTES = 20 << 20
+
+
+def latent_pass_shape(S: int, P: int, pool, score_rows: int):
+    """(the table columns a block of ``attend_latent``'s read holds, the
+    blocks a pass of its loop takes), from the shapes alone (``S`` slots of
+    ``P`` columns over ``pool``, ``score_rows`` rows of scores a slot: two a
+    query row and head): the ONE rule of the read and of the step rows'
+    ``latent_positions_read``. A block is an eighth of the table's width
+    (``block_pages_of``'s reasons: a slot is rounded up by half a block on
+    average, and what a block costs beside its rows, the queries gathered to
+    it and its weighted rows added to its slot, is the same however few rows
+    it holds: at GigaChat's 224 columns 14, 28 and 56 read 0.30, 0.32 and
+    0.38 of the table in 2.46, 2.20 and 2.45 ms). A pass is as many blocks,
+    ``S`` at most, as keep its float32 arrays under ``_LATENT_PASS_BYTES``,
+    in whole tiles of 8 where there are that many."""
+    block = max(1, P // 8)
+    item_bytes = 4 * score_rows * (block * pool.shape[1] + pool.shape[2])
+    items = max(1, min(S, _LATENT_PASS_BYTES // item_bytes))
+    return block, (items - items % 8 if items > 8 else items)
+
+
+def _latent_need(pool, tables, lengths, R: int, H: int):
+    """-> (a block's table columns, the blocks a pass takes, the blocks of
+    each slot [S] that hold its positions ``0 .. lengths + R - 1``: at least
+    one)."""
+    Bp, items = latent_pass_shape(*tables.shape, pool, 2 * R * H)
+    Bk = Bp * 2 * pool.shape[1]
+    return Bp, items, (lengths + R + Bk - 1) // Bk
+
+
+def latent_positions_read(pool, tables, lengths, R: int, H: int):
+    """The positions ``attend_latent`` gathers in one read with ``R`` query
+    rows of ``H`` heads a slot: each slot's blocks, whole (int32 scalar)."""
+    Bp, _, need = _latent_need(pool, tables, lengths, R, H)
+    return jnp.sum(need) * (Bp * 2 * pool.shape[1])
 
 
 def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
-    """Each slot's ``R`` query rows over every page of its table, in the
-    ABSORBED form.
+    """Each slot's ``R`` query rows over its LIVE pages, in the ABSORBED
+    form.
 
     q_nope [S, R, H, dn], q_rope [S, R, H, dr] (rotated): row ``r`` stands at
     position ``lengths + r`` and sees the cached rows up to its own, those of
@@ -540,20 +577,35 @@ def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
     a cached position and query row where expanding one would cost ``2 C H
     (dn + dv)``.
 
+    The read is ``attend_pages_blocked``'s: a slot's context is cut into
+    blocks of table columns, the blocks of all slots stand in ONE list (slot
+    by slot, as many of each as hold its positions ``0 .. lengths + R - 1``;
+    a slot of length 0 has one) and a ``fori_loop`` takes a few of them a
+    pass (``latent_pass_shape`` says how wide and how many), as many passes
+    as the list is long. A pass gathers its blocks' pages (``[items, block
+    rows, 2 W]``, whatever the table's width; a column past its slot's last
+    page names the block's first page again, so that nothing past a slot's
+    context is read at all), scores them, takes every slot's new running
+    maximum from its items' maxima, exponentiates against THAT, so an item's
+    weights and weighted rows are on its slot's scale already, and adds them
+    to their slots through a one-hot contraction over the items (a broadcast
+    of ``[slots, items]`` against a slot's ``R H x C`` float32 accumulator
+    would be gigabytes a pass). The list is built from the tables and
+    lengths alone: every layer of a step builds the same and the compiler
+    keeps one.
+
     The gathered pages are contracted as the pool stores them, two positions
     a row: the queries stand twice, ``[q, 0]`` against a row's first half and
     ``[0, q]`` against its second (at ``R = 1`` the 2 H rows fill the
     128-wide unit that H = 64 would leave half empty), and the weighted sum
     of rows is read from the matching halves. No copy of the gathered rows is
-    sliced or reshaped, and the ``2 R H`` rows of a slot meet its pages in
-    ONE product each way, so the pages are read once whatever ``R``. The
-    slots go ``LATENT_SLOT_BLOCK`` at a time (the largest divisor of ``S``
-    not over it), one block after another. -> o [S, R, H * dv]."""
+    sliced or reshaped, and the ``2 R H`` rows of a slot meet a block's pages
+    in ONE product each way, so the pages are read once whatever ``R``.
+    -> o [S, R, H * dv]."""
     with jax.named_scope("latent_attn"):
         S, P = tables.shape
         R, H, C = q_nope.shape[1], q_nope.shape[2], w_uk.shape[0]
-        W = pool.shape[2] // 2
-        N = P * pool.shape[1]               # rows of two positions a slot
+        half, W = pool.shape[1], pool.shape[2] // 2
         dt = pool.dtype
         q_lat = jnp.einsum("srhd,chd->srhc", q_nope, w_uk,
                            preferred_element_type=jnp.float32)
@@ -561,32 +613,85 @@ def attend_latent(q_nope, q_rope, w_uk, w_uv, pool, tables, lengths, scale):
         z = jnp.zeros_like(q)
         q2 = jnp.stack([jnp.concatenate([q, z], axis=-1),
                         jnp.concatenate([z, q], axis=-1)], axis=1)
-        pos = 2 * jnp.arange(N)[None, :] + jnp.arange(2)[:, None]   # [2, N]
+        q2 = q2.reshape(S, 2 * R * H, 2 * W)
 
-        def block(args):
-            q2, tables, lengths = args      # [B, 2, R, H, 2 W], [B, P], [B]
-            B = tables.shape[0]
-            rows = pool[tables].reshape(B, N, 2 * W)
-            s = jnp.einsum("sgw,snw->sgn", q2.reshape(B, 2 * R * H, 2 * W),
-                           rows, preferred_element_type=jnp.float32) * scale
-            s = s.reshape(B, 2, R, H, N)
-            last = lengths[:, None] + jnp.arange(R)[None, :]        # [B, R]
-            admit = pos[None, :, None, :] <= last[:, None, :, None]
-            s = jnp.where(admit[:, :, :, None, :], s, -1e30)
-            e = jnp.exp(s - s.max(axis=(1, 4), keepdims=True))
-            p = e / e.sum(axis=(1, 4), keepdims=True)
-            o2 = jnp.einsum("sgn,snw->sgw",
-                            p.reshape(B, 2 * R * H, N).astype(dt), rows,
+        Bp, I, need = _latent_need(pool, tables, lengths, R, H)
+        if P % Bp:
+            tables = jnp.pad(tables, ((0, 0), (0, -P % Bp)))
+        Bn, Bk = Bp * half, Bp * 2 * half   # a block's pool rows, positions
+        ends = jnp.cumsum(need)
+        # every block the tables could hold, in the list's order (and whole
+        # passes of them): whether a slot has it, its slot, its block of the
+        # slot's context, where the slot's first query row stands in it, its
+        # table columns
+        item = jnp.arange(-(-(-(-P // Bp) * S) // I) * I)
+        live = item < ends[-1]
+        slot = jnp.minimum(jnp.searchsorted(ends, item, side="right",
+                                            method="compare_all"), S - 1)
+        blk = jnp.where(live, item - (ends - need)[slot], 0)
+        at = jnp.where(live, lengths[slot] - blk * Bk, -R)
+        cols = tables.reshape(S, -1, Bp)[slot, blk]
+        cols = jnp.where(jnp.arange(Bp)[None, :] * (2 * half)
+                         < (at + R)[:, None], cols, cols[:, :1])
+        pos = 2 * jnp.arange(Bn)[None, :] + jnp.arange(2)[:, None]  # [2, Bn]
+
+        def blocks(it, carry):
+            # Every slot has a block, so a pass's ``I`` items are of at most
+            # ``I`` slots, one after another: the pass works on that window
+            # of the queries and of the running softmax and leaves the rest
+            # of the ``S`` where it is.
+            live_i, slot_i, cols_i, at_i = (
+                jax.lax.dynamic_slice_in_dim(a, it * I, I)
+                for a in (live, slot, cols, at))
+            first = jnp.minimum(slot_i[0], S - I)
+            mine = live_i[:, None] & (
+                slot_i[:, None] - first == jnp.arange(I)[None, :])
+            to = jnp.minimum(slot_i - first, I - 1)     # an item's slot there
+            q_w, m, l, acc = (      # [I, ..]: 2 R H x 2 W; R, H; R, H; R H C
+                jax.lax.dynamic_slice_in_dim(a, first, I)
+                for a in (q2,) + carry)
+            rows = pool[cols_i].reshape(I, Bn, 2 * W)
+            s = jnp.einsum("igw,inw->ign", q_w[to], rows,
+                           preferred_element_type=jnp.float32) * scale
+            s = s.reshape(I, 2, R, H, Bn)
+            last = at_i[:, None] + jnp.arange(R)[None, :]           # [I, R]
+            ok = (pos[None, :, None, :]
+                  <= last[:, None, :, None])[:, :, :, None, :]
+            s = jnp.where(ok, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(jnp.where(
+                mine.T[:, :, None, None], s.max(axis=(1, 4))[None], -1e30),
+                axis=1))
+            # against the slot's NEW maximum: an item's weights and weighted
+            # rows are on its slot's scale as they are made
+            p = jnp.where(ok, jnp.exp(
+                s - m_new[to][:, None, :, :, None]), 0.0)
+            o2 = jnp.einsum("ign,inw->igw",
+                            p.reshape(I, 2 * R * H, Bn).astype(dt), rows,
                             preferred_element_type=jnp.float32)
-            o2 = o2.reshape(B, 2, R, H, 2 * W)
-            return o2[:, 0, :, :, :C] + o2[:, 1, :, :, W:W + C]
+            o2 = o2.reshape(I, 2, R, H, 2 * W)
+            o_i = o2[:, 0, :, :, :C] + o2[:, 1, :, :, W:W + C]
+            add = lambda x: jnp.einsum(     # noqa: E731  items into slots
+                "is,ix->sx", mine.astype(jnp.float32), x.reshape(I, -1),
+                precision=jax.lax.Precision.HIGHEST)
+            fix = jnp.exp(m - m_new)
+            new = (m_new,
+                   l * fix + add(p.sum(axis=(1, 4))).reshape(l.shape),
+                   (acc.reshape(I, R, H, C) * fix[..., None]
+                    ).reshape(I, -1) + add(o_i))
+            return tuple(jax.lax.dynamic_update_slice_in_dim(a, w, first, 0)
+                         for a, w in zip(carry, new))
 
-        B = max(b for b in range(1, LATENT_SLOT_BLOCK + 1) if S % b == 0)
-        o_lat = jax.lax.map(block, (
-            q2.reshape(S // B, B, *q2.shape[1:]),
-            tables.reshape(S // B, B, P), lengths.reshape(S // B, B)))
-        o = jnp.einsum("srhc,chd->srhd",
-                       o_lat.reshape(S, R, H, C).astype(dt), w_uv)
+        # the weighted rows of a slot as ONE row of the accumulator: the
+        # slots are its major axis in any layout, so a pass updates its
+        # window in place
+        init = (jnp.full((S, R, H), -1e30, jnp.float32),
+                jnp.zeros((S, R, H), jnp.float32),
+                jnp.zeros((S, R * H * C), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, (ends[-1] + I - 1) // I, blocks,
+                                      init)
+        acc = acc.reshape(S, R, H, C)
+        o = jnp.einsum("srhc,chd->srhd", (acc / l[..., None]).astype(dt),
+                       w_uv)
         return o.reshape(S, R, -1)
 
 

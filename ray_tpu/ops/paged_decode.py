@@ -13,8 +13,8 @@ live block is never touched.
 ``kv_decode`` is the read of a K pool and a V pool kept as rows of whole
 lanes (``models/paged_ops.lane_pool_shape``), which
 ``paged_ops.paged_attention`` picks by what it can see of its pools. The
-absorbed-form read of a latent pool (``paged_ops.attend_latent``) is to join
-it here (ROADMAP S1 e).
+absorbed read of a latent pool (``paged_ops.attend_latent``: live pages in
+blocks, plain XLA, since PR 61) is to join it here (ROADMAP S1 e, way b).
 """
 
 from __future__ import annotations

@@ -237,15 +237,134 @@ def test_two_query_rows_are_two_calls_of_one(seed, lengths):
     assert float(jnp.abs(both[:, 0] - both[:, 1]).max()) > 1e-3
 
 
-def test_the_slots_go_through_in_blocks_and_read_the_same(monkeypatch):
+def _expanded(a):
+    """Attention over the keys and values EXPANDED from the slot's cached
+    rows, query row by query row, in numpy: what the absorbed form equals."""
+    f = lambda k: np.asarray(a[k], np.float64)      # noqa: E731
+    pool, tables, lengths = f("pool"), np.asarray(a["tables"]), a["lengths"]
+    q_nope, q_rope, w_uk, w_uv = map(f, ("q_nope", "q_rope", "w_uk", "w_uv"))
+    S, R, H, _ = q_nope.shape
+    C, W = w_uk.shape[0], pool.shape[2] // 2
+    out = np.zeros((S, R, H * w_uv.shape[2]))
+    for s in range(S):
+        rows = pool[tables[s]].reshape(-1, W)       # position by position
+        k = np.einsum("kc,chd->khd", rows[:, :C], w_uk)
+        v = np.einsum("kc,chd->khd", rows[:, :C], w_uv)
+        for r in range(R):
+            n = int(lengths[s]) + r + 1             # keys <= the row's own
+            sc = (np.einsum("hd,khd->hk", q_nope[s, r], k[:n])
+                  + np.einsum("hr,kr->hk", q_rope[s, r], rows[:n, C:])
+                  ) * a["scale"]
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            out[s, r] = np.einsum("hk,khd->hd", p, v[:n]).reshape(-1)
+    return out
+
+
+def _pass_shape(monkeypatch, block_pages, items):
+    """The read at a block of ``block_pages`` table columns, ``items`` blocks
+    a pass, whatever the rule would give at a toy's shapes."""
+    monkeypatch.setattr(paged_ops, "latent_pass_shape",
+                        lambda *a: (block_pages, items))
+
+
+# A table of 8 pages of 4 positions; at a block of 2 pages (8 positions) a
+# slot's last query row stands, case by case: anywhere, a length of 0 among
+# them; on a block's last position (the next block is not opened); with the
+# slot's rows on both sides of a block's edge; on the table's last position;
+# in a batch of one slot (the MTP draft's admission call).
+P_READ, PAGE_READ, BK_READ = 8, 4, 8
+READ_CASES = {
+    "ragged_with_a_zero": lambda R: [5, 21, 0, 9, 14, 2, 29, 12],
+    "at_a_blocks_edge": lambda R: [BK_READ - R, 2 * BK_READ - R,
+                                   3 * BK_READ - R],
+    "rows_straddle_an_edge": lambda R: [BK_READ - 1, 2 * BK_READ - 1, 4,
+                                        3 * BK_READ - 1],
+    "the_tables_last_position": lambda R: [P_READ * PAGE_READ - R, 3,
+                                           P_READ * PAGE_READ - R],
+    "one_slot": lambda R: [13],
+}
+
+
+@pytest.mark.parametrize("shape", [(2, None), (2, 3), (3, None), (1, 1)],
+                         ids=["2pages", "2pages_3a_pass",
+                              "3pages_not_a_divisor_of_8", "1page_1a_pass"])
+@pytest.mark.parametrize("R", [1, 2])
+@pytest.mark.parametrize("case", list(READ_CASES))
+def test_the_blocked_read_is_the_expanded_form_row_by_row(monkeypatch, case,
+                                                          R, shape):
+    lengths = READ_CASES[case](R)
+    a = _attention_case(7, lengths, R=R, page=PAGE_READ, P=P_READ)
+    _pass_shape(monkeypatch, shape[0], min(shape[1] or 8, len(lengths)))
+    got = paged_ops.attend_latent(**a)
+    assert got.shape == (len(lengths), R, 4 * 24)
+    np.testing.assert_allclose(got, _expanded(a), atol=3e-5, rtol=0)
+
+
+@pytest.mark.parametrize("block_pages, items", [
+    (8, 8), (4, 8), (2, 8), (1, 8), (3, 8), (5, 8), (2, 5), (2, 1), (1, 3),
+    (3, 2)])
+def test_every_block_width_and_pass_reads_the_same(monkeypatch, block_pages,
+                                                   items):
+    """One block as wide as the table and every slot's in one pass is the
+    table-wide read; every narrower block and shorter pass gives its
+    result (several blocks of one slot in a pass, a slot's blocks in two
+    passes, a last pass partly empty)."""
     a = _attention_case(3, [5, 21, 0, 9, 14, 2, 30, 12], R=2, P=8)
+    _pass_shape(monkeypatch, 8, 8)
     whole = paged_ops.attend_latent(**a)
-    monkeypatch.setattr(paged_ops, "LATENT_SLOT_BLOCK", 2)
+    np.testing.assert_allclose(whole, _expanded(a), atol=3e-5, rtol=0)
+    _pass_shape(monkeypatch, block_pages, items)
     np.testing.assert_allclose(paged_ops.attend_latent(**a), whole,
-                               atol=2e-6, rtol=0)
-    monkeypatch.setattr(paged_ops, "LATENT_SLOT_BLOCK", 3)  # no divisor: 2
-    np.testing.assert_allclose(paged_ops.attend_latent(**a), whole,
-                               atol=2e-6, rtol=0)
+                               atol=3e-6, rtol=0)
+
+
+@pytest.mark.parametrize("R", [1, 2])
+def test_the_read_stops_at_each_slots_last_live_page(monkeypatch, R):
+    """Nothing past a slot's context is gathered: not a block past its last
+    live one, however long its neighbour's table, and not the padding of its
+    last block (the engine's unreached columns all name page 0). Both hold
+    NaN here, which a gathered row would carry into the output through ``0 x
+    NaN``; the output is the clean pool's. The jaxpr holds one ``while`` and
+    no gather of the table's width."""
+    lengths = [5, 21, 9, 38, 1]
+    a = _attention_case(5, lengths, R=R, page=4, P=10)
+    _pass_shape(monkeypatch, 2, 5)
+    want = paged_ops.attend_latent(**a)
+    tables = np.asarray(a["tables"]).copy()
+    pool = np.asarray(a["pool"]).copy()
+    for s, n in enumerate(lengths):
+        reached = (n + R - 1) // 4 + 1          # pages the slot's rows touch
+        pool[tables[s, reached:]] = np.nan      # ... and every page past them
+        tables[s, reached:] = 0
+    pool[0] = np.nan
+    got = paged_ops.attend_latent(**{**a, "pool": jnp.asarray(pool),
+                                     "tables": jnp.asarray(tables)})
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    text = str(jax.make_jaxpr(lambda kw: paged_ops.attend_latent(**kw))(
+        {k: v for k, v in a.items() if k != "scale"} | {"scale": 0.2}
+    )).replace(" ", "")
+    assert "while" in text and "cumsum" in text
+    assert "[5,20," not in text             # a slot's table: 10 pages of 2 rows
+    assert "[5,4,48]" in text               # a block: 2 pages of 2 rows
+
+
+@pytest.mark.parametrize("R", [1, 2])
+def test_a_slot_opens_the_blocks_its_rows_reach_and_no_more(R):
+    """``latent_positions_read`` is the rule the read itself takes its blocks
+    from: a slot of length 0 one block, a slot whose last row stands on a
+    block's last position no block more, one position further the next."""
+    a = _attention_case(0, [0, 1, 2], R=R, page=4, P=24)    # blocks of 3 pages
+    block, items = paged_ops.latent_pass_shape(3, 24, a["pool"], 2 * R * 4)
+    assert (block, items) == (3, 3)
+    Bk = block * 4
+    for lengths, blocks in [([0, 0, 0], 3), ([Bk - R, 2 * Bk - R, 0], 4),
+                            ([Bk - R + 1, 2 * Bk - R + 1, 1], 6),
+                            ([24 * 4 - R, 0, Bk], 8 + 1 + 2)]:
+        read = paged_ops.latent_positions_read(
+            a["pool"], a["tables"], jnp.asarray(lengths, jnp.int32), R, 4)
+        assert int(read) == blocks * Bk, lengths
 
 
 @pytest.mark.parametrize("start", [0, 1, 2, 3, 6, 7])
@@ -575,9 +694,15 @@ def test_spans_and_the_step_rows_counters(params, _clean_ring, slow_device):
         f["active"] for f in flights)
     assert sum(f["accepted"] for f in landed) == committed - sum(
         f["active"] for f in flights)
+    # what the step's read gathered a layer: every slot's blocks, whole (a
+    # slot's rows round up by less than a block, an idle slot reads one)
+    block = 4 * paged_ops.latent_pass_shape(
+        3, 24, eng.pools_k[0], 2 * 2 * CFG.n_heads)[0]
+    assert block == 12
     for f in landed:
-        assert f["latent_positions_read"] == f["landed"] * 3 * 96
-        assert f["latent_positions"] <= f["latent_positions_read"]
+        assert f["latent_positions_read"] % block == 0
+        assert f["latent_positions"] <= f["latent_positions_read"] \
+            <= f["latent_positions"] + f["landed"] * 3 * block
         assert 0 <= f["experts_hit"] <= f["landed"] * 3 * CFG.experts_held
         assert f["expert_tokens_max"] <= f["landed"] * 2 * f["moe_rows"]
     first = landed[0]       # both slots, two rows each, no token committed

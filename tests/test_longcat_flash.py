@@ -153,19 +153,11 @@ def _attention_case(seed, lengths, page=4, P=6):
             jnp.asarray(lengths, jnp.int32), q_nope, q_rope, w)
 
 
-@pytest.mark.parametrize("seed, lengths", [(0, [5, 23, 0]), (1, [16, 8, 11]),
-                                           (2, [1, 2, 22])])
-def test_the_absorbed_form_is_the_expanded_form_row_by_row(seed, lengths):
-    """``attend_latent`` over pages (never a per-head key or value) against
-    attention over the keys and values EXPANDED from the same rows, in
-    float32, slot by slot and head by head."""
-    dense, pool, tables, lens, q_nope, q_rope, w = _attention_case(
-        seed, lengths)
+def _expanded_form(dense, q_nope, q_rope, w, lengths):
+    """Attention over the keys and values EXPANDED from each slot's rows, in
+    float32, slot by slot and head by head. -> [S, H * dv]."""
     C, dn = CFG.kv_lora_rank, CFG.qk_nope_head_dim
-    got = np.asarray(paged_ops.attend_latent(
-        jnp.asarray(q_nope)[:, None], jnp.asarray(q_rope)[:, None],
-        jnp.asarray(w[..., :dn]), jnp.asarray(w[..., dn:]), pool, tables,
-        lens, CFG.attn_scale))[:, 0]
+    out = []
     for s, n in enumerate(lengths):
         rows = dense[s, :n + 1]                         # keys <= the query's
         kv = np.einsum("kc,chd->khd", rows[:, :C], w)
@@ -174,8 +166,84 @@ def test_the_absorbed_form_is_the_expanded_form_row_by_row(seed, lengths):
               ) * CFG.attn_scale
         p = np.exp(sc - sc.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
-        want = np.einsum("hk,khd->hd", p, kv[..., dn:]).reshape(-1)
-        np.testing.assert_allclose(got[s], want, atol=2e-5)
+        out.append(np.einsum("hk,khd->hd", p, kv[..., dn:]).reshape(-1))
+    return np.stack(out)
+
+
+def _absorbed_form(pool, tables, lens, q_nope, q_rope, w):
+    dn = CFG.qk_nope_head_dim
+    return np.asarray(paged_ops.attend_latent(
+        jnp.asarray(q_nope)[:, None], jnp.asarray(q_rope)[:, None],
+        jnp.asarray(w[..., :dn]), jnp.asarray(w[..., dn:]), pool, tables,
+        lens, CFG.attn_scale))[:, 0]
+
+
+@pytest.mark.parametrize("seed, lengths", [(0, [5, 23, 0]), (1, [16, 8, 11]),
+                                           (2, [1, 2, 22])])
+def test_the_absorbed_form_is_the_expanded_form_row_by_row(seed, lengths):
+    """``attend_latent`` over pages (never a per-head key or value) against
+    attention over the keys and values EXPANDED from the same rows, in
+    float32, slot by slot and head by head."""
+    dense, pool, tables, lens, q_nope, q_rope, w = _attention_case(
+        seed, lengths)
+    got = _absorbed_form(pool, tables, lens, q_nope, q_rope, w)
+    np.testing.assert_allclose(
+        got, _expanded_form(dense, q_nope, q_rope, w, lengths), atol=2e-5)
+
+
+# The step's read walks each slot's blocks of ``latent_pass_shape`` table
+# columns. A table of 10 pages of 4 positions here, at blocks of 2 pages (8
+# positions; 3 do not divide the table) and passes of as many blocks as
+# slots or fewer: lengths anywhere and a 0 among them, queries ON a block's
+# last position (the next is not opened) and one past it (it is, for one
+# row), a slot at the table's end, a batch of one.
+@pytest.mark.parametrize("block_pages, items", [(2, 4), (2, 3), (3, 4),
+                                                (1, 1), (10, 4)])
+@pytest.mark.parametrize("lengths", [
+    [5, 23, 0, 30], [7, 15, 23, 31], [8, 16, 24, 32], [39, 0, 39, 1], [13]],
+    ids=["ragged_with_a_zero", "on_a_blocks_last_position",
+         "one_past_a_blocks_edge", "the_tables_last_position", "one_slot"])
+def test_the_blocked_read_is_the_expanded_form(monkeypatch, lengths,
+                                               block_pages, items):
+    dense, pool, tables, lens, q_nope, q_rope, w = _attention_case(
+        3, lengths, P=10)
+    monkeypatch.setattr(paged_ops, "latent_pass_shape",
+                        lambda *a: (block_pages, min(items, len(lengths))))
+    got = _absorbed_form(pool, tables, lens, q_nope, q_rope, w)
+    np.testing.assert_allclose(
+        got, _expanded_form(dense, q_nope, q_rope, w, lengths), atol=2e-5)
+
+
+def test_pages_past_a_slots_last_are_never_gathered(monkeypatch):
+    """The table's columns past a slot's context name page 0 in the engine,
+    and a neighbour's pages here: all NaN, as is the rest of the pool. A
+    gathered NaN would reach the output through ``0 x NaN``."""
+    lengths = [5, 23, 9, 38]
+    dense, pool, tables, lens, q_nope, q_rope, w = _attention_case(
+        4, lengths, P=10)
+    monkeypatch.setattr(paged_ops, "latent_pass_shape", lambda *a: (2, 3))
+    want = _absorbed_form(pool, tables, lens, q_nope, q_rope, w)
+    tables, live = np.asarray(tables).copy(), np.zeros(pool.shape[0], bool)
+    for s, n in enumerate(lengths):
+        live[tables[s, :n // 4 + 1]] = True
+        tables[s, n // 4 + 1:] = 0
+    pool = jnp.where(jnp.asarray(live)[:, None, None], pool, jnp.nan)
+    got = _absorbed_form(pool, jnp.asarray(tables), lens, q_nope, q_rope, w)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("cell, shape, rows, want", [
+    ("serve-longcat-agent-decode", (16, 288, 4609), 1, (36, 16)),
+    ("serve-gigachat-reasoning-mtp", (64, 224, 6655), 2, (28, 8)),
+    ("the MTP draft's admission call", (1, 224, 6655), 1, (28, 1))])
+def test_pass_shape_at_the_cells_shapes(cell, shape, rows, want):
+    """An eighth of the table's columns a block; as many blocks a pass as
+    slots, or as keep a pass's float32 scores and weighted rows where the
+    chip holds them (the widths the two cells were measured at)."""
+    S, P, pages = shape
+    pool = jax.ShapeDtypeStruct(paged_ops.latent_pool_shape(pages, 64, 576),
+                                jnp.bfloat16)
+    assert paged_ops.latent_pass_shape(S, P, pool, 2 * rows * 64) == want
 
 
 @pytest.mark.parametrize("offs", [0, 1, 2, 3])
@@ -459,10 +527,18 @@ def test_spans_and_the_step_rows_counters(params, _clean_ring, slow_device):
     landed = [f for f in steps if "latent_positions" in f]
     assert len(landed) == 5 and len([f for f in steps if f["active"]]) == 5
     assert "zero_picks" not in steps[0] and steps[0]["admitted"] == 2
+    block = 4 * paged_ops.latent_pass_shape(
+        3, 24, eng.pools_k[0], 2 * CFG.n_heads)[0]
+    assert block == 12          # an eighth of the table's 24 pages of 4
     for k, f in enumerate(landed):
         assert f["landed"] == 1 and f["moe_rows"] == 2
         # positions 45 + k and 9 + k, and the row the step wrote
         assert f["latent_positions"] == 45 + 9 + 2 * (k + 1)
+        # what a sublayer's read gathered: each slot's blocks whole, one
+        # block for the idle third slot
+        assert f["latent_positions_read"] == block * (
+            -(-(45 + k + 1) // block) + -(-(9 + k + 1) // block) + 1)
+        assert f["latent_positions"] <= f["latent_positions_read"]
         pairs = 2 * CFG.top_k * CFG.n_layers
         assert 0 <= f["zero_picks"] <= pairs
         assert 0 <= f["experts_hit"] <= CFG.n_layers * CFG.experts_held

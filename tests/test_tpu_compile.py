@@ -349,7 +349,7 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip,
     one: a described device is not ``jax.devices()``'s)."""
     from perfbench.aot_longcat import expanded_shapes
     from ray_tpu.models import longcat_flash as lc
-    from ray_tpu.models.paged_ops import latent_pool_shape
+    from ray_tpu.models.paged_ops import latent_pass_shape, latent_pool_shape
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = lc.LongcatFlashConfig(vocab_size=16384, n_layers=1,
@@ -378,7 +378,16 @@ def test_longcat_step_and_prefill_chunk_longcat_flash_widths(one_chip,
     assert expanded_shapes(f"bf16[{S},{max_len},64,128]", S * max_len, cfg)
     # the held experts' products are grouped by expert (PR 34): three a layer
     assert _grouped_products(text) == (0, 3 * cfg.n_layers)
-    assert m.temp_size_in_bytes < 0.6e9
+    # the absorbed read goes pass by pass over each slot's own blocks: the
+    # gathered pages of a pass, and no array of a slot's whole table (eight
+    # slots' tables at once were ``[2304, 32, 1152]`` until PR 61)
+    block, items = latent_pass_shape(S, max_len // page, pools[0],
+                                     2 * cfg.n_heads)
+    assert f"bf16[{items * block},32,1152]" in text
+    assert f"[{8 * max_len // page},32,1152]" not in text
+    assert f"[{S},{max_len // 2},1152]" not in text
+    assert f",{cfg.n_heads},{max_len // 2}]" not in text     # nor of scores
+    assert m.temp_size_in_bytes < 0.15e9     # 0.238 until PR 61, 0.086 now
     carry = _on(one_chip, jax.eval_shape(
         lambda: lc.prefill_carry(cfg, max_len)))
     compiled = lc._longcat_prefill_chunk.lower(

@@ -30,7 +30,7 @@ def test_deepseek_step_and_prefill_chunk_gigachat_widths(one_chip,
     from perfbench.aot_longcat import expanded_shapes
     from ray_tpu.models import deepseek_v3 as ds
     from ray_tpu.models.longcat_flash import prefill_carry
-    from ray_tpu.models.paged_ops import latent_pool_shape
+    from ray_tpu.models.paged_ops import latent_pass_shape, latent_pool_shape
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = ds.DeepseekV3Config(vocab_size=16032, n_layers=2, n_dense=1,
@@ -57,9 +57,16 @@ def test_deepseek_step_and_prefill_chunk_gigachat_widths(one_chip,
                 if " copy(" in ln and f"= {pool}" in ln]
     assert expanded_shapes(text, S * max_len, cfg) == []
     assert _grouped_products(text) == (0, 0)
-    # a block of slots' gathered pages, never all 64 slots'
+    # the absorbed read goes pass by pass over each slot's own blocks: the
+    # gathered pages of a pass, and no array of a slot's whole table (eight
+    # slots' tables at once were ``[1792, 32, 1152]`` until PR 61)
+    block, items = latent_pass_shape(S, max_len // page, pools[0],
+                                     2 * 2 * cfg.n_heads)
+    assert f"bf16[{items * block},32,1152]" in text
+    assert f"[{8 * max_len // page},32,1152]" not in text
     assert f"bf16[{S},{max_len // 2},1152]" not in text
-    assert m.temp_size_in_bytes < 0.4e9
+    assert f",{cfg.n_heads},{max_len // 2}]" not in text     # nor of scores
+    assert m.temp_size_in_bytes < 0.1e9     # 0.196 until PR 61, 0.053 now
     carry = _on(one_chip, jax.eval_shape(lambda: prefill_carry(cfg, max_len)))
     compiled = ds._deepseek_prefill_chunk.lower(
         params, i32((cfg.prefill_chunk,)), i32(()), i32(()), carry,
